@@ -10,14 +10,15 @@ healthy rate — losing one of dp replicas should cost at most its
 proportional share, because `pack_batch_dp` detours the drained shard's
 rows instead of stalling on them.
 
-Without dp real chips the bench forces 8 VIRTUAL CPU devices (the
-tests/conftest.py trick): every virtual device shares the same host
-cores, so a drained replica frees compute for the survivors and the
-ratio is structural, not comparative — `cpu_emulated: true` flags that,
-and `target_met` is only judged on real chips (same convention as
-multichip_bench.py).
+The devices are whatever jax finds; with fewer than 8 the mesh raises and
+the bench fails.  Only when the CALLER set `JAX_PLATFORMS=cpu` does it ask
+XLA for 8 virtual CPU devices (the tests/conftest.py trick, before jax
+starts): every virtual device shares the same host cores, so a drained
+replica frees compute for the survivors and the ratio is structural, not
+comparative — the output says `cpu_emulated: true`, and `target_met` is
+only judged on real chips (same convention as multichip_bench.py).
 
-Prints ONE JSON line.
+Prints ONE JSON line, stamped with platform, device_kind and device count.
 """
 
 from __future__ import annotations
@@ -34,24 +35,13 @@ DP, TP = 4, 2
 N_DOCS = 256
 DRAIN_REPLICA = 2
 
+# emulation is the caller's decision (JAX_PLATFORMS=cpu), never a fallback
+CPU_EMULATED = os.environ.get("JAX_PLATFORMS") == "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _flags:
+if CPU_EMULATED and "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + f" --xla_force_host_platform_device_count={N_DEVICES}"
     ).strip()
-
-
-def _ensure_devices() -> bool:
-    import jax
-
-    if len(jax.devices()) >= N_DEVICES and (
-        jax.devices()[0].platform != "cpu"
-    ):
-        return False
-    from __graft_entry__ import _force_virtual_cpu_devices
-
-    _force_virtual_cpu_devices(N_DEVICES)
-    return True
 
 
 def _corpus() -> list[str]:
@@ -79,15 +69,19 @@ def _ingest_once(enc, texts, capacity: int):
 
 
 def main() -> None:
-    cpu_emulated = _ensure_devices()
     os.environ["PATHWAY_DEVICE_PIPELINE"] = "1"
     os.environ.setdefault("PATHWAY_DEVICE_PROBE", "0")
 
+    import jax
+
     from pathway_tpu.analysis.mesh import MeshSpec
-    from pathway_tpu.internals import mesh_backend
+    from pathway_tpu.internals import compile_cache, mesh_backend
     from pathway_tpu.internals.device_pipeline import _PIPELINES
     from pathway_tpu.models.minilm import SentenceEncoder
     from pathway_tpu.models.transformer import TransformerConfig
+
+    compile_cache.configure()
+    devices = jax.devices()
 
     config = TransformerConfig(
         vocab_size=30522, hidden=128, layers=3, heads=4, mlp_dim=512,
@@ -100,11 +94,6 @@ def main() -> None:
 
     backend = mesh_backend.activate(MeshSpec.parse(f"dp={DP},tp={TP}"))
     try:
-        if backend is None:
-            raise RuntimeError(
-                f"mesh dp={DP},tp={TP} failed to activate on "
-                f"{N_DEVICES} devices"
-            )
         # warmup pays the packed-slab XLA compiles for both shapes
         _ingest_once(enc, texts[: N_DOCS // 4], capacity)
         ref, healthy_s = _ingest_once(enc, texts, capacity)
@@ -143,7 +132,10 @@ def main() -> None:
                 "n_devices": N_DEVICES,
                 "dp": DP,
                 "tp": TP,
-                "cpu_emulated": cpu_emulated,
+                "cpu_emulated": CPU_EMULATED,
+                "platform": devices[0].platform,
+                "device_kind": devices[0].device_kind,
+                "device_count": len(devices),
                 "n_docs": N_DOCS,
                 "drained_replica": DRAIN_REPLICA,
                 "healthy_docs_per_sec": round(healthy_rate, 1),
@@ -152,7 +144,7 @@ def main() -> None:
                 "target_ratio": round(target_ratio, 3),
                 "target_met": (
                     None
-                    if cpu_emulated
+                    if CPU_EMULATED
                     else degraded_rate / healthy_rate >= target_ratio
                 ),
                 "drain_latency_s": round(drain_s, 4),

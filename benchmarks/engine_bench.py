@@ -655,10 +655,7 @@ def bench_pipeline(n_docs=4096, chunk=256):
     it saves, so the packed arm can lose here even though on a real
     device (hidden 384+, projections dominate) padding waste is the
     term that matters — the no-pack arm is the CPU-meaningful number."""
-    import numpy as _np
-
-    import jax.numpy as _jnp
-
+    from pathway_tpu.internals import compile_cache
     from pathway_tpu.models.minilm import SentenceEncoder
     from pathway_tpu.models.transformer import TransformerConfig
     from pathway_tpu.stdlib.indexing.nearest_neighbors import (
@@ -676,14 +673,13 @@ def bench_pipeline(n_docs=4096, chunk=256):
     )
     encoder = SentenceEncoder("bench-tiny", config=tiny, max_len=64)
 
+    compile_cache.configure()
+
     def sync(impl):
-        # drain the pipeline (if any), then the scalar-readback quiesce
-        # that covers the classic arm's in-flight scatter chain too
+        # drain the pipeline (if any), then the quiesce that covers the
+        # classic arm's in-flight scatter chain too
         impl.drain()
-        impl.knn._flush()
-        _np.asarray(
-            _jnp.sum(impl.knn._buffer[:1, :4].astype(_jnp.float32))
-        )
+        impl._quiesce_device()
 
     stats = {}
 

@@ -598,7 +598,9 @@ def main() -> None:
         # child: one configuration, one JSON line
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
         os.environ.setdefault("PATHWAY_DEVICE_PROBE", "0")
-        from pathway_tpu.internals import qtrace
+        from pathway_tpu.internals import compile_cache, qtrace
+
+        compile_cache.configure()
 
         if not qtrace.ENABLED:
             print(json.dumps(

@@ -65,13 +65,13 @@ def cost_pass(view: Any, result: AnalysisResult) -> None:
         result.add(make_diag(
             "PWT802",
             "the cost ledger is enabled but the attached device "
-            f"('{costmodel.device_name()}') has no peak-FLOPs entry in "
+            f"('{costmodel.device_kind()}') has no peak-FLOPs entry in "
             "the chip table (internals/costmodel.py): attribution works, "
             "but every derived efficiency gauge "
-            "(pathway_cost_efficiency_pct, MFU-style ratios) will report "
-            "None; add the chip to DEVICE_PEAK_BF16_FLOPS or expect "
-            "absent efficiency series",
+            "(pathway_cost_efficiency_pct, MFU-style ratios) reports None "
+            "on the CPU backend and raises UnknownDeviceError on an "
+            "unlisted accelerator; add the chip to DEVICE_PEAK_BF16_FLOPS",
             trace=_trace_or_none(table),
             operator=view.op_label(table),
-            device=costmodel.device_name(),
+            device=costmodel.device_kind(),
         ))

@@ -25,9 +25,21 @@ def _spawn(args) -> int:
         program = program[1:]
     if program and program[0].endswith(".py"):
         program = [sys.executable, *program]
+    from pathway_tpu.internals.supervisor import worker_chip_env
+
+    try:
+        # on a TPU host worker i gets chip i; more workers than chips
+        # fails here, before any process starts
+        chip_envs = [
+            worker_chip_env(pid, args.processes, env_base)
+            for pid in range(args.processes)
+        ]
+    except ValueError as exc:
+        print(f"pathway spawn: {exc}", file=sys.stderr)
+        return 2
     procs = []
-    for pid in range(args.processes):
-        env = dict(env_base)
+    for pid, chip_env in enumerate(chip_envs):
+        env = dict(env_base, **chip_env)
         env["PATHWAY_PROCESS_ID"] = str(pid)
         procs.append(subprocess.Popen(program, env=env))
     code = 0

@@ -1,11 +1,10 @@
 """Mesh execution backend: `pw.run(mesh=...)` as a real device mesh.
 
-Until PR 8 the mesh argument only armed the PWT4xx compatibility lints.
-This module promotes it to a first-class backend: `activate()` builds a
-`jax.sharding.Mesh` over the process's devices (real chips, or
-CPU-emulated ones under `XLA_FLAGS=--xla_force_host_platform_device_count`
-for tests) and publishes it process-wide, so the framework ingest path
-picks it up at engine-build time:
+`activate()` builds a `jax.sharding.Mesh` over the process's devices (real
+chips, or CPU-emulated ones under
+`XLA_FLAGS=--xla_force_host_platform_device_count` for tests) and
+publishes it process-wide, so the framework ingest path picks it up at
+engine-build time:
 
   * `stdlib/indexing` index impls adopt the mesh for their
     `DeviceKnnIndex` row shard (search = per-shard top-k + all-gather
@@ -26,9 +25,8 @@ from an advisory lint into a load-bearing contract.
 
 Degradation rules (documented in ARCHITECTURE.md "Mesh backend"):
 
-  * fewer devices than the spec asks for -> the backend stays inactive
-    (warning log) and the mesh remains lint-only, exactly the pre-PR
-    behavior;
+  * fewer devices than the spec asks for -> `activate()` raises: a run
+    that asked for four chips must not quietly execute on one;
   * a non-power-of-two dp axis cannot shard the bucketed batch/index
     axes -> ingest stays single-device (PWT402 already flags embedder
     graphs in this state);
@@ -375,10 +373,9 @@ _BACKEND: Optional[MeshBackend] = None
 _lock = threading.Lock()
 
 
-def activate(spec) -> Optional[MeshBackend]:
-    """Build and publish the mesh for `spec` (a MeshSpec). Returns None —
-    leaving the mesh a pure lint target, the pre-PR behavior — when the
-    process doesn't have enough devices."""
+def activate(spec) -> MeshBackend:
+    """Build and publish the mesh for `spec` (a MeshSpec).  Raises
+    ValueError when the process has fewer devices than the mesh needs."""
     global _BACKEND
     import jax
     from jax.sharding import Mesh
@@ -387,14 +384,12 @@ def activate(spec) -> Optional[MeshBackend]:
         need = spec.devices()
         devices = jax.devices()
         if need > len(devices):
-            logger.warning(
-                "mesh %s needs %d devices but only %d are attached; "
-                "running single-device (the mesh still arms the PWT4xx "
-                "analysis lints)",
-                spec.describe(), need, len(devices),
-            )
             _BACKEND = None
-            return None
+            raise ValueError(
+                f"mesh {spec.describe()} needs {need} devices but only "
+                f"{len(devices)} are attached "
+                f"({devices[0].platform}: {devices[0].device_kind})"
+            )
         shape = tuple(count for _, count in spec.axes)
         names = tuple(name for name, _ in spec.axes)
         grid = np.asarray(devices[:need], dtype=object).reshape(shape)

@@ -256,11 +256,10 @@ def run(
         health.controller().on_run_start()
 
     # Build the mesh execution backend BEFORE the graph builds: index
-    # impls adopt it at build time (stdlib/indexing).  With too few
-    # devices the backend stays inactive and the mesh remains the pure
-    # lint target it was pre-backend.  Deactivation is in the finally
-    # below (and at the end of _run_threaded) so one run's mesh never
-    # leaks into the next.
+    # impls adopt it at build time (stdlib/indexing).  Too few devices
+    # for the mesh raises here, before any worker starts.  Deactivation
+    # is in the finally below (and at the end of _run_threaded) so one
+    # run's mesh never leaks into the next.
     if mesh is not None:
         from pathway_tpu.internals import mesh_backend
 

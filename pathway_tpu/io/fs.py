@@ -98,6 +98,41 @@ class _FsSubject(ConnectorSubjectBase):
         # schema defaults fill columns the payload does not carry
         self._defaults = schema_defaults(schema)
         self._seen: Dict[str, float] = {}
+        # streaming mode: what each file emitted, as (names, rows) chunks
+        # holding the very objects handed to the sink (names is None for
+        # row dicts, the column order for values tuples), so a file that
+        # disappears can be retracted row by row
+        self._file_rows: Dict[str, list] = {}
+        self._recording: Optional[list] = None
+
+    # the three emit entry points, recorded per file while streaming
+    def next(self, **kwargs) -> None:
+        if self._recording is not None:
+            self._recording.append((None, [kwargs]))
+        super().next(**kwargs)
+
+    def next_batch(self, rows: List[dict]) -> None:
+        if self._recording is not None:
+            self._recording.append((None, rows))
+        super().next_batch(rows)
+
+    def next_batch_tuples(self, values_list: List[tuple], names: List[str]) -> None:
+        if self._recording is not None:
+            self._recording.append((names, values_list))
+        super().next_batch_tuples(values_list, names)
+
+    def _retract_deleted(self, present: List[str]) -> bool:
+        """Retract every row of files seen earlier that are gone now
+        (reference: the posix-like scanner's delete detection)."""
+        gone = set(self._seen).difference(present)
+        for f in gone:
+            del self._seen[f]
+            for names, rows in self._file_rows.pop(f, ()):
+                for row in rows:
+                    self._remove(
+                        row if names is None else dict(zip(names, row))
+                    )
+        return bool(gone)
 
     def _owns(self, f: str) -> bool:
         """Partitioned reads: files are divided among workers by a stable
@@ -318,9 +353,13 @@ class _FsSubject(ConnectorSubjectBase):
             self.next_batch(rows)
 
     def run(self) -> None:
+        streaming = self.mode != "static"
         while True:
-            emitted_any = False
-            for f in self._list_files():
+            files = self._list_files()
+            emitted_any = streaming and self._retract_deleted(files)
+            if emitted_any:
+                self.commit()
+            for f in files:
                 try:
                     mtime = os.stat(f).st_mtime
                 except OSError:
@@ -328,7 +367,12 @@ class _FsSubject(ConnectorSubjectBase):
                 if self._seen.get(f) == mtime:
                     continue
                 self._seen[f] = mtime
-                self._emit_file(f)
+                if streaming:
+                    self._recording = self._file_rows.setdefault(f, [])
+                try:
+                    self._emit_file(f)
+                finally:
+                    self._recording = None
                 # commit per file: downstream batches pipeline host-side
                 # parsing of file N+1 against the (async-dispatched) device
                 # work of file N; as a barrier, the batch boundary is

@@ -105,13 +105,8 @@ def sequence_parallel_forward(params, config: TransformerConfig, ids, mask,
     `axis_name` of `mesh`. ids, mask: [B, L] with L divisible by the axis
     size. Returns logits [B, L, V] (pooling='none') or pooled [B, H]."""
     import jax
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map  # jax >= 0.8
-        _rep_kwargs = {"check_vma": False}
-    except ImportError:  # pragma: no cover — older jax
-        from jax.experimental.shard_map import shard_map
-        _rep_kwargs = {"check_rep": False}
 
     assert attn in ("ring", "ulysses"), attn
     l = ids.shape[1]
@@ -132,6 +127,6 @@ def sequence_parallel_forward(params, config: TransformerConfig, ids, mask,
         mesh=mesh,
         in_specs=(P(), P(None, axis_name), P(None, axis_name)),
         out_specs=out_spec,
-        **_rep_kwargs,
+        check_vma=False,
     )
     return jax.jit(fn)(params, ids, mask)

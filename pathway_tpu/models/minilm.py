@@ -112,11 +112,15 @@ class SentenceEncoder:
 
             axis = "dp" if "dp" in self.mesh.axis_names else self.mesh.axis_names[0]
             n_dev = self.mesh.shape[axis]
-            if ids.shape[0] % n_dev == 0:
-                sharding = NamedSharding(self.mesh, P(axis, None))
-                ids = jax.device_put(ids, sharding)
-                mask = jax.device_put(mask, sharding)
-        return (self.lm(ids, mask), len(texts))
+            if ids.shape[0] % n_dev:
+                raise ValueError(
+                    f"encoder batch of {ids.shape[0]} rows does not divide "
+                    f"over the {n_dev} devices of mesh axis {axis!r}"
+                )
+            sharding = NamedSharding(self.mesh, P(axis, None))
+            ids = jax.device_put(ids, sharding)
+            mask = jax.device_put(mask, sharding)
+        return (self.lm(ids, mask, mesh=self.mesh), len(texts))
 
     def encode_await(self, handle) -> np.ndarray:
         """Force a handle from encode_submit: one host transfer of the

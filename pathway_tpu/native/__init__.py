@@ -4,18 +4,34 @@ toolchain and loaded with ctypes.
 The reference keeps its hot runtime in Rust (src/engine, src/connectors);
 here the compute hot path is XLA, and the native layer covers the host-side
 feeding work that would otherwise bottleneck the chip — currently the batch
-tokenizer. Falls back to the pure-python implementations when no compiler
-is available.
+tokenizer. Falls back to the pure-python implementations when the build
+fails, and says so once in the log with the compiler's own words.
 """
 
 from __future__ import annotations
 
 import ctypes
 import hashlib
+import logging
 import os
 import subprocess
-import tempfile
 from typing import Optional
+
+logger = logging.getLogger(__name__)
+
+
+def _log_build_failure(what: str, exc: BaseException) -> None:
+    stderr = getattr(exc, "stderr", None)
+    if isinstance(stderr, bytes):
+        stderr = stderr.decode(errors="replace")
+    logger.warning(
+        "native %s unavailable, using the pure-python path: %s: %s%s",
+        what,
+        type(exc).__name__,
+        exc,
+        f"\n{stderr[-2000:]}" if stderr else "",
+    )
+
 
 _lib = None
 _build_failed = False
@@ -82,8 +98,9 @@ def load() -> Optional[ctypes.CDLL]:
         lib.count_tokens.argtypes = [ctypes.c_char_p, ctypes.c_int32]
         _lib = lib
         return lib
-    except Exception:  # noqa: BLE001 — fall back to python
+    except Exception as exc:  # noqa: BLE001 — fall back to python
         _build_failed = True
+        _log_build_failure("tokenizer", exc)
         return None
 
 
@@ -200,6 +217,7 @@ def load_wire_ext():
         )
         _wire_ext = mod
         return mod
-    except Exception:  # noqa: BLE001 — fall back to the python codec
+    except Exception as exc:  # noqa: BLE001 — fall back to the python codec
         _wire_ext_failed = True
+        _log_build_failure("wire codec", exc)
         return None

@@ -43,7 +43,7 @@ def ring_attention(q, k, v, kv_mask, *, axis_name: str = "sp",
     b, h, c, d = q.shape
     if sm_scale is None:
         sm_scale = 1.0 / float(np.sqrt(d))
-    sp = _static_axis_size(axis_name)
+    sp = int(lax.axis_size(axis_name))  # static under shard_map
     my = lax.axis_index(axis_name)
     rot = [(i, (i + 1) % sp) for i in range(sp)]
 
@@ -104,32 +104,16 @@ def ring_attention(q, k, v, kv_mask, *, axis_name: str = "sp",
     l0 = jnp.zeros((b, h, c, 1), dtype=jnp.float32)
     acc0 = jnp.zeros((b, h, c, d), dtype=jnp.float32)
     # constants are unvarying on the sp axis; mark them device-varying so
-    # both lax.cond branches agree on varying-axis types (pcast only
-    # exists under the vma system — older jax runs check_rep=False and
-    # needs no cast)
-    pcast = getattr(lax, "pcast", None)
-    if pcast is not None:
-        m0, l0, acc0 = (
-            pcast(x, axis_name, to="varying") for x in (m0, l0, acc0)
-        )
+    # both lax.cond branches agree on varying-axis types
+    m0, l0, acc0 = (
+        lax.pcast(x, axis_name, to="varying") for x in (m0, l0, acc0)
+    )
     carry = (m0, l0, acc0, k, v, kv_mask)
     for s in range(sp):  # sp is static under shard_map; unroll the ring
         carry = step(s, carry)
     m, l, acc, _, _, _ = carry
     l = jnp.where(l == 0.0, 1.0, l)
     return (acc / l).astype(q.dtype)
-
-
-def _static_axis_size(axis_name: str) -> int:
-    """Axis size is static under shard_map — read it from the trace env."""
-    import jax
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return int(lax.axis_size(axis_name))
-    # older jax: axis_frame returns the size itself (or a frame with one)
-    frame = jax.core.axis_frame(axis_name)
-    return int(getattr(frame, "size", frame))
 
 
 def ulysses_attention(q, k, v, kv_mask, *, axis_name: str = "sp",
@@ -146,7 +130,7 @@ def ulysses_attention(q, k, v, kv_mask, *, axis_name: str = "sp",
     import jax.numpy as jnp
     from jax import lax
 
-    sp = _static_axis_size(axis_name)
+    sp = int(lax.axis_size(axis_name))  # static under shard_map
     b, h, c, d = q.shape
     if h % sp != 0:
         raise ValueError(f"heads {h} not divisible by sp axis {sp}")

@@ -50,14 +50,18 @@ class BaseRestServer:
         terminate_on_error: bool = True,
         **kwargs,
     ):
-        """reference: servers.py run — pw.run under the hood."""
+        """reference: servers.py run — pw.run under the hood.  Every
+        other keyword (`with_http_server`, `mesh`, `slo`, ...) goes to
+        `pw.run` unchanged, on the calling thread or the server thread."""
         from pathway_tpu.internals.runner import run as pw_run
 
         if threaded:
-            t = threading.Thread(target=pw_run, daemon=True, name="pw-server")
+            t = threading.Thread(
+                target=pw_run, kwargs=kwargs, daemon=True, name="pw-server"
+            )
             t.start()
             return t
-        pw_run()
+        pw_run(**kwargs)
         return None
 
 

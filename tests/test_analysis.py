@@ -509,11 +509,11 @@ def test_pwt802_ledger_without_capacity_entry(monkeypatch):
     assert len(fs) == 1
     assert "pathway_cost_efficiency_pct" in fs[0].message
     # a known chip is silent
-    monkeypatch.setattr(costmodel, "_cached_name", "TPU v5e")
+    monkeypatch.setattr(costmodel, "_cached_kind", "TPU v5 lite")
     codes = {f.code for f in analyze(G, workers=1).findings}
     assert "PWT802" not in codes
     # ledger disabled: the efficiency gap is moot
-    monkeypatch.setattr(costmodel, "_cached_name", "unknown")
+    monkeypatch.setattr(costmodel, "_cached_kind", "unknown")
     monkeypatch.setattr(costledger, "ENABLED", False)
     codes = {f.code for f in analyze(G, workers=1).findings}
     assert "PWT802" not in codes

@@ -273,12 +273,16 @@ def test_pipeline_error_parks_and_replays():
         pipe.close()
 
 
-def test_impl_pipeline_failure_replays_synchronously():
+def test_impl_pipeline_failure_replays_synchronously(caplog):
     """An impl-level dispatch failure downgrades to the classic path and
-    replays the parked batches exactly once — every doc lands."""
+    replays the parked batches exactly once — every doc lands — and the
+    downgrade is visible: counted, and logged with the cause's traceback."""
+    from pathway_tpu.internals import device_pipeline
     from pathway_tpu.stdlib.indexing.nearest_neighbors import (
         _FusedKnnIndexImpl,
     )
+
+    fallbacks_before = device_pipeline.pipeline_status()["fallbacks"]
 
     impl = _FusedKnnIndexImpl(_encoder("fallback-tiny"), "cos", 32)
     texts = [f"delta doc{i} echo foxtrot" for i in range(12)]
@@ -293,9 +297,18 @@ def test_impl_pipeline_failure_replays_synchronously():
 
     impl.fused.dispatch_batch = flaky
     with _env(PATHWAY_DEVICE_PIPELINE="1", PATHWAY_INGEST_CHUNK="4"):
-        impl.add_many(range(12), texts, [None] * 12)
-        impl.drain()
+        with caplog.at_level("ERROR"):
+            impl.add_many(range(12), texts, [None] * 12)
+            impl.drain()
         assert impl._pipeline_broken
+        status = device_pipeline.pipeline_status()
+        assert status["fallbacks"] == fallbacks_before + 1
+        (record,) = [
+            r for r in caplog.records if "device pipeline disabled" in r.message
+        ]
+        assert record.exc_info[0] is RuntimeError
+        assert "injected dispatch failure" in caplog.text
+        assert "in flaky" in caplog.text  # the traceback, not one line
         assert len(impl.knn) == 12
         rows = impl.search_many([texts[5]], [1], [None])
         assert rows[0][0][0] == 5

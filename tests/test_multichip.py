@@ -125,12 +125,18 @@ def test_backend_activates_on_enough_devices():
     assert mesh_backend.active_backend() is None
 
 
-def test_backend_inactive_when_too_few_devices():
-    # degradation rule 1: not enough devices -> lint-only (None), never
-    # a crash
-    with _activated("dp=64,tp=2") as backend:
-        assert backend is None
-        assert mesh_backend.active_backend() is None
+def test_activate_raises_when_too_few_devices():
+    # a mesh that needs more devices than are attached is an error, not a
+    # single-device run under a warning
+    with pytest.raises(ValueError, match="needs 128 devices but only"):
+        mesh_backend.activate(MeshSpec.parse("dp=64,tp=2"))
+    assert mesh_backend.active_backend() is None
+    # ... and pw.run(mesh=...) raises it before anything executes
+    t = pw.debug.table_from_rows(pw.schema_from_types(k=str), [("a",)])
+    pw.io.subscribe(t, on_change=lambda *a, **k: None)
+    with pytest.raises(ValueError, match="needs 128 devices"):
+        pw.run(mesh="dp=64,tp=2")
+    assert mesh_backend.active_backend() is None
 
 
 def test_backend_non_pow2_dp_keeps_single_device_ingest():

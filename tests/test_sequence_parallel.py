@@ -6,14 +6,8 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map  # jax >= 0.8
-    _REP_KWARGS = {"check_vma": False}
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
-    _REP_KWARGS = {"check_rep": False}
 
 
 def _mesh_sp():
@@ -48,7 +42,7 @@ def test_sp_attention_exact(causal, strategy):
         mesh=mesh,
         in_specs=(P(None, None, "sp", None),) * 3 + (P(None, "sp"),),
         out_specs=P(None, None, "sp", None),
-        **_REP_KWARGS,
+        check_vma=False,
     )
     out = jax.jit(sharded)(q, k, v, mask)
     ref = _reference_attention(q, k, v, mask, 1.0 / np.sqrt(d), causal)

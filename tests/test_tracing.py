@@ -473,9 +473,9 @@ def test_device_monitor_healthy_and_down():
     assert samples["pathway_device_rtt_ms"][0][1] == 1.5
     assert samples["pathway_device_healthy"][0][1] == 1.0
 
-    mon.probe = lambda timeout_s: (None, "tunnel down")
+    mon.probe = lambda timeout_s: (None, "device lost")
     mon.probe_once()
-    assert not mon.last["healthy"] and mon.last["error"] == "tunnel down"
+    assert not mon.last["healthy"] and mon.last["error"] == "device lost"
     samples = check_exposition(render_registries([mon.metrics]))
     assert samples["pathway_device_healthy"][0][1] == 0.0
     # rtt gauge goes absent rather than lying with a stale number

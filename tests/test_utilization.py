@@ -83,12 +83,40 @@ def test_batch_useful_flops_uses_average_real_seq():
     assert costmodel.encoder_useful_flops(0, 4) == 0.0
 
 
-def test_unknown_device_peak_is_zero_and_mfu_none():
-    assert costmodel.device_peak_flops("cpu:0 (TFRT)") == 0.0
+def test_peaks_resolve_by_device_kind():
+    # the CPU backend has no peak: 0.0 -> MFU None, tier-1 unchanged
+    assert costmodel.device_kind() == "cpu"
+    assert costmodel.device_peak_flops() == 0.0
+    assert costmodel.device_peak_flops("cpu") == 0.0
+    assert not costmodel.device_capacity_known()
     assert costmodel.mfu_pct(1e12, peak=0.0) is None
     assert costmodel.mfu_pct(197e12 / 2, peak=197e12) == pytest.approx(50.0)
-    assert costmodel.device_peak_flops("TPU v5 lite core") == 197e12
-    assert costmodel.device_hbm_bytes_per_sec("TPU v5p chip") == 2765e9
+    # the local backend's device_kind strings, exactly
+    assert costmodel.device_peak_flops("TPU v5 lite") == 197e12
+    assert costmodel.device_hbm_bytes_per_sec("TPU v5 lite") == 819e9
+    assert costmodel.device_hbm_bytes("TPU v5 lite") == 16e9
+    assert costmodel.device_hbm_bytes_per_sec("TPU v5") == 2765e9
+    assert costmodel.device_capacity_known("TPU v5 lite")
+    assert set(costmodel.DEVICE_PEAK_BF16_FLOPS) == set(
+        costmodel.DEVICE_HBM_BYTES_PER_SEC
+    ) == set(costmodel.DEVICE_HBM_BYTES)
+
+
+def test_unknown_accelerator_is_an_error_on_the_measuring_path(monkeypatch):
+    # str(jax.devices()[0]) — the id form — is not a device_kind
+    for name in ("TPU_0(process=0,(0,0,0,0))", "TPU v5 lite0", "NVIDIA H100"):
+        with pytest.raises(costmodel.UnknownDeviceError, match="device_kind"):
+            costmodel.device_peak_flops(name)
+        assert not costmodel.device_capacity_known(name)
+    # an attached accelerator the table does not list fails every consumer
+    # that would print MFU or efficiency, instead of turning them into None
+    monkeypatch.setattr(costmodel, "_cached_kind", "TPU v9 hypothetical")
+    with pytest.raises(costmodel.UnknownDeviceError):
+        costmodel.mfu_pct(1e12)
+    with pytest.raises(costmodel.UnknownDeviceError):
+        utilization.tracker().snapshot()
+    with pytest.raises(costmodel.UnknownDeviceError):
+        costmodel.device_hbm_bytes()
 
 
 # ---------------------------------------------------------------------------
