@@ -35,8 +35,10 @@ set-up facts of this run, not benchmark numbers.
                                     tiny corpus, 2 layers, kernels in
                                     interpret mode, "platform": "cpu"
 
-No phase's failure is caught: the first one ends the run non-zero.  The
-last line of stdout is one JSON object, {"ok": true, "device": {...}, ...}.
+No phase's failure is caught: the first one ends the run non-zero.  Stdout
+is two JSON lines: the report (stamp, each phase's pass, wall and compile
+seconds), then the verdict, which has exactly these keys and is the last
+line: {"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}.
 """
 
 from __future__ import annotations
@@ -811,31 +813,34 @@ def main() -> int:
     ]
     if args.chips > 1:
         phases.append(("too_many_chips", phase_too_many_chips))
-    report = {}
+    phase_reports = {}
     t_run = time.perf_counter()
     for name, fn in phases:
         clock.phase = name
         log(f"phase {name} ...")
         t0 = time.perf_counter()
         facts = fn(ctx)
-        report[name] = {
+        phase_reports[name] = {
             "pass": True,
             "wall_s": round(time.perf_counter() - t0, 2),
             "compile_s": round(clock.seconds.get(name, 0.0), 2),
             "compiles": clock.compiles.get(name, 0),
             **facts,
         }
-        log(f"phase {name} passed: {json.dumps(report[name])}")
+        log(f"phase {name} passed: {json.dumps(phase_reports[name])}")
 
     stamp = ctx["stamp"]
     cache_files = sum(len(fs) for _, _, fs in os.walk(ctx["cache_dir"]))
-    result = {
+    verdict = {
         "ok": True,
         "device": {
             "platform": stamp["platform"],
             "kind": stamp["device_kind"],
             "count": stamp["device_count"],
         },
+    }
+    report = {
+        **verdict,
         "chips_used": args.chips,
         "note": "set-up facts of one run, not benchmark numbers",
         "stamp": stamp,
@@ -843,11 +848,13 @@ def main() -> int:
         "compile_s_total": round(sum(clock.seconds.values()), 2),
         "compile_cache_hits": clock.cache_hits,
         "compile_cache_files": cache_files,
-        "phases": report,
+        "phases": phase_reports,
     }
     with open(os.path.join(out, f"result_chips{args.chips}.json"), "w") as f:
-        json.dump(result, f, indent=1)
-    print(json.dumps(result), flush=True)
+        json.dump(report, f, indent=1)
+    print(json.dumps(report), flush=True)
+    # the last line of stdout is the verdict alone, with exactly these keys
+    print(json.dumps(verdict), flush=True)
     return 0
 
 

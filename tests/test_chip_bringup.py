@@ -379,9 +379,14 @@ def test_chip_smoke_dry_run_passes_at_tiny_size(tmp_path):
         timeout=600,
     )
     assert proc.returncode == 0, proc.stderr[-4000:]
-    result = json.loads(proc.stdout.strip().splitlines()[-1])
+    report_line, verdict_line = proc.stdout.strip().splitlines()
+    # the last line is the verdict alone: exactly these keys, nothing else
+    assert json.loads(verdict_line) == {
+        "ok": True,
+        "device": {"platform": "cpu", "kind": "cpu", "count": 1},
+    }
+    result = json.loads(report_line)
     assert result["ok"] is True
-    assert result["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
     assert result["stamp"]["dry_run"] is True
     assert result["stamp"]["compile_cache_dir"] == os.path.join(
         REPO, ".jax_cache"
