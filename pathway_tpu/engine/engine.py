@@ -31,6 +31,7 @@ from pathway_tpu.engine.value import ERROR, Error, Pointer
 from pathway_tpu.internals import provenance as _provenance
 from pathway_tpu.internals import qtrace as _qtrace
 from pathway_tpu.internals import sanitizer as _sanitizer
+from pathway_tpu.internals.tracing import install_gc_hook, span as _span
 
 
 class EngineError(Exception):
@@ -222,6 +223,11 @@ class Engine:
         self._scheduled_times: set[int] = set()
         self._gc_ticks = 0
         self._gc_disabled = False
+        # host.gc spans: _gc_pulse's collections and automatic ones
+        install_gc_hook()
+        # one span object for every tick of this engine (process_time is
+        # not re-entered), so a tick allocates none
+        self._tick_span = _span("engine.tick")
         # per-node wall-time dump destination (the always-on metrics
         # registry is the single instrumented path; this env var only
         # selects the JSON-lines dump of it at finish())
@@ -460,7 +466,7 @@ class Engine:
         events for nodes that did work.  One perf_counter call per node —
         a node's interval ends where the next one starts, so bookkeeping
         (~0.3us) rides on the successor's bucket rather than doubling the
-        timer cost."""
+        timer cost.  The tick is timed once, by its `engine.tick` span."""
         perf = time_mod.perf_counter
         rec = m.recorder
         rec_append = rec.events.append
@@ -468,40 +474,42 @@ class Engine:
         errs_seen = len(err_log)
         errs_tick = 0
         rows_tick0 = self.stats_rows
-        t0 = perf()
-        t_prev = t0
-        try:
-            for node in self.nodes:
-                self.current_node = node
-                rows0 = self.stats_rows
-                node.process(time)
-                t_now = perf()
-                dt = t_now - t_prev
-                t_prev = t_now
-                node._lat_child.observe(dt)
-                rows = self.stats_rows - rows0
-                n_err = len(err_log) - errs_seen
-                if rows:
-                    node._rows_out += rows
-                if n_err:
-                    errs_seen += n_err
-                    errs_tick += n_err
-                if rows or n_err or dt > 1e-4:
-                    rec.seq = seq = rec.seq + 1
-                    rec_append(
-                        (t_now, time, "node", node._idx, node.name,
-                         dt, rows, n_err, seq)
-                    )
-        finally:
-            self.current_node = None
-        t_end = perf()
-        m.tick_hist.observe(t_end - t0)
+        tick = self._tick_span
+        tick.epoch = time
+        with tick:
+            t_prev = tick.t0
+            try:
+                for node in self.nodes:
+                    self.current_node = node
+                    rows0 = self.stats_rows
+                    node.process(time)
+                    t_now = perf()
+                    dt = t_now - t_prev
+                    t_prev = t_now
+                    node._lat_child.observe(dt)
+                    rows = self.stats_rows - rows0
+                    n_err = len(err_log) - errs_seen
+                    if rows:
+                        node._rows_out += rows
+                    if n_err:
+                        errs_seen += n_err
+                        errs_tick += n_err
+                    if rows or n_err or dt > 1e-4:
+                        rec.seq = seq = rec.seq + 1
+                        rec_append(
+                            (t_now, time, "node", node._idx, node.name,
+                             dt, rows, n_err, seq)
+                        )
+            finally:
+                self.current_node = None
+                tick.rows = self.stats_rows - rows_tick0
+        m.tick_hist.observe(tick.dur)
         m.ticks += 1
         m.last_tick_monotonic = time_mod.monotonic()
         rec.seq = seq = rec.seq + 1
         rec_append(
-            (t_end, time, "tick", -1, "", t_end - t0,
-             self.stats_rows - rows_tick0, errs_tick, seq)
+            (tick.t1, time, "tick", -1, "", tick.dur, tick.rows, errs_tick,
+             seq)
         )
 
     def _process_time_traced(self, time: int, m, tr) -> None:
@@ -517,52 +525,49 @@ class Engine:
         errs_seen = len(err_log)
         errs_tick = 0
         rows_tick0 = self.stats_rows
-        t0 = perf()
-        ep = tr.begin_epoch(time, t0)
-        spans_append = ep.spans.append
-        t_prev = t0
-        try:
-            for node in self.nodes:
-                self.current_node = node
-                rows0 = self.stats_rows
-                node.process(time)
-                t_now = perf()
-                dt = t_now - t_prev
-                node._lat_child.observe(dt)
-                rows = self.stats_rows - rows0
-                n_err = len(err_log) - errs_seen
-                if rows:
-                    node._rows_out += rows
-                if n_err:
-                    errs_seen += n_err
-                    errs_tick += n_err
-                if rows or n_err or dt > 1e-5:
-                    spans_append((node._idx, node.name, t_prev, dt, rows))
-                take_aux = getattr(node, "take_aux_spans", None)
-                if take_aux is not None:
-                    # device-pipeline attribution: host-prep / dispatch /
-                    # wait spans accrue on pipeline threads between ticks
-                    # and ride the owning node's idx in the span store
-                    for a_name, a_t0, a_dur, a_rows in take_aux():
-                        spans_append((node._idx, a_name, a_t0, a_dur, a_rows))
-                if rows or n_err or dt > 1e-4:
-                    rec.seq = seq = rec.seq + 1
-                    rec_append(
-                        (t_now, time, "node", node._idx, node.name,
-                         dt, rows, n_err, seq)
-                    )
-                t_prev = t_now
-        finally:
-            self.current_node = None
-        t_end = perf()
-        ep.t1 = t_end
-        m.tick_hist.observe(t_end - t0)
+        tick = self._tick_span
+        tick.epoch = time
+        with tick:
+            ep = tr.begin_epoch(time, tick.t0)
+            spans_append = ep.spans.append
+            t_prev = tick.t0
+            try:
+                for node in self.nodes:
+                    self.current_node = node
+                    rows0 = self.stats_rows
+                    node.process(time)
+                    t_now = perf()
+                    dt = t_now - t_prev
+                    node._lat_child.observe(dt)
+                    rows = self.stats_rows - rows0
+                    n_err = len(err_log) - errs_seen
+                    if rows:
+                        node._rows_out += rows
+                    if n_err:
+                        errs_seen += n_err
+                        errs_tick += n_err
+                    if rows or n_err or dt > 1e-5:
+                        spans_append(
+                            (node._idx, node.name, t_prev, dt, rows)
+                        )
+                    if rows or n_err or dt > 1e-4:
+                        rec.seq = seq = rec.seq + 1
+                        rec_append(
+                            (t_now, time, "node", node._idx, node.name,
+                             dt, rows, n_err, seq)
+                        )
+                    t_prev = t_now
+            finally:
+                self.current_node = None
+                tick.rows = self.stats_rows - rows_tick0
+        ep.t1 = tick.t1
+        m.tick_hist.observe(tick.dur)
         m.ticks += 1
         m.last_tick_monotonic = time_mod.monotonic()
         rec.seq = seq = rec.seq + 1
         rec_append(
-            (t_end, time, "tick", -1, "", t_end - t0,
-             self.stats_rows - rows_tick0, errs_tick, seq)
+            (tick.t1, time, "tick", -1, "", tick.dur, tick.rows, errs_tick,
+             seq)
         )
 
     def dump_diagnostics(self, *, reason: str = "manual") -> dict:

@@ -42,7 +42,7 @@ import weakref
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
-from pathway_tpu.internals import memtrack, utilization
+from pathway_tpu.internals import memtrack, tracing, utilization
 from pathway_tpu.internals.metrics import MetricsRegistry
 
 
@@ -127,25 +127,27 @@ class DevicePipeline:
         # completion-to-completion device-time estimate (see
         # internals/utilization.py module docstring)
         self._last_completion = 0.0
-        workers = prep_workers or _env_int("PATHWAY_PIPELINE_PREP_WORKERS", 2)
+        self.prep_workers = prep_workers or _env_int(
+            "PATHWAY_PIPELINE_PREP_WORKERS", 2
+        )
         self._pool = ThreadPoolExecutor(
-            max_workers=workers, thread_name_prefix=f"{name}-prep"
+            max_workers=self.prep_workers, thread_name_prefix=f"{name}-prep"
         )
         self._cond = threading.Condition()
-        self._pending: Deque[Tuple[int, Any, Any]] = collections.deque()
+        # (seq, epoch, item, prepare future): seq is the submission number,
+        # epoch the engine tick that submitted — together the identifier
+        # every span of this batch carries (internals/tracing.py)
+        self._pending: Deque[Tuple[int, Any, Any, Any]] = collections.deque()
+        # (handle, dispatch end, meta, seq, epoch)
         self._inflight: Deque[Any] = collections.deque()
         self._submitted = 0
         self._dispatched = 0
-        self._drains = 0
         self._rows = 0
         self._real_tokens = 0
         self._slab_tokens = 0
         self._error: Optional[BaseException] = None
         self._failed: List[Any] = []
         self._stop = False
-        self._spans: Deque[Tuple[str, float, float, int]] = collections.deque(
-            maxlen=512
-        )
         self._thread = threading.Thread(
             target=self._run, name=f"{name}-dispatch", daemon=True
         )
@@ -164,15 +166,23 @@ class DevicePipeline:
         """Hand one batch to the pipeline. Blocks (backpressure) while the
         prepared queue is full; raises DevicePipelineError if a previous
         batch failed (the caller then replays take_failed() synchronously)."""
+        epoch = tracing.current_epoch()
         with self._cond:
             self._raise_if_failed()
-            while len(self._pending) >= self.max_prepared:
-                self._cond.wait()
-                self._raise_if_failed()
+            if len(self._pending) >= self.max_prepared:
+                # one producer (the engine thread), so the number this
+                # batch will get is known before the wait
+                with tracing.span(
+                    "pipeline.submit_blocked",
+                    seq=self._submitted + 1, epoch=epoch,
+                ):
+                    while len(self._pending) >= self.max_prepared:
+                        self._cond.wait()
+                        self._raise_if_failed()
             self._submitted += 1
             seq = self._submitted
-            fut = self._pool.submit(self._prep_timed, item)
-            self._pending.append((seq, item, fut))
+            fut = self._pool.submit(self._prep_timed, item, seq, epoch)
+            self._pending.append((seq, epoch, item, fut))
             self._cond.notify_all()
 
     def barrier(self) -> None:
@@ -188,27 +198,23 @@ class DevicePipeline:
         """Barrier, then wait until every in-flight dispatch has EXECUTED
         on device (snapshot / rollback / failover / finish contract)."""
         self.barrier()
-        t0 = time.perf_counter()
-        waited = False
-        while True:
-            with self._cond:
-                if not self._inflight:
-                    break
-                handle, disp_end, meta = self._inflight.popleft()
-            waited = True
-            self._wait(handle)
-            self._note_completion(disp_end, meta)
-        if self._quiesce is not None:
-            self._quiesce()
-            waited = True
-        with self._cond:
-            self._drains += 1
-            if waited:
-                self._note_span("pipeline:drain", t0, 0)
-        if waited and utilization.ENABLED:
-            utilization.tracker().note_span(
-                "drain", time.perf_counter() - t0
-            )
+        with tracing.span("pipeline.drain") as sp:
+            waited = False
+            while True:
+                with self._cond:
+                    if not self._inflight:
+                        break
+                    handle, disp_end, meta, seq, epoch = (
+                        self._inflight.popleft()
+                    )
+                waited = True
+                self._wait(handle)
+                self._note_completion(disp_end, meta, seq, epoch)
+            if self._quiesce is not None:
+                self._quiesce()
+                waited = True
+            if not waited:
+                sp.cancel()  # nothing was in flight: no drain to account
 
     def set_pressure_scale(self, scale: float) -> None:
         """Scale the live queue/window sizes toward `scale` of their
@@ -257,15 +263,6 @@ class DevicePipeline:
 
     # -- observability -----------------------------------------------------
 
-    def take_aux_spans(self) -> List[Tuple[str, float, float, int]]:
-        """Pop accumulated (name, start_perf, duration_s, rows) spans —
-        host-prep vs device-dispatch vs wait/drain attribution for the
-        epoch tracer."""
-        with self._cond:
-            spans = list(self._spans)
-            self._spans.clear()
-            return spans
-
     def stats(self) -> Dict[str, Any]:
         with self._cond:
             slab = self._slab_tokens
@@ -274,7 +271,6 @@ class DevicePipeline:
                 "dispatched": self._dispatched,
                 "queue_depth": len(self._pending),
                 "in_flight": len(self._inflight),
-                "drains": self._drains,
                 "rows": self._rows,
                 "real_tokens": self._real_tokens,
                 "slab_tokens": slab,
@@ -282,6 +278,7 @@ class DevicePipeline:
                     1.0 - self._real_tokens / slab if slab else None
                 ),
                 "replicas": self.replicas,
+                "prep_workers": self.prep_workers,
             }
 
     def replica_stats(self) -> List[Dict[str, Any]]:
@@ -325,10 +322,9 @@ class DevicePipeline:
                 f"{self._error})"
             ) from self._error
 
-    def _note_span(self, kind: str, t0: float, rows: int) -> None:
-        self._spans.append((kind, t0, time.perf_counter() - t0, rows))
-
-    def _note_completion(self, disp_end: float, meta: Dict[str, Any]) -> None:
+    def _note_completion(
+        self, disp_end: float, meta: Dict[str, Any], seq: int, epoch: Any
+    ) -> None:
         """A waited handle finished executing: estimate its device busy
         interval (completion-to-completion; dispatches execute in-order)
         and feed the utilization window + the mesh straggler detector."""
@@ -342,14 +338,6 @@ class DevicePipeline:
         with self._cond:
             device_s = max(0.0, t_end - max(self._last_completion, disp_end))
             self._last_completion = t_end
-            self._spans.append(
-                (
-                    "pipeline:device",
-                    t_end - device_s,
-                    device_s,
-                    int(meta.get("rows", 0)),
-                )
-            )
         from pathway_tpu.internals import qtrace
 
         if qtrace.ENABLED:
@@ -368,8 +356,13 @@ class DevicePipeline:
                 bytes_moved=float(meta.get("slab_bytes", 0)),
                 docs=int(meta.get("rows", 0)),
             )
+        # an estimate, so totals and ring only; utilization's window gets
+        # device_s from the record's subscription
+        tracing.record(
+            "pipeline.device", t_end - device_s, t_end,
+            seq=seq, epoch=epoch, rows=int(meta.get("rows", 0)),
+        )
         if utilization.ENABLED:
-            utilization.tracker().note_span("device", device_s)
             if self.replicas > 1:
                 from pathway_tpu.internals.mesh_backend import active_backend
 
@@ -407,47 +400,53 @@ class DevicePipeline:
                 self._replica_real[r] += real // self.replicas
                 self._replica_slab[r] += slab // self.replicas
 
-    def _prep_timed(self, item: Any) -> Tuple[Any, Dict[str, Any]]:
-        t0 = time.perf_counter()
-        payload, meta = self._prepare(item)
-        dur = time.perf_counter() - t0
-        with self._cond:
-            self._spans.append(
-                ("pipeline:prep", t0, dur, int(meta.get("rows", 0)))
-            )
-        if utilization.ENABLED:
-            utilization.tracker().note_span("prep", dur)
+    def _prep_timed(
+        self, item: Any, seq: int, epoch: Any
+    ) -> Tuple[Any, Dict[str, Any]]:
+        with tracing.span("pipeline.prep", seq=seq, epoch=epoch) as sp:
+            payload, meta = self._prepare(item)
+            sp.rows = int(meta.get("rows", 0))
         return payload, meta
 
     def _run(self) -> None:
         while True:
+            # the four spans of this loop partition the thread's time:
+            # starved (nothing submitted), prep_wait (submitted, not yet
+            # prepared), window_wait (the chip is behind), launch
             with self._cond:
-                while not self._pending and not self._stop:
-                    self._cond.wait()
+                if not self._pending and not self._stop:
+                    with tracing.span("pipeline.starved"):
+                        while not self._pending and not self._stop:
+                            self._cond.wait()
                 if not self._pending:
                     return
-                seq, item, fut = self._pending.popleft()
+                seq, epoch, item, fut = self._pending.popleft()
                 self._cond.notify_all()
             try:
-                payload, meta = fut.result()
+                with tracing.span("pipeline.prep_wait", seq=seq, epoch=epoch):
+                    payload, meta = fut.result()
                 # window: wait the OLDEST handle only when double-buffering
                 # is exhausted — batch N executes while N+1 enqueues
                 while True:
                     with self._cond:
                         if len(self._inflight) < self.max_in_flight:
                             break
-                        handle, disp_end, old_meta = self._inflight.popleft()
-                    t0 = time.perf_counter()
-                    self._wait(handle)
-                    wait_dur = time.perf_counter() - t0
-                    with self._cond:
-                        self._spans.append(("pipeline:wait", t0, wait_dur, 0))
-                    if utilization.ENABLED:
-                        utilization.tracker().note_span("wait", wait_dur)
-                    self._note_completion(disp_end, old_meta)
-                t0 = time.perf_counter()
-                handle = self._dispatch(payload)
-                disp_end = time.perf_counter()
+                        handle, disp_end, old_meta, old_seq, old_epoch = (
+                            self._inflight.popleft()
+                        )
+                    with tracing.span(
+                        "pipeline.window_wait", seq=old_seq, epoch=old_epoch
+                    ):
+                        self._wait(handle)
+                    self._note_completion(
+                        disp_end, old_meta, old_seq, old_epoch
+                    )
+                rows = int(meta.get("rows", 0))
+                with tracing.span(
+                    "pipeline.launch", seq=seq, epoch=epoch, rows=rows
+                ) as launch:
+                    handle = self._dispatch(payload)
+                disp_end = launch.t1
                 if memtrack.ENABLED:
                     # packed slab bytes live on device until the handle
                     # retires (_note_completion books the -delta)
@@ -455,14 +454,12 @@ class DevicePipeline:
                         "pipeline_inflight", self,
                         float(meta.get("slab_bytes", 0)),
                     )
-                rows = int(meta.get("rows", 0))
                 real = int(meta.get("real_tokens", 0))
                 slab = int(meta.get("slab_tokens", 0))
                 with self._cond:
-                    self._spans.append(
-                        ("pipeline:dispatch", t0, disp_end - t0, rows)
+                    self._inflight.append(
+                        (handle, disp_end, meta, seq, epoch)
                     )
-                    self._inflight.append((handle, disp_end, meta))
                     self._dispatched = seq
                     self._rows += rows
                     self._real_tokens += real
@@ -470,9 +467,7 @@ class DevicePipeline:
                     self._account_replicas(meta, rows, real, slab)
                     self._cond.notify_all()
                 if utilization.ENABLED:
-                    t = utilization.tracker()
-                    t.note_span("dispatch", disp_end - t0)
-                    t.note_batch(
+                    utilization.tracker().note_batch(
                         rows, real, slab,
                         float(meta.get("useful_flops", 0.0)),
                     )
@@ -480,7 +475,7 @@ class DevicePipeline:
                 with self._cond:
                     self._failed.append(item)
                     while self._pending:
-                        _seq, p_item, p_fut = self._pending.popleft()
+                        _seq, _epoch, p_item, p_fut = self._pending.popleft()
                         p_fut.cancel()
                         self._failed.append(p_item)
                     self._dispatched = self._submitted
@@ -663,8 +658,8 @@ def pipeline_status() -> Dict[str, Any]:
                 "dispatched",
                 "queue_depth",
                 "in_flight",
-                "drains",
                 "rows",
+                "prep_workers",
             )
         }
         out.update(agg)

@@ -58,6 +58,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
+from pathway_tpu.internals import tracing
 from pathway_tpu.internals.backoff import Backoff
 from pathway_tpu.internals.metrics import FlightRecorder, MetricsRegistry
 
@@ -128,6 +129,10 @@ class HealthController:
         self._roll_last: Optional[Dict[str, Any]] = None
         # -- backpressure state ---------------------------------------
         self._bp_scale = 1.0
+        # the `health.pressure` counter of the span table: seconds with
+        # the scale under 1 are booked from this mark (monotonic)
+        self._pressure_mark = time.monotonic()
+        self._pressure_lock = threading.Lock()
         self._pressure = False
         self._pressure_reason: Optional[str] = None
         self._throttle_s = 0.0
@@ -425,17 +430,34 @@ class HealthController:
             return f"bound_state={state}"
         return None
 
-    def _on_pressure(self, reason: str) -> None:
+    def _account_pressure(self) -> None:
+        """Bring `health.pressure`'s total_s (seconds with back-pressure
+        scale < 1) up to now: before the scale changes, and before every
+        reading of the span table."""
+        with self._pressure_lock:
+            now = time.monotonic()
+            if self._bp_scale < 1.0:
+                tracing.add("health.pressure", now - self._pressure_mark, 0)
+            self._pressure_mark = now
+
+    def _set_bp_scale(self, scale: float) -> None:
+        """The one place the scale changes: applied to the pipelines, and
+        counted (`health.pressure` count = number of scale changes)."""
         from pathway_tpu.internals import device_pipeline
 
+        self._account_pressure()
+        old = self._bp_scale
+        self._bp_scale = device_pipeline.set_backpressure_scale(scale)
+        if self._bp_scale != old:
+            tracing.add("health.pressure")
+
+    def _on_pressure(self, reason: str) -> None:
         first = not self._pressure
         self._pressure = True
         self._pressure_reason = reason
         new_scale = max(BP_MIN_SCALE, self._bp_scale * BP_DECREASE)
         if new_scale < self._bp_scale or first:
-            self._bp_scale = device_pipeline.set_backpressure_scale(
-                max(new_scale, BP_MIN_SCALE)
-            )
+            self._set_bp_scale(max(new_scale, BP_MIN_SCALE))
             self._act("throttle", name=reason)
             logger.warning(
                 "health: backpressure engaged (%s) — pipeline budget "
@@ -445,16 +467,12 @@ class HealthController:
         self._throttle_s = self._throttle_backoff.next_delay()
 
     def _on_pressure_clear(self) -> None:
-        from pathway_tpu.internals import device_pipeline
-
         was_pressure = self._pressure
         self._pressure = False
         self._throttle_s = 0.0
         self._throttle_backoff.reset()
         if self._bp_scale < 1.0:
-            self._bp_scale = device_pipeline.set_backpressure_scale(
-                min(1.0, self._bp_scale + BP_INCREASE)
-            )
+            self._set_bp_scale(min(1.0, self._bp_scale + BP_INCREASE))
             if self._bp_scale >= 1.0:
                 self._act(
                     "relax",
@@ -512,8 +530,6 @@ class HealthController:
         """Reset transient per-run state (runner.run calls this before
         workers start).  Action counters and the flight recorder are
         cumulative — operators read them across runs."""
-        from pathway_tpu.internals import device_pipeline
-
         with self._lock:
             self._drained.clear()
             self._pressure = False
@@ -522,16 +538,14 @@ class HealthController:
             self._throttle_backoff.reset()
             self._next_pressure_check = 0.0
             if self._bp_scale < 1.0:
-                self._bp_scale = device_pipeline.set_backpressure_scale(1.0)
+                self._set_bp_scale(1.0)
 
     def on_run_end(self) -> None:
         """Release any held backpressure so one run's throttle never
         leaks into the next (runner.run's finally)."""
-        from pathway_tpu.internals import device_pipeline
-
         with self._lock:
             if self._bp_scale < 1.0:
-                self._bp_scale = device_pipeline.set_backpressure_scale(1.0)
+                self._set_bp_scale(1.0)
             self._throttle_s = 0.0
             self._pressure = False
 
@@ -551,6 +565,15 @@ def controller() -> HealthController:
             if c is None:
                 c = _CONTROLLER = HealthController()
     return c
+
+
+def _refresh_pressure() -> None:
+    c = _CONTROLLER
+    if c is not None:
+        c._account_pressure()
+
+
+tracing.on_read(_refresh_pressure)
 
 
 def reset_for_tests() -> HealthController:

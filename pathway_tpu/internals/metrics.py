@@ -22,6 +22,7 @@ from __future__ import annotations
 import json
 import math
 import os
+import threading
 import time as time_mod
 from collections import deque
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
@@ -103,39 +104,79 @@ class Gauge:
         yield f"{name}{labels} {_fmt_value(self.value)}"
 
 
+_SETTLE_LOCK = threading.Lock()  # settling is rare: one lock for all
+
+
 class Histogram:
     """Log2-bucket latency histogram child.
 
-    ``observe`` is the hot path: one float add + frexp + list bump — no
-    locks (int/float mutations are atomic under the GIL; readers see a
-    monotonic, possibly slightly stale view, which is what Prometheus
-    scrapes want)."""
+    ``observe`` is the hot path: one float add and one list append — no
+    locks (they are atomic under the GIL; readers see a monotonic,
+    possibly slightly stale view, which is what Prometheus scrapes
+    want).  The buckets and the quantile digest are brought up to date
+    when they are read, or after ``_PENDING_LIMIT`` observations."""
 
     kind = "histogram"
-    __slots__ = ("counts", "sum", "digest")
+    __slots__ = ("_counts", "sum", "_digest", "_pending")
+
+    _PENDING_LIMIT = 512
 
     def __init__(self) -> None:
-        self.counts = [0] * (_N_BUCKETS + 1)  # last slot = +Inf
+        self._counts = [0] * (_N_BUCKETS + 1)  # last slot = +Inf
         self.sum = 0.0
         # companion quantile digest: exposition still renders the log2
         # buckets (stable scrape format), but percentile() answers from
         # the digest so dashboard p50/p99 stop being bucket midpoints
-        self.digest = Digest()
+        self._digest = Digest()
+        self._pending: List[float] = []  # observed, not yet in either
+
+    @property
+    def counts(self) -> List[int]:
+        if self._pending:
+            self._settle()
+        return self._counts
+
+    @property
+    def digest(self) -> "Digest":
+        if self._pending:
+            self._settle()
+        return self._digest
+
+    @digest.setter
+    def digest(self, digest: "Digest") -> None:
+        if self._pending:
+            self._settle()
+        self._digest = digest
+
+    def _settle(self) -> None:
+        # the list is never swapped, so an observer appending meanwhile
+        # loses nothing; one settler at a time takes what is there
+        with _SETTLE_LOCK:
+            pending = self._pending
+            waiting = pending[:]
+            del pending[: len(waiting)]
+            counts = self._counts
+            for x in waiting:
+                if x > 0.0:
+                    # frexp: x = m * 2**e with 0.5 <= m < 1, so 2**(e-1)
+                    # <= x < 2**e and the le=2**e bucket (index e -
+                    # _MIN_EXP) contains x.
+                    i = _frexp(x)[1] - _MIN_EXP
+                    if i < 0:
+                        i = 0
+                    elif i > _N_BUCKETS:
+                        i = _N_BUCKETS
+                    counts[i] += 1
+                else:
+                    counts[0] += 1
+            self._digest.observe_many(waiting)
 
     def observe(self, x: float) -> None:
         self.sum += x
-        self.digest.observe(x)
-        if x > 0.0:
-            # frexp: x = m * 2**e with 0.5 <= m < 1, so 2**(e-1) <= x < 2**e
-            # and the le=2**e bucket (index e - _MIN_EXP) contains x.
-            i = _frexp(x)[1] - _MIN_EXP
-            if i < 0:
-                i = 0
-            elif i > _N_BUCKETS:
-                i = _N_BUCKETS
-            self.counts[i] += 1
-        else:
-            self.counts[0] += 1
+        pending = self._pending
+        pending.append(x)
+        if len(pending) >= self._PENDING_LIMIT:
+            self._settle()
 
     @property
     def count(self) -> int:
@@ -244,6 +285,25 @@ class Digest:
 
     # Histogram-compatible alias
     add = observe
+
+    def observe_many(self, xs: List[float]) -> None:
+        """`observe` of each of `xs` in order, in one call (same sums, to
+        the last bit: added one by one)."""
+        if not xs:
+            return
+        self._buf.extend(xs)
+        self.count += float(len(xs))
+        total = self.sum
+        for x in xs:
+            total += x
+        self.sum = total
+        lo, hi = min(xs), max(xs)
+        if lo < self.min:
+            self.min = lo
+        if hi > self.max:
+            self.max = hi
+        if len(self._buf) >= self._buf_limit:
+            self._compress()
 
     def merge(self, other: "Digest") -> None:
         if other.count == 0:

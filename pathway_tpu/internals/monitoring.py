@@ -595,7 +595,10 @@ class PrometheusServer:
         from pathway_tpu.internals.qtrace import qtrace_status
         from pathway_tpu.internals.sanitizer import sanitizer_status
         from pathway_tpu.internals.serving import serving_status
-        from pathway_tpu.internals.tracing import merged_critical_path
+        from pathway_tpu.internals.tracing import (
+            merged_critical_path,
+            spans_status,
+        )
         from pathway_tpu.internals.utilization import utilization_status
 
         return {
@@ -607,6 +610,12 @@ class PrometheusServer:
             # latency attribution for the latest sampled epoch (all
             # in-process workers; see internals/tracing.py)
             "critical_path": merged_critical_path(self._engines()),
+            # the span record (internals/tracing.py): cumulative totals per
+            # span name — count, wall, cpu, self, rows, max — the
+            # program's clock at this reading, and the longest garbage
+            # collection of each recent second; a reader differences two
+            # readings
+            "spans": spans_status(),
             # accelerator health (internals/device_probe.py)
             "device": device_status(),
             # async ingest pipeline (internals/device_pipeline.py):

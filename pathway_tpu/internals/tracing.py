@@ -21,16 +21,26 @@ Overhead budget: unsampled ticks pay one attribute load + one modulo;
 sampled ticks add one tuple append per active node.  The perf-smoke
 guard (tests/test_perf_smoke.py) holds the default-sampling cost of the
 whole observability layer under 5% of the bare loop.
+
+The span record (second half of this module) is the always-on,
+process-wide companion: ``span(name, seq=, epoch=, rows=)`` at batch,
+tick, file and collection granularity — never per row — feeds per-name
+totals (``/status`` "spans"), one bounded ring (``dump_trace``), and a
+``jax.profiler.TraceAnnotation`` so that under a profiler capture the
+span lies on the capture's clock beside the device's ops.  Epoch
+sampling above governs only the per-node spans inside a tick.
 """
 
 from __future__ import annotations
 
+import gc
 import json
 import os
+import sys
 import threading
 import time as time_mod
 from collections import deque
-from typing import Any, Dict, Iterable, List, Optional
+from typing import Any, Callable, Dict, Iterable, List, Optional
 
 
 class _EpochRecord:
@@ -187,24 +197,25 @@ def critical_path_from_events(
             _, w, _ep, idx, name, _ts, dur, rows = ev
             entries.append(
                 {
-                    # aux spans from the async device pipeline
-                    # (pipeline:prep / pipeline:dispatch / pipeline:wait /
-                    # pipeline:drain) ride the owning node's idx but are
-                    # attributed as their own kind: they run on pipeline
-                    # threads CONCURRENT with the tick, so "node" would
-                    # misread as serial engine-loop time.  The estimated
-                    # per-dispatch device busy interval (pipeline:device,
-                    # internals/utilization.py) gets its own kind — it is
-                    # CHIP time, not host pipeline time
-                    "kind": (
-                        "device"
-                        if name == "pipeline:device"
-                        else "pipeline"
-                        if name.startswith("pipeline:")
-                        else "node"
-                    ),
+                    "kind": "node",
                     "worker": w,
                     "node": idx,
+                    "name": name,
+                    "duration_ms": round(dur * 1000, 4),
+                    "rows": rows,
+                }
+            )
+        elif kind == "pspan" and ev[7] == epoch and ev[2] != "engine.tick":
+            # program spans of the batches this epoch submitted: they run
+            # on pipeline threads CONCURRENT with the tick (and outlive
+            # it), so each is attributed to its layer — the prefix of its
+            # name — never as serial engine-loop time
+            _, w, name, _thread, _ts, dur, _seq, _ep, _parent, rows = ev
+            entries.append(
+                {
+                    "kind": name.split(".", 1)[0],
+                    "worker": w,
+                    "node": -1,
                     "name": name,
                     "duration_ms": round(dur * 1000, 4),
                     "rows": rows,
@@ -263,6 +274,7 @@ def merged_critical_path(engines: Iterable[Any]) -> Optional[dict]:
         tr = getattr(m, "trace", None) if m is not None else None
         if tr is not None:
             events.extend(tr.export_events())
+    events.extend(export_span_events())
     return critical_path_from_events(events)
 
 
@@ -293,6 +305,8 @@ def gather_trace_events(engine) -> List[tuple]:
         tr = getattr(m, "trace", None) if m is not None else None
         if tr is not None:
             events.extend(tr.export_events())
+    # the process's span record, once, under this engine's worker id
+    events.extend(export_span_events(getattr(engine, "worker_id", 0)))
     tcp = group.tcp if group is not None else None
     if tcp is None and coord is not None and hasattr(coord, "_recv_loop"):
         tcp = coord  # plain TcpCoordinator (threads == 1)
@@ -328,9 +342,42 @@ def build_chrome_trace(events: Iterable[tuple]) -> dict:
             }
         )
     flow_id = 0
+    span_tids: Dict[tuple, int] = {}  # (worker, thread name) -> tid
     for ev in events:
         kind = ev[0]
-        if kind == "tick":
+        if kind == "pspan":
+            _, w, name, thread, ts, dur, seq, epoch, parent, rows = ev
+            tid = span_tids.get((w, thread))
+            if tid is None:
+                # tids 0 and 1 are the worker's tick and node rows
+                tid = span_tids[(w, thread)] = 2 + len(span_tids)
+                te.append(
+                    {
+                        "ph": "M",
+                        "name": "thread_name",
+                        "pid": w,
+                        "tid": tid,
+                        "args": {"name": thread},
+                    }
+                )
+            te.append(
+                {
+                    "ph": "X",
+                    "cat": name.split(".", 1)[0],
+                    "name": name,
+                    "pid": w,
+                    "tid": tid,
+                    "ts": round(ts * 1e6, 1),
+                    "dur": round(dur * 1e6, 1),
+                    "args": {
+                        "seq": seq,
+                        "epoch": epoch,
+                        "parent": parent,
+                        "rows": rows,
+                    },
+                }
+            )
+        elif kind == "tick":
             _, w, epoch, ts, dur = ev
             te.append(
                 {
@@ -545,3 +592,374 @@ def merge_flight_tails(
         )
     )
     return merged
+
+
+# ---------------------------------------------------------------------------
+# The span record: process-wide, always on
+# ---------------------------------------------------------------------------
+#
+# One record per process, because the threads it covers (connector,
+# engine, pipeline prep and dispatch) belong to the process and not to an
+# engine.  Span sites are batch / tick / file / collection granularity
+# (under ~200 spans a second on the busiest ingest), so there is no
+# switch: a span costs two perf_counter reads, two thread_time reads
+# (sampled where the name's spans are short), an inactive
+# TraceAnnotation's flag test and no lock.
+
+SPAN_RING = 8192  # closed spans kept for dump_trace
+GC_RECENT_S = 64  # seconds of per-second longest collections kept
+SHORT_SPAN_S = 1e-3  # mean duration under which CPU time is sampled
+_perf = time_mod.perf_counter
+_thread_time = time_mod.thread_time
+_FIELDS = ("count", "total_s", "cpu_s", "self_s", "rows", "max_s", "open_s")
+
+
+class _ThreadSpans:
+    """One thread's side of the record: its stack of open spans and its
+    own totals, so that closing a span takes no lock (the totals of all
+    threads are summed when they are read)."""
+
+    __slots__ = ("name", "thread", "stack", "totals", "ring", "gc_span", "gc_t0")
+
+    def __init__(self, ring: deque):
+        self.thread = threading.current_thread()
+        self.name = self.thread.name
+        self.stack: list = []
+        # name -> [count, total_s, cpu_s, self_s, rows, max_s]
+        self.totals: Dict[str, list] = {}
+        self.ring = ring
+        self.gc_span = None
+        self.gc_t0 = None
+
+    def close(
+        self, name, t0, t1, cpu_s, self_s, seq, epoch, parent, rows
+    ) -> None:
+        dur = t1 - t0
+        tot = self.totals.get(name)
+        if tot is None:
+            tot = self.totals[name] = [0, 0.0, 0.0, 0.0, 0, 0.0]
+        tot[0] += 1
+        tot[1] += dur
+        tot[2] += cpu_s
+        tot[3] += self_s
+        tot[4] += rows
+        if dur > tot[5]:
+            tot[5] = dur
+        self.ring.append((name, self.name, t0, t1, seq, epoch, parent, rows))
+        sink = _SUBSCRIBERS.get(name)
+        if sink is not None:
+            sink(dur)
+
+
+def _fold(into: Dict[str, list], totals: Dict[str, list]) -> None:
+    for name, tot in list(totals.items()):
+        acc = into.get(name)
+        if acc is None:
+            into[name] = list(tot)
+        else:
+            for i in range(5):
+                acc[i] += tot[i]
+            if tot[5] > acc[5]:
+                acc[5] = tot[5]
+
+
+class SpanRecord:
+    """Every thread's totals, the ring of closed spans (name, thread, t0,
+    t1, seq, epoch, parent, rows; perf_counter times), and the longest
+    recent garbage collections."""
+
+    def __init__(self, capacity: int = SPAN_RING):
+        # the list of threads.  Re-entrant: a collection can start while
+        # this thread holds it, and the hook below may take it (`here`)
+        self.lock = threading.RLock()
+        self.threads: List[_ThreadSpans] = []
+        self.retired: Dict[str, list] = {}  # totals of threads that ended
+        self.ring: deque = deque(maxlen=capacity)
+        # slot `second % GC_RECENT_S` -> (t_end_monotonic, duration_s,
+        # generation): the longest collection that ended in that second (a
+        # maximum between two /status readings cannot be differenced from
+        # cumulative totals).  Collections do not overlap, so no lock
+        self.gc_recent: list = [None] * GC_RECENT_S
+        self.local = threading.local()
+        self.wall_off = time_mod.time() - _perf()
+
+    def here(self) -> _ThreadSpans:
+        try:
+            return self.local.spans
+        except AttributeError:
+            mine = self.local.spans = _ThreadSpans(self.ring)
+            with self.lock:
+                if len(self.threads) >= 64:
+                    # threads come and go (a server's request threads):
+                    # keep what the ended ones measured, not the threads
+                    for ended in [t for t in self.threads if not t.thread.is_alive()]:
+                        _fold(self.retired, ended.totals)
+                        self.threads.remove(ended)
+                self.threads.append(mine)
+            return mine
+
+    def totals(self) -> Dict[str, list]:
+        """name -> the six totals of its closed spans, and seventh the
+        seconds so far of its spans that are open right now.  A reader
+        that differences `total_s + open_s` between two readings gets the
+        time inside the interval exactly, however long a span is (the
+        dispatch thread of a chip-bound ingest waits two seconds at a
+        time)."""
+        with self.lock:
+            out = {name: list(tot) for name, tot in self.retired.items()}
+            threads = list(self.threads)
+        for t in threads:
+            _fold(out, t.totals)
+        for tot in out.values():
+            tot.append(0.0)
+        now = _perf()
+        for t in threads:
+            for sp in list(t.stack):
+                tot = out.get(sp.name)
+                if tot is None:
+                    tot = out[sp.name] = [0, 0.0, 0.0, 0.0, 0, 0.0, 0.0]
+                tot[6] += max(0.0, now - sp.t0)
+        return out
+
+
+_RECORD = SpanRecord()
+# name -> callable(duration_s), run where a span of that name closes:
+# wiring, not state, so reset_spans() keeps it
+_SUBSCRIBERS: Dict[str, Callable[[float], None]] = {}
+# run before the totals are read, so that a counter of elapsed time
+# (health.pressure) is up to date at every reading
+_REFRESHERS: List[Callable[[], None]] = []
+
+
+def subscribe(name: str, sink: Callable[[float], None]) -> None:
+    """Hand every closed span of `name` to `sink(duration_s)` — how
+    internals/utilization.py receives the pipeline's durations."""
+    _SUBSCRIBERS[name] = sink
+
+
+def on_read(refresh: Callable[[], None]) -> None:
+    """Run `refresh()` before every reading of the totals."""
+    if refresh not in _REFRESHERS:
+        _REFRESHERS.append(refresh)
+
+
+def reset_spans(capacity: int = SPAN_RING) -> SpanRecord:
+    """Fresh totals and ring (tests scope a record to one scenario).  A
+    span open on another thread closes into the record it was opened in."""
+    global _RECORD
+    _RECORD = SpanRecord(capacity)
+    return _RECORD
+
+
+_ANNOTATION = None  # jax's TraceAnnotation class, once jax is loaded
+
+
+def _find_annotation():
+    """Never imports jax (the connector and the engine stay jax-free):
+    the class is taken from the module once something else loaded it.
+    With jax loaded and no capture running a span pays one flag test."""
+    global _ANNOTATION
+    _ANNOTATION = getattr(
+        sys.modules.get("jax.profiler"), "TraceAnnotation", None
+    )
+    return _ANNOTATION
+
+
+class span:
+    """``with span("pipeline.launch", seq=7, epoch=12, rows=512): ...``
+
+    `seq` and `epoch` default to the enclosing span's on this thread, so
+    children need not be told.  Inside the body `rows` may still be set;
+    `cancel()` keeps the span out of the record (a drain that had nothing
+    to wait for).  After the body `t0`, `t1` and `dur` hold the timing,
+    for a caller that feeds it elsewhere too.  A span that has exited may
+    be entered again (the engine keeps one object for its ticks and sets
+    `epoch` and `rows` anew each time)."""
+
+    __slots__ = (
+        "name", "seq", "epoch", "rows", "t0", "t1", "dur", "child_s",
+        "_cancelled", "_stats", "_ann", "_mine", "_cpu_weight", "_cpu0",
+    )
+
+    def __init__(self, name: str, *, seq=None, epoch=None, rows: int = 0):
+        self.name = name
+        self.seq = seq
+        self.epoch = epoch
+        self.rows = rows
+        self._cancelled = False
+        self._stats = None  # further stats for the annotation (host.gc's generation)
+        self._ann = None
+
+    def cancel(self) -> None:
+        self._cancelled = True
+
+    def __enter__(self) -> "span":
+        mine = self._mine = _RECORD.here()
+        self.child_s = 0.0
+        stack = mine.stack
+        if stack:
+            parent = stack[-1]
+            if self.seq is None:
+                self.seq = parent.seq
+            if self.epoch is None:
+                self.epoch = parent.epoch
+        annotation = _ANNOTATION or _find_annotation()
+        if annotation is not None and annotation.is_enabled():
+            # a capture is running: the span goes onto its clock
+            stats = self._stats or {}
+            if self.seq is not None:
+                stats["seq"] = self.seq
+            if self.epoch is not None:
+                stats["epoch"] = self.epoch
+            self._ann = annotation(self.name, **stats)
+            self._ann.__enter__()
+        # thread_time is a system call (0.5 us here, three times that on
+        # a loaded machine) where perf_counter is not.  A name whose spans
+        # average under a millisecond — the engine's tick on a fast graph
+        # — has its CPU time read on one span in 16 and counted 16 times
+        tot = mine.totals.get(self.name)
+        if tot is None or tot[1] >= tot[0] * SHORT_SPAN_S:
+            self._cpu_weight = 1
+        elif tot[0] & 15:
+            self._cpu_weight = 0
+        else:
+            self._cpu_weight = 16
+        self._cpu0 = _thread_time() if self._cpu_weight else 0.0
+        self.t0 = _perf()
+        # pushed last: a reading of the open spans finds this one only
+        # with the t0 of this entry (a span object may be entered again)
+        stack.append(self)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        t1 = self.t1 = _perf()
+        weight = self._cpu_weight
+        cpu_s = weight * (_thread_time() - self._cpu0) if weight else 0.0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        mine = self._mine
+        stack = mine.stack
+        stack.pop()
+        dur = self.dur = t1 - self.t0
+        parent = None
+        if stack:
+            parent = stack[-1]
+            parent.child_s += dur
+            parent = parent.name
+        if not self._cancelled:
+            mine.close(
+                self.name, self.t0, t1, cpu_s, dur - self.child_s,
+                self.seq, self.epoch, parent, self.rows,
+            )
+        return False
+
+
+def current_epoch():
+    """The epoch of the innermost open span on this thread (the engine
+    tick that is running), or None."""
+    stack = _RECORD.here().stack
+    return stack[-1].epoch if stack else None
+
+
+def record(name: str, t0: float, t1: float, *, seq=None, epoch=None,
+           rows: int = 0) -> None:
+    """A span timed by the caller (perf_counter), into totals and ring
+    only: no annotation and no nesting.  For an interval that is an
+    estimate, like the pipeline's completion-to-completion device time —
+    the profiler's device plane has the truth."""
+    _RECORD.here().close(name, t0, t1, 0.0, t1 - t0, seq, epoch, None, rows)
+
+
+def add(name: str, seconds: float = 0.0, n: int = 1) -> None:
+    """A counter in the same table: `n` more occurrences and `seconds`
+    more of `total_s`.  For what has no span — time spent in a state
+    that may still hold when the table is read."""
+    totals = _RECORD.here().totals
+    tot = totals.get(name)
+    if tot is None:
+        tot = totals[name] = [0, 0.0, 0.0, 0.0, 0, 0.0]
+    tot[0] += n
+    tot[1] += seconds
+
+
+def spans_status() -> Dict[str, Any]:
+    """The `"spans"` key of /status: cumulative totals of the closed
+    spans per name plus `open_s`, the seconds so far of those open now;
+    the program's clock at this reading; and the longest collection of
+    each recent second as (t_end_monotonic, duration_s, generation)."""
+    for refresh in list(_REFRESHERS):
+        refresh()
+    rec = _RECORD
+    totals = rec.totals()
+    now = time_mod.monotonic()
+    gc_recent = sorted(
+        list(held) for held in list(rec.gc_recent)
+        if held is not None and held[0] > now - GC_RECENT_S
+    )
+    return {
+        "monotonic_s": now,
+        "totals": {
+            name: dict(zip(_FIELDS, tot)) for name, tot in sorted(totals.items())
+        },
+        "gc_recent": gc_recent,
+    }
+
+
+def export_span_events(worker: int = 0) -> List[tuple]:
+    """The ring as wire-safe tuples for the Chrome writer:
+    ("pspan", worker, name, thread, start_wall, dur, seq, epoch, parent,
+    rows)."""
+    rec = _RECORD
+    off = rec.wall_off
+    return [
+        ("pspan", worker, name, thread, t0 + off, t1 - t0, seq, epoch,
+         parent, rows)
+        for name, thread, t0, t1, seq, epoch, parent, rows in list(rec.ring)
+    ]
+
+
+# -- host.gc --------------------------------------------------------------
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """gc.callbacks hook: every collection into the `host.gc` totals and
+    the per-second maxima; one of generation 1 or 2 also as a span (ring,
+    annotation, the enclosing span's self time).  Generation 0 runs every
+    few hundred allocations — row granularity — so it gets no span."""
+    rec = _RECORD
+    mine = rec.here()
+    generation = info["generation"]
+    if phase == "start":
+        if generation == 0:
+            mine.gc_t0 = _perf()
+        else:
+            sp = span("host.gc")
+            sp._stats = {"generation": generation}
+            mine.gc_span = sp.__enter__()
+        return
+    if generation == 0:
+        if mine.gc_t0 is None:
+            return  # the hook came in between this collection's two calls
+        dur = _perf() - mine.gc_t0
+        mine.gc_t0 = None
+        add("host.gc", dur)
+    else:
+        sp = mine.gc_span
+        if sp is None:
+            return
+        mine.gc_span = None
+        sp.rows = info.get("collected", 0)
+        sp.__exit__(None, None, None)
+        dur = sp.dur
+    t_end = time_mod.monotonic()
+    slot = int(t_end) % GC_RECENT_S
+    held = rec.gc_recent[slot]
+    if held is None or int(held[0]) != int(t_end) or dur > held[1]:
+        rec.gc_recent[slot] = (t_end, dur, generation)
+
+
+def install_gc_hook() -> None:
+    """Installed once a process, by the first engine."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)

@@ -3,9 +3,10 @@
 MFU existed only as a post-hoc bench computation; this module is the
 runtime version: the async device pipeline (internals/device_pipeline.py)
 reports every dispatched batch (rows, real/slab tokens, useful FLOPs
-from internals/costmodel.py) and every prep/dispatch/wait/drain span
-into a process-wide rolling window, and three gauges answer "is the
-device fed RIGHT NOW":
+from internals/costmodel.py) into a process-wide rolling window, the
+span record (internals/tracing.py) hands it the duration of every
+pipeline.prep / launch / window_wait / drain / device span where the
+span closes, and three gauges answer "is the device fed RIGHT NOW":
 
   pathway_device_mfu_pct        useful FLOPs over the window's wall
                                 time vs the chip's peak (None when the
@@ -49,6 +50,7 @@ import threading
 import time
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
+from pathway_tpu.internals import tracing
 from pathway_tpu.internals.metrics import MetricsRegistry
 
 # Cheap guard read by every hook site (device_pipeline dispatch loop).
@@ -193,6 +195,26 @@ _TRACKER = UtilizationTracker()
 
 def tracker() -> UtilizationTracker:
     return _TRACKER
+
+
+def _window_feed(kind: str):
+    def sink(duration_s: float) -> None:
+        if ENABLED:
+            _TRACKER.note_span(kind, duration_s)
+
+    return sink
+
+
+# the one subscription that replaces a note_span call beside every span
+# site of the pipeline: span name -> the window's kind
+for _name, _kind in (
+    ("pipeline.prep", "prep"),
+    ("pipeline.launch", "dispatch"),
+    ("pipeline.window_wait", "wait"),
+    ("pipeline.drain", "drain"),
+    ("pipeline.device", "device"),
+):
+    tracing.subscribe(_name, _window_feed(_kind))
 
 
 def current_bound_state() -> str:

@@ -15,6 +15,7 @@ from typing import Any, Dict, List, Optional
 
 from pathway_tpu.internals import dtype as dt
 from pathway_tpu.internals.parse_graph import G
+from pathway_tpu.internals.tracing import span
 from pathway_tpu.internals.schema import (
     ColumnSchema,
     Schema,
@@ -104,19 +105,24 @@ class _FsSubject(ConnectorSubjectBase):
         # disappears can be retracted row by row
         self._file_rows: Dict[str, list] = {}
         self._recording: Optional[list] = None
+        self._rows_emitted = 0  # the `rows` of each file's connector.read span
 
-    # the three emit entry points, recorded per file while streaming
+    # the three emit entry points, counted, and recorded per file while
+    # streaming
     def next(self, **kwargs) -> None:
+        self._rows_emitted += 1
         if self._recording is not None:
             self._recording.append((None, [kwargs]))
         super().next(**kwargs)
 
     def next_batch(self, rows: List[dict]) -> None:
+        self._rows_emitted += len(rows)
         if self._recording is not None:
             self._recording.append((None, rows))
         super().next_batch(rows)
 
     def next_batch_tuples(self, values_list: List[tuple], names: List[str]) -> None:
+        self._rows_emitted += len(values_list)
         if self._recording is not None:
             self._recording.append((names, values_list))
         super().next_batch_tuples(values_list, names)
@@ -370,7 +376,12 @@ class _FsSubject(ConnectorSubjectBase):
                 if streaming:
                     self._recording = self._file_rows.setdefault(f, [])
                 try:
-                    self._emit_file(f)
+                    # read + parse of one file; the commit below, which
+                    # can wait for the engine, is outside the span
+                    with span("connector.read") as read_span:
+                        emitted = self._rows_emitted
+                        self._emit_file(f)
+                        read_span.rows = self._rows_emitted - emitted
                 finally:
                     self._recording = None
                 # commit per file: downstream batches pipeline host-side

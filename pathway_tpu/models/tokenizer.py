@@ -18,6 +18,8 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from pathway_tpu.internals.tracing import span
+
 _WORD_RE = re.compile(r"[A-Za-z0-9]+|[^\sA-Za-z0-9]")
 
 PAD_ID = 0
@@ -216,28 +218,33 @@ def encode_batch(
         # the native path matches the python tokenizer exactly only for
         # lowercased ASCII input; anything else takes the python path so
         # ids never depend on whether a compiler was available
-        native = _try_native(tokenizer, texts, max_len, batch_bucket)
+        with span("prep.tokenize", rows=len(texts)):
+            native = _try_native(tokenizer, texts, max_len, batch_bucket)
         if native is not None:
             return native
-    if pair_texts is not None:
-        encoded = [
-            tokenizer.encode_pair(a, b, max_len)
-            for a, b in zip(texts, pair_texts)
-        ]
-    else:
-        encoded = [tokenizer.encode(t, max_len) for t in texts]
-    longest = max((len(e) for e in encoded), default=1)
-    seq_len = seq_bucket_length(longest, maximum=max_len)
-    batch = len(encoded)
-    padded_batch = bucket_length(max(batch, 1), minimum=8, maximum=1 << 16) if batch_bucket else batch
-    pad_id = getattr(tokenizer, "pad_id", PAD_ID)
-    dtype = _wire_dtype(tokenizer)
-    ids = np.full((padded_batch, seq_len), pad_id, dtype=dtype)
-    mask = np.zeros((padded_batch, seq_len), dtype=dtype)
-    for i, e in enumerate(encoded):
-        e = e[:seq_len]
-        ids[i, : len(e)] = e
-        mask[i, : len(e)] = 1
+    # two spans a batch (never one a text): the per-text encode loop, and
+    # the slab fill after it
+    with span("prep.tokenize", rows=len(texts)):
+        if pair_texts is not None:
+            encoded = [
+                tokenizer.encode_pair(a, b, max_len)
+                for a, b in zip(texts, pair_texts)
+            ]
+        else:
+            encoded = [tokenizer.encode(t, max_len) for t in texts]
+    with span("prep.pack", rows=len(texts)):
+        longest = max((len(e) for e in encoded), default=1)
+        seq_len = seq_bucket_length(longest, maximum=max_len)
+        batch = len(encoded)
+        padded_batch = bucket_length(max(batch, 1), minimum=8, maximum=1 << 16) if batch_bucket else batch
+        pad_id = getattr(tokenizer, "pad_id", PAD_ID)
+        dtype = _wire_dtype(tokenizer)
+        ids = np.full((padded_batch, seq_len), pad_id, dtype=dtype)
+        mask = np.zeros((padded_batch, seq_len), dtype=dtype)
+        for i, e in enumerate(encoded):
+            e = e[:seq_len]
+            ids[i, : len(e)] = e
+            mask[i, : len(e)] = 1
     return ids, mask
 
 
@@ -281,44 +288,46 @@ def pack_batch(
     the row count buckets like a sequence axis — packed rows are never
     mesh-sharded, so the power-of-two batch contract does not apply.
     """
-    encoded = [tokenizer.encode(t, max_len) for t in texts]
-    longest = max((len(e) for e in encoded), default=1)
-    slab = max(1, int(token_budget))
-    if longest > slab:
-        slab = seq_bucket_length(longest, maximum=max(max_len, longest))
-    rows: List[List[List[int]]] = []
-    used: List[int] = []
-    slots: List[Tuple[int, int]] = []
-    for e in encoded:
-        need = len(e)
-        row = -1
-        for r in range(len(rows)):
-            if used[r] + need <= slab and len(rows[r]) < max_segments:
-                row = r
-                break
-        if row < 0:
-            rows.append([])
-            used.append(0)
-            row = len(rows) - 1
-        slots.append((row, len(rows[row])))
-        rows[row].append(e)
-        used[row] += need
-    n_rows = max(len(rows), 1)
-    padded_rows = (
-        seq_bucket_length(n_rows, minimum=8, maximum=1 << 16)
-        if row_bucket
-        else n_rows
-    )
-    pad_id = getattr(tokenizer, "pad_id", PAD_ID)
-    dtype = _wire_dtype(tokenizer)
-    ids = np.full((padded_rows, slab), pad_id, dtype=dtype)
-    seg = np.zeros((padded_rows, slab), dtype=dtype)
-    for r, docs in enumerate(rows):
-        at = 0
-        for s, e in enumerate(docs):
-            ids[r, at : at + len(e)] = e
-            seg[r, at : at + len(e)] = s + 1
-            at += len(e)
+    with span("prep.tokenize", rows=len(texts)):
+        encoded = [tokenizer.encode(t, max_len) for t in texts]
+    with span("prep.pack", rows=len(texts)):
+        longest = max((len(e) for e in encoded), default=1)
+        slab = max(1, int(token_budget))
+        if longest > slab:
+            slab = seq_bucket_length(longest, maximum=max(max_len, longest))
+        rows: List[List[List[int]]] = []
+        used: List[int] = []
+        slots: List[Tuple[int, int]] = []
+        for e in encoded:
+            need = len(e)
+            row = -1
+            for r in range(len(rows)):
+                if used[r] + need <= slab and len(rows[r]) < max_segments:
+                    row = r
+                    break
+            if row < 0:
+                rows.append([])
+                used.append(0)
+                row = len(rows) - 1
+            slots.append((row, len(rows[row])))
+            rows[row].append(e)
+            used[row] += need
+        n_rows = max(len(rows), 1)
+        padded_rows = (
+            seq_bucket_length(n_rows, minimum=8, maximum=1 << 16)
+            if row_bucket
+            else n_rows
+        )
+        pad_id = getattr(tokenizer, "pad_id", PAD_ID)
+        dtype = _wire_dtype(tokenizer)
+        ids = np.full((padded_rows, slab), pad_id, dtype=dtype)
+        seg = np.zeros((padded_rows, slab), dtype=dtype)
+        for r, docs in enumerate(rows):
+            at = 0
+            for s, e in enumerate(docs):
+                ids[r, at : at + len(e)] = e
+                seg[r, at : at + len(e)] = s + 1
+                at += len(e)
     return ids, seg, slots
 
 

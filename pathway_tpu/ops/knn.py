@@ -26,6 +26,7 @@ import numpy as np
 
 from pathway_tpu.internals import memtrack
 from pathway_tpu.internals import serving as _serving
+from pathway_tpu.internals.tracing import span
 
 
 def _format_rows(scores, idx, key_of_slot) -> list:
@@ -736,10 +737,14 @@ class FusedEmbedSearch:
             ids = jax.device_put(ids, sharding)
             second = jax.device_put(second, sharding)
             shards = [self.backend.dp_shard_of(k) for k in keys]
+        # launch.encode / launch.scatter: children of the pipeline's
+        # pipeline.launch span (its seq and epoch are inherited); what is
+        # left of the parent is the eager glue between the two programs
         if kind in ("packed", "packed_dp"):
-            pooled = self.encoder.lm.encode_packed(
-                ids, second, PACK_MAX_SEGMENTS, params=self._params()
-            )
+            with span("launch.encode"):
+                pooled = self.encoder.lm.encode_packed(
+                    ids, second, PACK_MAX_SEGMENTS, params=self._params()
+                )
             rows = np.fromiter(
                 (r for r, _ in slots), dtype=np.int64, count=len(slots)
             )
@@ -748,9 +753,12 @@ class FusedEmbedSearch:
             )
             emb = pooled[rows, segs]  # device-side gather, [B, d]
         else:
-            emb = self.encoder.lm(ids, second)[: len(keys)]
+            with span("launch.encode"):
+                emb = self.encoder.lm(ids, second)
+            emb = emb[: len(keys)]
         if keys:
-            self.index.add_batch(keys, emb, shards=shards)
+            with span("launch.scatter", rows=len(keys)):
+                self.index.add_batch(keys, emb, shards=shards)
         return emb
 
     def search_texts(self, texts, k: int) -> list:

@@ -286,11 +286,6 @@ class _FusedKnnIndexImpl(IndexImpl):
         — the snapshot / rollback / failover / finish contract."""
         self._sync_pipeline(full=True)
 
-    def take_aux_spans(self):
-        if self._pipeline is None:
-            return []
-        return self._pipeline.take_aux_spans()
-
     def add_many(self, keys, values, metas) -> None:
         from pathway_tpu.internals.device_pipeline import DevicePipelineError
 

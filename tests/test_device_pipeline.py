@@ -329,6 +329,9 @@ def test_pipeline_status_and_gauges():
         _FusedKnnIndexImpl,
     )
 
+    from pathway_tpu.internals import tracing
+
+    tracing.reset_spans()
     impl = _FusedKnnIndexImpl(_encoder("status-tiny"), "cos", 32)
     texts = [f"india doc{i} juliet kilo" for i in range(16)]
     with _env(
@@ -348,8 +351,17 @@ def test_pipeline_status_and_gauges():
         assert "pathway_device_pad_waste_ratio" in rendered
         assert "pathway_device_pipeline_queue_depth" in rendered
         assert "pathway_device_pipeline_occupancy" in rendered
-        # aux spans attribute host prep vs device dispatch
-        spans = impl.take_aux_spans()
-        kinds = {name for name, _t0, _dur, _rows in spans}
-        assert "pipeline:prep" in kinds
-        assert "pipeline:dispatch" in kinds
+        # the span record attributes host prep vs device launch, with the
+        # rows and the submission number of each chunk
+        spans = [
+            ev for ev in tracing.export_span_events()
+            if ev[3].startswith("knn-ingest")
+        ]
+        by_name = {}
+        for _k, _w, name, _thread, _ts, _dur, seq, _ep, _parent, rows in spans:
+            by_name.setdefault(name, []).append((seq, rows))
+        assert sorted(by_name["pipeline.prep"]) == [(1, 8), (2, 8)]
+        assert sorted(by_name["pipeline.launch"]) == [(1, 8), (2, 8)]
+        assert {"launch.encode", "launch.scatter", "prep.tokenize",
+                "prep.pack"} <= set(by_name)
+        assert status["prep_workers"] >= 2

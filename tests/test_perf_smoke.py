@@ -11,6 +11,13 @@ smoke tests by design: fast enough for tier-1, no timing assertions
 
 from __future__ import annotations
 
+import functools
+import json
+import os
+import statistics
+import subprocess
+import sys
+
 import pytest
 
 import pathway_tpu as pw
@@ -84,65 +91,67 @@ def test_columnar_flatten_selected_with_live_counters():
     assert stats["VectorFlattenNode"]["batches_processed"] > 0
 
 
+@functools.lru_cache(maxsize=None)
+def _overhead_readings(round_: int) -> dict:
+    """arm -> [the reading of each of nine child interpreters]: tests/
+    _overhead_guard.py once per string-hash seed, one after the other."""
+    guard = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         "_overhead_guard.py")
+    readings: dict = {}
+    for seed in range(9 * round_, 9 * round_ + 9):
+        out = subprocess.run(
+            [sys.executable, guard], capture_output=True, text=True,
+            timeout=300,
+            env={**os.environ, "PYTHONHASHSEED": str(seed),
+                 "JAX_PLATFORMS": "cpu"},
+        )
+        assert out.returncode == 0, out.stderr[-2000:]
+        for arm, ratio in json.loads(out.stdout.splitlines()[-1]).items():
+            readings.setdefault(arm, []).append(ratio)
+    return readings
+
+
 @pytest.mark.perf_smoke
-def test_observability_overhead_under_5pct():
-    """The metrics layer runs unconditionally, so its cost on the engine
-    microbench loop (source -> 3 rowwise maps, hundreds of rows/tick) must
-    stay under 5% vs `Engine(metrics=False)`.  Min-of-N interleaved
-    timings keep scheduler noise out of the ratio.
+@pytest.mark.parametrize("arm", ["metrics", "metrics_and_span_record"])
+def test_observability_overhead_under_5pct(arm):
+    """The metrics layer and the span record run unconditionally, so their
+    cost on the engine microbench loop (source -> 3 rowwise maps, hundreds
+    of rows/tick) must stay under 5% vs `Engine(metrics=False)`.
 
-    GC is quiesced around the timed loops for the same reason
-    `Engine.run_static` calls `_gc_quiesce`: threshold-triggered cyclic
-    collections rescan the process's entire live heap, so embedded in a
-    large test suite they'd bill suite-wide GC cost to whichever arm
-    happens to allocate the triggering object."""
-    import gc
-    from time import perf_counter
+    Two arms.  `metrics`: per-node histograms, flight recorder and the
+    `engine.tick` span against the bare loop.  `metrics_and_span_record`:
+    the same loop handing one batch a tick to a DevicePipeline (its spans
+    on the prep and dispatch threads, the submission's epoch taken on
+    this one) against the bare loop with the same pipeline and the record
+    stubbed out of it.
 
-    from pathway_tpu.engine.engine import InputQueueSource, RowwiseNode
-
-    ROWS, TICKS, REPS = 512, 40, 5
-    deltas = [(ref_scalar("k", i), (i,), 1) for i in range(ROWS)]
-
-    def ident(keys, cols):
-        return cols[0]
-
-    def run_once(metrics: bool) -> float:
-        eng = Engine(metrics=metrics)
-        src = InputQueueSource(eng)
-        node = src
-        for _ in range(3):
-            node = RowwiseNode(eng, [node], ident)
-        try:
-            time = 2
-            for _ in range(8):  # warmup (allocators, bytecode caches)
-                src.push(time, deltas)
-                eng.process_time(time)
-                time += 2
-            t0 = perf_counter()
-            for _ in range(TICKS):
-                src.push(time, deltas)
-                eng.process_time(time)
-                time += 2
-            return perf_counter() - t0
-        finally:
-            eng._gc_unfreeze()
-
-    on, off = [], []
-    gc.collect()
-    gc_was_enabled = gc.isenabled()
-    gc.disable()
-    try:
-        for _ in range(REPS):
-            on.append(run_once(True))
-            off.append(run_once(False))
-    finally:
-        if gc_was_enabled:
-            gc.enable()
-    ratio = min(on) / min(off)
-    assert ratio < 1.05, (
-        f"always-on metrics overhead {ratio:.3f}x "
-        f"(on={min(on):.4f}s off={min(off):.4f}s)"
+    How it is measured (tests/_overhead_guard.py), so that it holds on a
+    loaded machine and from one run of the suite to the next; a min of 5
+    wall-clock timings of a 16 ms loop did neither.  The clock is the
+    engine thread's own CPU time: what the loop pays, whatever else the
+    machine runs.  Both engines live side by side and take turns in
+    blocks of 10 ticks, which side first alternating, so every pair of
+    blocks sees the same machine; a reading is the median of 40 such
+    pairs' ratios, which one interrupted block cannot move.  That much
+    repeats to half a point within one process and no further: a reading
+    carries a part that is the process's own (the string-hash seed, and
+    with it the layout of every dict the loops touch: seeds 0 to 15 read
+    0.996 to 1.044 on the first arm, each steadily for as long as its
+    process lived), which no repetition inside one interpreter removes.
+    So the test takes one reading in each of nine child interpreters,
+    hash seeds 0 to 8, and judges their median; where that is not under
+    the limit (neighbours that thrash the caches raise every child's
+    reading for a while: the instrumented loop touches more memory), nine
+    more children with the next seeds, and the median of all eighteen.
+    The limit stays 5%."""
+    readings: list = []
+    for round_ in range(2):
+        readings += _overhead_readings(round_)[arm]
+        if statistics.median(readings) < 1.05:
+            break
+    assert statistics.median(readings) < 1.05, (
+        f"always-on observability overhead, one reading a child: "
+        f"{[round(r, 4) for r in readings]}"
     )
 
 
