@@ -10,9 +10,10 @@ heads, FFN 1536, vocab 30,522, bf16), in ONE process that owns the chip:
   sync     jax.block_until_ready on a matmul chain feeding a donated-
            buffer scatter may not return before the chain's physical
            lower bound (FLOPs / peak) has passed.
-  kernels  knn_topk and flash_attention compiled by Mosaic
-           (interpret=False) at the main path's shapes, against
-           lax.top_k / _reference_attention on the same chip.
+  kernels  knn_topk, flash_attention and segment_attention compiled by
+           Mosaic (interpret=False) at the main path's shapes, against
+           lax.top_k / _reference_attention / _segment_attention on the
+           same chip.
   serve    seeded corpus -> jsonl files -> pw.io.jsonlines.read(streaming)
            -> DocumentStore(SentenceTransformerEmbedder, BruteForceKnn)
            -> DocumentStoreServer.run(threaded, with_http_server): wait on
@@ -429,6 +430,46 @@ def phase_kernels(ctx: dict) -> dict:
         check(err < 5e-2, f"flash {(b, h, l, hd)} causal={causal}: err {err}")
         flash_err[f"{(b, h, l, hd)} causal={causal}"] = round(err, 5)
     facts["flash_attention_max_err"] = flash_err
+
+    # the packed ingest path's fused kernel at the e5 slab's geometry (L
+    # not a multiple of the tile, mixed segments, trailing padding, a row
+    # that is all padding) and at MiniLM's, against the dense definition
+    # with its scores at HIGHEST precision
+    from pathway_tpu.models.transformer import _segment_attention
+    from pathway_tpu.ops.kernels.segment_attention import segment_attention
+
+    slabs = (
+        [(2, 2, 40, 64), (2, 4, 256, 32)]
+        if dry
+        else [(8, 16, 504, 64), (8, 12, 256, 32)]
+    )
+    segment_err = {}
+    for b, h, l, hd in slabs:
+        rng = np.random.default_rng(l + hd)
+        qkv = jnp.asarray(
+            rng.standard_normal((b, l, 3 * h * hd)), dtype=jnp.bfloat16
+        )
+        seg = np.zeros((b, l), dtype=np.int32)
+        for r in range(b - 1):  # row r packs r + 1 documents; the last none
+            bounds = np.linspace(0, l - r * (l // 16), r + 2).astype(int)
+            for i in range(r + 1):
+                seg[r, bounds[i]:bounds[i + 1]] = i + 1
+        seg_d = jnp.asarray(seg)
+        out = segment_attention(qkv, seg_d, h, interpret=interpret)
+        q, k, v = (
+            x.astype(jnp.float32).reshape(b, l, h, hd).transpose(0, 2, 1, 3)
+            for x in jnp.split(qkv, 3, axis=-1)
+        )
+        with jax.default_matmul_precision("highest"):
+            ref = _segment_attention(q, k, v, seg_d, 1.0 / float(np.sqrt(hd)))
+        ref32 = np.asarray(ref.transpose(0, 2, 1, 3).reshape(b, l, h * hd))
+        out32 = np.asarray(out.astype(jnp.float32))
+        check(out32.shape == (b, l, h * hd), f"segment shape {out32.shape}")
+        check(np.isfinite(out32).all(), "segment attention: non-finite output")
+        err = float(np.max(np.abs(out32 - ref32)[seg > 0]))
+        check(err < 5e-2, f"segment attention {(b, h, l, hd)}: err {err}")
+        segment_err[f"{(b, h, l, hd)}"] = round(err, 5)
+    facts["segment_attention_max_err"] = segment_err
     return facts
 
 

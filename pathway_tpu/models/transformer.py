@@ -159,8 +159,12 @@ def _attention(q, k, v, mask, causal: bool, use_flash, mesh=None):
 
     if use_flash is None:
         # flash where O(L^2) score materialization hurts, dense at short L;
-        # the crossover is not measured on this machine (ROADMAP queue 3
-        # item 6: the gate has no cell on either side yet)
+        # this gate's crossover is not measured on this machine (ROADMAP
+        # queue 3 item 6: it has no cell on either side yet).  The packed
+        # side was (PR 28, `packed_attention_fused`): there dense scores
+        # lose to a kernel that keeps a slab row in VMEM from L 32-256 up,
+        # which says the crossover is low, not where it is for this
+        # kernel (f32 operands, head_dim padded to 128 lanes in HBM)
         use_flash = jax.default_backend() == "tpu" and q.shape[2] > 256
     if use_flash:
         from pathway_tpu.ops.kernels import flash_attention
@@ -178,6 +182,13 @@ def _attention(q, k, v, mask, causal: bool, use_flash, mesh=None):
     )
 
 
+def _mesh_axis(mesh, name: str, size: int):
+    """`name` if the mesh has that axis and it divides `size`, else None
+    (replicated): the shard_map spec of a kernel's batch or head axis."""
+    fits = name in mesh.axis_names and size % mesh.shape[name] == 0
+    return name if fits else None
+
+
 def _flash_attention_on_mesh(mesh, q, k, v, mask, causal: bool):
     """Mosaic kernels cannot be partitioned automatically ("wrap the call
     in a shard_map", the TPU compiler says): inside a jit that spans a
@@ -189,11 +200,8 @@ def _flash_attention_on_mesh(mesh, q, k, v, mask, causal: bool):
 
     from pathway_tpu.ops.kernels import flash_attention
 
-    def axis(name: str, size: int):
-        fits = name in mesh.axis_names and size % mesh.shape[name] == 0
-        return name if fits else None
-
-    dp, tp = axis("dp", q.shape[0]), axis("tp", q.shape[1])
+    dp = _mesh_axis(mesh, "dp", q.shape[0])
+    tp = _mesh_axis(mesh, "tp", q.shape[1])
     qkv = P(dp, tp, None, None)
     return shard_map(
         lambda q, k, v, m: flash_attention(q, k, v, m, causal=causal),
@@ -210,10 +218,17 @@ def _segment_attention(q, k, v, seg, sm_scale):
     document, 0 = padding. Mirrors `_reference_attention`'s numerics
     (f32 scores, NEG_INF additive mask, +1e-30 softmax denominator) so a
     doc packed with neighbors attends over exactly the tokens it would
-    see alone. At packed slab lengths (<=512) the O(L^2) scores are the
-    dense-MXU regime where flash loses (see `_attention`'s measured
-    gate), so no Pallas variant is needed. Pad rows produce finite
-    garbage that per-segment pooling never reads."""
+    see alone. Pad rows produce finite garbage that per-segment pooling
+    never reads.
+
+    This is the numerical definition, the path off the TPU and the
+    tests' reference. On the TPU the packed path runs
+    `ops/kernels/segment_attention.py` instead (`packed_attention_fused`
+    decides): this dense form writes the f32 scores [B,H,L,L] to HBM and
+    reads them back twice, which at the e5 ingest slab [440,16,504,64]
+    took 40.1 ms a layer, transposes included, against 5.3 ms for the
+    kernel, and at MiniLM's [320,12,256,32] 3.56 against 1.00 ms (chip
+    runs, PR 28; PERF.md section 6 has every shape)."""
     import jax.numpy as jnp
 
     from pathway_tpu.ops.kernels.flash_attention import NEG_INF
@@ -226,6 +241,60 @@ def _segment_attention(q, k, v, seg, sm_scale):
     p = jnp.exp(s - s.max(-1, keepdims=True))
     p = p / (p.sum(-1, keepdims=True) + 1e-30)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), v)
+
+
+def packed_attention_fused(config: TransformerConfig, length: int,
+                           use_flash: Optional[bool] = None) -> bool:
+    """Whether a packed slab of `length` tokens runs the fused kernel
+    (`ops/kernels/segment_attention.py`) or `_segment_attention`. Decided
+    from what the code can see: the backend and the static shape. The
+    choice is the same for every batch of one compiled shape, so the
+    launch site (`ops/knn.py`) asks again to count it. `use_flash`
+    overrides (tests run the kernel interpreted on the CPU)."""
+    if use_flash is not None:
+        return use_flash
+    import jax
+
+    from pathway_tpu.ops.kernels.segment_attention import supports
+
+    if jax.default_backend() != "tpu" or not supports(
+        length, config.hidden, config.head_dim
+    ):
+        return False
+    # Kernel / dense with its transposes, one layer, [B,H,L,hd] (chip
+    # runs, PR 28): [440,16,504,64] 5.24 / 40.05 ms, [320,12,256,32] 1.01 /
+    # 3.56 ms, [8,16,504,64] 120 / 387 us, [64,16,128,64] 140 / 200 us,
+    # [32,16,64,64] 65 / 92 us, [32,12,256,32] 111 / 115 us.  Dense wins
+    # where a row's work is a few latency-bound matmul pairs: 32-wide
+    # heads on a key axis of one 128-lane tile ([64,12,128,32] 79 / 38 us,
+    # [32,12,64,32] 42 / 19 us, [8,12,32,32] 10 / 10 us) and, by the launch
+    # floor's margin, L 32 at any width ([8,16,32,64] 15 / 10 us).
+    if config.head_dim >= 64:
+        return length > 32
+    return length > 128
+
+
+def _fused_segment_attention(qkv, seg, heads: int, mesh=None):
+    """The fused kernel over qkv [B, L, 3·hidden] as the QKV matmul left
+    it. Inside a jit that spans a mesh it runs per device under
+    shard_map, like `_flash_attention_on_mesh`: slab rows over 'dp' when
+    they divide (pack_batch_dp pads replicas to a common block), the
+    hidden axis whole on every device."""
+    from pathway_tpu.ops.kernels.segment_attention import segment_attention
+
+    if mesh is None:
+        return segment_attention(qkv, seg, heads)
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    dp = _mesh_axis(mesh, "dp", qkv.shape[0])
+    return shard_map(
+        lambda qkv, seg: segment_attention(qkv, seg, heads),
+        mesh=mesh,
+        in_specs=(P(dp, None, None), P(dp, None)),
+        out_specs=P(dp, None, None),
+        check_vma=False,
+    )(qkv, seg)
 
 
 def _packed_positions(seg):
@@ -268,7 +337,10 @@ def forward(
     distinguished by segment ids; attention is confined within segments,
     positions restart per segment, and pooling returns [B, max_segments,
     H] — one L2-normalized vector per packed doc slot. mask is ignored
-    (seg > 0 is the validity mask); causal packed decode is unsupported."""
+    (seg > 0 is the validity mask); causal packed decode is unsupported.
+    `use_flash` means there what it means unpacked: None decides from
+    backend and shape (`packed_attention_fused`), True and False force
+    the fused kernel and the dense path."""
     import jax
     import jax.numpy as jnp
 
@@ -280,6 +352,7 @@ def forward(
             raise ValueError("packed segment batching requires a bidirectional encoder")
         pos = _packed_positions(seg)
         x = params["embed"][ids] + params["pos_embed"][pos]
+        fused = packed_attention_fused(config, l, use_flash)
     else:
         x = params["embed"][ids] + params["pos_embed"][:l][None, :, :]
     if post_ln and "type_embed" in params:
@@ -302,16 +375,22 @@ def forward(
             y @ layer["qkv"].astype(compute_dtype)
             + layer["qkv_b"].astype(compute_dtype)
         )
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, l, heads, hd).transpose(0, 2, 1, 3)
-        k = k.reshape(b, l, heads, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(b, l, heads, hd).transpose(0, 2, 1, 3)
-        if seg is not None:
-            ctx = _segment_attention(q, k, v, seg, 1.0 / np.sqrt(hd))
+        if seg is not None and fused:
+            # q, k, v read in place and ctx written in [B, L, hidden]
+            ctx = _fused_segment_attention(qkv, seg, heads, mesh)
         else:
-            ctx = _attention(q, k, v, mask, config.causal, use_flash, mesh)
-        ctx = ctx.astype(compute_dtype)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, l, config.hidden)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(b, l, heads, hd).transpose(0, 2, 1, 3)
+            k = k.reshape(b, l, heads, hd).transpose(0, 2, 1, 3)
+            v = v.reshape(b, l, heads, hd).transpose(0, 2, 1, 3)
+            if seg is not None:
+                ctx = _segment_attention(q, k, v, seg, 1.0 / np.sqrt(hd))
+            else:
+                ctx = _attention(
+                    q, k, v, mask, config.causal, use_flash, mesh
+                )
+            ctx = ctx.astype(compute_dtype)
+            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, l, config.hidden)
         attn_out = (
             ctx @ layer["out"].astype(compute_dtype)
             + layer["out_b"].astype(compute_dtype)
@@ -409,7 +488,7 @@ class TransformerLM:
         # the mesh (hashable) is static: one executable per mesh and shape
         self._encode_jit = jax.jit(_fwd, static_argnames=("mesh",))
 
-        def _fwd_packed(params, ids, seg, max_segments):
+        def _fwd_packed(params, ids, seg, max_segments, mesh=None):
             import jax.numpy as jnp
 
             return forward(
@@ -419,12 +498,15 @@ class TransformerLM:
                 mask=None,
                 seg=seg.astype(jnp.int32),
                 max_segments=max_segments,
+                mesh=mesh,
             )
 
         # max_segments is a static one-hot width; callers pass a fixed
         # constant (tokenizer.PACK_MAX_SEGMENTS) so there is one compile
         # per (R, L) slab shape, same cache discipline as the classic path
-        self._packed_jit = jax.jit(_fwd_packed, static_argnums=(3,))
+        self._packed_jit = jax.jit(
+            _fwd_packed, static_argnums=(3,), static_argnames=("mesh",)
+        )
         self._mesh_params: tuple | None = None
 
     def mesh_params(self, mesh):
@@ -450,17 +532,20 @@ class TransformerLM:
         self._mesh_params = (mesh, placed)
         return placed
 
-    def encode_packed(self, ids, seg, max_segments: int, *, params=None):
+    def encode_packed(self, ids, seg, max_segments: int, *, params=None,
+                      mesh=None):
         """Packed ragged encode: ids/seg from tokenizer.pack_batch (wire
         dtypes; upcast on device). Returns [R, max_segments, H] pooled
         L2-normalized vectors; empty slots are zero. Inputs are NOT
         donated — the device-side int upcast changes the buffer dtype, so
-        XLA could never reuse them and would warn on every dispatch."""
+        XLA could never reuse them and would warn on every dispatch.
+        `mesh`: pass it whenever params or inputs are sharded over one."""
         return self._packed_jit(
             self.params if params is None else params,
             ids,
             seg,
             int(max_segments),
+            mesh=mesh,
         )
 
     def __call__(self, ids, mask, *, params=None, mesh=None):

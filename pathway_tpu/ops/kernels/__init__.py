@@ -9,7 +9,10 @@ single VMEM-resident passes:
   * flash_attention — online-softmax blocked attention (encoder + causal
     decoder), O(L) memory instead of the [L, L] score matrix;
   * knn_block_topk — streaming similarity + per-block top-k, never
-    materializing the [Q, N] score matrix in HBM.
+    materializing the [Q, N] score matrix in HBM;
+  * segment_attention (its module) — the packed ingest path's attention:
+    one slab row per grid step, segment mask, softmax and p @ v in VMEM,
+    q/k/v read in place from the QKV matmul's output.
 
 Every kernel runs `interpret=True` off-TPU so the CPU test mesh exercises
 identical code paths.

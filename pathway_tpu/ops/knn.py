@@ -26,6 +26,7 @@ import numpy as np
 
 from pathway_tpu.internals import memtrack
 from pathway_tpu.internals import serving as _serving
+from pathway_tpu.internals import tracing
 from pathway_tpu.internals.tracing import span
 
 
@@ -550,6 +551,7 @@ def _compiled_fused_packed_search(
             None,
             seg=seg.astype(jnp.int32),
             max_segments=max_segments,
+            mesh=mesh,
         )
         emb = pooled[rows, segs]  # [Q, H], device-side gather
         if mesh is not None:
@@ -725,6 +727,7 @@ class FusedEmbedSearch:
         Ordering matters: the scatter donates the previous index buffer,
         so batches must dispatch in submission order."""
         from pathway_tpu.models.tokenizer import PACK_MAX_SEGMENTS
+        from pathway_tpu.models.transformer import packed_attention_fused
 
         kind, keys, ids, second, slots = payload
         shards = None
@@ -743,8 +746,16 @@ class FusedEmbedSearch:
         if kind in ("packed", "packed_dp"):
             with span("launch.encode"):
                 pooled = self.encoder.lm.encode_packed(
-                    ids, second, PACK_MAX_SEGMENTS, params=self._params()
+                    ids, second, PACK_MAX_SEGMENTS, params=self._params(),
+                    mesh=self.index.mesh,
                 )
+            # which attention this slab's program runs: static per shape,
+            # so it is counted per batch, here, and not inside the jit
+            fused = packed_attention_fused(self.encoder.config, ids.shape[1])
+            tracing.add(
+                "launch.encode.attn_fused" if fused
+                else "launch.encode.attn_dense"
+            )
             rows = np.fromiter(
                 (r for r, _ in slots), dtype=np.int64, count=len(slots)
             )

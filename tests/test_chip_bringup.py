@@ -449,3 +449,58 @@ def test_flash_attention_runs_per_device_under_a_mesh(shape, names):
         lambda p, i, m: forward(p, config, i, m, use_flash=True, mesh=mesh)
     )(placed, ids, mask)
     assert float(np.max(np.abs(np.asarray(sharded) - np.asarray(dense)))) < 5e-3
+
+
+@pytest.mark.parametrize(
+    "shape,names", [((4,), ("dp",)), ((4, 2), ("dp", "tp")), ((8,), ("knn",))]
+)
+def test_fused_segment_attention_runs_per_device_under_a_mesh(shape, names):
+    """The packed path's kernel is a Mosaic kernel too: under a mesh
+    forward(seg=...) runs it inside shard_map, slab rows over 'dp' where
+    there is such an axis; the pooled vectors must be the dense path's."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from pathway_tpu.models.transformer import (
+        TransformerConfig,
+        forward,
+        init_params,
+        param_sharding_rules,
+    )
+
+    n = int(np.prod(shape))
+    if len(jax.devices()) < n:
+        pytest.skip(f"needs {n} devices (conftest emulates 8)")
+    config = TransformerConfig(
+        vocab_size=512, hidden=128, layers=1, heads=4, mlp_dim=128, max_len=64
+    )
+    params = init_params(jax.random.PRNGKey(0), config)
+    rng = np.random.default_rng(0)
+    ids = rng.integers(4, 512, size=(8, 40)).astype(np.int32)
+    seg = np.ones_like(ids)
+    seg[:, 17:] = 2
+    seg[3, 29:] = 0  # trailing padding
+    seg[5] = 0  # a replica's filler row
+    dense = forward(
+        params, config, ids, None, seg=seg, max_segments=2, use_flash=False
+    )
+
+    mesh = Mesh(
+        np.asarray(jax.devices()[:n], dtype=object).reshape(shape), names
+    )
+    shardings = jax.tree_util.tree_map(
+        lambda spec: NamedSharding(mesh, spec),
+        param_sharding_rules(config, mesh),
+        is_leaf=lambda x: isinstance(x, P),
+    )
+    placed = jax.device_put(params, shardings)
+    sharded = jax.jit(
+        lambda p, i, s: forward(
+            p, config, i, None, seg=s, max_segments=2, use_flash=True,
+            mesh=mesh,
+        )
+    )(placed, ids, seg)
+    assert np.isfinite(np.asarray(sharded)).all()
+    assert float(np.max(np.abs(np.asarray(sharded) - np.asarray(dense)))) < 5e-3

@@ -359,6 +359,57 @@ def test_launch_children_come_from_the_index_path(record):
     assert 0 < launch["self_s"] < launch["total_s"]
 
 
+def test_packed_batches_are_counted_by_the_attention_they_ran(
+    record, monkeypatch
+):
+    """ops/knn.py counts every packed batch under the attention its
+    compiled slab shape runs (`packed_attention_fused`: backend and
+    static shape): the dense path off the TPU; the fused kernel where the
+    choice says so (forced here, interpreted on the CPU).  Both names are
+    in /status "spans", and both paths fill the index alike."""
+    import numpy as np
+
+    from tests.test_device_pipeline import _env
+    from pathway_tpu.models import transformer
+    from pathway_tpu.models.minilm import SentenceEncoder
+    from pathway_tpu.stdlib.indexing.nearest_neighbors import (
+        _FusedKnnIndexImpl,
+    )
+
+    config = transformer.TransformerConfig(
+        vocab_size=512, hidden=128, layers=1, heads=4, mlp_dim=128, max_len=64
+    )
+    texts = [f"oscar doc{i} papa quebec romeo" for i in range(12)]
+
+    def ingest(name: str):
+        impl = _FusedKnnIndexImpl(
+            SentenceEncoder(name, config=config, max_len=32), "cos", 32
+        )
+        with _env(PATHWAY_DEVICE_PIPELINE="1", PATHWAY_PACK_TOKEN_BUDGET="64",
+                  PATHWAY_INGEST_CHUNK="4"):
+            impl.add_many(range(12), texts, [None] * 12)
+            impl.drain()
+        return np.asarray(impl.knn._buffer.astype("float32"))[:12]
+
+    assert not transformer.packed_attention_fused(config, 64)  # no TPU here
+    dense = ingest("attn-dense")
+    assert _totals("launch.encode.attn_dense")["count"] == 3
+    assert "launch.encode.attn_fused" not in tracing.spans_status()["totals"]
+
+    monkeypatch.setattr(
+        transformer, "packed_attention_fused",
+        lambda config, length, use_flash=None: True,
+    )
+    fused = ingest("attn-fused")
+    assert _totals("launch.encode.attn_fused")["count"] == 3
+    assert _totals("launch.encode.attn_dense")["count"] == 3
+    np.testing.assert_allclose(fused, dense, atol=2e-2, rtol=0)
+    status = tracing.spans_status()["totals"]
+    assert status["launch.encode.attn_fused"]["count"] == 3
+    assert status["launch.encode.attn_dense"]["count"] == 3
+    assert status["launch.encode"]["count"] == 6
+
+
 # -- /status --------------------------------------------------------------------
 
 
