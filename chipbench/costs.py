@@ -1,10 +1,9 @@
-"""The yardstick's arithmetic: chip peaks and the work an algorithm needs.
-
-A copy of the sound parts of pathway_tpu/internals/costmodel.py, kept here
-so that a later PR can change the program and not the yardstick.  Work is
-counted from shapes and token counts — what the algorithm needs, never what
-a kernel happens to execute: padding, recomputation and layout copies are
-not work.
+"""The yardstick's arithmetic that every architecture shares: chip peaks
+and the roofline.  The work a model needs (FLOPs and bytes from shapes and
+token counts) is its architecture's own file,
+`chipbench/architectures/<a>/costs.py`.  Kept here, and not taken from
+pathway_tpu/internals/costmodel.py, so that a later PR can change the
+program and not the yardstick.
 
 Peaks: Google Cloud TPU documentation, "System architecture" page of each
 TPU version (peak bf16 compute, HBM bandwidth and capacity per chip),
@@ -53,35 +52,18 @@ def peaks(device_kind: str) -> dict:
     return PEAKS[device_kind]
 
 
-def encoder_flops(model: dict, tokens: int) -> float:
-    """Forward FLOPs of one document of `tokens` real tokens: per layer
-    and token 2*(4*h*h) for the q, k, v and output projections, 2*(2*h*ffn)
-    for the MLP and 2*2*tokens*h for attention scores and mix (a document
-    attends within itself).  Norms, softmax, GELU, pooling and the
-    embedding gather are left out (under 2% at these widths)."""
-    h, ffn, layers = model["hidden"], model["mlp_dim"], model["layers"]
-    per_token = layers * (2 * (4 * h * h + 2 * h * ffn) + 4 * tokens * h)
-    return float(tokens) * per_token
+DTYPE_BYTES = {"float32": 4, "bfloat16": 2}
 
 
-def encoder_layer_params(model: dict) -> int:
-    """Parameters of the encoder's layers: the matrices and biases of
-    attention and MLP and two LayerNorms a layer."""
-    h, ffn = model["hidden"], model["mlp_dim"]
-    return model["layers"] * (4 * h * h + 2 * h * ffn + 9 * h + ffn)
-
-
-def encoder_weight_bytes(model: dict, bytes_per_param: int = 2) -> float:
-    """Bytes of the layer weights one encoder program has to read once, in
-    the type it computes in (bf16): the matrices and biases of every layer.
-    The embedding table is gathered, not streamed, and is left out."""
-    return float(bytes_per_param * encoder_layer_params(model))
-
-
-def encoder_activation_bytes(model: dict, tokens: int) -> float:
-    """The least a document's activations move through HBM: its hidden
-    states written and read once per layer, in bf16."""
-    return float(2 * 2 * tokens * model["hidden"] * model["layers"])
+def dtype_bytes(name: str) -> int:
+    """Bytes an element of the named type takes on the chip (a store's
+    `index_dtype`).  A type that is not in the table is an error."""
+    if name not in DTYPE_BYTES:
+        raise LookupError(
+            f"dtype {name!r} has no entry in chipbench/costs.py DTYPE_BYTES "
+            f"(known: {sorted(DTYPE_BYTES)})"
+        )
+    return DTYPE_BYTES[name]
 
 
 def roofline_seconds(flops: float, nbytes: float, device_kind: str) -> dict:

@@ -100,12 +100,12 @@ def exit_when_the_server_thread_dies() -> None:
 
 
 def dry_cut(cell: spec.Cell) -> spec.Cell:
-    """The CPU rehearsal's sizes: two layers, a small store, small files.
-    Widths stay as published."""
+    """The CPU rehearsal's sizes: the architecture's own cut of the model
+    (never a width), a small store, small files."""
     import dataclasses
 
     config = json.loads(json.dumps(cell.config))
-    config["model"]["layers"] = 2
+    config["model"] = cell.arch.costs.dry_cut(config["model"])
     config["store"]["reserved_space"] = 8192
     tr = json.loads(json.dumps(cell.traffic))
     tr["docs_per_file"] = 64
@@ -123,31 +123,20 @@ class Server:
     def __init__(self, cell: spec.Cell, seed: int, docs_dir: str,
                  refresh_interval_s: float | None = None):
         import pathway_tpu as pw
-        from pathway_tpu.models.transformer import TransformerConfig
         from pathway_tpu.stdlib.indexing.nearest_neighbors import BruteForceKnnFactory
         from pathway_tpu.xpacks.llm.document_store import DocumentStore
-        from pathway_tpu.xpacks.llm.embedders import SentenceTransformerEmbedder
         from pathway_tpu.xpacks.llm.servers import DocumentStoreServer
 
-        model, store = cell.config["model"], cell.config["store"]
-        tconfig = TransformerConfig(
-            vocab_size=model["vocab_size"], hidden=model["hidden"],
-            layers=model["layers"], heads=model["heads"], mlp_dim=model["mlp_dim"],
-            max_len=model["max_position_embeddings"], causal=False,
-            pooling=model["pooling"], dtype=model["dtype"],
-            norm_style=model["norm_style"],
-        )
+        store = cell.config["store"]
+        self.program = cell.arch.program
         table = pw.io.jsonlines.read(
             docs_dir, schema=pw.schema_from_types(data=str), mode="streaming",
             batch_per_file=True,
             refresh_interval=refresh_interval_s or cell.traffic["refresh_interval_s"],
         )
-        embedder = SentenceTransformerEmbedder(
-            model["name"], config=tconfig, max_len=store["max_len"],
-            seed=reference.weight_seed(seed),
-        )
         factory = BruteForceKnnFactory(
-            embedder=embedder, reserved_space=store["reserved_space"]
+            embedder=self.program.embedder(cell.config["model"], store, seed),
+            reserved_space=store["reserved_space"],
         )
         self.port = free_port()
         self.k = int(store["k"])
@@ -214,14 +203,13 @@ class Server:
     def stop(self) -> None:
         import pathway_tpu as pw
         from pathway_tpu.internals.runner import last_engine
-        from pathway_tpu.models import minilm
 
         last_engine().terminate_flag.set()
         self.thread.join(timeout=60)
         if self.thread.is_alive():
             raise RuntimeError("the server did not stop")
         pw.G.clear()
-        minilm._model_cache.clear()
+        self.program.release()
         gc.collect()
 
 
@@ -528,7 +516,7 @@ def ingest_cell(session: Session) -> dict:
     # -- compare with the plain reference -----------------------------------------
     t_ref = time.monotonic()
     store, model = cell.config["store"], cell.config["model"]
-    encoder = reference.Encoder(model, args.seed, max_len=store["max_len"])
+    encoder = cell.arch.reference.Encoder(model, args.seed, max_len=store["max_len"])
     numbers = compare.compare(
         queries, own_answers, probes, probe_answers, pool, encoder.embed, store["k"]
     )
@@ -572,7 +560,8 @@ def emit(session: Session, outcome: dict) -> None:
     else:
         reduced = session.reduced_trace()
         ctx = dict(
-            outcome["ctx"], cell=cell, device=session.device, peaks=session.peaks,
+            outcome["ctx"], cell=cell, arch=cell.arch, device=session.device,
+            peaks=session.peaks,
             trace=reduced, status_open=session.status_open,
             status_close=session.status_close,
             status_interval_s=session.status_interval_s,

@@ -1,10 +1,8 @@
 """Per-layer metric readers.  Each module has `read(ctx, **args)` and gives
 a number, or None where it finds nothing to read (never 0 for a share of a
 roofline or of a peak).  `ctx` is what chipbench/harness.py gathered in the
-traced run; chipbench/README.md lists its keys."""
-
-
-from chipbench import costs
+traced run; chipbench/README.md lists its keys.  What a model costs comes
+from the cell's architecture, `ctx["arch"].costs`."""
 
 
 def program_time(reduced: dict, programs: list) -> tuple:
@@ -19,11 +17,11 @@ def program_time(reduced: dict, programs: list) -> tuple:
 
 def serve_flops(ctx: dict) -> float:
     """What the window's answered queries need: 2*N*D scoring FLOPs a
-    query over the provisioned buffer plus the queries' encoder FLOPs."""
-    config = ctx["cell"].config
-    n, d = config["store"]["reserved_space"], config["model"]["hidden"]
+    query over the provisioned buffer plus the queries' model FLOPs."""
+    config, costs = ctx["cell"].config, ctx["arch"].costs
+    n, d = config["store"]["reserved_space"], costs.embed_dim(config["model"])
     return 2.0 * n * d * ctx["queries_answered"] + sum(
-        costs.encoder_flops(config["model"], t) for t in ctx["query_tokens"]
+        costs.flops(config["model"], t) for t in ctx["query_tokens"]
     )
 
 

@@ -1,7 +1,7 @@
 """Share of its roofline that the encoder programs reached: the least
-time the chips could take for the window's documents (chipbench/costs.py,
-from token counts) over the device time of the named programs in the
-trace, summed over chips."""
+time the chips could take for the window's documents (the architecture's
+costs, from token counts) over the device time of the named programs in
+the trace, summed over chips."""
 
 from chipbench import costs
 from chipbench.readers import program_time, window_tokens
@@ -14,13 +14,13 @@ def read(ctx: dict, programs: list):
     seconds, runs = program_time(reduced, programs)
     if seconds <= 0 or runs <= 0:
         return None
-    model = ctx["cell"].config["model"]
+    model, work = ctx["cell"].config["model"], ctx["arch"].costs
     tokens = window_tokens(ctx)
-    flops = sum(costs.encoder_flops(model, t) for t in tokens)
+    flops = sum(work.flops(model, t) for t in tokens)
     # every run of the program reads the layer weights once; under dp each
     # chip runs it, and `runs` counts every chip's
-    nbytes = runs * costs.encoder_weight_bytes(model) + sum(
-        costs.encoder_activation_bytes(model, t) for t in tokens
+    nbytes = runs * work.weight_bytes(model) + sum(
+        work.activation_bytes(model, t) for t in tokens
     )
     least = costs.roofline_seconds(flops, nbytes, ctx["device"]["kind"])
     return 100.0 * least["seconds"] / seconds

@@ -1,7 +1,9 @@
 """What the fullest chip holds that is filled, beside the peak the result
 line's `device` reports: the index rows written so far (set-up's and the
-window's, a chip's share under dp) plus the encoder's parameters as the
-program keeps them (float32)."""
+window's, a chip's share under dp; a row is its vector in the store's
+`index_dtype` and a byte that says it is valid) plus the model's
+parameters as the program keeps them (the architecture's
+`resident_param_bytes`)."""
 
 
 from chipbench import costs
@@ -15,11 +17,6 @@ def read(ctx: dict):
     if rows is None:
         return None
     config, chips = ctx["cell"].config, ctx["cell"].chips
-    m = config["model"]
-    h = m["hidden"]
-    params = (
-        m["vocab_size"] * h + m["max_position_embeddings"] * h + 2 * h
-        + costs.encoder_layer_params(m)
-    )
-    row_bytes = 4 * h + 1
-    return (int(rows) * row_bytes / chips + 4 * params) / 1e9
+    work, model = ctx["arch"].costs, config["model"]
+    row_bytes = costs.dtype_bytes(config["store"]["index_dtype"]) * work.embed_dim(model) + 1
+    return (int(rows) * row_bytes / chips + work.resident_param_bytes(model)) / 1e9

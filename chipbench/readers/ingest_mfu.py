@@ -1,17 +1,15 @@
-"""The whole step's share of the chips' peak: useful encoder FLOPs of
-every document the window ingested over window x chips x peak FLOP/s."""
+"""The whole step's share of the chips' peak: useful model FLOPs (the
+architecture's count) of every document the window ingested over
+window x chips x peak FLOP/s."""
 
-from chipbench import costs
 from chipbench.readers import window_tokens
 
 
 def read(ctx: dict):
     if ctx["peaks"] is None:
         return None
-    model = ctx["cell"].config["model"]
-    flops = sum(
-        costs.encoder_flops(model, t) for t in window_tokens(ctx)
-    )
+    model, work = ctx["cell"].config["model"], ctx["arch"].costs
+    flops = sum(work.flops(model, t) for t in window_tokens(ctx))
     if flops <= 0:
         return None
     peak = ctx["peaks"]["flops"] * ctx["cell"].chips

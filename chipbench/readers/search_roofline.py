@@ -1,7 +1,7 @@
 """Share of its roofline that the fused embed-and-search programs
 reached.  The least a batch can take: one read of the provisioned buffer
-(every row is scored, filled or not) against 2*Q*N*D scoring FLOPs plus
-the queries' encoder FLOPs, whichever binds (chipbench/costs.py)."""
+(every row is scored, filled or not) and of the model's weights against
+2*Q*N*D scoring FLOPs plus the queries' model FLOPs, whichever binds."""
 
 from chipbench import costs
 from chipbench.readers import program_time, serve_flops
@@ -14,8 +14,12 @@ def read(ctx: dict, programs: list):
     seconds, runs = program_time(reduced, programs)
     if seconds <= 0 or runs <= 0:
         return None
-    config = ctx["cell"].config
-    n, d = config["store"]["reserved_space"], config["model"]["hidden"]
-    nbytes = runs * (4.0 * n * d + costs.encoder_weight_bytes(config["model"]))
+    config, work = ctx["cell"].config, ctx["arch"].costs
+    store, model = config["store"], config["model"]
+    buffer_bytes = (
+        float(costs.dtype_bytes(store["index_dtype"]))
+        * store["reserved_space"] * work.embed_dim(model)
+    )
+    nbytes = runs * (buffer_bytes + work.weight_bytes(model))
     least = costs.roofline_seconds(serve_flops(ctx), nbytes, ctx["device"]["kind"])
     return 100.0 * least["seconds"] / seconds

@@ -1,4 +1,4 @@
-"""The whole step's share of the chip's peak: encoder plus scoring FLOPs
+"""The whole step's share of the chip's peak: model plus scoring FLOPs
 (2*N*D a query over the provisioned buffer) of every query answered in the
 window over window x chips x peak FLOP/s."""
 

@@ -20,7 +20,7 @@ import time
 
 import numpy as np
 
-from chipbench import compare, reference, spec, traffic
+from chipbench import compare, spec, traffic
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -212,7 +212,7 @@ def retrieve_cell(session) -> dict:
     rng = np.random.default_rng([int(args.seed), 4])
     pool = [docs[requests[i]["source"]] for i in kept]
     pool += [docs[int(g)] for g in rng.integers(0, len(docs), size=int(tr["pool_docs"]))]
-    encoder = reference.Encoder(model, args.seed, max_len=store["max_len"])
+    encoder = cell.arch.reference.Encoder(model, args.seed, max_len=store["max_len"])
     numbers = compare.compare([], [], probes, answers, pool, encoder.embed, k)
     control = None
     if args.control:

@@ -3,13 +3,18 @@
 Everything that belongs to one configuration, one traffic mix or one
 per-layer metric is a file of its own:
 
-  configuration <c>   chipbench/configs/<c>.json      (its `file` entry)
+  configuration <c>   chipbench/configs/<c>.json      (its `file` entry),
+                      which names its architecture
+  architecture <a>    chipbench/architectures/<a>/program.py, reference.py
+                      and costs.py: how the program is built for a
+                      configuration of that architecture, its plain
+                      reference, and what it costs (chipbench/README.md)
   traffic mix <t>     chipbench/traffic/<t>.json
   per-layer metric m  chipbench/metrics/<m>.json, which names a reader
                       module chipbench/readers/<reader>.py
 
-so a later PR adds a cell or a metric by adding files and entries, never
-by editing one that is there.
+so a later PR adds a cell, a metric or a model by adding files and entries,
+never by editing one that is there.
 """
 
 from __future__ import annotations
@@ -46,11 +51,66 @@ class Metric:
         return module.read(ctx, **self.args)
 
 
+ARCHITECTURE_FILES = ("program", "reference", "costs")
+
+
+class UnknownArchitecture(LookupError):
+    pass
+
+
+@dataclasses.dataclass(frozen=True)
+class Architecture:
+    """The files of one architecture, each imported when first asked for:
+    `program` alone imports pathway_tpu, and a run imports it only once
+    its host-only work has started."""
+
+    name: str
+
+    def _module(self, part: str):
+        return importlib.import_module(f"chipbench.architectures.{self.name}.{part}")
+
+    @property
+    def program(self):
+        return self._module("program")
+
+    @property
+    def reference(self):
+        return self._module("reference")
+
+    @property
+    def costs(self):
+        return self._module("costs")
+
+
+def architectures() -> list:
+    """Names of the architectures there are: the directories under
+    chipbench/architectures/ that hold the three files."""
+    base = os.path.join(HERE, "architectures")
+    return sorted(
+        d for d in os.listdir(base)
+        if all(os.path.isfile(os.path.join(base, d, part + ".py"))
+               for part in ARCHITECTURE_FILES)
+    )
+
+
+def architecture(config: dict) -> Architecture:
+    """The architecture a configuration's file names.  A missing or unknown
+    name is an error, never a default."""
+    name, known = config.get("architecture"), architectures()
+    if name not in known:
+        raise UnknownArchitecture(
+            f"configuration {config.get('name')!r} names the architecture "
+            f"{name!r}; chipbench/architectures/ has {known}"
+        )
+    return Architecture(name)
+
+
 @dataclasses.dataclass(frozen=True)
 class Cell:
     name: str
     chips: int
     config: dict
+    arch: Architecture
     traffic: dict
     end_to_end: tuple  # names of the end-to-end metrics this cell reports
     per_layer: tuple  # Metric objects this cell reports
@@ -96,10 +156,12 @@ def cell(workload: str) -> Cell:
                 args=meta.get("args", {}),
             )
         )
+    config = _load(os.path.join(ROOT, config_entry["file"]))
     return Cell(
         name=workload,
         chips=int(entry["chips"]),
-        config=_load(os.path.join(ROOT, config_entry["file"])),
+        config=config,
+        arch=architecture(config),
         traffic=traffic_file(entry["traffic"]),
         end_to_end=end_to_end,
         per_layer=tuple(per_layer),
