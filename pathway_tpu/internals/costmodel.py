@@ -222,7 +222,15 @@ def encoder_useful_flops(
 
 def encoder_flops_for_config(config: Any, real_tokens: int, rows: int) -> float:
     """`encoder_useful_flops` with the geometry read off a
-    TransformerConfig (hidden / mlp_dim / layers attributes)."""
+    TransformerConfig (hidden / mlp_dim / layers attributes).  A
+    configuration whose FLOPs a token are not a dense encoder's says them
+    itself (`active_flops_per_token(seq)`, models/moe_mla.py: the experts
+    held and selected, not every parameter)."""
+    per_token = getattr(config, "active_flops_per_token", None)
+    if per_token is not None:
+        if real_tokens <= 0:
+            return 0.0
+        return real_tokens * float(per_token(real_tokens / max(rows, 1)))
     return encoder_useful_flops(
         real_tokens,
         rows,

@@ -122,16 +122,24 @@ def _rms_norm(x, scale, eps):
     return (x32 * (1.0 / jnp.sqrt(var + eps)) * scale).astype(x.dtype)
 
 
-def _rope(x, positions, theta):
-    """x: [B, H, L, D]; positions: [B, L] absolute token positions."""
+def _rope(x, positions, theta, *, freqs=None, interleaved=False):
+    """x: [B, H, L, D]; positions: [B, L] absolute token positions.
+    `freqs` [D/2] replaces the plain theta ladder (a scaled one, as YaRN's).
+    `interleaved`: the pairs are (x[2i], x[2i+1]), as the DeepSeek family
+    stores them, and not (x[i], x[i+D/2]); the output is in the split
+    layout either way, which q.k does not see as long as q and k agree."""
     import jax.numpy as jnp
 
     d = x.shape[-1]
     half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if freqs is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     angles = positions[:, None, :, None].astype(jnp.float32) * freqs  # B,1,L,half
     cos, sin = jnp.cos(angles), jnp.sin(angles)
-    x1, x2 = x[..., :half], x[..., half:]
+    if interleaved:
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+    else:
+        x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
     )

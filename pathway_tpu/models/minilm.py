@@ -23,7 +23,7 @@ from pathway_tpu.models.tokenizer import (
 from pathway_tpu.models.transformer import (
     MINILM_L6,
     TransformerConfig,
-    TransformerLM,
+    model_module,
 )
 
 _model_cache: dict = {}
@@ -58,7 +58,7 @@ class SentenceEncoder:
         self.tokenizer = tokenizer or HashTokenizer(
             vocab_size=self.config.vocab_size
         )
-        self.lm = TransformerLM(self.config, params=params, seed=seed)
+        self.lm = model_module(self.config).LM(self.config, params=params, seed=seed)
         if mesh is not None:
             axis = "dp" if "dp" in mesh.axis_names else mesh.axis_names[0]
             n_dev = mesh.shape[axis]

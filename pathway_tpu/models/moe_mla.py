@@ -1,0 +1,642 @@
+"""Latent-attention + shared-expert MoE trunk (the DeepSeek-V3 family's
+layer; A.X-K1's published sizes are the defaults), as ONE expert-parallel
+rank runs it: the document store's embedder on the ingest path.
+
+What one rank of `ep_size` holds of a layer: attention, norms, router and
+the shared expert whole (they are replicated), and `experts_held` of the
+`n_routed_experts` routed experts, from `expert_offset`.  The router keeps
+its published width and its experts per token; the rank computes its own
+experts' part of the result for the tokens routed to them, and that
+partial sum (plus the shared expert and the residual) goes on to the next
+layer.  Nothing stands in for the absent ranks or their exchange.
+
+Per layer, x [T, hidden], every norm RMSNorm, no biases:
+
+  h = norm(x); c_q = norm(h W_qa); q = c_q W_qb -> heads of [nope | rope]
+  [c_kv | k_rope] = h W_kva; c_kv = norm(c_kv); c_kv W_kvb -> heads of
+  [k_nope | v]; RoPE (YaRN ladder, interleaved pairs) on q_rope and on
+  k_rope, which all heads share; positions restart at every segment
+  score = (q_nope.k_nope + q_rope.k_rope) * scale; token i sees j iff same
+  segment and j <= i; f32 softmax; x += concat_heads(p v) W_o
+  h = norm(x); the leading dense layers: x += (silu(h W_g) * (h W_u)) W_d
+  the others: s = sigmoid(h W_r); I = top-k(s); w_e = factor * s_e /
+  sum_{i in I} s_i; x += sum_{e in I, e held} w_e FFN_e(h) + FFN_shared(h)
+
+then a final norm, the mean over a segment's tokens and L2 normalisation,
+as `transformer.forward` pools.  This is the prefill form of latent
+attention: no head, no latent cache, no generation (PERF.md section 7).
+
+Program shape: the q and kv up-projections are kept as separate matrices
+per part (`wq_b_nope` / `wq_b_rope`, `wk_b` / `wv_b`: the published
+matrices' columns, regrouped once at init), so that every operand of the
+attention kernel (`ops/kernels/mla_attention.py`) leaves its matmul in the
+layout the kernel reads.  The held experts run as grouped matmuls
+(`jax.lax.ragged_dot`, on the TPU the device op `ragged-dot`) over a
+static buffer of the selected (token, held expert) pairs sorted by expert;
+pairs beyond the buffer are counted (`overflow`), never dropped silently.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+import weakref
+from collections import deque
+from typing import Any, Dict, Optional
+
+import numpy as np
+
+from pathway_tpu.models.decoder import _rms_norm, _rope
+from pathway_tpu.models.transformer import TransformerLM, _packed_positions
+
+
+@dataclasses.dataclass(frozen=True)
+class MoeMlaConfig:
+    # `vocab_size` is the rows of the embedding this rank holds (a sliced
+    # vocabulary is a smaller vocabulary: the tokenizer draws from it)
+    vocab_size: int = 20480
+    hidden: int = 7168
+    layers: int = 6
+    first_k_dense: int = 1
+    heads: int = 64
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    dense_mlp_dim: int = 18432
+    expert_mlp_dim: int = 2048
+    n_routed_experts: int = 192
+    experts_per_token: int = 8
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 2.5
+    experts_held: int = 12
+    expert_offset: int = 0
+    rope_theta: float = 10000.0
+    rope_factor: float = 32.0
+    rope_original_max_len: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    norm_eps: float = 1e-6
+    max_len: int = 512
+    dtype: str = "bfloat16"  # what the matmuls compute in
+    param_dtype: str = "bfloat16"  # what the parameters are resident in
+    pooling: str = "mean"
+    causal: bool = True
+
+    @property
+    def expert_layers(self) -> int:
+        return self.layers - self.first_k_dense
+
+    @property
+    def sm_scale(self) -> float:
+        """(nope + rope)^-0.5 times YaRN's mscale squared."""
+        m = 0.1 * self.rope_mscale_all_dim * math.log(self.rope_factor) + 1.0
+        return float((self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5 * m * m)
+
+    def active_flops_per_token(self, seq: float) -> float:
+        """Forward FLOPs one token of a `seq`-token document needs on this
+        rank (`internals/costmodel.py` multiplies by the real tokens): the
+        five attention matrices, causal attention within the document (half
+        the square), the dense layers, and for an expert layer the router,
+        the shared expert and the expected held pairs."""
+        h, heads = self.hidden, self.heads
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        proj = (
+            h * self.q_lora_rank + self.q_lora_rank * heads * qk
+            + h * (self.kv_lora_rank + self.qk_rope_head_dim)
+            + self.kv_lora_rank * heads * (self.qk_nope_head_dim + self.v_head_dim)
+            + heads * self.v_head_dim * h
+        )
+        attn = heads * (qk + self.v_head_dim) * seq / 2.0
+        held = self.experts_per_token * self.experts_held / self.n_routed_experts
+        expert = 3 * h * self.expert_mlp_dim
+        moe = h * self.n_routed_experts + (self.n_shared_experts + held) * expert
+        dense = 3 * h * self.dense_mlp_dim
+        return 2.0 * (
+            self.layers * (proj + attn)
+            + self.first_k_dense * dense + self.expert_layers * moe
+        )
+
+
+TINY = MoeMlaConfig(
+    vocab_size=512, hidden=128, layers=3, heads=4, q_lora_rank=48,
+    kv_lora_rank=32, dense_mlp_dim=256, expert_mlp_dim=64,
+    n_routed_experts=16, experts_per_token=4, experts_held=4, max_len=128,
+    dtype="float32", param_dtype="float32",
+)
+
+
+def _dtype(name: str):
+    import jax.numpy as jnp
+
+    return jnp.bfloat16 if name == "bfloat16" else jnp.float32
+
+
+@functools.lru_cache(maxsize=None)
+def _normal(shape: tuple, fan_in: int, store: str):
+    """The program that makes one leaf from a key: N(0, 1/fan_in) drawn in
+    float32, kept in `store`.  One program a shape, so the float32 draw
+    never reaches HBM."""
+    import jax
+    import jax.numpy as jnp
+
+    def make(key):
+        w = jax.random.normal(key, shape, dtype=jnp.float32) / np.sqrt(fan_in)
+        return w.astype(_dtype(store))
+
+    return jax.jit(make)
+
+
+def init_params(rng, config: MoeMlaConfig) -> Dict[str, Any]:
+    """Random weights, made leaf by leaf in float32 and kept in
+    `param_dtype`.  The recipe (chipbench's reference repeats it from the
+    configuration file's `init`, not from here): split the key into
+    2 + layers; key 0 the embedding ~ N(0, 1); layer i splits key 2+i into
+    10; every matrix ~ N(0, 1/fan_in), so that activations keep unit
+    scale and the router's logits have a spread of order 1; expert e of a
+    layer (its global index) takes `fold_in(key 9, e)` split into 3, so a
+    rank's experts are the uncut model's."""
+    import jax
+    import jax.numpy as jnp
+
+    c = config
+    h, heads = c.hidden, c.heads
+    qk = c.qk_nope_head_dim + c.qk_rope_head_dim
+    kv = c.qk_nope_head_dim + c.v_head_dim
+
+    def dense(key, shape, fan_in=None):
+        return _normal(tuple(shape), shape[-2] if fan_in is None else fan_in, c.param_dtype)(key)
+
+    def per_head(w, widths):
+        """Columns [heads x sum(widths)] regrouped part by part."""
+        parts = jnp.split(
+            w.reshape(w.shape[0], heads, sum(widths)), np.cumsum(widths)[:-1], axis=2
+        )
+        return [p.reshape(w.shape[0], -1) for p in parts]
+
+    keys = jax.random.split(rng, 2 + c.layers)
+    params: Dict[str, Any] = {
+        "embed": dense(keys[0], (c.vocab_size, h), fan_in=1),
+        "ln_f": jnp.ones((h,)),
+        "layers": [],
+    }
+    for i in range(c.layers):
+        k = jax.random.split(keys[2 + i], 10)
+        wq_b_nope, wq_b_rope = per_head(
+            dense(k[1], (c.q_lora_rank, heads * qk)),
+            (c.qk_nope_head_dim, c.qk_rope_head_dim),
+        )
+        wk_b, wv_b = per_head(
+            dense(k[3], (c.kv_lora_rank, heads * kv)),
+            (c.qk_nope_head_dim, c.v_head_dim),
+        )
+        layer = {
+            "ln1": jnp.ones((h,)), "ln2": jnp.ones((h,)),
+            "q_ln": jnp.ones((c.q_lora_rank,)),
+            "kv_ln": jnp.ones((c.kv_lora_rank,)),
+            "wq_a": dense(k[0], (h, c.q_lora_rank)),
+            "wq_b_nope": wq_b_nope, "wq_b_rope": wq_b_rope,
+            "wkv_a": dense(k[2], (h, c.kv_lora_rank + c.qk_rope_head_dim)),
+            "wk_b": wk_b, "wv_b": wv_b,
+            "wo": dense(k[4], (heads * c.v_head_dim, h)),
+        }
+        if i < c.first_k_dense:
+            f = c.dense_mlp_dim
+            layer.update(
+                gate=dense(k[5], (h, f)), up=dense(k[6], (h, f)),
+                down=dense(k[7], (f, h)),
+            )
+        else:
+            f, fs = c.expert_mlp_dim, c.expert_mlp_dim * c.n_shared_experts
+            held = [
+                jax.random.split(jax.random.fold_in(k[9], c.expert_offset + e), 3)
+                for e in range(c.experts_held)
+            ]
+            layer.update(
+                router=dense(k[5], (h, c.n_routed_experts)),
+                shared_gate=dense(k[6], (h, fs)), shared_up=dense(k[7], (h, fs)),
+                shared_down=dense(k[8], (fs, h)),
+                experts_gate=jnp.stack([dense(ke[0], (h, f)) for ke in held]),
+                experts_up=jnp.stack([dense(ke[1], (h, f)) for ke in held]),
+                experts_down=jnp.stack([dense(ke[2], (f, h)) for ke in held]),
+            )
+        params["layers"].append(layer)
+    return params
+
+
+def _one_chip_only(mesh) -> None:
+    if mesh is not None:
+        raise NotImplementedError(
+            "moe_mla runs one expert-parallel rank on one chip: the exchange "
+            "across ranks, and so a mesh, is not built (PERF.md section 7)"
+        )
+
+
+def param_sharding_rules(config: MoeMlaConfig, mesh):
+    _one_chip_only(mesh)
+
+
+def yarn_freqs(config: MoeMlaConfig) -> np.ndarray:
+    """YaRN's frequency ladder [rope_dim / 2] as the DeepSeek family
+    computes it: the plain ladder where a pair turns more than
+    `beta_fast` times over the original length, the ladder divided by
+    `factor` where it turns less than `beta_slow` times, a linear ramp
+    between."""
+    dim, base = config.qk_rope_head_dim, config.rope_theta
+    plain = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def turns_at(n_rot: float) -> float:
+        return dim * math.log(config.rope_original_max_len / (n_rot * 2 * math.pi)) / (
+            2 * math.log(base)
+        )
+
+    low = max(math.floor(turns_at(config.rope_beta_fast)), 0)
+    high = min(math.ceil(turns_at(config.rope_beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (plain / config.rope_factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
+
+
+def _mla_segment_attention(q_nope, q_rope, k_nope, k_rope, v, seg, sm_scale, heads):
+    """Dense causal latent attention with a pairwise same-segment mask:
+    the numerical definition, the path off the TPU and the tests'
+    reference of `ops/kernels/mla_attention.py` (operands in its layouts).
+    Writes the f32 scores [B, H, L, L]: 3.6 GB at the ingest slab."""
+    import jax.numpy as jnp
+
+    from pathway_tpu.ops.kernels.flash_attention import NEG_INF
+
+    b, l, _ = q_nope.shape
+    split = lambda a: a.reshape(b, l, heads, -1)  # noqa: E731
+    s = jnp.einsum(
+        "bqhd,bkhd->bhqk", split(q_nope), split(k_nope),
+        preferred_element_type=jnp.float32,
+    ) + jnp.einsum(
+        "bqhd,bkd->bhqk", split(q_rope), k_rope,
+        preferred_element_type=jnp.float32,
+    )
+    at = jnp.arange(l)
+    see = (
+        (seg[:, None, :, None] == seg[:, None, None, :])
+        & (seg[:, None, :, None] > 0)
+        & (at[None, None, None, :] <= at[None, None, :, None])
+    )
+    s = jnp.where(see, s * sm_scale, NEG_INF)
+    p = jnp.exp(s - s.max(-1, keepdims=True))
+    p = p / (p.sum(-1, keepdims=True) + 1e-30)
+    ctx = jnp.einsum(
+        "bhqk,bkhd->bqhd", p.astype(v.dtype), split(v),
+        preferred_element_type=jnp.float32,
+    )
+    return ctx.reshape(b, l, -1).astype(q_nope.dtype)
+
+
+def packed_attention_fused(config: MoeMlaConfig, length: int,
+                           use_flash: Optional[bool] = None) -> bool:
+    """Whether a slab of `length` tokens runs the fused kernel or the dense
+    definition: the backend and the static shape, as
+    `transformer.packed_attention_fused` decides for the encoders (its
+    measured floor for heads of 64 lanes and more, L > 32, is taken over;
+    below it a row's scores are a few kilobytes).  The launch site asks
+    again to count the batch.  `use_flash` overrides (tests)."""
+    if use_flash is not None:
+        return use_flash
+    import jax
+
+    from pathway_tpu.ops.kernels.mla_attention import supports
+
+    return (
+        jax.default_backend() == "tpu"
+        and length > 32
+        and supports(length, config.heads, config.qk_nope_head_dim,
+                     config.qk_rope_head_dim, config.v_head_dim)
+    )
+
+
+def _attention(x, layer, config: MoeMlaConfig, pos, seg, fused: bool, freqs):
+    """The attention half of a layer, without the residual.  x: [B, L, h]."""
+    from pathway_tpu.ops.kernels.mla_attention import mla_segment_attention
+
+    c = config
+    b, l, _ = x.shape
+    dt = x.dtype
+    h = _rms_norm(x, layer["ln1"], c.norm_eps)
+    c_q = _rms_norm(h @ layer["wq_a"].astype(dt), layer["q_ln"], c.norm_eps)
+    q_nope = c_q @ layer["wq_b_nope"].astype(dt)
+    q_rope = c_q @ layer["wq_b_rope"].astype(dt)
+    kv_a = h @ layer["wkv_a"].astype(dt)
+    c_kv = _rms_norm(kv_a[..., : c.kv_lora_rank], layer["kv_ln"], c.norm_eps)
+    k_nope = c_kv @ layer["wk_b"].astype(dt)
+    v = c_kv @ layer["wv_b"].astype(dt)
+
+    def rotate(a, n_heads: int):
+        # one "batch" a token, so that the rotation needs no transposes
+        flat = a.reshape(b * l, n_heads, 1, c.qk_rope_head_dim)
+        out = _rope(flat, pos.reshape(b * l, 1), c.rope_theta, freqs=freqs,
+                    interleaved=True)
+        return out.reshape(b, l, n_heads * c.qk_rope_head_dim)
+
+    q_rope = rotate(q_rope, c.heads)
+    k_rope = rotate(kv_a[..., c.kv_lora_rank:], 1)
+    if fused:
+        ctx = mla_segment_attention(
+            q_nope, q_rope, k_nope, k_rope, v, seg, sm_scale=c.sm_scale
+        )
+    else:
+        ctx = _mla_segment_attention(
+            q_nope, q_rope, k_nope, k_rope, v, seg, c.sm_scale, c.heads
+        )
+    return ctx @ layer["wo"].astype(dt)
+
+
+def _swiglu(h, gate, up, down):
+    import jax
+
+    dt = h.dtype
+    return (jax.nn.silu(h @ gate.astype(dt)) * (h @ up.astype(dt))) @ down.astype(dt)
+
+
+def route(h, router, config: MoeMlaConfig):
+    """h: [T, hidden] -> (experts [T, k] int32, weights [T, k] f32): plain
+    top-k over all sigmoid scores (no group limit, no correction bias),
+    the chosen scores normalised to sum to one and scaled.  The logits are
+    f32: products of the compute dtype's operands, accumulated in f32."""
+    import jax
+    import jax.numpy as jnp
+
+    logits = jnp.dot(h, router.astype(h.dtype), preferred_element_type=jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    top, experts = jax.lax.top_k(scores, config.experts_per_token)
+    weights = config.routed_scaling_factor * top / top.sum(-1, keepdims=True)
+    return experts.astype(jnp.int32), weights
+
+
+PAIR_ROWS = 512  # the buffer's rows come in whole tiles of the grouped matmul
+
+
+def pair_capacity(tokens: int, config: MoeMlaConfig) -> int:
+    """Rows of the static buffer of (token, held expert) pairs for a slab
+    of `tokens` slots.  Every pair there can be (tokens x k) up to 4,096
+    rows: small batches cannot overflow.  Above that one row a token slot,
+    where the expected load is tokens x k x held / routed (half a row a
+    token at 12 of 192, top-8) and the slab's padding routes nothing.
+    Rounded up to whole tiles: at 14,112 rows, which 512 does not divide,
+    the TPU's grouped matmul took 8.6 ms where it takes 2.5 at 14,336
+    (chip runs, PR 30)."""
+    every = tokens * config.experts_per_token
+    return -(-min(every, max(tokens, 4096)) // PAIR_ROWS) * PAIR_ROWS
+
+
+def held_experts(h, valid, layer, config: MoeMlaConfig, capacity: Optional[int] = None):
+    """The routed experts' part of an expert layer that this rank
+    computes.  h: [T, hidden] (normed), valid: [T] bool (padding routes
+    nothing).  Returns (y [T, hidden], tokens per held expert
+    [experts_held] int32, pairs selected and held but beyond the buffer
+    () int32).
+
+    Selected pairs on held experts are sorted by expert into a buffer of
+    `capacity` rows; three grouped matmuls (gate, up, down) run over the
+    groups' rows only.  The results go back to their tokens by gathers
+    from the token's side, one pass for every held pair the busiest token
+    has (4 or 5 of its 8 as a rule): a scatter-add of the buffer's rows
+    took 14.0 ms at [14336, 7168] where a pass takes 1.5 (chip runs, PR
+    30)."""
+    import jax
+    import jax.numpy as jnp
+
+    c = config
+    t, k, n_held = h.shape[0], c.experts_per_token, c.experts_held
+    capacity = pair_capacity(t, c) if capacity is None else capacity
+    experts, weights = route(h, layer["router"], c)
+    local = experts - c.expert_offset
+    held = (local >= 0) & (local < n_held) & valid[:, None]
+    group = jnp.where(held, local, n_held).reshape(-1)  # [T*k]; n_held = not ours
+    counts = jnp.sum(
+        group[:, None] == jnp.arange(n_held, dtype=jnp.int32)[None, :], axis=0,
+        dtype=jnp.int32,
+    )
+    ends = jnp.minimum(jnp.cumsum(counts), capacity)
+    sizes = jnp.diff(ends, prepend=0)  # the groups as the buffer holds them
+    overflow = counts.sum() - ends[-1]
+    order = jnp.argsort(group, stable=True)  # held pairs first, by expert
+    dt = h.dtype
+    # (a small slab's buffer is a whole tile, more rows than it has pairs)
+    buffer = jnp.pad(order, (0, max(0, capacity - t * k)))[:capacity]
+    rows = h[buffer // k]  # [capacity, hidden]
+    with jax.named_scope("expert_matmul"):
+        gate = jax.lax.ragged_dot(rows, layer["experts_gate"].astype(dt), sizes)
+        up = jax.lax.ragged_dot(rows, layer["experts_up"].astype(dt), sizes)
+        out = jax.lax.ragged_dot(
+            jax.nn.silu(gate) * up, layer["experts_down"].astype(dt), sizes
+        )
+    # rows past the groups' end were never written: a zero weight does
+    # not silence what they hold
+    filled = jnp.arange(capacity) < ends[-1]
+    out = jnp.where(filled[:, None], out, jnp.zeros_like(out))
+    # a pair's row in the buffer, and each token's pairs that are in it
+    # moved to the front of its k slots
+    row = jnp.argsort(order).reshape(t, k).astype(jnp.int32)
+    mine = held & (row < ends[-1])
+    nth = jnp.cumsum(mine, axis=1) - 1
+    slot = mine[:, :, None] & (nth[:, :, None] == jnp.arange(k)[None, None, :])
+    row_of = jnp.sum(jnp.where(slot, row[:, :, None], 0), axis=1)  # [T, k]
+    weight_of = jnp.sum(jnp.where(slot, weights[:, :, None], 0.0), axis=1).astype(dt)
+
+    def nth_pair(j):
+        at = jax.lax.dynamic_slice_in_dim(row_of, j, 1, axis=1)[:, 0]
+        return jax.lax.dynamic_slice_in_dim(weight_of, j, 1, axis=1) * out[at]
+
+    # summed in the compute dtype, as the residual stream is: most tokens
+    # have one held pair or none, and the sum is added to x in that type
+    passes = jnp.max(jnp.sum(mine, axis=1))
+    y = jax.lax.fori_loop(1, passes, lambda j, y: y + nth_pair(j), nth_pair(0))
+    return y, counts, overflow
+
+
+# token slots the trunk takes at a time.  A slab's rows do not see each
+# other (attention stays inside a row, routing inside a token), so a slab
+# over this runs as equal groups of rows, one after the other inside the
+# one program: the activations of a 28k-token ingest slab, 2.7 GB, halve,
+# which is what lets two dispatches be in flight beside the parameters and
+# the store on a 16 GB chip (PERF.md section 6, PR 30), while a group still
+# hands each held expert hundreds of rows
+CHUNK_TOKENS = 16384
+
+
+def row_chunks(rows: int, length: int) -> int:
+    """Into how many equal groups of rows a [rows, length] slab is cut:
+    the fewest whose groups hold at most CHUNK_TOKENS token slots."""
+    for n in range(1, rows + 1):
+        if rows % n == 0 and rows // n * length <= CHUNK_TOKENS:
+            return n
+    return rows
+
+
+def _trunk(params, config: MoeMlaConfig, ids, seg, max_segments: int, fused: bool):
+    """ids, seg: [B, L] -> (pooled unit vectors [B, max_segments, hidden]
+    f32, tokens per held expert [expert layers, experts_held], overflow
+    [expert layers])."""
+    import jax.numpy as jnp
+
+    c = config
+    b, l = ids.shape
+    dt = _dtype(c.dtype)
+    pos = _packed_positions(seg)
+    freqs = jnp.asarray(yarn_freqs(c))
+    valid = (seg > 0).reshape(-1)
+    x = params["embed"][ids].astype(dt)
+    expert_tokens = [jnp.zeros((0, c.experts_held), jnp.int32)]
+    overflow = [jnp.zeros((0,), jnp.int32)]
+    for layer in params["layers"]:
+        x = x + _attention(x, layer, c, pos, seg, fused, freqs)
+        h = _rms_norm(x, layer["ln2"], c.norm_eps)
+        if "router" in layer:
+            routed, counts, over = held_experts(
+                h.reshape(b * l, c.hidden), valid, layer, c
+            )
+            expert_tokens.append(counts[None])
+            overflow.append(over[None])
+            x = x + routed.reshape(b, l, c.hidden) + _swiglu(
+                h, layer["shared_gate"], layer["shared_up"], layer["shared_down"]
+            )
+        else:
+            x = x + _swiglu(h, layer["gate"], layer["up"], layer["down"])
+    x = _rms_norm(x, params["ln_f"], c.norm_eps)
+    # per-segment mean pooling on the MXU, as transformer.forward pools
+    oh = (seg[:, :, None] == jnp.arange(1, max_segments + 1)[None, None, :]).astype(dt)
+    pooled = jnp.einsum("blh,bls->bsh", x, oh) / (oh.sum(axis=1)[:, :, None] + 1e-9)
+    pooled = pooled.astype(jnp.float32)
+    pooled = pooled / (jnp.linalg.norm(pooled, axis=-1, keepdims=True) + 1e-9)
+    return pooled, jnp.concatenate(expert_tokens), jnp.concatenate(overflow)
+
+
+def forward(
+    params,
+    config: MoeMlaConfig,
+    ids,
+    mask,
+    *,
+    use_flash: Optional[bool] = None,
+    seg=None,
+    max_segments: int = 0,
+    mesh=None,
+    with_stats: bool = False,
+):
+    """`transformer.forward`'s contract for the causal trunk.  ids, mask:
+    [B, L] int32 -> pooled unit vectors [B, hidden]; packed (seg is not
+    None): [B, max_segments, hidden], one per packed document, mask
+    ignored.  The unpacked form IS the packed one with one segment a row,
+    so the two cannot drift.  `with_stats` also returns {"expert_tokens":
+    [expert layers, experts_held], "overflow": [expert layers], "tokens":
+    ()}: the real tokens each held expert saw, the selected held pairs
+    that did not fit the buffer (they must be 0), the real tokens."""
+    import jax
+    import jax.numpy as jnp
+
+    _one_chip_only(mesh)
+    packed = seg is not None
+    if not packed:
+        seg, max_segments = (mask > 0).astype(jnp.int32), 1
+    b, l = ids.shape
+    fused = packed_attention_fused(config, l, use_flash)
+    n = row_chunks(b, l)
+    if n == 1:
+        pooled, expert_tokens, overflow = _trunk(
+            params, config, ids, seg, max_segments, fused
+        )
+    else:
+        pooled, expert_tokens, overflow = jax.lax.map(
+            lambda part: _trunk(params, config, *part, max_segments, fused),
+            (ids.reshape(n, b // n, l), seg.reshape(n, b // n, l)),
+        )
+        pooled = pooled.reshape(b, max_segments, config.hidden)
+        expert_tokens, overflow = expert_tokens.sum(0), overflow.sum(0)
+    if not packed:
+        pooled = pooled[:, 0, :]
+    if not with_stats:
+        return pooled
+    return pooled, {
+        "expert_tokens": expert_tokens, "overflow": overflow,
+        "tokens": (seg > 0).sum(dtype=jnp.int32),
+    }
+
+
+# the models whose statistics a reading of the span record first brings up
+# to date.  Weak: the record outlives a model and may not keep one (and
+# its parameters) alive
+_LIVE: "weakref.WeakSet[MoeMlaLM]" = weakref.WeakSet()
+
+
+def _count_finished() -> None:
+    """Before a reading of the record: count what the device has finished,
+    never waiting (a /status request must not hang behind a dispatch)."""
+    for lm in list(_LIVE):
+        lm.count_stats(wait=False)
+
+
+class MoeMlaLM(TransformerLM):
+    """`TransformerLM` for this trunk: the same entry points, and the packed
+    encode's routing statistics folded into the span record's counters
+    (`moe.*`, internals/tracing.py) once the device has produced them."""
+
+    def __init__(self, config: MoeMlaConfig, params=None, seed: int = 0):
+        import jax
+
+        super().__init__(config, params=params, seed=seed)
+
+        def _fwd_packed_moe_mla(params, ids, seg, max_segments):
+            import jax.numpy as jnp
+
+            return forward(
+                params, config, ids.astype(jnp.int32), None,
+                seg=seg.astype(jnp.int32), max_segments=max_segments,
+                with_stats=True,
+            )
+
+        self._packed_jit = jax.jit(_fwd_packed_moe_mla, static_argnums=(3,))
+        self._stats: deque = deque()  # of dispatches not yet counted
+        from pathway_tpu.internals import tracing
+
+        _LIVE.add(self)
+        tracing.on_read(_count_finished)
+
+    def encode_packed(self, ids, seg, max_segments: int, *, params=None,
+                      mesh=None):
+        _one_chip_only(mesh)
+        pooled, stats = self._packed_jit(
+            self.params if params is None else params, ids, seg, int(max_segments)
+        )
+        self._stats.append(stats)
+        self.count_stats(wait=False)
+        return pooled
+
+    def count_stats(self, wait: bool = True) -> None:
+        """Adds the finished dispatches' statistics to the counters: with
+        `wait=False` (the dispatch thread after a launch, a reading of the
+        record) only what the device has already produced, in dispatch
+        order, so neither ever blocks on the device."""
+        from pathway_tpu.internals import tracing
+
+        k = self.config.experts_per_token
+        while self._stats:
+            try:
+                stats = self._stats.popleft()
+            except IndexError:  # another thread counted it
+                return
+            if not wait and not stats["tokens"].is_ready():
+                self._stats.appendleft(stats)
+                return
+            per_expert = np.asarray(stats["expert_tokens"])
+            layers = per_expert.shape[0]
+            tracing.add("moe.pairs_routed", n=int(stats["tokens"]) * k * layers)
+            tracing.add("moe.pairs_held", n=int(per_expert.sum()))
+            tracing.add("moe.expert_tokens_max", n=int(per_expert.max(axis=1).sum()))
+            tracing.add(
+                "moe.expert_tokens_mean", n=int(round(per_expert.mean(axis=1).sum()))
+            )
+            tracing.add("moe.overflow_pairs", n=int(np.asarray(stats["overflow"]).sum()))
+
+
+LM = MoeMlaLM

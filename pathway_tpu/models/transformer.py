@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import importlib
 from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
@@ -461,15 +462,26 @@ def forward(
     return pooled
 
 
+def model_module(config):
+    """The module of the model a configuration belongs to: the one that
+    defines the configuration's type.  It has that model's `forward`,
+    `init_params`, `param_sharding_rules`, `packed_attention_fused` and
+    `LM` (this module for a `TransformerConfig`, `models/moe_mla.py` for a
+    `MoeMlaConfig`).  The one rule by which `TransformerLM`, the encoders
+    and the fused programs of `ops/knn.py` find a configuration's model."""
+    return importlib.import_module(type(config).__module__)
+
+
 class TransformerLM:
     """Bundles config+params with jitted entry points."""
 
-    def __init__(self, config: TransformerConfig, params=None, seed: int = 0):
+    def __init__(self, config, params=None, seed: int = 0):
         import jax
 
         self.config = config
+        model = model_module(config)
         if params is None:
-            params = init_params(jax.random.PRNGKey(seed), config)
+            params = model.init_params(jax.random.PRNGKey(seed), config)
         self.params = params
 
         def _fwd(params, ids, mask, mesh=None):
@@ -477,7 +489,7 @@ class TransformerLM:
             # device: 16-bit ids/mask halve the token upload vs int32
             import jax.numpy as jnp
 
-            return forward(
+            return model.forward(
                 params,
                 config=self.config,
                 ids=ids.astype(jnp.int32),
@@ -491,7 +503,7 @@ class TransformerLM:
         def _fwd_packed(params, ids, seg, max_segments, mesh=None):
             import jax.numpy as jnp
 
-            return forward(
+            return model.forward(
                 params,
                 config=self.config,
                 ids=ids.astype(jnp.int32),
@@ -522,7 +534,7 @@ class TransformerLM:
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
-        rules = param_sharding_rules(self.config, mesh)
+        rules = model_module(self.config).param_sharding_rules(self.config, mesh)
         shardings = jax.tree_util.tree_map(
             lambda spec: NamedSharding(mesh, spec),
             rules,
@@ -599,3 +611,6 @@ class TransformerLM:
             ids[np.arange(b), lengths + 1] = nxt
             mask[np.arange(b), lengths + 1] = 1
         return np.stack(out_tokens, axis=1)
+
+
+LM = TransformerLM  # `model_module(config).LM`

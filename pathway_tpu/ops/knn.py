@@ -505,7 +505,9 @@ def _compiled_fused_search(config, metric: str, k: int, mesh=None, n_rows: int =
     import jax
     import jax.numpy as jnp
 
-    from pathway_tpu.models.transformer import forward
+    from pathway_tpu.models.transformer import model_module
+
+    forward = model_module(config).forward
 
     def fused(params, ids_mask, buffer, valid):
         # single packed input ([2,B,L], narrow wire dtype upcast here) and
@@ -541,7 +543,9 @@ def _compiled_fused_packed_search(
     import jax
     import jax.numpy as jnp
 
-    from pathway_tpu.models.transformer import forward
+    from pathway_tpu.models.transformer import model_module
+
+    forward = model_module(config).forward
 
     def fused(params, ids, seg, rows, segs, buffer, valid):
         pooled = forward(
@@ -727,7 +731,7 @@ class FusedEmbedSearch:
         Ordering matters: the scatter donates the previous index buffer,
         so batches must dispatch in submission order."""
         from pathway_tpu.models.tokenizer import PACK_MAX_SEGMENTS
-        from pathway_tpu.models.transformer import packed_attention_fused
+        from pathway_tpu.models.transformer import model_module
 
         kind, keys, ids, second, slots = payload
         shards = None
@@ -751,7 +755,9 @@ class FusedEmbedSearch:
                 )
             # which attention this slab's program runs: static per shape,
             # so it is counted per batch, here, and not inside the jit
-            fused = packed_attention_fused(self.encoder.config, ids.shape[1])
+            fused = model_module(self.encoder.config).packed_attention_fused(
+                self.encoder.config, ids.shape[1]
+            )
             tracing.add(
                 "launch.encode.attn_fused" if fused
                 else "launch.encode.attn_dense"

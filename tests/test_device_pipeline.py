@@ -85,6 +85,37 @@ def test_pack_batch_slots_and_invariants():
     assert seg.max() <= PACK_MAX_SEGMENTS
 
 
+@pytest.mark.parametrize("size", ["small", "large"])
+def test_pack_batch_small_batches_pack_longest_first(size, monkeypatch):
+    """A small batch takes the same rows whatever order its documents come
+    in (longest first); a large one keeps first-fit in arrival order."""
+    from pathway_tpu.models import tokenizer as tk
+
+    tok = _encoder("pack-order", max_len=64).tokenizer
+    rng = np.random.default_rng(7)
+    texts = [" ".join(f"w{j}" for j in range(int(n))) for n in rng.integers(5, 60, size=40)]
+    if size == "large":
+        monkeypatch.setattr(tk, "PACK_SORT_ROWS", 4)  # the batch fills more rows
+    shapes, first_rows = set(), set()
+    for seed in range(6):
+        order = np.random.default_rng(seed).permutation(len(texts))
+        ids, seg, slots = pack_batch(
+            tok, [texts[i] for i in order], max_len=64, token_budget=64,
+            row_bucket=False,
+        )
+        shapes.add(np.asarray(ids).shape)
+        first_rows.add(slots[0][0])
+        for (r, s), i in zip(slots, order):  # a slot still finds its document
+            want_ids, want_mask = encode_batch(tok, [texts[i]], max_len=64)
+            want = np.asarray(want_ids)[0][np.asarray(want_mask)[0] > 0]
+            got = np.asarray(ids)[r][np.asarray(seg)[r] == s + 1]
+            assert np.array_equal(got.astype(np.int64), want.astype(np.int64))
+    if size == "small":
+        assert len(shapes) == 1
+    else:
+        assert first_rows == {0}  # the first to arrive opens the first row
+
+
 def test_pack_batch_budget_overflow_grows_slab():
     tok = _encoder("pack-long", max_len=64).tokenizer
     long_doc = "stream table engine " * 20
