@@ -20,12 +20,12 @@ Scheduling model:
 
 from __future__ import annotations
 
-import gc
 import os
 import threading
 import time as time_mod
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
+from pathway_tpu.engine.collector import POLICY as _collector
 from pathway_tpu.engine.stream import Delta, TableState, consolidate
 from pathway_tpu.engine.value import ERROR, Error, Pointer
 from pathway_tpu.internals import provenance as _provenance
@@ -221,9 +221,7 @@ class Engine:
         self.error_log: List[ErrorLogEntry] = []
         self.error_log_nodes: List["ErrorLogNode"] = []
         self._scheduled_times: set[int] = set()
-        self._gc_ticks = 0
-        self._gc_disabled = False
-        # host.gc spans: _gc_pulse's collections and automatic ones
+        # host.gc spans: the collector policy's pulses and any other
         install_gc_hook()
         # one span object for every tick of this engine (process_time is
         # not re-entered), so a tick allocates none
@@ -656,37 +654,24 @@ class Engine:
             with open(dest, "a") as fh:
                 fh.write("\n".join(lines) + "\n")
 
-    def _gc_pulse(self) -> None:
-        """Keep cyclic-GC pauses off the hot loop.  Engine state (delta
-        tuples, Pointers, group dicts) is acyclic but gc-tracked, so at
-        millions of rows every gen-2 collection stalls a tick for seconds
-        scanning live state.  Every 16 ticks: collect the young gens
-        (recent cyclic garbage, cheap), then freeze survivors into the
-        permanent generation so automatic collections stop rescanning
-        them.  Every 1024 ticks a full unfreeze+collect reclaims any
-        frozen cycles (e.g. abandoned UDF closures).  `finish()` always
-        unfreezes, so repeated runs in one process don't pin garbage."""
-        self._gc_ticks += 1
-        if self._gc_ticks % 1024 == 0:
-            gc.unfreeze()
-            gc.collect()
-            gc.freeze()
-        elif self._gc_ticks % 16 == 0:
-            gc.collect(1)
-            gc.freeze()
+    # the collector policy of a run (engine/collector.py): the process's,
+    # reached through the engine by whoever drives one
+    _gc_run = staticmethod(_collector.run)
+    _gc_pulse = staticmethod(_collector.pulse)
+    _gc_unfreeze = staticmethod(_collector.unfreeze)
 
     def run_static(self) -> None:
         """Batch mode: all inputs at time 0, then drain scheduled times
         (temporal buffers flush at +inf on end)."""
         try:
-            self._gc_quiesce()
-            self.process_time(0)
-            while True:
-                t = self.global_next_time()
-                if t is None:
-                    break
-                self.process_time(t)
-            self.finish()
+            with self._gc_run():
+                self.process_time(0)
+                while True:
+                    t = self.global_next_time()
+                    if t is None:
+                        break
+                    self.process_time(t)
+                self.finish()
         except BaseException:
             # crash-dump flight recorder: an uncaught run failure leaves a
             # structured post-mortem behind (engine.last_diagnostics and,
@@ -697,33 +682,6 @@ class Engine:
                 except Exception:  # noqa: BLE001 — never mask the real error
                     pass
             raise
-        finally:
-            # finish() unfreezes on the success path; this covers
-            # exceptions mid-run so the process's GC is never left frozen
-            self._gc_unfreeze()
-            self._gc_restore()
-
-    def _gc_quiesce(self) -> None:
-        """Suspend automatic cyclic GC for the run.  The batch kernels
-        allocate in bursts (one tuple/Pointer per output row), and each
-        burst otherwise trips threshold-triggered collections that rescan
-        live engine state mid-tick — measured at >3x the actual kernel
-        cost on join-heavy graphs.  `_gc_pulse` keeps collecting on its
-        own explicit cadence, so garbage is still reclaimed; `finish()`
-        re-enables iff we were the ones to disable."""
-        if gc.isenabled():
-            self._gc_disabled = True
-            gc.disable()
-
-    def _gc_restore(self) -> None:
-        if self._gc_disabled:
-            self._gc_disabled = False
-            gc.enable()
-
-    def _gc_unfreeze(self) -> None:
-        if self._gc_ticks >= 16:
-            self._gc_ticks = 0
-            gc.unfreeze()
 
     def _drain(self) -> None:
         # A delta can traverse at most the full node chain per pass, so a

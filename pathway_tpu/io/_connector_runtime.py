@@ -544,12 +544,11 @@ class StreamingDriver:
         return writer
 
     def run(self, sources: List[LiveSource]) -> None:
-        try:
+        # the collector policy of a run, as run_static has it: restored
+        # on every way out (end, exception; a failover rollback and a
+        # rolling restart stay inside it)
+        with self.engine._gc_run():
             self._run(sources)
-        finally:
-            # finish() unfreezes on the success path; this also covers
-            # exceptions mid-stream (engine._gc_pulse freezes the gc)
-            self.engine._gc_unfreeze()
 
     def _run(self, sources: List[LiveSource]) -> None:
         import os
@@ -965,6 +964,8 @@ class StreamingDriver:
                     self.engine.process_time(nxt)
                 first = False
                 nxt = self.engine.global_next_time()
+            # an idle stream has no ticks: the flush is its cadence
+            self.engine._gc_pulse()
             last_flush = time_mod.monotonic()
 
         # live failover: with snapshots on and a failover-capable
@@ -1026,6 +1027,11 @@ class StreamingDriver:
                     self.engine.last_failover_recovery_s = (
                         time_mod.monotonic() - failover_started
                     )
+                # what start-up built (imports, parameters, compile
+                # caches, restored state) never dies: one full collection,
+                # frozen before the first streamed batch (after a rollback
+                # it also reclaims the state that was dropped)
+                self.engine._gc_pulse(full=True)
                 if not started:
                     start_t = time_mod.monotonic()
                     for live in sinks:

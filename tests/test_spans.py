@@ -508,6 +508,32 @@ def test_young_collections_are_counted_without_a_span(record):
     assert len({int(t_end) for t_end, _d, _g in recent}) == len(recent)
 
 
+def test_a_quiesced_run_with_no_data_still_shows_recent_collections(record):
+    """`host.gc_pause_max_ms` reads `gc_recent`: six seconds of a streaming
+    run that ingests nothing, with the automatic collector off, must leave
+    collections there — the policy's pulses on their floor in time."""
+    import pathway_tpu as pw
+
+    class Idle(pw.io.python.ConnectorSubject):
+        def run(self):
+            time.sleep(6.0)
+
+    class Schema(pw.Schema):
+        x: int
+
+    seen = []
+    table = pw.io.python.read(Idle(), schema=Schema)
+    pw.io.subscribe(table, on_change=lambda *a, **k: seen.append(1))
+    before = time.monotonic()
+    pw.run(monitoring_level=None)
+    spans = tracing.spans_status()
+    assert not seen
+    assert spans["totals"]["gc.automatic"]["count"] == 0
+    assert spans["totals"]["gc.pulses"]["count"] >= 5
+    recent = [r for r in spans["gc_recent"] if r[0] >= before]
+    assert len(recent) >= 5 and all(r[1] > 0 and r[2] in (1, 2) for r in recent)
+
+
 # -- the profiler's clock -------------------------------------------------------
 
 
