@@ -643,107 +643,6 @@ def bench_exchange(n_rows=300_000, vocab=40_000, churn_pairs=15_000):
     return rps
 
 
-def bench_pipeline(n_docs=4096, chunk=256):
-    """Ingest A/B of the async device pipeline: PATHWAY_DEVICE_PIPELINE=1
-    (worker-thread tokenize+pack, packed ragged slabs, double-buffered
-    dispatch) vs =0 (classic synchronous per-batch path), both through
-    the stdlib fused KNN impl's add_many — the exact code the
-    DocumentStore ingest hot path runs.  CPU-safe: a tiny hash-tokenizer
-    encoder.  A third arm (pipeline on, PATHWAY_PACK_TOKEN_BUDGET=0)
-    isolates the pipelining from the packing: on a tiny-hidden CPU model
-    attention is quadratic in the slab length and outweighs the padding
-    it saves, so the packed arm can lose here even though on a real
-    device (hidden 384+, projections dominate) padding waste is the
-    term that matters — the no-pack arm is the CPU-meaningful number."""
-    from pathway_tpu.internals import compile_cache
-    from pathway_tpu.models.minilm import SentenceEncoder
-    from pathway_tpu.models.transformer import TransformerConfig
-    from pathway_tpu.stdlib.indexing.nearest_neighbors import (
-        _FusedKnnIndexImpl,
-    )
-
-    rng = random.Random(7)
-    words = [f"w{i}" for i in range(512)]
-    docs = [
-        " ".join(rng.choices(words, k=rng.randrange(8, 48))) + f" d{i}"
-        for i in range(n_docs)
-    ]
-    tiny = TransformerConfig(
-        vocab_size=512, hidden=32, layers=1, heads=2, mlp_dim=64, max_len=64
-    )
-    encoder = SentenceEncoder("bench-tiny", config=tiny, max_len=64)
-
-    compile_cache.configure()
-
-    def sync(impl):
-        # drain the pipeline (if any), then the quiesce that covers the
-        # classic arm's in-flight scatter chain too
-        impl.drain()
-        impl._quiesce_device()
-
-    stats = {}
-
-    def run(flag: str, budget: str | None = None) -> float:
-        saved = _os.environ.get("PATHWAY_DEVICE_PIPELINE")
-        saved_budget = _os.environ.get("PATHWAY_PACK_TOKEN_BUDGET")
-        _os.environ["PATHWAY_DEVICE_PIPELINE"] = flag
-        if budget is not None:
-            _os.environ["PATHWAY_PACK_TOKEN_BUDGET"] = budget
-        try:
-            impl = _FusedKnnIndexImpl(encoder, "cos", n_docs)
-            # warmup pass pays the (packed-)shape compiles
-            impl.add_many(range(chunk), docs[:chunk], [None] * chunk)
-            sync(impl)
-            best = 0.0
-            for _ in range(2):
-                t0 = _time.perf_counter()
-                for s in range(0, n_docs, chunk):
-                    impl.add_many(
-                        range(s, s + chunk),
-                        docs[s : s + chunk],
-                        [None] * chunk,
-                    )
-                sync(impl)
-                best = max(best, n_docs / (_time.perf_counter() - t0))
-            if impl._pipeline is not None:
-                stats[(flag, budget)] = impl._pipeline.stats()
-                impl._pipeline.close()
-            return best
-        finally:
-            if saved is None:
-                del _os.environ["PATHWAY_DEVICE_PIPELINE"]
-            else:
-                _os.environ["PATHWAY_DEVICE_PIPELINE"] = saved
-            if budget is not None:
-                if saved_budget is None:
-                    del _os.environ["PATHWAY_PACK_TOKEN_BUDGET"]
-                else:
-                    _os.environ["PATHWAY_PACK_TOKEN_BUDGET"] = saved_budget
-
-    classic = run("0")
-    pipelined = run("1")
-    pipelined_nopack = run("1", budget="0")
-    pipe_stats = stats.get(("1", None), {})
-    print(json.dumps({
-        "metric": "ingest_pipeline_docs_per_sec",
-        "value": round(pipelined),
-        "unit": "docs/s through fused embed+index add_many "
-                "(async pipeline, packed slabs)",
-        "classic_docs_per_sec": round(classic),
-        "pipeline_nopack_docs_per_sec": round(pipelined_nopack),
-        "pipeline_vs_classic": round(pipelined / classic, 2),
-        "pipeline_nopack_vs_classic": round(pipelined_nopack / classic, 2),
-        "pad_waste_ratio": (
-            round(pipe_stats["pad_waste_ratio"], 4)
-            if pipe_stats.get("pad_waste_ratio") is not None
-            else None
-        ),
-        "batches_dispatched": pipe_stats.get("dispatched"),
-        "n_docs": n_docs,
-    }))
-    return pipelined / classic
-
-
 def bench_tick_overhead(workers=(2, 4), duration_s=3.0):
     """Coordination cost per streaming tick: N workers run an idle
     streaming pipeline (10 ms autocommit) and report ticks/s plus
@@ -952,8 +851,6 @@ if __name__ == "__main__":
         bench_flatten_columnar()
     elif "--exchange" in _sys.argv:
         bench_exchange()
-    elif "--pipeline" in _sys.argv:
-        bench_pipeline()
     elif "--fusion" in _sys.argv:
         bench_fused_chain()
     elif "--provenance" in _sys.argv:

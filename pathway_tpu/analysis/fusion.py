@@ -28,21 +28,20 @@ ever be a chain tail.
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 from pathway_tpu.analysis.graph import GraphView
+from pathway_tpu.internals import config as _config
 
 _FUSABLE_KINDS = {"select", "filter"}
 
 
 def fusion_enabled() -> bool:
     """Fusion is on by default; PATHWAY_DISABLE_FUSION=1 restores the
-    classic one-node-per-op build (A/B lever for benchmarks and tests)."""
-    return os.environ.get("PATHWAY_DISABLE_FUSION", "0").lower() not in (
-        "1", "true", "yes",
-    )
+    classic one-node-per-op build (the reference the tests compare
+    against, in processes they cannot patch)."""
+    return not _config.env("PATHWAY_DISABLE_FUSION")
 
 
 def udf_barrier(apply_sites: Iterable[Any]) -> Optional[Tuple[str, str]]:
@@ -212,7 +211,7 @@ def plan_for_build(graph: Any, extra_tables: Iterable[Any] = ()):
     if not fusion_enabled():
         return None
     plan = plan_fusion(GraphView(graph, extra_tables=extra_tables))
-    force = os.environ.get("PATHWAY_FUSION_FORCE_SKIP", "")
+    force = _config.env("PATHWAY_FUSION_FORCE_SKIP")
     if force:
         # drift injection for the PWT599 negative tests: the plan still
         # claims these chains (to_dict is unchanged) but the build drops

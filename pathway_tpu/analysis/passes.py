@@ -8,8 +8,8 @@ that guesses is worse than no lint).
 The columnar-eligibility pass does not re-implement the runtime gates:
 joins expose `_columnar_reasons()` next to `_join_keys_hashable()`,
 reduce records the gate outcome (`use_vector` + reasons) on its OpSpec
-from the very variable the build closure captures, and flatten asks
-`vector_flatten_supported()`.  Prediction and selection share one source
+from the very variable the build closure captures, and flatten reads
+`vector_flatten.VECTOR_FLATTEN_ENABLED`.  Prediction and selection share one source
 of truth, which is what lets `verify_against_plan` treat a mismatch as
 an internal error (PWT399) rather than an expected drift.
 """
@@ -230,7 +230,7 @@ def _prediction(
 def columnar_pass(
     view: GraphView, result: AnalysisResult, *, workers: int = 1
 ) -> None:
-    from pathway_tpu.engine.vector_flatten import vector_flatten_supported
+    from pathway_tpu.engine import vector_flatten
 
     seen_joins: Set[int] = set()
     for table, op in view.ops():
@@ -299,7 +299,7 @@ def columnar_pass(
         elif op.kind == "flatten":
             reasons = (
                 []
-                if vector_flatten_supported()
+                if vector_flatten.VECTOR_FLATTEN_ENABLED
                 else ["vector flatten disabled by configuration"]
             )
             result.predictions.append(
@@ -452,8 +452,7 @@ def udf_pass(
 # ---------------------------------------------------------------------------
 
 # Deterministic stand-in for typical short-document corpora (final token
-# counts per doc, CLS/SEP included — roughly what bench.py's synthetic
-# ingest feeds the embedder). The lint is a shape argument, not a data
+# counts per doc, CLS/SEP included). The lint is a shape argument, not a data
 # argument: any distribution with mean/max in this range predicts the
 # same verdict, and determinism keeps the golden matrix stable.
 _SAMPLE_TOKEN_LENGTHS = (18, 24, 30, 34, 38, 42, 48, 56)
@@ -494,8 +493,7 @@ def embedder_pass(
                 "of two (minimum 8) and every doc pads to the bucket "
                 "max, so most MXU cycles process pad tokens; raise "
                 "max_batch_size or keep packed ragged batching on "
-                "(PATHWAY_PACK_TOKEN_BUDGET > 0 with the default "
-                "PATHWAY_DEVICE_PIPELINE=1)",
+                "(PATHWAY_PACK_TOKEN_BUDGET > 0)",
                 trace=_trace_or_none(table),
                 operator=view.op_label(table),
                 udf=fname,
@@ -673,8 +671,7 @@ def mesh_pass(
                     f"dp={dp} axis would never divide the batch axis "
                     "evenly. Use a power-of-two dp device count, or "
                     "drop the mesh and run the single-device async "
-                    "pipeline (PATHWAY_DEVICE_PIPELINE=1, the "
-                    "default); models/minilm.py enforces the same "
+                    "pipeline; models/minilm.py enforces the same "
                     "rule at encoder build time",
                     trace=trace, operator=operator,
                     udf=fname, dp=dp,

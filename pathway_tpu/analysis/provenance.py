@@ -23,10 +23,10 @@ an unarmed job records no lineage, so opacity costs nothing.
 
 from __future__ import annotations
 
-import os
 from typing import Any
 
 from pathway_tpu.analysis.diagnostics import AnalysisResult, make_diag
+from pathway_tpu.internals import config as _config
 
 # Operators whose output keys are derived with no lineage hook: the
 # tracker cannot map an output row of these back to its input rows.
@@ -66,7 +66,7 @@ def provenance_pass(view: Any, result: AnalysisResult) -> None:
             operator=view.op_label(table),
             kind=op.kind,
         ))
-    if os.environ.get("PATHWAY_PROVENANCE_REQUIRE") == "1":
+    if _config.env("PATHWAY_PROVENANCE_REQUIRE"):
         table, op = opaque[0]
         result.add(make_diag(
             "PWT1099",

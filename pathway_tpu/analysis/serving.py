@@ -34,9 +34,7 @@ def serving_pass(
     when the serving tier is enabled and armed (a non-zero batch window);
     `slo` is the p99 target in milliseconds threaded from pw.run(slo=)
     with PATHWAY_SLO_P99_MS as the CLI-path fallback."""
-    import os
-
-    from pathway_tpu.internals import serving
+    from pathway_tpu.internals import config as _config, serving
 
     if not serving.ENABLED:
         return
@@ -66,12 +64,7 @@ def serving_pass(
             ))
 
     if slo is None:
-        env_slo = os.environ.get("PATHWAY_SLO_P99_MS")
-        if env_slo:
-            try:
-                slo = float(env_slo)
-            except ValueError:
-                slo = None
+        slo = _config.env("PATHWAY_SLO_P99_MS")
     if slo is not None and window_ms > float(slo):
         table, op = indexes[0]
         result.add(make_diag(

@@ -9,6 +9,8 @@ import os
 import subprocess
 import sys
 
+from pathway_tpu.internals import config as _config
+
 
 def _spawn(args) -> int:
     """Launch a program across N processes with worker env vars set
@@ -61,7 +63,7 @@ def _replay(args) -> int:
 
 
 def _spawn_from_env(args) -> int:
-    spawn_args = os.environ.get("PATHWAY_SPAWN_ARGS", "")
+    spawn_args = _config.env("PATHWAY_SPAWN_ARGS")
     argv = spawn_args.split() + list(args.program)
     return main(["spawn", *argv])
 

@@ -20,7 +20,6 @@ Scheduling model:
 
 from __future__ import annotations
 
-import os
 import threading
 import time as time_mod
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
@@ -28,6 +27,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 from pathway_tpu.engine.collector import POLICY as _collector
 from pathway_tpu.engine.stream import Delta, TableState, consolidate
 from pathway_tpu.engine.value import ERROR, Error, Pointer
+from pathway_tpu.internals import config as _config
 from pathway_tpu.internals import provenance as _provenance
 from pathway_tpu.internals import qtrace as _qtrace
 from pathway_tpu.internals import sanitizer as _sanitizer
@@ -229,7 +229,7 @@ class Engine:
         # per-node wall-time dump destination (the always-on metrics
         # registry is the single instrumented path; this env var only
         # selects the JSON-lines dump of it at finish())
-        self._node_timing_dest: str | None = os.environ.get(
+        self._node_timing_dest: str | None = _config.env(
             "PATHWAY_NODE_TIMING_LOG"
         )
         self._timing_dumped = False

@@ -44,6 +44,7 @@ import threading
 import time as time_mod
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from pathway_tpu.internals import config as _config
 from pathway_tpu.internals import costledger as _costledger
 from pathway_tpu.internals import sanitizer as _sanitizer
 
@@ -55,9 +56,7 @@ logger = logging.getLogger("pathway_tpu.exchange")
 # partitioning, sender-side consolidation, fused frame encoding and
 # per-peer writer threads. The classic row-wise path stays available as
 # the always-working fallback (and the parity baseline for tests).
-VECTOR_EXCHANGE_ENABLED = (
-    os.environ.get("PATHWAY_DISABLE_VECTOR_EXCHANGE") != "1"
-)
+VECTOR_EXCHANGE_ENABLED = not _config.env("PATHWAY_DISABLE_VECTOR_EXCHANGE")
 
 # chunked sends bound peak frame/socket buffers on bulk-ingest batches (a
 # single million-row message costs hundreds of MB on both ends)
@@ -71,7 +70,7 @@ _SEND_QUEUE_FRAMES = 64
 # real agree round can reach (rounds restart from 0 after every failover).
 FENCE_ROUND = (1 << 64) - 1
 
-_TRACE = os.environ.get("PATHWAY_EXCHANGE_TRACE") == "1"
+_TRACE = _config.env("PATHWAY_EXCHANGE_TRACE")
 
 
 def _trace(worker_id: int, msg: str) -> None:
@@ -262,7 +261,7 @@ class TcpCoordinator(Coordinator):
         self.worker_id = worker_id
         self.worker_count = worker_count
         self.first_port = first_port
-        self.run_id = run_id or os.environ.get("PATHWAY_RUN_ID", "")
+        self.run_id = run_id or _config.env("PATHWAY_RUN_ID")
         self.host = host
         self._lock = threading.Lock()
         self._cv = threading.Condition(self._lock)
@@ -302,10 +301,8 @@ class TcpCoordinator(Coordinator):
         # Overlapped sends need a second core to overlap ONTO — on a
         # single-CPU host the extra thread is pure GIL ping-pong, so the
         # default is auto; PATHWAY_EXCHANGE_WRITERS=1/0 forces it.
-        writers_env = os.environ.get("PATHWAY_EXCHANGE_WRITERS")
-        if writers_env is not None:
-            self._use_writers = writers_env == "1"
-        else:
+        self._use_writers = _config.env("PATHWAY_EXCHANGE_WRITERS")
+        if self._use_writers is None:
             self._use_writers = (
                 VECTOR_EXCHANGE_ENABLED and (os.cpu_count() or 1) > 1
             )
@@ -761,10 +758,7 @@ class TcpCoordinator(Coordinator):
            the peer's old-timeline frames, and purging again could eat a
            round-0 vote the peer sent right after its hello."""
         if timeout is None:
-            try:
-                timeout = float(os.environ.get("PATHWAY_REJOIN_TIMEOUT", 30))
-            except ValueError:
-                timeout = 30.0
+            timeout = _config.env("PATHWAY_REJOIN_TIMEOUT")
         with self._cv:
             targets = set(self._dead) | set(self._rejoined)
         _trace(self.worker_id, f"rendezvous start targets={sorted(targets)}")
@@ -1061,12 +1055,7 @@ class ThreadGroupCoordinator:
         self._parked: set = set()
         self._generation = 0
         self._restarts = 0
-        try:
-            self._max_restarts = int(
-                os.environ.get("PATHWAY_MAX_FAILOVERS", 3)
-            )
-        except ValueError:
-            self._max_restarts = 3
+        self._max_restarts = _config.env("PATHWAY_MAX_FAILOVERS")
         # (dest_thread, channel, time) -> {sender_global: [deltas]}
         self._data: Dict[tuple, dict] = {}
         # (dest_thread, channel, time) -> {sender_global}
@@ -1116,9 +1105,7 @@ class ThreadGroupCoordinator:
                 or self._failover_pending
                 or self._aborted
                 or self._restarts >= self._max_restarts
-                or not (
-                    injected or os.environ.get("PATHWAY_FAILOVER") == "1"
-                )
+                or not (injected or _config.env("PATHWAY_FAILOVER"))
             ):
                 return False
             self._restarts += 1

@@ -19,9 +19,6 @@ from typing import Any, Callable, List, Sequence, Tuple
 
 from pathway_tpu.engine.value import ERROR, Error, Json, Pointer, ref_scalar
 from pathway_tpu.internals import dtype as dt
-from pathway_tpu.internals.device_pipeline import (
-    pipeline_enabled as _pipeline_enabled,
-)
 from pathway_tpu.internals import expression as expr_mod
 from pathway_tpu.internals import sanitizer as _sanitizer
 from pathway_tpu.internals.expression import (
@@ -560,12 +557,7 @@ def _compile_apply(expr: ApplyExpression, ctx: EvalContext) -> BatchProgram:
 
             submit = getattr(fun, "submit_batch", None)
             awaitf = getattr(fun, "await_batch", None)
-            if (
-                submit is not None
-                and awaitf is not None
-                and len(chunks) > 1
-                and _pipeline_enabled()
-            ):
+            if submit is not None and awaitf is not None and len(chunks) > 1:
                 # two-phase async batched UDF (device-pipelined embedders):
                 # submit every chunk first — each submit tokenizes and
                 # enqueues an async device dispatch — then await in order,

@@ -22,7 +22,6 @@ skip the consolidation pass on emit.
 
 from __future__ import annotations
 
-import os
 from typing import Any, List
 
 import numpy as np
@@ -33,7 +32,8 @@ from pathway_tpu.engine.stream import Delta
 from pathway_tpu.engine.value import Error, flatten_triples_batch
 from pathway_tpu.internals import provenance as _provenance
 
-# Flip to force the classic FlattenNode everywhere (tests / A-B benches).
+# Build-time switch, read when a flatten node is built: tests patch it
+# to force the classic FlattenNode (the parity reference) everywhere.
 VECTOR_FLATTEN_ENABLED = True
 
 _M64 = (1 << 64) - 1
@@ -42,13 +42,6 @@ _MIX = FlattenNode._MIX
 _MIX2 = FlattenNode._MIX2
 _MIX_HI, _MIX_LO = _MIX >> 64, _MIX & _M64
 _MIX2_HI, _MIX2_LO = _MIX2 >> 64, _MIX2 & _M64
-
-
-def vector_flatten_supported() -> bool:
-    """Build-time switch: module flag + env escape hatch."""
-    return VECTOR_FLATTEN_ENABLED and not os.environ.get(
-        "PATHWAY_DISABLE_VECTOR_FLATTEN"
-    )
 
 
 def _mulhi64(a: np.ndarray, b) -> np.ndarray:
@@ -206,5 +199,5 @@ def make_flatten_node(engine: Engine, input_: Node, flat_idx: int) -> FlattenNod
     """Build-time selection mirroring `internals/groupbys.py`: columnar
     unless disabled. Flatten has no dtype gate — element extraction stays
     row-wise python, so every classic branch is supported."""
-    cls = VectorFlattenNode if vector_flatten_supported() else FlattenNode
+    cls = VectorFlattenNode if VECTOR_FLATTEN_ENABLED else FlattenNode
     return cls(engine, input_, flat_idx)

@@ -43,7 +43,6 @@ Two execution modes mirror the classic node exactly:
 
 from __future__ import annotations
 
-import os
 from typing import Any, Dict, List, Set
 
 from pathway_tpu.engine.operators import JoinNode, _DiffCache
@@ -57,15 +56,9 @@ from pathway_tpu.engine.value import (
 )
 from pathway_tpu.internals import provenance as _provenance
 
-# Flip to force the classic JoinNode everywhere (tests / A-B benches).
+# Build-time switch, read when a join node is built: tests patch it to
+# force the classic JoinNode (the parity reference) everywhere.
 VECTOR_JOIN_ENABLED = True
-
-
-def vector_join_supported() -> bool:
-    """Build-time switch: module flag + env escape hatch."""
-    return VECTOR_JOIN_ENABLED and not os.environ.get(
-        "PATHWAY_DISABLE_VECTOR_JOIN"
-    )
 
 
 class VectorJoinNode(JoinNode):

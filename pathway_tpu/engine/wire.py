@@ -482,10 +482,11 @@ def _safe_getattr(obj, name, *default):
 
 def _restricted_loads(raw: bytes) -> Any:
     import io as _io
-    import os
     import pickle
 
-    if os.environ.get("PATHWAY_WIRE_UNSAFE_PICKLE") == "1":
+    from pathway_tpu.internals import config as _config
+
+    if _config.env("PATHWAY_WIRE_UNSAFE_PICKLE"):
         return pickle.loads(raw)
 
     class _Unpickler(pickle.Unpickler):

@@ -16,7 +16,7 @@ compiles some hundred small programs (scatters, slices, probes) beside
 the few large ones, and with jax's default one-second threshold they made
 up half of a warm start's compile time (chip run, PR 21).
 
-Entry points (``chip_smoke.py``, ``bench.py``, ``benchmarks/*.py``) call
+Entry points (``chip_smoke.py``, ``chipbench``) call
 ``configure()`` before their first compile.  This module imports jax only
 inside ``configure()``, so ``import pathway_tpu`` stays jax-free.
 """

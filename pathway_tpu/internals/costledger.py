@@ -50,29 +50,25 @@ peak is unknown, which PWT802 lints); ``cost_status()`` is the
 
 ``PATHWAY_COSTLEDGER=0`` disables everything: every hook site guards on
 the module attribute ``ENABLED``, so the disabled cost is one attribute
-read (enforced by tests/test_perf_smoke.py).  Imports only the stdlib —
-never jax.
-
-Config:
-  PATHWAY_COSTLEDGER=0        disable (default: enabled)
-  PATHWAY_COST_WINDOW_S=F     rolling share window (default 30 — the
-                              utilization window, so the conservation
-                              cross-check compares like with like)
+read (enforced by tests/test_perf_smoke.py).  Never imports jax.
 """
 
 from __future__ import annotations
 
-import os
 import threading
 import time as time_mod
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
-ENABLED = os.environ.get("PATHWAY_COSTLEDGER", "1") != "0"
+from pathway_tpu.internals import config as _config
+
+ENABLED = _config.env("PATHWAY_COSTLEDGER")
 
 WORKLOADS = ("ingest", "serve", "maintenance")
 
-WINDOW_S = float(os.environ.get("PATHWAY_COST_WINDOW_S", "30") or 30)
+# rolling share window: the utilization window, so the conservation
+# cross-check compares like with like
+WINDOW_S = 30.0
 
 # memtrack component -> workload for the pull-time HBM-resident gauge
 # (memtrack.COMPONENT_WORKLOADS mirrors this; kept there so the two

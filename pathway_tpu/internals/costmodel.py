@@ -1,13 +1,9 @@
 """Analytic FLOPs/bytes cost model — the single source of truth.
 
-Before the utilization PR this model lived in three places (bench.py
-`_mfu_facts`/`_device_peak_flops`, benchmarks/roofline_check.py
-`useful_flops_per_doc`/`_peak`, benchmarks/generation_bench.py
-`_peak_flops`/`_hbm_bytes_per_sec`) and could silently drift.  Every
-MFU number the repo prints — offline bench artifacts, the roofline
-probes, and the live `pathway_device_mfu_pct` gauge — now derives from
-the formulas here, so "live vs offline divergence" can only mean a
-measurement problem, never two cost models disagreeing.
+Every MFU number the program prints — the live `pathway_device_mfu_pct`
+gauge, the cost ledger's shares, the capacity analysis — derives from
+the formulas here.  (The benchmark's own cost functions live with it,
+under ``chipbench/``, and import nothing from this module.)
 
 Contract (documented in ARCHITECTURE.md "Device utilization"):
 
@@ -157,17 +153,6 @@ def encoder_param_count(
     h, m = hidden, mlp_dim
     per_layer = 4 * h * h + 2 * h * m + 9 * h + m
     return vocab_size * h + max_len * h + 2 * h + layers * per_layer
-
-
-def encoder_param_bytes(config: Any) -> int:
-    """Parameter bytes (float32) for a TransformerConfig-shaped object."""
-    return 4 * encoder_param_count(
-        vocab_size=int(getattr(config, "vocab_size", 30522)),
-        hidden=int(getattr(config, "hidden", MINILM_HIDDEN)),
-        layers=int(getattr(config, "layers", MINILM_LAYERS)),
-        mlp_dim=int(getattr(config, "mlp_dim", MINILM_MLP_DIM)),
-        max_len=int(getattr(config, "max_len", 512)),
-    )
 
 
 def encoder_flops_per_token(

@@ -27,15 +27,11 @@ exception parks the failing item plus everything still queued in a
 `take_failed()` list, surfaces as DevicePipelineError at the next
 submit/barrier/drain, and the caller replays those items on the classic
 synchronous path exactly once.
-
-`PATHWAY_DEVICE_PIPELINE=0` restores the classic synchronous per-batch
-path wholesale (read per call, like the other runtime gates).
 """
 
 from __future__ import annotations
 
 import collections
-import os
 import threading
 import time
 import weakref
@@ -46,17 +42,10 @@ from pathway_tpu.internals import memtrack, tracing, utilization
 from pathway_tpu.internals.metrics import MetricsRegistry
 
 
-def pipeline_enabled() -> bool:
-    """PATHWAY_DEVICE_PIPELINE gate, read per call: default on, "0"
-    restores the classic synchronous per-batch ingest path."""
-    return os.environ.get("PATHWAY_DEVICE_PIPELINE", "1") != "0"
-
-
-def _env_int(name: str, default: int, floor: int = 1) -> int:
-    try:
-        return max(floor, int(os.environ.get(name, "") or default))
-    except ValueError:
-        return default
+# depths of the three stages; a caller that needs others passes them
+MAX_PREPARED = 4  # prepared batches queued ahead of the dispatcher
+MAX_IN_FLIGHT = 2  # dispatches on the device at once: double-buffered
+PREP_WORKERS = 2  # threads that tokenize and pack
 
 
 class DevicePipelineError(RuntimeError):
@@ -101,10 +90,8 @@ class DevicePipeline:
         self._dispatch = dispatch
         self._wait = wait or _default_wait
         self._quiesce = quiesce
-        self.max_prepared = max_prepared or _env_int("PATHWAY_PIPELINE_QUEUE", 4)
-        self.max_in_flight = max_in_flight or _env_int(
-            "PATHWAY_PIPELINE_IN_FLIGHT", 2
-        )
+        self.max_prepared = max_prepared or MAX_PREPARED
+        self.max_in_flight = max_in_flight or MAX_IN_FLIGHT
         # health-controller backpressure: the configured sizes are the
         # ceiling; set_pressure_scale() shrinks the live knobs toward 1
         # and restores them when pressure clears (AIMD)
@@ -127,9 +114,7 @@ class DevicePipeline:
         # completion-to-completion device-time estimate (see
         # internals/utilization.py module docstring)
         self._last_completion = 0.0
-        self.prep_workers = prep_workers or _env_int(
-            "PATHWAY_PIPELINE_PREP_WORKERS", 2
-        )
+        self.prep_workers = prep_workers or PREP_WORKERS
         self._pool = ThreadPoolExecutor(
             max_workers=self.prep_workers, thread_name_prefix=f"{name}-prep"
         )
@@ -644,7 +629,6 @@ def pipeline_status() -> Dict[str, Any]:
     """/status payload: aggregate view over live pipelines."""
     pipes = list(_PIPELINES)
     out: Dict[str, Any] = {
-        "enabled": pipeline_enabled(),
         "active": len(pipes),
         "fallbacks": _STATS["fallbacks"],
         "backpressure_scale": _PRESSURE_SCALE,

@@ -22,11 +22,12 @@ Config: ``PATHWAY_DEVICE_PROBE=0`` disables the monitor entirely;
 from __future__ import annotations
 
 import atexit
-import os
 import sys
 import threading
 import time as time_mod
 from typing import Any, Dict, Optional, Tuple
+
+from pathway_tpu.internals import config as _config
 
 _ProbeResult = Tuple[Optional[float], Optional[str]]
 
@@ -109,12 +110,7 @@ class DeviceMonitor:
         from pathway_tpu.internals.metrics import MetricsRegistry
 
         if interval_s is None:
-            try:
-                interval_s = float(
-                    os.environ.get("PATHWAY_DEVICE_PROBE_INTERVAL_S", 300)
-                )
-            except ValueError:
-                interval_s = 300.0
+            interval_s = _config.env("PATHWAY_DEVICE_PROBE_INTERVAL_S")
         self.interval_s = max(1.0, interval_s)
         self.timeout_s = timeout_s
         self.probe = probe
@@ -238,7 +234,7 @@ def ensure_monitor() -> Optional[DeviceMonitor]:
     """Start (once) and return the process-wide device monitor; None when
     PATHWAY_DEVICE_PROBE=0."""
     global _monitor
-    if os.environ.get("PATHWAY_DEVICE_PROBE") == "0":
+    if not _config.env("PATHWAY_DEVICE_PROBE"):
         return None
     with _monitor_lock:
         if _monitor is None:
@@ -263,7 +259,7 @@ atexit.register(_quiesce_at_exit)
 
 def device_status() -> Dict[str, Any]:
     """The ``"device"`` key for /status."""
-    if os.environ.get("PATHWAY_DEVICE_PROBE") == "0":
+    if not _config.env("PATHWAY_DEVICE_PROBE"):
         return {"status": "disabled"}
     if _monitor is None:
         return {"status": "not_started"}

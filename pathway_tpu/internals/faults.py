@@ -52,10 +52,11 @@ Kinds:
 
 from __future__ import annotations
 
-import os
 import threading
 import time as time_mod
 from typing import Any, Dict, List, Optional, Tuple
+
+from pathway_tpu.internals import config as _config
 
 # Cheap guard consulted by every hook site before taking _lock.
 ACTIVE = False
@@ -110,8 +111,7 @@ class _Directive:
 _lock = threading.Lock()
 _directives: List[_Directive] = []
 
-# (kind, detail, monotonic_ts) — bench.py reads the kill timestamp to
-# compute failover_recovery_s; tests assert on what actually fired.
+# (kind, detail, monotonic_ts) — tests assert on what actually fired.
 events: List[Tuple[str, Dict[str, Any], float]] = []
 
 
@@ -156,7 +156,7 @@ def install_from_env() -> None:
     """Arm from ``PATHWAY_FAULTS`` if it is set; otherwise leave any
     API-installed directives in place (the driver calls this once per
     run, and in-process tests install() before calling pw.run)."""
-    spec = os.environ.get("PATHWAY_FAULTS")
+    spec = _config.env("PATHWAY_FAULTS")
     if spec is not None:
         install(spec)
 

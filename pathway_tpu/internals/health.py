@@ -16,7 +16,7 @@ all-or-nothing sync fallback:
       .drain_replica`` — the replica's index shard stays searchable, so
       retrieval remains ranking-exact), barriers the in-flight pipeline
       windows from a one-shot helper thread, and re-admits the replica
-      after ``PATHWAY_HEALTH_READMIT_PROBES`` consecutive healthy ticks.
+      after ``READMIT_PROBES`` consecutive healthy ticks.
 
   rolling restart
       ``pathway-tpu restart`` (or GET /restart on the monitoring server)
@@ -53,48 +53,32 @@ from __future__ import annotations
 
 import collections
 import logging
-import os
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
-from pathway_tpu.internals import tracing
+from pathway_tpu.internals import config as _config, tracing
 from pathway_tpu.internals.backoff import Backoff
 from pathway_tpu.internals.metrics import FlightRecorder, MetricsRegistry
 
 logger = logging.getLogger("pathway_tpu")
 
 # Cheap guard read by every hook site (driver flush tick, event drain).
-ENABLED = os.environ.get("PATHWAY_HEALTH", "1") != "0"
-
-
-def _env_int(name: str, default: int) -> int:
-    try:
-        return int(os.environ.get(name, "") or default)
-    except ValueError:
-        return default
-
-
-def _env_float(name: str, default: float) -> float:
-    try:
-        return float(os.environ.get(name, "") or default)
-    except ValueError:
-        return default
-
+ENABLED = _config.env("PATHWAY_HEALTH")
 
 # Consecutive healthy ticks a drained replica must show before re-admit.
-READMIT_PROBES = _env_int("PATHWAY_HEALTH_READMIT_PROBES", 3)
+READMIT_PROBES = 3
 
 # AIMD constants (documented in ARCHITECTURE.md "Self-healing runtime"):
 # multiplicative decrease under pressure, additive increase on clear.
-BP_DECREASE = _env_float("PATHWAY_HEALTH_BP_DECREASE", 0.5)
-BP_INCREASE = _env_float("PATHWAY_HEALTH_BP_INCREASE", 0.25)
-BP_MIN_SCALE = _env_float("PATHWAY_HEALTH_BP_MIN_SCALE", 0.125)
+BP_DECREASE = 0.5
+BP_INCREASE = 0.25
+BP_MIN_SCALE = 0.125
 
 # Wall-clock pacing of the (slightly costlier) memory/bound-state reads
 # when no fault harness is armed; with faults ACTIVE every tick
 # evaluates so chaos runs stay deterministic in logical time.
-PRESSURE_CHECK_S = _env_float("PATHWAY_HEALTH_PRESSURE_CHECK_S", 0.2)
+PRESSURE_CHECK_S = 0.2
 
 _ACTIONS = (
     "drain", "readmit", "restart", "restart_done", "throttle", "relax",

@@ -242,7 +242,7 @@ class JoinResult:
         from pathway_tpu.internals.type_interpreter import infer_dtype
 
         reasons = []
-        if not vector_join.vector_join_supported():
+        if not vector_join.VECTOR_JOIN_ENABLED:
             reasons.append("vector join disabled by configuration")
 
         def resolve(ref: ColumnReference) -> dt.DType:
@@ -293,7 +293,7 @@ class JoinResult:
         from pathway_tpu.engine.exchange import exchange_by_key
 
         node_cls = JoinNode
-        if vector_join.vector_join_supported() and self._join_keys_hashable():
+        if vector_join.VECTOR_JOIN_ENABLED and self._join_keys_hashable():
             node_cls = vector_join.VectorJoinNode
         node = node_cls(
             ctx.engine,

@@ -15,9 +15,10 @@ from __future__ import annotations
 
 import base64
 import json
-import os
 from dataclasses import dataclass, field
 from typing import FrozenSet
+
+from pathway_tpu.internals import config as _config
 
 # default verifying key for pw-v2 licenses (hex, 32 bytes). Generated for
 # this open build; deployments override with PATHWAY_LICENSE_PUBKEY.
@@ -83,7 +84,7 @@ def parse_license(key: str | None) -> License:
                 f"license key payload unreadable: {exc}"
             ) from exc
     elif key.startswith("pw-v1."):
-        if os.environ.get("PATHWAY_LICENSE_PUBKEY"):
+        if _config.env("PATHWAY_LICENSE_PUBKEY"):
             # a deployment that configured a verifying key has opted into
             # real enforcement: unsigned keys no longer count
             raise LicenseError(
@@ -119,9 +120,7 @@ def _verify_signature(payload: bytes, signature: bytes) -> None:
     """Ed25519 over the raw payload bytes (reference: license.rs)."""
     from pathway_tpu.internals import _ed25519
 
-    pub_hex = os.environ.get(
-        "PATHWAY_LICENSE_PUBKEY", DEFAULT_LICENSE_PUBKEY
-    )
+    pub_hex = _config.env("PATHWAY_LICENSE_PUBKEY") or DEFAULT_LICENSE_PUBKEY
     try:
         pub = bytes.fromhex(pub_hex)
     except ValueError as exc:

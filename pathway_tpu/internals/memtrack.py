@@ -33,7 +33,7 @@ Accounting model (documented in ARCHITECTURE.md "Memory accounting"):
 Time-to-full forecaster: ingest hook sites report (docs, per-device
 bytes) deltas into a rolling window; docs/s x bytes/doc against the
 current headroom projects exhaustion.  When headroom drops below
-``PATHWAY_MEM_HEADROOM_WARN_PCT`` percent of capacity the module warns
+``HEADROOM_WARN_PCT`` percent of capacity the module warns
 ONCE and drops a flight-recorder event, so the operator learns the
 index is 10 minutes from OOM before the OOM.
 
@@ -51,30 +51,25 @@ from __future__ import annotations
 
 import collections
 import logging
-import os
 import sys
 import threading
 import time
 import weakref
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
-from pathway_tpu.internals import faults
+from pathway_tpu.internals import config as _config, faults
 from pathway_tpu.internals.metrics import FlightRecorder, MetricsRegistry
 
 logger = logging.getLogger("pathway_tpu")
 
 # Cheap guard read by every hook site.
-ENABLED = os.environ.get("PATHWAY_MEMTRACK", "1") != "0"
+ENABLED = _config.env("PATHWAY_MEMTRACK")
 
 # Headroom percentage below which the warn-once + flight event fires.
-HEADROOM_WARN_PCT = float(
-    os.environ.get("PATHWAY_MEM_HEADROOM_WARN_PCT", "10") or 10
-)
+HEADROOM_WARN_PCT = 10.0
 
 # Forecast rolling-window length (seconds of ingest deltas retained).
-FORECAST_WINDOW_S = float(
-    os.environ.get("PATHWAY_MEM_FORECAST_WINDOW_S", "60") or 60
-)
+FORECAST_WINDOW_S = 60.0
 
 # The component names the hook sites use (label values are open — these
 # are the ones wired today; ARCHITECTURE.md documents them).
@@ -129,12 +124,9 @@ def hbm_capacity_bytes() -> Optional[float]:
     the gauges, and the PWT6xx capacity pass all share:
     PATHWAY_ASSUME_HBM_BYTES override -> live jax bytes_limit -> the
     costmodel chip table -> None (unknown; consumers omit, never guess)."""
-    env = os.environ.get("PATHWAY_ASSUME_HBM_BYTES")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
+    assumed = _config.env("PATHWAY_ASSUME_HBM_BYTES")
+    if assumed is not None:
+        return assumed
     stats = jax_memory_stats()
     if stats and stats.get("bytes_limit"):
         return float(stats["bytes_limit"])

@@ -40,7 +40,6 @@ from __future__ import annotations
 
 import collections
 import logging
-import os
 import threading
 import time
 from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
@@ -50,20 +49,13 @@ import numpy as np
 logger = logging.getLogger(__name__)
 
 
-def _env_float(name: str, default: float) -> float:
-    try:
-        return float(os.environ.get(name, "") or default)
-    except ValueError:
-        return default
-
-
 # Straggler detection knobs (documented in ARCHITECTURE.md "Device
 # utilization"): a replica whose windowed device-seconds exceed the
 # replica mean by SKEW_THRESHOLD for PATIENCE consecutive dispatches is
 # flagged — flight-recorder event + warn-once log.
-SKEW_THRESHOLD = _env_float("PATHWAY_MESH_SKEW_THRESHOLD", 1.5)
-SKEW_PATIENCE = int(_env_float("PATHWAY_MESH_SKEW_PATIENCE", 3))
-SKEW_WINDOW_S = _env_float("PATHWAY_MESH_SKEW_WINDOW_S", 30.0)
+SKEW_THRESHOLD = 1.5
+SKEW_PATIENCE = 3
+SKEW_WINDOW_S = 30.0
 
 
 class MeshBackend:
@@ -102,7 +94,7 @@ class MeshBackend:
             "pathway_mesh_replica_skew_ratio",
             help="Max replica windowed device-seconds over the replica "
             "mean (1.0 = balanced; straggler flagged above "
-            "PATHWAY_MESH_SKEW_THRESHOLD)",
+            f"{SKEW_THRESHOLD})",
             callback=self._skew_ratio_or_none,
         )
         self.recorder = FlightRecorder(capacity=128)

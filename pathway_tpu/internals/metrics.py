@@ -27,6 +27,10 @@ import time as time_mod
 from collections import deque
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
+from pathway_tpu.internals import config as _config
+
+FLIGHT_RECORDER_SIZE = 512  # events an engine's flight recorder keeps
+
 # log2 bucket upper bounds: 2^-20 s (~1 us) .. 2^4 s (16 s); one extra
 # implicit +Inf slot.  Powers of two make observe() a frexp, and merged
 # histograms from different workers always share boundaries.
@@ -680,7 +684,7 @@ class EngineMetrics:
         self.engine = engine
         reg = self.registry = MetricsRegistry(worker=str(engine.worker_id))
         self.recorder = FlightRecorder(
-            capacity=int(os.environ.get("PATHWAY_FLIGHT_RECORDER_SIZE", 512)),
+            capacity=FLIGHT_RECORDER_SIZE,
             worker=engine.worker_id,
         )
         # epoch tracing (sampled span store; see internals/tracing.py)
@@ -689,16 +693,11 @@ class EngineMetrics:
         # is set — the engine loop None-checks it, so the default cost
         # is a single attribute load per tick
         self.slow_watch = None
-        slow_ms = os.environ.get("PATHWAY_SLOW_TICK_MS")
-        if slow_ms:
-            try:
-                threshold = float(slow_ms)
-            except ValueError:
-                threshold = 0.0
-            if threshold > 0:
-                self.slow_watch = SlowTickWatchdog(
-                    engine, self.recorder, threshold
-                )
+        threshold = _config.env("PATHWAY_SLOW_TICK_MS")
+        if threshold is not None and threshold > 0:
+            self.slow_watch = SlowTickWatchdog(
+                engine, self.recorder, threshold
+            )
         self.node_hist = reg.histogram(
             "pathway_node_process_seconds",
             help="per-node process() wall time per tick",
@@ -952,7 +951,7 @@ def dump_diagnostics(engine, *, reason: str = "manual") -> Dict[str, Any]:
         "freshness": m.sink_freshness_stats() if m is not None else [],
     }
     engine.last_diagnostics = diag
-    dest = os.environ.get("PATHWAY_DIAGNOSTICS_DIR")
+    dest = _config.env("PATHWAY_DIAGNOSTICS_DIR")
     if dest:
         try:
             os.makedirs(dest, exist_ok=True)

@@ -26,6 +26,8 @@ import threading
 import time
 from typing import Any, Dict, Optional
 
+from pathway_tpu.internals import config as _config
+
 MAX_SECONDS = 120.0
 
 _lock = threading.Lock()  # held for the WHOLE capture: the busy guard
@@ -41,7 +43,7 @@ def _trace_dir(out_dir: Optional[str]) -> str:
     if out_dir:
         os.makedirs(out_dir, exist_ok=True)
         return out_dir
-    base = os.environ.get("PATHWAY_PROFILE_DIR")
+    base = _config.env("PATHWAY_PROFILE_DIR")
     if base:
         os.makedirs(base, exist_ok=True)
         return tempfile.mkdtemp(prefix="capture-", dir=base)

@@ -46,9 +46,10 @@ Disabled (the default) every hook site is one module attribute read
 
 from __future__ import annotations
 
-import os
 import threading
 from typing import Any, Dict, List, Optional
+
+from pathway_tpu.internals import config as _config
 
 ACTIVE = False
 _TRACKER: Optional["ProvenanceTracker"] = None
@@ -72,7 +73,7 @@ def install(enable: bool = True) -> None:
 def install_from_env() -> None:
     """Arm once per run from PATHWAY_PROVENANCE (runner.run calls this
     next to sanitizer.install_from_env, before the graph builds)."""
-    if os.environ.get("PATHWAY_PROVENANCE", "0") == "1":
+    if _config.env("PATHWAY_PROVENANCE"):
         install(True)
 
 
@@ -130,20 +131,8 @@ class ProvenanceTracker:
         self.epochs_seen = 0
         self.epochs_recorded = 0
         self._seen_epoch_set: set = set()
-        try:
-            self.sample_every = max(
-                1, int(os.environ.get("PATHWAY_PROVENANCE_SAMPLE", "1"))
-            )
-        except ValueError:
-            self.sample_every = 1
-        try:
-            self.budget_bytes = int(
-                os.environ.get(
-                    "PATHWAY_PROVENANCE_BUDGET_BYTES", str(64 * 1024 * 1024)
-                )
-            )
-        except ValueError:
-            self.budget_bytes = 64 * 1024 * 1024
+        self.sample_every = max(1, _config.env("PATHWAY_PROVENANCE_SAMPLE"))
+        self.budget_bytes = _config.env("PATHWAY_PROVENANCE_BUDGET_BYTES")
         # source node op -> next row offset
         self._source_offsets: Dict[str, int] = {}
         # keystrs the serving result-cache answered without a dispatch;

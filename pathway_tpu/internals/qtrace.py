@@ -48,30 +48,27 @@ before the punctuation that completes it).
 
 ``PATHWAY_QTRACE=0`` disables everything: every hook site guards on the
 module attribute ``ENABLED``, so the disabled cost is one attribute
-read.  This module imports only the stdlib (never jax).
+read.  This module never imports jax.
 
 Config:
   PATHWAY_QTRACE=0            disable (default: enabled)
-  PATHWAY_QTRACE_SAMPLE=N     trace every Nth query (default 1 = all)
   PATHWAY_SLO_P99_MS=F        declarative p99 target in ms
-  PATHWAY_SLO_WINDOW_S=F      burn-rate window (default 60)
-  PATHWAY_SLO_BURN_SUSTAIN_S=F  sustained-burn threshold (default 30)
-  PATHWAY_QTRACE_EXEMPLAR_K=F exemplar trigger factor over p99 (default 1.5)
 """
 
 from __future__ import annotations
 
 import logging
-import os
 import threading
 import time as time_mod
 from collections import deque
 from operator import itemgetter
 from typing import Any, Dict, List, Optional
 
+from pathway_tpu.internals import config as _config
+
 _BY_VALUE = itemgetter(1)
 
-ENABLED = os.environ.get("PATHWAY_QTRACE", "1") != "0"
+ENABLED = _config.env("PATHWAY_QTRACE")
 
 logger = logging.getLogger("pathway_tpu.qtrace")
 
@@ -90,12 +87,10 @@ _QUANTILES = (0.5, 0.95, 0.99, 0.999)
 # pids so query spans merge cleanly into engine.dump_trace() output
 _TRACE_PID = 9999
 
-
-def _env_float(name: str, default: float) -> float:
-    try:
-        return float(os.environ.get(name, default))
-    except ValueError:
-        return default
+SAMPLE_EVERY = 1  # trace every Nth query: all of them
+EXEMPLAR_K = 1.5  # a query this many times over the p99 keeps its spans
+SLO_WINDOW_S = 60.0  # burn-rate window
+SLO_BURN_SUSTAIN_S = 30.0  # a burn this long is warned about
 
 
 class QueryTracer:
@@ -125,9 +120,7 @@ class QueryTracer:
         # legitimately holds >4096 spans in flight must not pay it on
         # every begin (nothing would be stale yet anyway)
         self._last_evict = 0.0
-        self.sample_every = max(
-            1, int(_env_float("PATHWAY_QTRACE_SAMPLE", 1))
-        )
+        self.sample_every = SAMPLE_EVERY
         self._seq = 0
         # "cache" is an extra reporting stage (not in the mark chain):
         # result-cache hits book their search_start->device_end wall
@@ -141,7 +134,7 @@ class QueryTracer:
         self._finish_walls: deque = deque(maxlen=8192)  # for QPS
         # slow-query exemplars: full span trees, capped ring
         self.exemplars: deque = deque(maxlen=32)
-        self.exemplar_k = _env_float("PATHWAY_QTRACE_EXEMPLAR_K", 1.5)
+        self.exemplar_k = EXEMPLAR_K
         self._recent: deque = deque(maxlen=64)  # last finished spans
         # exemplar threshold cache: quantile() compresses the digest, so
         # computing p99 on EVERY finish would put a sort on the serving
@@ -151,15 +144,9 @@ class QueryTracer:
         self._p99_cache: Optional[float] = None
         self.recorder = FlightRecorder(capacity=128)
         # SLO burn state
-        self.slo_p99_ms: Optional[float] = None
-        env_slo = os.environ.get("PATHWAY_SLO_P99_MS")
-        if env_slo:
-            try:
-                self.slo_p99_ms = float(env_slo)
-            except ValueError:
-                pass
-        self.slo_window_s = _env_float("PATHWAY_SLO_WINDOW_S", 60.0)
-        self.burn_sustain_s = _env_float("PATHWAY_SLO_BURN_SUSTAIN_S", 30.0)
+        self.slo_p99_ms: Optional[float] = _config.env("PATHWAY_SLO_P99_MS")
+        self.slo_window_s = SLO_WINDOW_S
+        self.burn_sustain_s = SLO_BURN_SUSTAIN_S
         self._slo_samples: deque = deque(maxlen=8192)  # (wall, violated)
         self.slo_violations = 0
         self._burn_since: Optional[float] = None

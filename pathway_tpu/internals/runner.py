@@ -12,6 +12,7 @@ import threading
 from typing import Any, Dict, List, Optional
 
 from pathway_tpu.engine.engine import CaptureNode, Engine
+from pathway_tpu.internals import config as _config
 from pathway_tpu.internals.parse_graph import G
 
 
@@ -114,8 +115,8 @@ _last_engine = None
 
 
 def last_engine():
-    """The engine of the most recent pw.run in this process (benchmarks
-    and tests inspect coordinator/tick counters post-run)."""
+    """The engine of the most recent pw.run in this process (the
+    benchmark harness stops it; tests inspect its counters post-run)."""
     return _last_engine
 
 
@@ -477,13 +478,9 @@ def _supervise_thread_group(group, ts, worker, threads: int) -> None:
     aborted the barrier, survivors roll back and park in
     failover_rendezvous; we join the corpse, reset the group state and
     start a replacement thread on the same slot)."""
-    import os
     import time as time_mod
 
-    try:
-        rejoin_timeout = float(os.environ.get("PATHWAY_REJOIN_TIMEOUT", "30"))
-    except ValueError:
-        rejoin_timeout = 30.0
+    rejoin_timeout = _config.env("PATHWAY_REJOIN_TIMEOUT")
     while True:
         if group._failover_pending and not group._aborted:
             failed = sorted(group._failed)

@@ -38,9 +38,10 @@ metric families, and ``sanitizer`` flight-recorder events.
 
 from __future__ import annotations
 
-import os
 import threading
 from typing import Any, Dict, List, Optional
+
+from pathway_tpu.internals import config as _config
 
 ACTIVE = False
 _TRACKER: Optional["SanitizerTracker"] = None
@@ -65,7 +66,7 @@ def install_from_env() -> None:
     """Arm once per run from PATHWAY_SANITIZE (runner.run calls this
     next to faults.install_from_env — arming must precede node build so
     UDF programs compile with the hashing wrapper)."""
-    if os.environ.get("PATHWAY_SANITIZE", "0") == "1":
+    if _config.env("PATHWAY_SANITIZE"):
         install(True)
 
 

@@ -16,8 +16,7 @@ to a single ``ENABLED`` read, enforced by tests/test_perf_smoke.py):
       ``PATHWAY_SERVE_MAX_BATCH``) and pushes the whole batch into the
       connector under ONE commit.  The engine then sees N queries in one
       tick, `ExternalIndexNode` batches them into one
-      ``FusedEmbedSearch`` program (reusing ``tokenizer.pack_batch``
-      slabs when ``PATHWAY_SERVE_PACK_QUERIES=1``), and the existing
+      ``FusedEmbedSearch`` program, and the existing
       per-key response futures de-multiplex the results — per-query
       qtrace spans stay intact, annotated with the batch occupancy they
       rode in.
@@ -60,50 +59,28 @@ states), ``serving_metrics()`` joins the Prometheus exposition, and
 
 from __future__ import annotations
 
-import os
 import threading
 import time as time_mod
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from pathway_tpu.internals import config as _config
+
 # Cheap guard read by every hook site (HTTP ingress, knn add/remove,
 # index-node search, health tick).
-ENABLED = os.environ.get("PATHWAY_SERVING", "1") != "0"
-
-
-def _env_float(name: str, default: float) -> float:
-    try:
-        return float(os.environ.get(name, "") or default)
-    except ValueError:
-        return default
-
-
-def _env_int(name: str, default: int) -> int:
-    try:
-        return int(os.environ.get(name, "") or default)
-    except ValueError:
-        return default
+ENABLED = _config.env("PATHWAY_SERVING")
 
 
 def batch_window_ms() -> float:
     """Arrival-queue hold time before a partial batch flushes.  0
-    disables coalescing (every request commits alone — the per-query
-    baseline arm of serving_bench)."""
-    return max(0.0, _env_float("PATHWAY_SERVE_BATCH_WINDOW_MS", 2.0))
+    disables coalescing (every request commits alone)."""
+    return max(0.0, _config.env("PATHWAY_SERVE_BATCH_WINDOW_MS"))
 
 
 def max_batch() -> int:
     """Size trigger: a batch this large flushes without waiting out the
     window."""
-    return max(1, _env_int("PATHWAY_SERVE_MAX_BATCH", 64))
-
-
-def pack_queries() -> bool:
-    """Opt-in packed multi-query search (tokenizer.pack_batch slabs for
-    the query batch).  Off by default: packed encoding is numerically
-    equivalent but not bitwise identical to the classic bucketed encode,
-    and the coalescing win does not depend on it."""
-    return os.environ.get("PATHWAY_SERVE_PACK_QUERIES", "0") != "0"
+    return max(1, _config.env("PATHWAY_SERVE_MAX_BATCH"))
 
 
 def tenant_rate() -> float:
@@ -111,7 +88,7 @@ def tenant_rate() -> float:
     tokens/s); 0.0 means tenant limits are off.  Read at build time by
     analyzer PWT801 (limits armed while query tracing is off means shed
     decisions are unattributable)."""
-    return max(0.0, _env_float("PATHWAY_SERVE_TENANT_RATE", 0.0))
+    return max(0.0, _config.env("PATHWAY_SERVE_TENANT_RATE"))
 
 
 # Result-key cluster count for remove-precision invalidation.  A removed
@@ -120,11 +97,11 @@ N_CLUSTERS = 256
 
 # Serving-priority scale applied to ingest pipelines while the SLO burns
 # (fraction of their configured queue/in-flight ceilings they keep).
-PRIORITY_SCALE = _env_float("PATHWAY_SERVE_PRIORITY_SCALE", 0.5)
+PRIORITY_SCALE = 0.5
 
 # Burn-rate hysteresis: engage priority at >= ON, release at < OFF.
-BURN_ON = _env_float("PATHWAY_SERVE_BURN_ON", 1.0)
-BURN_OFF = _env_float("PATHWAY_SERVE_BURN_OFF", 0.5)
+BURN_ON = 1.0
+BURN_OFF = 0.5
 
 # Serving's target share of attributed device time while the SLO burns.
 # With the cost ledger live the partitioner steers to this share instead
@@ -132,7 +109,7 @@ BURN_OFF = _env_float("PATHWAY_SERVE_BURN_OFF", 0.5)
 # serving actually holds LESS device time than the target, and releases
 # as soon as it reaches it — burn caused by something other than device
 # contention (e.g. host-bound tokenize) no longer starves ingest.
-SERVE_SHARE_TARGET = _env_float("PATHWAY_SERVE_SHARE_TARGET", 0.5)
+SERVE_SHARE_TARGET = 0.5
 
 # Partitioner tick pacing (wall clock).
 _PARTITION_TICK_S = 0.25
@@ -171,12 +148,12 @@ class AdmissionController:
     work happens."""
 
     def __init__(self):
-        self.bound = max(1, _env_int("PATHWAY_SERVE_QUEUE", 256))
-        self.rate = max(0.0, _env_float("PATHWAY_SERVE_TENANT_RATE", 0.0))
-        default_burst = max(1.0, self.rate) if self.rate > 0 else 1.0
-        self.burst = max(
-            1.0, _env_float("PATHWAY_SERVE_TENANT_BURST", default_burst)
-        )
+        self.bound = max(1, _config.env("PATHWAY_SERVE_QUEUE"))
+        self.rate = tenant_rate()
+        burst = _config.env("PATHWAY_SERVE_TENANT_BURST")
+        if burst is None:
+            burst = self.rate  # a second's worth of the rate
+        self.burst = max(1.0, burst)
         self._lock = threading.Lock()
         self.depth = 0
         self._tenants: Dict[str, _TokenBucket] = {}
@@ -263,7 +240,7 @@ class ResultCache:
     stale, while removals keep unrelated hot entries warm."""
 
     def __init__(self):
-        self.capacity = max(0, _env_int("PATHWAY_SERVE_CACHE", 1024))
+        self.capacity = max(0, _config.env("PATHWAY_SERVE_CACHE"))
         self._lock = threading.Lock()
         self._entries: "OrderedDict[tuple, dict]" = OrderedDict()
         self.gen_global = 0

@@ -34,6 +34,8 @@ import subprocess
 import time as time_mod
 from typing import Callable, Dict, List, Optional, Sequence
 
+from pathway_tpu.internals import config as _config
+
 # Exit code a worker script uses to signal "killed by fault injection,
 # please respawn me" (the chaos scripts catch WorkerKilled and exit with
 # this; anything nonzero is restartable under PATHWAY_FAILOVER=1).
@@ -69,7 +71,7 @@ class RestartPolicy:
             return False
         if injected:
             return True
-        return os.environ.get("PATHWAY_FAILOVER") == "1"
+        return _config.env("PATHWAY_FAILOVER")
 
     def note_restart(self, *, graceful: bool = False) -> None:
         if graceful:

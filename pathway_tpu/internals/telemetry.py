@@ -10,10 +10,11 @@ server_endpoint=...)` or the PATHWAY_MONITORING_SERVER env var."""
 from __future__ import annotations
 
 import contextlib
-import os
 from typing import Any, Optional
 
-_config: dict = {"endpoint": os.environ.get("PATHWAY_MONITORING_SERVER")}
+from pathway_tpu.internals import config as _options
+
+_config: dict = {"endpoint": _options.env("PATHWAY_MONITORING_SERVER")}
 _tracer = None
 
 

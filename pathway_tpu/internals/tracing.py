@@ -15,7 +15,7 @@ Sampling config (read once per engine):
   PATHWAY_TRACE=0          tracing fully off
   PATHWAY_TRACE=1          trace every epoch
   PATHWAY_TRACE_SAMPLE=N   trace epochs where time % N == 0 (default 16)
-  PATHWAY_TRACE_EPOCHS=K   ring capacity in epochs (default 128)
+The ring keeps the last ``TRACE_EPOCHS`` sampled epochs.
 
 Overhead budget: unsampled ticks pay one attribute load + one modulo;
 sampled ticks add one tuple append per active node.  The perf-smoke
@@ -41,6 +41,10 @@ import threading
 import time as time_mod
 from collections import deque
 from typing import Any, Callable, Dict, Iterable, List, Optional
+
+from pathway_tpu.internals import config as _config
+
+TRACE_EPOCHS = 128  # sampled epochs a TraceStore's ring keeps
 
 
 class _EpochRecord:
@@ -76,24 +80,16 @@ class TraceStore:
         sample_every: int | None = None,
         capacity: int | None = None,
     ):
-        env = os.environ
-        mode = env.get("PATHWAY_TRACE")
+        mode = _config.env("PATHWAY_TRACE")
         self.enabled = mode != "0"
         if sample_every is None:
-            if mode == "1":
-                sample_every = 1
-            else:
-                try:
-                    sample_every = int(env.get("PATHWAY_TRACE_SAMPLE", 16))
-                except ValueError:
-                    sample_every = 16
+            sample_every = (
+                1 if mode == "1" else _config.env("PATHWAY_TRACE_SAMPLE")
+            )
         self.sample_every = max(1, sample_every)
         self.worker_id = worker_id
         if capacity is None:
-            try:
-                capacity = int(env.get("PATHWAY_TRACE_EPOCHS", 128))
-            except ValueError:
-                capacity = 128
+            capacity = TRACE_EPOCHS
         self.epochs: deque = deque(maxlen=max(1, capacity))
         self.current: Optional[_EpochRecord] = None
         # perf_counter -> wall-clock offset, sampled once (flight-recorder

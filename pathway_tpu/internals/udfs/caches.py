@@ -13,6 +13,8 @@ import os
 import pickle
 from typing import Any, Callable
 
+from pathway_tpu.internals import config as _config
+
 
 class CacheStrategy:
     def get(self, key: str, default=None):
@@ -40,7 +42,7 @@ class DiskCache(CacheStrategy):
     diskcache). Stored under PATHWAY_PERSISTENT_STORAGE or ./Cache."""
 
     def __init__(self, name: str | None = None, size_limit: int | None = None):
-        root = os.environ.get("PATHWAY_PERSISTENT_STORAGE", "./Cache")
+        root = _config.env("PATHWAY_PERSISTENT_STORAGE")
         self._dir = os.path.join(root, "udf_cache", name or "default")
         os.makedirs(self._dir, exist_ok=True)
 

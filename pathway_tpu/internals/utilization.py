@@ -35,7 +35,7 @@ device executes the dispatch chain in-order, so consecutive completion
 timestamps bracket its busy time; when a wait returns instantly the
 batch had already finished and the interval over-counts the gap — it is
 an upper bound between observations, good enough for skew/attribution,
-and never used for MFU (MFU is judged on wall time, same as bench.py).
+and never used for MFU (MFU is judged on wall time).
 
 ``PATHWAY_DEVICE_UTIL=0`` disables everything; hook sites guard on the
 module-global ``ENABLED`` so the disabled cost is one attribute read
@@ -45,20 +45,19 @@ module-global ``ENABLED`` so the disabled cost is one attribute read
 from __future__ import annotations
 
 import collections
-import os
 import threading
 import time
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
-from pathway_tpu.internals import tracing
+from pathway_tpu.internals import config as _config, tracing
 from pathway_tpu.internals.metrics import MetricsRegistry
 
 # Cheap guard read by every hook site (device_pipeline dispatch loop).
-ENABLED = os.environ.get("PATHWAY_DEVICE_UTIL", "1") != "0"
+ENABLED = _config.env("PATHWAY_DEVICE_UTIL")
 
 # Rolling-window length: long enough to smooth chunked ingest, short
 # enough that /status answers about NOW.
-WINDOW_S = float(os.environ.get("PATHWAY_UTIL_WINDOW_S", "30") or 30)
+WINDOW_S = 30.0
 
 # Bound-state thresholds (module constants so tests and ARCHITECTURE.md
 # pin the same numbers).
@@ -254,9 +253,8 @@ def device_window_seconds() -> float:
 
 
 def reset_window(window_s: float = WINDOW_S) -> UtilizationTracker:
-    """Replace the process tracker with a fresh (empty) window — used by
-    tests and by bench.py to scope the live-MFU cross-check to exactly
-    one measured phase."""
+    """Replace the process tracker with a fresh (empty) window — the
+    tests' fixture, scoping a live-MFU check to one measured phase."""
     global _TRACKER
     _TRACKER = UtilizationTracker(window_s)
     return _TRACKER

@@ -17,6 +17,7 @@ import time as time_mod
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from pathway_tpu.engine.value import Pointer, ref_scalar
+from pathway_tpu.internals import config as _config
 from pathway_tpu.internals import dtype as dt
 from pathway_tpu.internals import sanitizer as _sanitizer
 from pathway_tpu.internals.parse_graph import G
@@ -980,7 +981,7 @@ class StreamingDriver:
             and hasattr(coord, "enable_failover")
         ):
             coord.enable_failover()
-        max_failovers = int(os.environ.get("PATHWAY_MAX_FAILOVERS", "3"))
+        max_failovers = _config.env("PATHWAY_MAX_FAILOVERS")
         failovers = 0
         failover_started = 0.0
         while True:

@@ -2,8 +2,8 @@
 
 TPU-native replacement for the reference's local HF pipeline
 (reference: xpacks/llm/llms.py HFPipelineChat:456 — torch pipeline,
-batch 32). Geometry for the Private-RAG target (Mistral-7B-class) is defined
-in transformer.MISTRAL_7B; without pretrained weights (zero egress) the
+batch 32). Geometry for the Private-RAG target (Mistral-7B-class) is
+decoder.MISTRAL_7B_DECODER; without pretrained weights (zero egress) the
 default instance is a random-weight tiny decoder that exercises the exact
 compute path (tokenize → bucketed batch → jit forward → greedy decode).
 """

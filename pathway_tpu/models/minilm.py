@@ -70,8 +70,7 @@ class SentenceEncoder:
                     f"so a {n_dev}-way '{axis}' shard would never divide "
                     f"the batch axis evenly. Use a power-of-two device "
                     f"count on that axis, or drop the mesh and run the "
-                    f"single-device async pipeline "
-                    f"(PATHWAY_DEVICE_PIPELINE=1, the default)"
+                    f"single-device async pipeline"
                 )
         self.mesh = mesh
 

@@ -11,13 +11,13 @@ a small set of shapes.
 
 from __future__ import annotations
 
-import os
 import re
 import zlib
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from pathway_tpu.internals import config as _config
 from pathway_tpu.internals.tracing import span
 
 _WORD_RE = re.compile(r"[A-Za-z0-9]+|[^\sA-Za-z0-9]")
@@ -260,13 +260,8 @@ def pack_token_budget(default: int = 256) -> int:
     read per call like PATHWAY_INGEST_CHUNK). 0 disables packing and the
     ingest path falls back to the classic one-doc-per-row bucketed
     encode."""
-    raw = os.environ.get("PATHWAY_PACK_TOKEN_BUDGET", "")
-    if not raw:
-        return default
-    try:
-        return max(0, int(raw))
-    except ValueError:
-        return default
+    budget = _config.env("PATHWAY_PACK_TOKEN_BUDGET")
+    return default if budget is None else max(0, budget)
 
 
 def pack_batch(

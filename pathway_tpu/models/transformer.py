@@ -51,30 +51,6 @@ MINILM_L6 = TransformerConfig(
     vocab_size=30522, hidden=384, layers=6, heads=12, mlp_dim=1536
 )
 
-# Mistral-7B-class geometry (the reference's Private-RAG HFPipelineChat
-# target, llms.py:456); instantiate smaller variants for tests
-MISTRAL_7B = TransformerConfig(
-    vocab_size=32000,
-    hidden=4096,
-    layers=32,
-    heads=32,
-    mlp_dim=14336,
-    max_len=4096,
-    causal=True,
-    pooling="none",
-)
-
-TINY_DECODER = TransformerConfig(
-    vocab_size=1024,
-    hidden=64,
-    layers=2,
-    heads=4,
-    mlp_dim=128,
-    max_len=128,
-    causal=True,
-    pooling="none",
-)
-
 
 def init_params(rng, config: TransformerConfig) -> Dict[str, Any]:
     import jax
@@ -572,45 +548,6 @@ class TransformerLM:
             mask=mask,
             mesh=mesh,
         )
-
-    # -- greedy generation (decoder) --------------------------------------
-    def generate(self, ids: np.ndarray, mask: np.ndarray, max_new_tokens: int = 16):
-        """Greedy decode; recomputes the prefix each step (fine for the
-        test-scale decoder; a KV-cached lax.scan path is the optimization
-        target for the Private-RAG config)."""
-        import jax.numpy as jnp
-
-        ids = np.asarray(ids)
-        mask = np.asarray(mask)
-        max_len = self.config.max_len
-        if ids.shape[1] > max_len:
-            ids = ids[:, :max_len]
-            mask = mask[:, :max_len]
-        out_tokens = []
-        for _ in range(max_new_tokens):
-            logits = self._encode_jit(self.params, ids=ids, mask=mask)
-            lengths = mask.sum(axis=1) - 1
-            last = np.asarray(logits)[
-                np.arange(ids.shape[0]), lengths, :
-            ]
-            nxt = last.argmax(-1).astype(np.int32)
-            out_tokens.append(nxt)
-            b, l = ids.shape
-            if (lengths + 1 >= l).any():
-                if l >= max_len:
-                    # context window exhausted — positional table is the
-                    # hard ceiling; stop rather than overflow pos_embed
-                    break
-                grow = min(l, max_len - l)
-                ids = np.concatenate(
-                    [ids, np.zeros((b, grow), dtype=ids.dtype)], axis=1
-                )
-                mask = np.concatenate(
-                    [mask, np.zeros((b, grow), dtype=mask.dtype)], axis=1
-                )
-            ids[np.arange(b), lengths + 1] = nxt
-            mask[np.arange(b), lengths + 1] = 1
-        return np.stack(out_tokens, axis=1)
 
 
 LM = TransformerLM  # `model_module(config).LM`

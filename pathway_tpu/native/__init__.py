@@ -17,6 +17,8 @@ import os
 import subprocess
 from typing import Optional
 
+from pathway_tpu.internals import config as _config
+
 logger = logging.getLogger(__name__)
 
 
@@ -42,12 +44,9 @@ def _source_path(name: str) -> str:
 
 
 def _cache_dir() -> str:
-    root = os.environ.get(
-        "PATHWAY_NATIVE_CACHE",
-        os.path.join(
-            os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-            "pathway_tpu",
-        ),
+    root = _config.env("PATHWAY_NATIVE_CACHE") or os.path.join(
+        os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
+        "pathway_tpu",
     )
     os.makedirs(root, exist_ok=True)
     return root
@@ -58,7 +57,7 @@ def load() -> Optional[ctypes.CDLL]:
     global _lib, _build_failed
     if _lib is not None:
         return _lib
-    if _build_failed or os.environ.get("PATHWAY_DISABLE_NATIVE"):
+    if _build_failed or _config.env("PATHWAY_DISABLE_NATIVE"):
         return None
     source = _source_path("tokenizer.cpp")
     try:
@@ -154,7 +153,7 @@ def load_wire_ext():
     global _wire_ext, _wire_ext_failed
     if _wire_ext is not None:
         return _wire_ext
-    if _wire_ext_failed or os.environ.get("PATHWAY_DISABLE_NATIVE"):
+    if _wire_ext_failed or _config.env("PATHWAY_DISABLE_NATIVE"):
         return None
     try:
         import importlib.machinery

@@ -532,46 +532,6 @@ def _compiled_fused_search(config, metric: str, k: int, mesh=None, n_rows: int =
     return jax.jit(fused)
 
 
-@functools.lru_cache(maxsize=None)
-def _compiled_fused_packed_search(
-    config, metric: str, k: int, max_segments: int, mesh=None, n_rows: int = 0
-):
-    """Packed-query variant of the fused program: the query batch arrives
-    as tokenizer.pack_batch slabs (ids/seg [R, L] + per-query gather
-    indices), so a coalesced serving batch costs one slab-sized encode
-    instead of one padded [B, L] encode — same one-jit discipline."""
-    import jax
-    import jax.numpy as jnp
-
-    from pathway_tpu.models.transformer import model_module
-
-    forward = model_module(config).forward
-
-    def fused(params, ids, seg, rows, segs, buffer, valid):
-        pooled = forward(
-            params,
-            config,
-            ids.astype(jnp.int32),
-            None,
-            seg=seg.astype(jnp.int32),
-            max_segments=max_segments,
-            mesh=mesh,
-        )
-        emb = pooled[rows, segs]  # [Q, H], device-side gather
-        if mesh is not None:
-            top_scores, top_idx = _sharded_search_body(
-                mesh, n_rows, k, metric
-            )(buffer, valid, emb)
-        else:
-            scores = _similarity(buffer, valid, emb, metric)
-            top_scores, top_idx = jax.lax.top_k(scores, k)
-        return jnp.concatenate(
-            [top_scores, top_idx.astype(jnp.float32)], axis=1
-        )
-
-    return jax.jit(fused)
-
-
 class FusedEmbedSearch:
     """tokens → encoder → similarity → top_k in ONE jit call.
 
@@ -791,20 +751,17 @@ class FusedEmbedSearch:
         from pathway_tpu.internals import qtrace as _qtrace
 
         t0 = time_mod.perf_counter() if _qtrace.ENABLED else 0.0
-        if _serving.ENABLED and len(texts) > 1 and _serving.pack_queries():
-            packed = self._packed_query_search(texts, k_eff)
-        else:
-            # ids/mask are wire-narrowed by encode_batch (one shared
-            # dtype); the fused jit upcasts on device
-            ids, mask = encode_batch(
-                self.encoder.tokenizer, texts, max_len=self.encoder.max_len
-            )
-            packed = self._fn(k_eff)(
-                self._params(),
-                np.stack([ids, mask]),
-                self.index._buffer,
-                self.index._valid_dev,
-            )
+        # ids/mask are wire-narrowed by encode_batch (one shared dtype);
+        # the fused jit upcasts on device
+        ids, mask = encode_batch(
+            self.encoder.tokenizer, texts, max_len=self.encoder.max_len
+        )
+        packed = self._fn(k_eff)(
+            self._params(),
+            np.stack([ids, mask]),
+            self.index._buffer,
+            self.index._valid_dev,
+        )
         packed = np.asarray(packed)[: len(texts)]
         if _qtrace.ENABLED:
             # pure device portion of the query (encode+search dispatch to
@@ -817,45 +774,6 @@ class FusedEmbedSearch:
         scores = packed[:, :k_eff]
         idx = packed[:, k_eff:].astype(np.int64)
         return _format_rows(scores, idx, self.index._key_of_slot)
-
-    def _packed_query_search(self, texts, k_eff: int):
-        """Serving opt-in (PATHWAY_SERVE_PACK_QUERIES=1): tokenize the
-        coalesced query batch into token-budget slabs and run packed
-        encode → per-query gather → similarity → top_k as ONE jit.  Off
-        by default — the packed reduction order is numerically equivalent
-        but not bitwise identical to the classic bucketed encode."""
-        from pathway_tpu.models.tokenizer import (
-            PACK_MAX_SEGMENTS,
-            pack_batch,
-            pack_token_budget,
-        )
-
-        ids, seg, slots = pack_batch(
-            self.encoder.tokenizer,
-            texts,
-            max_len=self.encoder.max_len,
-            token_budget=pack_token_budget() or 256,
-            max_segments=PACK_MAX_SEGMENTS,
-        )
-        # gather indices bucketed so occupancy jitter between serving
-        # batches reuses the same compiled executable
-        qb = _next_bucket(len(slots))
-        rows = np.zeros((qb,), dtype=np.int64)
-        segs = np.zeros((qb,), dtype=np.int64)
-        for i, (r, s) in enumerate(slots):
-            rows[i] = r
-            segs[i] = s
-        return _compiled_fused_packed_search(
-            self.encoder.config,
-            self.index.metric,
-            k_eff,
-            PACK_MAX_SEGMENTS,
-            mesh=self.index.mesh,
-            n_rows=self.index.capacity if self.index.mesh is not None else 0,
-        )(
-            self._params(), ids, seg, rows, segs,
-            self.index._buffer, self.index._valid_dev,
-        )
 
 
 def _sharded_search_body(mesh, n_rows: int, k: int, metric: str):

@@ -10,7 +10,6 @@ unchanged."""
 from __future__ import annotations
 
 import logging
-import os
 import enum
 from dataclasses import dataclass
 from typing import Any, Callable, Optional
@@ -18,6 +17,7 @@ from typing import Any, Callable, Optional
 import numpy as np
 
 from pathway_tpu.engine.index_node import IndexImpl
+from pathway_tpu.internals import config as _config
 from pathway_tpu.internals import serving as _serving
 from pathway_tpu.ops.knn import DeviceKnnIndex
 from pathway_tpu.stdlib.indexing._filters import evaluate_filter
@@ -192,15 +192,11 @@ class _FusedKnnIndexImpl(IndexImpl):
         chunk i+1 overlaps the device work of chunk i (the trade between
         the two is not measured on this machine).  Read per call so the
         knob works after import; invalid/negative values mean 'off'."""
-        try:
-            return max(0, int(os.environ.get("PATHWAY_INGEST_CHUNK", "0")))
-        except ValueError:
-            return 0
+        return max(0, _config.env("PATHWAY_INGEST_CHUNK"))
 
     # -- async device pipeline wiring --------------------------------------
 
     def _use_pipeline(self) -> bool:
-        from pathway_tpu.internals.device_pipeline import pipeline_enabled
         from pathway_tpu.internals.device_probe import device_degraded
 
         # a factory-attached mesh keeps the classic dispatch (sharded
@@ -210,8 +206,7 @@ class _FusedKnnIndexImpl(IndexImpl):
         # devices bypass the pipeline so in-flight work drains and new
         # batches take the synchronous path the monitor already guards
         return (
-            pipeline_enabled()
-            and not self._pipeline_broken
+            not self._pipeline_broken
             and (self.knn.mesh is None or self._backend is not None)
             and not device_degraded()
         )
@@ -313,8 +308,8 @@ class _FusedKnnIndexImpl(IndexImpl):
                         self.fused.embed_and_add(keys_c, texts_c)
                     break
         elif texts:
-            # classic synchronous path (PATHWAY_DEVICE_PIPELINE=0, mesh,
-            # degraded device, or prior pipeline failure); finish any
+            # classic synchronous path (factory mesh, degraded device,
+            # or prior pipeline failure); finish any
             # still-pipelined work first so delta order is preserved
             self._sync_pipeline(full=True)
             step = self._ingest_chunk() or len(texts) or 1

@@ -1,7 +1,7 @@
 """What the chip bring-up added, checked on the CPU: the compile cache's
 one place, the in-process device probe, one process per chip in the
 launchers, keyword forwarding in the REST servers, delete detection in
-the fs connector, and chip_smoke.py / bench.py failing without a chip."""
+the fs connector, and chip_smoke.py failing without a chip."""
 
 from __future__ import annotations
 
@@ -356,15 +356,6 @@ def test_chip_smoke_alone_in_a_directory_fails(tmp_path):
     )
     assert proc.returncode != 0
     assert proc.stdout.strip() == ""
-
-
-def test_bench_without_a_tpu_exits_nonzero_and_prints_no_value():
-    proc = _run_python(
-        [os.path.join(REPO, "bench.py")], {"JAX_PLATFORMS": "cpu"}
-    )
-    assert proc.returncode != 0
-    assert proc.stdout.strip() == ""
-    assert "needs a TPU" in proc.stderr
 
 
 def test_chip_smoke_dry_run_passes_at_tiny_size(tmp_path):

@@ -1,5 +1,5 @@
 """Cost & efficiency observability (internals/costledger.py,
-benchmarks/bench_compare.py, `pathway-tpu top`).
+`pathway-tpu top`).
 
 Covers the cost PR's acceptance contract: charges accumulate into
 (workload, route, tenant) cells, batched searches split their device
@@ -9,9 +9,7 @@ holds within 5% on the 8-device CPU mesh under concurrent ingest +
 serving with two tenants, result-cache hits book a distinct "cache"
 stage with zero device charge plus a computed savings gauge, the
 DeviceTimePartitioner's binary burn heuristic is refined by the ledger's
-serve share, the regression sentinel judges a series of bench rounds
-correctly (and flags an injected regression), and the `top` renderer
-works against /status JSON alone."""
+serve share, and the `top` renderer works against /status JSON alone."""
 
 from __future__ import annotations
 
@@ -346,142 +344,6 @@ def test_partitioner_share_gates_engage_and_release():
     finally:
         part.release_for_tests()
         serving.shutdown()
-
-
-# ---------------------------------------------------------------------------
-# bench regression sentinel on inline synthetic rounds
-# ---------------------------------------------------------------------------
-
-
-def _synthetic_round(scale: float) -> dict:
-    """One healthy bench payload; `scale` moves every rate together."""
-    return {
-        "metric": "docs/sec embedded+indexed",
-        "unit": "docs/s",
-        "platform": "tpu",
-        "device_kind": "TPU v5 lite",
-        "device_count": 1,
-        "n_docs": 16384,
-        "value": 20000.0 * scale,
-        "serving_qps_64clients": 600.0 * scale,
-        "device_phase_docs_per_sec": 23000.0 * scale,
-        "serving_p50_ms": 12.0 / scale,
-        "ingest_runs_docs_per_sec": [19000.0, 20000.0, 21000.0],
-    }
-
-
-def _write_synthetic_series(directory) -> None:
-    """r01-r04 healthy (r04 inside every band of the r01-r03 median), r05
-    a round that did not measure."""
-    import json
-
-    payloads = [_synthetic_round(s) for s in (0.95, 1.0, 1.05, 0.9)]
-    payloads.append({"value": None, "error": "no TPU"})
-    for i, parsed in enumerate(payloads, start=1):
-        with open(directory / f"BENCH_r0{i}.json", "w") as fh:
-            json.dump({"round": i, "parsed": parsed}, fh)
-
-
-def test_bench_compare_ok_on_synthetic_series(tmp_path):
-    """r05 did not measure, so r04 is judged against the median of
-    r01-r03 — and passes."""
-    from benchmarks import bench_compare
-
-    _write_synthetic_series(tmp_path)
-    rounds = bench_compare.load_rounds(str(tmp_path))
-    assert [n for n, _ in rounds] == [
-        f"BENCH_r0{i}.json" for i in range(1, 6)
-    ]
-    result = bench_compare.compare_series(rounds)
-    assert result["verdict"] == "ok"
-    assert result["latest"] == "BENCH_r04.json"
-    assert result["baseline_rounds"] == [
-        "BENCH_r01.json", "BENCH_r02.json", "BENCH_r03.json"
-    ]
-    # the round without a value is skipped, not judged as a regression
-    assert result["skipped_rounds"] == ["BENCH_r05.json"]
-    assert result["judged"] > 0 and result["failed"] == []
-    line = bench_compare.verdict_line(result)
-    assert line.startswith("bench-compare: ok BENCH_r04.json")
-
-
-def test_bench_compare_flags_injected_regression(tmp_path):
-    from benchmarks import bench_compare
-
-    _write_synthetic_series(tmp_path)
-    rounds = bench_compare.load_rounds(str(tmp_path))
-    healthy = [p for _n, p in rounds if bench_compare.is_healthy(p)]
-    injected = dict(healthy[-1])
-    injected["serving_qps_64clients"] = 1.0  # throughput collapses
-    result = bench_compare.compare_series(
-        rounds + [("BENCH_r99.json", injected)]
-    )
-    assert result["verdict"] == "regression"
-    assert result["failed"] == ["serving_qps_64clients"]
-    assert result["worst"]["key"] == "serving_qps_64clients"
-    assert result["worst"]["direction"] == "higher-better"
-    assert "REGRESSION" in bench_compare.verdict_line(result)
-
-
-def test_bench_compare_contract_awareness():
-    """Descriptor keys are never judged; *_ms keys regress upward,
-    throughput keys downward; a series with no measured round is skipped,
-    a single healthy round is insufficient data."""
-    from benchmarks import bench_compare
-
-    base = {
-        "value": 100.0, "metric": "x", "unit": "docs/s",
-        "device_kind": "TPU v5 lite", "device_count": 1,
-        "ingest_docs_per_sec": 100.0, "serving_p50_ms": 10.0,
-    }
-    rounds = [("BENCH_r01.json", dict(base)), ("BENCH_r02.json", dict(base))]
-
-    # a different device count is configuration, not a regression
-    moved = dict(base, device_count=4, n_docs=1)
-    res = bench_compare.compare_series(rounds + [("BENCH_r03.json", moved)])
-    assert res["verdict"] == "ok"
-    assert all(
-        c["key"] not in bench_compare.DESCRIPTOR_KEYS for c in res["checks"]
-    )
-
-    # direction: a latency rising past 1 + LOWER_TOL regresses
-    slow = dict(base, serving_p50_ms=10.0 * 1.6)
-    res = bench_compare.compare_series(rounds + [("BENCH_r03.json", slow)])
-    assert res["verdict"] == "regression"
-    assert res["failed"] == ["serving_p50_ms"]
-    # ... but the same latency key DROPPING is an improvement, in band
-    fast = dict(base, serving_p50_ms=1.0)
-    res = bench_compare.compare_series(rounds + [("BENCH_r03.json", fast)])
-    assert res["verdict"] == "ok"
-
-    unmeasured = {"value": None, "error": "no TPU"}
-    res = bench_compare.compare_series([("BENCH_r01.json", unmeasured)])
-    assert res["verdict"] == "skipped" and res["worst"] is None
-    res = bench_compare.compare_series([("BENCH_r01.json", dict(base))])
-    assert res["verdict"] == "insufficient-data" and res["worst"] is None
-
-
-def test_bench_compare_judges_a_current_payload_against_the_series(tmp_path):
-    """A fresh payload appended to the series is judged as its newest
-    round, and the verdict always names a worst key."""
-    from benchmarks import bench_compare
-
-    _write_synthetic_series(tmp_path)
-    rounds = bench_compare.load_rounds(str(tmp_path))
-    current = _synthetic_round(1.0)
-    result = bench_compare.compare_series(rounds + [("current", current)])
-    assert result["verdict"] == "ok" and result["latest"] == "current"
-    assert result["baseline_rounds"] == [
-        f"BENCH_r0{i}.json" for i in range(1, 5)
-    ]
-    assert result["worst"]["key"] in current
-    # lists (per-run series) are shape, not a single measurement
-    assert all(
-        c["key"] != "ingest_runs_docs_per_sec" for c in result["checks"]
-    )
-    halved = dict(current, value=current["value"] / 2)
-    result = bench_compare.compare_series(rounds + [("current", halved)])
-    assert result["verdict"] == "regression" and result["failed"] == ["value"]
 
 
 # ---------------------------------------------------------------------------

@@ -160,9 +160,11 @@ def test_packed_positions_restart_per_segment():
 
 
 def test_sync_async_ingest_value_parity():
-    """PATHWAY_DEVICE_PIPELINE=1 vs =0 produce byte-identical index
-    buffers when packing is pinned off: identical chunk boundaries feed
-    identical compiled dispatches, async only reorders WHEN they run."""
+    """The pipelined route and the synchronous one (the recovery path
+    after a failed batch, forced here through `_pipeline_broken`)
+    produce byte-identical index buffers when packing is pinned off:
+    identical chunk boundaries feed identical compiled dispatches, async
+    only reorders WHEN they run."""
     from pathway_tpu.stdlib.indexing.nearest_neighbors import (
         _FusedKnnIndexImpl,
     )
@@ -170,15 +172,12 @@ def test_sync_async_ingest_value_parity():
     texts = [f"alpha bravo doc{i} charlie delta" for i in range(48)]
     keys = list(range(len(texts)))
 
-    def ingest(flag: str):
-        with _env(
-            PATHWAY_DEVICE_PIPELINE=flag,
-            PATHWAY_PACK_TOKEN_BUDGET="0",
-            PATHWAY_INGEST_CHUNK="16",
-        ):
+    def ingest(synchronous: bool):
+        with _env(PATHWAY_PACK_TOKEN_BUDGET="0", PATHWAY_INGEST_CHUNK="16"):
             impl = _FusedKnnIndexImpl(
                 _encoder("parity-tiny"), "cos", len(texts)
             )
+            impl._pipeline_broken = synchronous
             impl.add_many(keys, texts, [None] * len(keys))
             impl.drain()
             used_pipeline = impl._pipeline is not None
@@ -186,8 +185,8 @@ def test_sync_async_ingest_value_parity():
                 impl.knn._buffer.astype("float32")
             )[: len(keys)], used_pipeline
 
-    sync_buf, sync_used = ingest("0")
-    async_buf, async_used = ingest("1")
+    sync_buf, sync_used = ingest(True)
+    async_buf, async_used = ingest(False)
     assert not sync_used and async_used
     assert np.array_equal(sync_buf, async_buf)
 
@@ -232,7 +231,7 @@ def test_device_flap_mid_pipeline_drains_and_degrades():
     device_probe._monitor = monitor
     faults.install("device_flap@probes=1")
     try:
-        with _env(PATHWAY_DEVICE_PIPELINE="1", PATHWAY_INGEST_CHUNK="8"):
+        with _env(PATHWAY_INGEST_CHUNK="8"):
             impl.add_many(range(12), texts[:12], [None] * 12)
             assert impl._pipeline is not None
             pipe = impl._pipeline
@@ -304,6 +303,40 @@ def test_pipeline_error_parks_and_replays():
         pipe.close()
 
 
+@pytest.mark.parametrize(
+    "variable, attribute, depth",
+    [
+        ("PATHWAY_PIPELINE_QUEUE", "max_prepared", 4),
+        ("PATHWAY_PIPELINE_IN_FLIGHT", "max_in_flight", 2),
+        ("PATHWAY_PIPELINE_PREP_WORKERS", "prep_workers", 2),
+    ],
+)
+def test_pipeline_depths_are_constants_not_environment(
+    variable, attribute, depth
+):
+    """The three depths are the module's constants whatever the
+    environment says; a caller that needs others passes them."""
+    from pathway_tpu.internals.device_pipeline import DevicePipeline
+
+    def build(**kwargs):
+        return DevicePipeline(
+            lambda item: (item, {}), lambda payload: None,
+            wait=lambda _h: None, name="test-depths", **kwargs
+        )
+
+    with _env(**{variable: "7"}):
+        pipe = build()
+        try:
+            assert getattr(pipe, attribute) == depth
+        finally:
+            pipe.close()
+        pipe = build(**{attribute: 3})
+        try:
+            assert getattr(pipe, attribute) == 3
+        finally:
+            pipe.close()
+
+
 def test_impl_pipeline_failure_replays_synchronously(caplog):
     """An impl-level dispatch failure downgrades to the classic path and
     replays the parked batches exactly once — every doc lands — and the
@@ -327,7 +360,7 @@ def test_impl_pipeline_failure_replays_synchronously(caplog):
         return orig(payload)
 
     impl.fused.dispatch_batch = flaky
-    with _env(PATHWAY_DEVICE_PIPELINE="1", PATHWAY_INGEST_CHUNK="4"):
+    with _env(PATHWAY_INGEST_CHUNK="4"):
         with caplog.at_level("ERROR"):
             impl.add_many(range(12), texts, [None] * 12)
             impl.drain()
@@ -365,15 +398,10 @@ def test_pipeline_status_and_gauges():
     tracing.reset_spans()
     impl = _FusedKnnIndexImpl(_encoder("status-tiny"), "cos", 32)
     texts = [f"india doc{i} juliet kilo" for i in range(16)]
-    with _env(
-        PATHWAY_DEVICE_PIPELINE="1",
-        PATHWAY_PACK_TOKEN_BUDGET="64",
-        PATHWAY_INGEST_CHUNK="8",
-    ):
+    with _env(PATHWAY_PACK_TOKEN_BUDGET="64", PATHWAY_INGEST_CHUNK="8"):
         impl.add_many(range(16), texts, [None] * 16)
         impl.drain()
         status = pipeline_status()
-        assert status["enabled"]
         assert status["active"] >= 1
         assert status["rows"] >= 16
         assert status["pad_waste_ratio"] is not None

@@ -23,10 +23,14 @@ def both_paths(request, monkeypatch):
     the classic row-wise fallback nodes forever (the gates would
     otherwise hide them on every eligible graph)."""
     if request.param == "classic":
-        from pathway_tpu.engine import vector_reduce
+        from pathway_tpu.engine import (
+            vector_flatten,
+            vector_join,
+            vector_reduce,
+        )
 
-        monkeypatch.setenv("PATHWAY_DISABLE_VECTOR_JOIN", "1")
-        monkeypatch.setenv("PATHWAY_DISABLE_VECTOR_FLATTEN", "1")
+        monkeypatch.setattr(vector_join, "VECTOR_JOIN_ENABLED", False)
+        monkeypatch.setattr(vector_flatten, "VECTOR_FLATTEN_ENABLED", False)
         # groupbys.py reads VECTOR_REDUCERS at build time
         monkeypatch.setattr(vector_reduce, "VECTOR_REDUCERS", set())
     return request.param

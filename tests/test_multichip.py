@@ -213,29 +213,28 @@ def test_mesh_backend_ingest_parity_vs_single_device():
     queries = [texts[3], texts[17], "token3 alpha"]
     enc = _encoder("mesh-parity-tiny", max_len=16)
 
-    with _env(PATHWAY_DEVICE_PIPELINE="1"):
-        ref = _FusedKnnIndexImpl(enc, "cos", 64)
-        ref.add_many(keys, texts, [None] * len(keys))
-        ref.drain()
-        ref_rows = ref.search_many(
+    ref = _FusedKnnIndexImpl(enc, "cos", 64)
+    ref.add_many(keys, texts, [None] * len(keys))
+    ref.drain()
+    ref_rows = ref.search_many(
+        queries, [3] * len(queries), [None] * len(queries)
+    )
+
+    with _activated("dp=4,tp=2") as backend:
+        impl = _FusedKnnIndexImpl(enc, "cos", 64)
+        assert impl.knn.mesh is backend.mesh
+        impl.add_many(keys, texts, [None] * len(keys))
+        impl.drain()
+        assert impl._pipeline is not None, "mesh backend must pipeline"
+        assert impl._pipeline.replicas == backend.dp
+        stats = impl._pipeline.stats()
+        assert stats["rows"] == len(keys)
+        per_replica = impl._pipeline.replica_stats()
+        assert len(per_replica) == backend.dp
+        assert sum(r["rows"] for r in per_replica) == len(keys)
+        rows = impl.search_many(
             queries, [3] * len(queries), [None] * len(queries)
         )
-
-        with _activated("dp=4,tp=2") as backend:
-            impl = _FusedKnnIndexImpl(enc, "cos", 64)
-            assert impl.knn.mesh is backend.mesh
-            impl.add_many(keys, texts, [None] * len(keys))
-            impl.drain()
-            assert impl._pipeline is not None, "mesh backend must pipeline"
-            assert impl._pipeline.replicas == backend.dp
-            stats = impl._pipeline.stats()
-            assert stats["rows"] == len(keys)
-            per_replica = impl._pipeline.replica_stats()
-            assert len(per_replica) == backend.dp
-            assert sum(r["rows"] for r in per_replica) == len(keys)
-            rows = impl.search_many(
-                queries, [3] * len(queries), [None] * len(queries)
-            )
     assert [[k for k, _ in r] for r in rows] == [
         [k for k, _ in r] for r in ref_rows
     ]
@@ -326,7 +325,7 @@ def test_degraded_mesh_device_flap_drains_and_falls_back():
     faults.install("device_flap@probes=1")
     try:
         with _activated("dp=4,tp=2") as backend, _env(
-            PATHWAY_DEVICE_PIPELINE="1", PATHWAY_INGEST_CHUNK="8"
+            PATHWAY_INGEST_CHUNK="8"
         ):
             impl = _FusedKnnIndexImpl(_encoder("mesh-flap-tiny"), "cos", 64)
             assert impl.knn.mesh is backend.mesh

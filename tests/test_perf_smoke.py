@@ -323,18 +323,17 @@ def test_ineligible_graphs_stay_classic():
 
 @pytest.mark.perf_smoke
 def test_async_device_pipeline_selected_when_enabled(monkeypatch):
-    """The async ingest pipeline is selection-gated like the columnar
-    nodes: with the default env (PATHWAY_DEVICE_PIPELINE unset = on) an
-    eligible ingest MUST route through the DevicePipeline — proven by
-    the pipeline's own dispatch counters, not timing (the docs/s claim
-    lives in benchmarks/engine_bench.py --pipeline)."""
+    """The async ingest pipeline is selected by what the code observes:
+    an eligible ingest (no factory mesh, device not degraded, no failed
+    batch) MUST route through the DevicePipeline — proven by the
+    pipeline's own dispatch counters, not timing (the docs/s claim is
+    the benchmark's, BENCHMARK.json)."""
     from pathway_tpu.models.minilm import SentenceEncoder
     from pathway_tpu.models.transformer import TransformerConfig
     from pathway_tpu.stdlib.indexing.nearest_neighbors import (
         _FusedKnnIndexImpl,
     )
 
-    monkeypatch.delenv("PATHWAY_DEVICE_PIPELINE", raising=False)
     tiny = TransformerConfig(
         vocab_size=512, hidden=32, layers=1, heads=2, mlp_dim=64, max_len=32
     )
@@ -1038,7 +1037,7 @@ def test_qtrace_default_sampling_overhead_under_5pct():
     begin, the mark chain, a device charge, finish into the digests —
     mirroring the rest connector's one-commit-per-query shape.  Ticks
     are sized at 1024 rows (~0.8 ms) to match the measured serving-path
-    per-query engine cost (benchmarks/serving_bench.py p50 ~1.1 ms), so
+    per-query engine cost (p50 ~1.1 ms on the CPU), so
     the ratio guards the real claim: hooks <5% of a served query.  The
     span lifecycle itself measures ~18 us.  Paired per-rep ratios with
     the min judged, as in the health-controller guard: each rep's
@@ -1473,8 +1472,8 @@ def test_provenance_armed_idle_overhead_under_5pct(monkeypatch):
     bookkeeping.  That must stay under 5% on the engine microbench loop
     — same min-of-N interleaved protocol as the sanitizer guard above.
     (The cost of actually RECORDING lineage is the measured, sampling-
-    controllable number `engine_bench --provenance` and bench.py's
-    `provenance_overhead` key report — not a guarded invariant.)"""
+    controllable number `engine_bench --provenance` reports — not a
+    guarded invariant.)"""
     import gc
     from time import perf_counter
 

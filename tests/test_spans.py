@@ -336,8 +336,7 @@ def test_launch_children_come_from_the_index_path(record):
 
     impl = _FusedKnnIndexImpl(_encoder("spans-tiny"), "cos", 32)
     texts = [f"lima doc{i} mike november" for i in range(12)]
-    with _env(PATHWAY_DEVICE_PIPELINE="1", PATHWAY_PACK_TOKEN_BUDGET="64",
-              PATHWAY_INGEST_CHUNK="4"):
+    with _env(PATHWAY_PACK_TOKEN_BUDGET="64", PATHWAY_INGEST_CHUNK="4"):
         with tracing.span("engine.tick", epoch=6):
             impl.add_many(range(12), texts, [None] * 12)
         impl.drain()
@@ -385,8 +384,7 @@ def test_packed_batches_are_counted_by_the_attention_they_ran(
         impl = _FusedKnnIndexImpl(
             SentenceEncoder(name, config=config, max_len=32), "cos", 32
         )
-        with _env(PATHWAY_DEVICE_PIPELINE="1", PATHWAY_PACK_TOKEN_BUDGET="64",
-                  PATHWAY_INGEST_CHUNK="4"):
+        with _env(PATHWAY_PACK_TOKEN_BUDGET="64", PATHWAY_INGEST_CHUNK="4"):
             impl.add_many(range(12), texts, [None] * 12)
             impl.drain()
         return np.asarray(impl.knn._buffer.astype("float32"))[:12]
