@@ -32,8 +32,12 @@ matrices' columns, regrouped once at init), so that every operand of the
 attention kernel (`ops/kernels/mla_attention.py`) leaves its matmul in the
 layout the kernel reads.  The held experts run as grouped matmuls
 (`jax.lax.ragged_dot`, on the TPU the device op `ragged-dot`) over a
-static buffer of the selected (token, held expert) pairs sorted by expert;
-pairs beyond the buffer are counted (`overflow`), never dropped silently.
+static buffer of the selected (token, held expert) pairs sorted by expert,
+each expert's group begun on a tile of the grouped matmul where the buffer
+has the room; pairs beyond the buffer are counted (`overflow`), never
+dropped silently.  What the path costs follows the pairs the slab holds:
+the results return to their tokens in one gather a token, the few tokens
+with several pairs summed first in a compact list (`held_experts`).
 """
 
 from __future__ import annotations
@@ -373,7 +377,11 @@ def route(h, router, config: MoeMlaConfig):
     return experts.astype(jnp.int32), weights
 
 
-PAIR_ROWS = 512  # the buffer's rows come in whole tiles of the grouped matmul
+# a tile of the TPU's grouped matmul: it works a tile and a group at a time
+# (12 groups of 256 rows took the time of 12 of 512, and 12 of 384, every
+# second of which lies across two tiles, half as much again: chip runs, PR
+# 33), so the buffer's rows come in whole tiles and a group begins one
+PAIR_ROWS = 512
 
 
 def pair_capacity(tokens: int, config: MoeMlaConfig) -> int:
@@ -381,79 +389,181 @@ def pair_capacity(tokens: int, config: MoeMlaConfig) -> int:
     of `tokens` slots.  Every pair there can be (tokens x k) up to 4,096
     rows: small batches cannot overflow.  Above that one row a token slot,
     where the expected load is tokens x k x held / routed (half a row a
-    token at 12 of 192, top-8) and the slab's padding routes nothing.
-    Rounded up to whole tiles: at 14,112 rows, which 512 does not divide,
-    the TPU's grouped matmul took 8.6 ms where it takes 2.5 at 14,336
-    (chip runs, PR 30)."""
+    token at 12 of 192, top-8) and the slab's padding routes nothing: the
+    room that lets every group begin a tile (`held_experts`), and a skewed
+    router's pairs still fit one after the other.  Rounded up to whole
+    tiles: at 14,112 rows, which 512 does not divide, the TPU's grouped
+    matmul took 8.6 ms where it takes 2.5 at 14,336 (chip runs, PR 30)."""
     every = tokens * config.experts_per_token
     return -(-min(every, max(tokens, 4096)) // PAIR_ROWS) * PAIR_ROWS
 
 
-def held_experts(h, valid, layer, config: MoeMlaConfig, capacity: Optional[int] = None):
+def combine_rows(tokens: int, config: MoeMlaConfig) -> int:
+    """Slots of the compact list of tokens with two or more pairs in the
+    buffer (`held_experts`' return).  A slab of at most 4,096 token slots
+    (the buffer's least size) has a slot a token: it needs no list, cannot
+    spill, and its program stays as small as it was, which is what the
+    search programs' query slabs are loaded for.  Above that an eighth of
+    the token slots in whole tiles: 2,048 for 14,112, where 12 of 192
+    experts held at top-8 give about 915 such tokens."""
+    if tokens <= 4096:
+        return tokens
+    return -(-tokens // (8 * PAIR_ROWS)) * PAIR_ROWS
+
+
+def held_experts(h, valid, layer, config: MoeMlaConfig, capacity: Optional[int] = None,
+                 *, listed: Optional[int] = None, with_stats: bool = False):
     """The routed experts' part of an expert layer that this rank
     computes.  h: [T, hidden] (normed), valid: [T] bool (padding routes
     nothing).  Returns (y [T, hidden], tokens per held expert
     [experts_held] int32, pairs selected and held but beyond the buffer
-    () int32).
+    () int32), and with `with_stats` a fourth: {"multi_pair_tokens",
+    "combine_spills", "groups_aligned", "groups_packed"}, each () int32.
+    `capacity` and `listed` override `pair_capacity` and `combine_rows`
+    (tests).
 
     Selected pairs on held experts are sorted by expert into a buffer of
-    `capacity` rows; three grouped matmuls (gate, up, down) run over the
-    groups' rows only.  The results go back to their tokens by gathers
-    from the token's side, one pass for every held pair the busiest token
-    has (4 or 5 of its 8 as a rule): a scatter-add of the buffer's rows
-    took 14.0 ms at [14336, 7168] where a pass takes 1.5 (chip runs, PR
-    30)."""
+    `capacity` rows, and three grouped matmuls (gate, up, down) run over
+    the groups' rows.  The TPU's grouped matmul works a 512-row tile and a
+    group at a time, so a group of 476 rows that begins in the middle of a
+    tile costs two tiles' time: 12 such groups one after the other took
+    6.74 ms for the three matmuls whatever the buffer's size (14,336 rows
+    or 7,168), and 4.17 ms with every group moved to a tile's first row
+    (chip runs, PR 33).  So each group begins a tile where the buffer has
+    the room (12 to 17 of its 28 tiles at the ingest slab), and otherwise
+    the groups follow each other as the overflow count assumes: the layout
+    is data (`sizes`), not a second program.
+
+    The results go back to their tokens in one gather a token.  A pair's
+    weight is put on its row in the buffer.  A token with one pair reads
+    that row, a token with none a zero.  The tokens with two or more (8%
+    at 12 of 192 experts held, top-8) are first summed, in the compute
+    dtype and in their slots' order, in a list of `listed` slots appended
+    to the buffer, and read their sum.  More such tokens than slots is
+    seen in the input: then the others' further pairs are added pass by
+    pass over every token, and no pair is dropped.  Where the list has a
+    slot a token (a small slab: `combine_rows`), the tokens are the list
+    and their sums are `y`: the search programs' query slabs stay as small
+    as they were (a program's load from the compile cache took 0.28 s for
+    0.15 with the list built there too, chip runs, PR 33).  At [14112,
+    7168] the return took 7.1 ms as one pass over every token for every
+    held pair the busiest token has (4 or 5 of its 8; 1.5 ms a pass in the
+    program) and takes 3.8 (chip runs, PR 33); a scatter-add of the
+    buffer's rows took 14.0 ms (chip runs, PR 30)."""
     import jax
     import jax.numpy as jnp
 
     c = config
     t, k, n_held = h.shape[0], c.experts_per_token, c.experts_held
     capacity = pair_capacity(t, c) if capacity is None else capacity
+    listed = combine_rows(t, c) if listed is None else listed
     experts, weights = route(h, layer["router"], c)
     local = experts - c.expert_offset
     held = (local >= 0) & (local < n_held) & valid[:, None]
     group = jnp.where(held, local, n_held).reshape(-1)  # [T*k]; n_held = not ours
-    counts = jnp.sum(
-        group[:, None] == jnp.arange(n_held, dtype=jnp.int32)[None, :], axis=0,
-        dtype=jnp.int32,
-    )
-    ends = jnp.minimum(jnp.cumsum(counts), capacity)
-    sizes = jnp.diff(ends, prepend=0)  # the groups as the buffer holds them
-    overflow = counts.sum() - ends[-1]
+    member = group[None, :] == jnp.arange(n_held, dtype=jnp.int32)[:, None]
+    counts = jnp.sum(member, axis=1, dtype=jnp.int32)
+    tiles = -(-counts // PAIR_ROWS) * PAIR_ROWS
+    aligned = tiles.sum() <= capacity
+    ends = jnp.minimum(jnp.cumsum(jnp.where(aligned, tiles, counts)), capacity)
+    sizes = jnp.diff(ends, prepend=0)  # the groups as the grouped matmul sees them
+    starts = ends - sizes
+    kept = jnp.minimum(counts, sizes)  # the pairs of each that the buffer holds
+    overflow = counts.sum() - kept.sum()
     order = jnp.argsort(group, stable=True)  # held pairs first, by expert
+    first = jnp.cumsum(counts) - counts  # a group's first pair in that order
+    shift = starts - first  # how far down the buffer from there its rows lie
     dt = h.dtype
-    # (a small slab's buffer is a whole tile, more rows than it has pairs)
-    buffer = jnp.pad(order, (0, max(0, capacity - t * k)))[:capacity]
-    rows = h[buffer // k]  # [capacity, hidden]
+
+    def of(per_group, mask):  # mask: [experts_held, n] bool, one group a column at most
+        return jnp.sum(jnp.where(mask, per_group[:, None], 0), axis=0)
+
+    # the pair a buffer row holds, if any
+    at = jnp.arange(capacity, dtype=jnp.int32)[None, :]
+    holds = (starts[:, None] <= at) & (at < (starts + kept)[:, None])
+    filled = holds.any(axis=0)
+    pair = jnp.where(filled, order[jnp.clip(at[0] - of(shift, holds), 0, t * k - 1)], 0)
+    rows = h[pair // k]  # [capacity, hidden]
     with jax.named_scope("expert_matmul"):
         gate = jax.lax.ragged_dot(rows, layer["experts_gate"].astype(dt), sizes)
         up = jax.lax.ragged_dot(rows, layer["experts_up"].astype(dt), sizes)
         out = jax.lax.ragged_dot(
             jax.nn.silu(gate) * up, layer["experts_down"].astype(dt), sizes
         )
-    # rows past the groups' end were never written: a zero weight does
-    # not silence what they hold
-    filled = jnp.arange(capacity) < ends[-1]
-    out = jnp.where(filled[:, None], out, jnp.zeros_like(out))
-    # a pair's row in the buffer, and each token's pairs that are in it
-    # moved to the front of its k slots
-    row = jnp.argsort(order).reshape(t, k).astype(jnp.int32)
-    mine = held & (row < ends[-1])
+    # a pair's weight goes on here.  The other rows hold no pair or were
+    # never written: a zero weight does not silence what they hold
+    weight = weights.reshape(-1)[pair].astype(dt)
+    out = jnp.where(filled[:, None], weight[:, None] * out, jnp.zeros_like(out))
+    # a pair's row in the buffer; each token's pairs that are in it moved
+    # to the front of its k slots
+    nth_sorted = jnp.argsort(order).astype(jnp.int32)
+    mine = held & (nth_sorted < of(first + kept, member)).reshape(t, k)
+    row = (nth_sorted + of(shift, member)).reshape(t, k)
     nth = jnp.cumsum(mine, axis=1) - 1
     slot = mine[:, :, None] & (nth[:, :, None] == jnp.arange(k)[None, None, :])
     row_of = jnp.sum(jnp.where(slot, row[:, :, None], 0), axis=1)  # [T, k]
-    weight_of = jnp.sum(jnp.where(slot, weights[:, :, None], 0.0), axis=1).astype(dt)
+    pairs_of = jnp.sum(mine, axis=1, dtype=jnp.int32)  # [T]
+    multi = pairs_of > 1
+    n_multi = jnp.sum(multi, dtype=jnp.int32)
 
-    def nth_pair(j):
-        at = jax.lax.dynamic_slice_in_dim(row_of, j, 1, axis=1)[:, 0]
-        return jax.lax.dynamic_slice_in_dim(weight_of, j, 1, axis=1) * out[at]
+    def nth_pair(rows_of, j, has):
+        at_j = jax.lax.dynamic_slice_in_dim(rows_of, j, 1, axis=1)[:, 0]
+        return jnp.where(has[:, None], out[at_j], jnp.zeros((), dt))
 
-    # summed in the compute dtype, as the residual stream is: most tokens
-    # have one held pair or none, and the sum is added to x in that type
-    passes = jnp.max(jnp.sum(mine, axis=1))
-    y = jax.lax.fori_loop(1, passes, lambda j, y: y + nth_pair(j), nth_pair(0))
-    return y, counts, overflow
+    def summed(rows_of, pairs):
+        """Each entry's pairs, in the compute dtype as the residual stream
+        is, in their slots' order: as many passes as the busiest has."""
+        return jax.lax.fori_loop(
+            1, jnp.max(pairs),
+            lambda j, acc: acc + nth_pair(rows_of, j, j < pairs),
+            nth_pair(rows_of, 0, 0 < pairs),
+        )
 
+    if listed >= t:  # a slot a token: the tokens are the list
+        y = summed(row_of, pairs_of)
+    else:
+        # the tokens with two or more pairs, in the list's slots (the search
+        # compares every slot with every token: 0.02 ms where the binary
+        # search's loop took 0.21, chip runs, PR 33)
+        listed_at = jnp.cumsum(multi, dtype=jnp.int32) - 1
+        in_list = multi & (listed_at < listed)
+        token_of = jnp.minimum(
+            jnp.searchsorted(
+                listed_at, jnp.arange(listed, dtype=jnp.int32), method="compare_all"
+            ),
+            t - 1,
+        )
+        comb = summed(
+            row_of[token_of],
+            jnp.where(jnp.arange(listed) < n_multi, pairs_of[token_of], 0),
+        )
+        y = jnp.concatenate([out, comb])[
+            jnp.where(in_list, capacity + listed_at, row_of[:, 0])
+        ]
+        y = jnp.where((pairs_of > 0)[:, None], y, jnp.zeros_like(y))
+        # more such tokens than slots: the others' further pairs, in as
+        # many passes over every token as the busiest of them has pairs
+        spilled = multi & ~in_list
+        y = jax.lax.fori_loop(
+            1, jnp.where(n_multi > listed, jnp.max(pairs_of), 1),
+            lambda j, y: y + nth_pair(row_of, j, spilled & (j < pairs_of)), y,
+        )
+    if not with_stats:
+        return y, counts, overflow
+    return y, counts, overflow, {
+        "multi_pair_tokens": n_multi,
+        "combine_spills": (n_multi > listed).astype(jnp.int32),
+        "groups_aligned": aligned.astype(jnp.int32),
+        "groups_packed": 1 - aligned.astype(jnp.int32),
+    }
+
+
+# what `held_experts` counts of a pass (a row group's pass through one expert
+# layer) beside the tokens per expert and the overflow: each a counter
+# `moe.<name>`
+LAYER_PASS_STATS = (
+    "multi_pair_tokens", "combine_spills", "groups_aligned", "groups_packed",
+)
 
 # token slots the trunk takes at a time.  A slab's rows do not see each
 # other (attention stays inside a row, routing inside a token), so a slab
@@ -476,8 +586,8 @@ def row_chunks(rows: int, length: int) -> int:
 
 def _trunk(params, config: MoeMlaConfig, ids, seg, max_segments: int, fused: bool):
     """ids, seg: [B, L] -> (pooled unit vectors [B, max_segments, hidden]
-    f32, tokens per held expert [expert layers, experts_held], overflow
-    [expert layers])."""
+    f32, {"expert_tokens": tokens per held expert [expert layers,
+    experts_held], "overflow" and each of LAYER_PASS_STATS [expert layers]})."""
     import jax.numpy as jnp
 
     c = config
@@ -487,17 +597,18 @@ def _trunk(params, config: MoeMlaConfig, ids, seg, max_segments: int, fused: boo
     freqs = jnp.asarray(yarn_freqs(c))
     valid = (seg > 0).reshape(-1)
     x = params["embed"][ids].astype(dt)
-    expert_tokens = [jnp.zeros((0, c.experts_held), jnp.int32)]
-    overflow = [jnp.zeros((0,), jnp.int32)]
+    stats = {"expert_tokens": [jnp.zeros((0, c.experts_held), jnp.int32)]}
+    for name in ("overflow",) + LAYER_PASS_STATS:
+        stats[name] = [jnp.zeros((0,), jnp.int32)]
     for layer in params["layers"]:
         x = x + _attention(x, layer, c, pos, seg, fused, freqs)
         h = _rms_norm(x, layer["ln2"], c.norm_eps)
         if "router" in layer:
-            routed, counts, over = held_experts(
-                h.reshape(b * l, c.hidden), valid, layer, c
+            routed, counts, over, more = held_experts(
+                h.reshape(b * l, c.hidden), valid, layer, c, with_stats=True
             )
-            expert_tokens.append(counts[None])
-            overflow.append(over[None])
+            for name, value in dict(more, expert_tokens=counts, overflow=over).items():
+                stats[name].append(value[None])
             x = x + routed.reshape(b, l, c.hidden) + _swiglu(
                 h, layer["shared_gate"], layer["shared_up"], layer["shared_down"]
             )
@@ -509,7 +620,7 @@ def _trunk(params, config: MoeMlaConfig, ids, seg, max_segments: int, fused: boo
     pooled = jnp.einsum("blh,bls->bsh", x, oh) / (oh.sum(axis=1)[:, :, None] + 1e-9)
     pooled = pooled.astype(jnp.float32)
     pooled = pooled / (jnp.linalg.norm(pooled, axis=-1, keepdims=True) + 1e-9)
-    return pooled, jnp.concatenate(expert_tokens), jnp.concatenate(overflow)
+    return pooled, {name: jnp.concatenate(parts) for name, parts in stats.items()}
 
 
 def forward(
@@ -530,8 +641,10 @@ def forward(
     ignored.  The unpacked form IS the packed one with one segment a row,
     so the two cannot drift.  `with_stats` also returns {"expert_tokens":
     [expert layers, experts_held], "overflow": [expert layers], "tokens":
-    ()}: the real tokens each held expert saw, the selected held pairs
-    that did not fit the buffer (they must be 0), the real tokens."""
+    (), and [expert layers] each of LAYER_PASS_STATS}: the real tokens each
+    held expert saw, the selected held pairs that did not fit the buffer
+    (they must be 0), the real tokens, and what `held_experts` met, summed
+    over the row groups."""
     import jax
     import jax.numpy as jnp
 
@@ -543,24 +656,19 @@ def forward(
     fused = packed_attention_fused(config, l, use_flash)
     n = row_chunks(b, l)
     if n == 1:
-        pooled, expert_tokens, overflow = _trunk(
-            params, config, ids, seg, max_segments, fused
-        )
+        pooled, stats = _trunk(params, config, ids, seg, max_segments, fused)
     else:
-        pooled, expert_tokens, overflow = jax.lax.map(
+        pooled, stats = jax.lax.map(
             lambda part: _trunk(params, config, *part, max_segments, fused),
             (ids.reshape(n, b // n, l), seg.reshape(n, b // n, l)),
         )
         pooled = pooled.reshape(b, max_segments, config.hidden)
-        expert_tokens, overflow = expert_tokens.sum(0), overflow.sum(0)
+        stats = {name: per_group.sum(0) for name, per_group in stats.items()}
     if not packed:
         pooled = pooled[:, 0, :]
     if not with_stats:
         return pooled
-    return pooled, {
-        "expert_tokens": expert_tokens, "overflow": overflow,
-        "tokens": (seg > 0).sum(dtype=jnp.int32),
-    }
+    return pooled, dict(stats, tokens=(seg > 0).sum(dtype=jnp.int32))
 
 
 # the models whose statistics a reading of the span record first brings up
@@ -637,6 +745,8 @@ class MoeMlaLM(TransformerLM):
                 "moe.expert_tokens_mean", n=int(round(per_expert.mean(axis=1).sum()))
             )
             tracing.add("moe.overflow_pairs", n=int(np.asarray(stats["overflow"]).sum()))
+            for name in LAYER_PASS_STATS:
+                tracing.add("moe." + name, n=int(np.asarray(stats[name]).sum()))
 
 
 LM = MoeMlaLM

@@ -316,6 +316,34 @@ def test_sixteen_ranks_add_up_to_the_uncut_layer():
     assert np.abs(uncut[valid] - common[valid]).max() > 0.1  # the experts add something
 
 
+def _expert_layer(seed: int, adversarial: bool):
+    """TINY's first expert layer; `adversarial`: the held experts' router
+    columns win for every token, so every token holds k pairs here."""
+    from pathway_tpu.models import moe_mla as M
+
+    layer = dict(M.init_params(jax.random.PRNGKey(seed), M.TINY)["layers"][1])
+    if adversarial:
+        bias = jnp.zeros_like(layer["router"]).at[:, : M.TINY.experts_held].set(1.0)
+        layer["router"] = layer["router"] * 0.01 + bias
+    return layer
+
+
+def _plain_sum(h, valid, layer, config) -> np.ndarray:
+    """The routed part without a buffer: every held expert over every
+    token, and of that each selected pair of a valid token, weighted."""
+    from pathway_tpu.models import moe_mla as M
+
+    experts, weights = M.route(h, layer["router"], config)
+    experts, weights = np.asarray(experts), np.asarray(weights)
+    want = np.zeros(h.shape, np.float32)
+    for e in range(config.experts_held):
+        out = M._swiglu(h, layer["experts_gate"][e], layer["experts_up"][e],
+                        layer["experts_down"][e])
+        selected = (experts == config.expert_offset + e) & np.asarray(valid)[:, None]
+        want += (weights * selected).sum(1)[:, None] * np.asarray(out)
+    return want
+
+
 def test_no_selected_held_pair_is_dropped_and_overflow_is_counted():
     """Adversarial routing: every token's k experts are held here.  The
     buffer of a small slab takes every pair; a buffer forced too small
@@ -323,11 +351,7 @@ def test_no_selected_held_pair_is_dropped_and_overflow_is_counted():
     from pathway_tpu.models import moe_mla as M
 
     config = M.TINY
-    params = M.init_params(jax.random.PRNGKey(9), config)
-    layer = dict(params["layers"][1])
-    # the held experts' router columns win for every token
-    bias = jnp.zeros_like(layer["router"]).at[:, : config.experts_held].set(1.0)
-    layer["router"] = layer["router"] * 0.01 + bias
+    layer = _expert_layer(9, adversarial=True)
     t, k = 40, config.experts_per_token
     h = jnp.abs(jnp.asarray(np.random.default_rng(9).normal(size=(t, config.hidden)),
                             jnp.float32))
@@ -339,17 +363,80 @@ def test_no_selected_held_pair_is_dropped_and_overflow_is_counted():
     assert M.pair_capacity(t, config) == 512 >= t * k  # a whole tile, every pair
     assert M.pair_capacity(500, M.MoeMlaConfig()) == 4096 >= 500 * 8
     assert M.pair_capacity(14112, M.MoeMlaConfig()) == 14336  # a row a token slot
+    # the list of tokens with several pairs: a slot a token up to the
+    # buffer's least size, then an eighth of the slots in whole tiles
+    assert M.combine_rows(t, config) == t and M.combine_rows(4032, M.MoeMlaConfig()) == 4032
+    assert M.combine_rows(14112, M.MoeMlaConfig()) == 2048
     # against the plain sum over the selected pairs
-    want = np.zeros((t, config.hidden), np.float32)
-    for tok in range(36):
-        for e, w in zip(np.asarray(experts[tok]), np.asarray(weights[tok])):
-            out = M._swiglu(h[tok][None], layer["experts_gate"][e],
-                            layer["experts_up"][e], layer["experts_down"][e])
-            want[tok] += float(w) * np.asarray(out)[0]
-    np.testing.assert_allclose(np.asarray(y), want, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(y), _plain_sum(h, valid, layer, config), atol=2e-4)
     assert not np.asarray(y)[36:].any()  # padding routes nothing
     _, counts, overflow = M.held_experts(h, valid, layer, config, capacity=100)
     assert int(counts.sum()) == 36 * k and int(overflow) == 36 * k - 100
+
+
+# routing, token slots, buffer rows, list slots -> multi-pair tokens that
+# spill, the groups' layout.  None: the sizes the slab's length gives (a
+# 512-row buffer, every token listed).  TINY holds 4 experts: their groups
+# begin a tile each where the buffer has the tiles for it
+_RETURN_CASES = {
+    "random": (False, 40, None, None, False, "packed"),
+    "random-list-of-three": (False, 40, None, 3, True, "packed"),
+    "adversarial-spill": (True, 40, None, 5, True, "packed"),
+    "adversarial-list-of-one": (True, 40, None, 1, True, "packed"),
+    "aligned": (False, 300, 2560, None, False, "aligned"),
+    "aligned-spill": (False, 300, 2560, 16, True, "aligned"),
+    "aligned-two-tiles-a-group": (True, 600, 4608, 128, True, "aligned"),
+    "no-room-to-align": (True, 600, 3584, None, False, "packed"),
+    "skewed-router-overflows": (True, 300, 1024, 64, True, "packed"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RETURN_CASES))
+def test_return_to_the_tokens_equals_the_plain_sum(case):
+    """`held_experts` (groups begun on tiles or one after the other; a
+    pair's weight on the buffer's side, the tokens with several pairs
+    summed in a compact list, one gather a token) against the plain sum
+    over the selected pairs: with room in the list, and with the list
+    forced under the multi-pair count, where the loop over the remaining
+    passes runs and no pair is dropped."""
+    from pathway_tpu.models import moe_mla as M
+
+    adversarial, t, capacity, listed, spills, layout = _RETURN_CASES[case]
+    config = M.TINY
+    layer = _expert_layer(9, adversarial)
+    rng = np.random.default_rng(17)
+    h = jnp.asarray(rng.normal(size=(t, config.hidden)), jnp.float32)
+    if adversarial:
+        h = jnp.abs(h)
+    valid = jnp.asarray(rng.random(t) < 0.9)
+    y, counts, overflow, stats = M.held_experts(
+        h, valid, layer, config, capacity, listed=listed, with_stats=True
+    )
+    experts, _ = M.route(h, layer["router"], config)
+    held_pairs = (np.asarray(experts) < config.experts_held) & np.asarray(valid)[:, None]
+    assert int(counts.sum()) == held_pairs.sum()
+    assert int(stats["groups_aligned"]) == (layout == "aligned")
+    assert int(stats["groups_packed"]) == (layout == "packed")
+    if case == "aligned-two-tiles-a-group":
+        assert int(counts.min()) > M.PAIR_ROWS
+    if int(overflow) == 0:
+        assert int(stats["multi_pair_tokens"]) == (held_pairs.sum(1) > 1).sum()
+        np.testing.assert_allclose(
+            np.asarray(y), _plain_sum(h, valid, layer, config), atol=2e-4
+        )
+    else:  # the skewed router's buffer is full: what it leaves out is counted
+        assert int(overflow) == held_pairs.sum() - capacity
+    assert bool(stats["combine_spills"]) == spills
+    assert int(stats["multi_pair_tokens"]) > (listed or t) or not spills
+    # neither the list's size nor the groups' layout changes a bit
+    other = M.held_experts(h, valid, layer, config, capacity, listed=t)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(other[0]))
+    if int(overflow) == 0:
+        packed = M.held_experts(
+            h, valid, layer, config, -(-int(counts.sum()) // 512) * 512, with_stats=True
+        )
+        assert int(packed[3]["groups_packed"]) == 1 and int(packed[2]) == 0
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(packed[0]))
 
 
 def test_routing_statistics_reach_the_span_record():
@@ -377,6 +464,13 @@ def test_routing_statistics_reach_the_span_record():
     assert totals["moe.overflow_pairs"]["count"] == 0
     assert totals["moe.expert_tokens_mean"]["count"] == round(held / config.experts_held)
     assert totals["moe.expert_tokens_max"]["count"] >= totals["moe.expert_tokens_mean"]["count"]
+    # the return's and the buffer's counters: a pass is a row group's pass
+    # through one expert layer; a small slab lists every token, and its one
+    # tile has no room for a tile a group
+    assert 0 < totals["moe.multi_pair_tokens"]["count"] < 52 * layers
+    assert totals["moe.combine_spills"]["count"] == 0
+    assert totals["moe.groups_aligned"]["count"] == 0
+    assert totals["moe.groups_packed"]["count"] == layers
     # the unpacked form is the same trunk
     mask = (seg > 0).astype(np.int16)
     np.testing.assert_allclose(
