@@ -15,7 +15,6 @@ import numpy as np
 
 from pathway_tpu.models.tokenizer import (
     PACK_MAX_SEGMENTS,
-    HashTokenizer,
     encode_batch,
     pack_batch,
     pack_token_budget,
@@ -55,10 +54,11 @@ class SentenceEncoder:
             tokenizer = hf_loader.load_tokenizer(model)
         self.config = config or MINILM_L6
         self.max_len = min(max_len, self.config.max_len)
-        self.tokenizer = tokenizer or HashTokenizer(
-            vocab_size=self.config.vocab_size
-        )
-        self.lm = model_module(self.config).LM(self.config, params=params, seed=seed)
+        # the configuration's module says how its model reads a text, as it
+        # says which trunk runs (a checkpoint brings its own vocabulary)
+        model = model_module(self.config)
+        self.tokenizer = tokenizer or model.tokenizer(self.config)
+        self.lm = model.LM(self.config, params=params, seed=seed)
         if mesh is not None:
             axis = "dp" if "dp" in mesh.axis_names else mesh.axis_names[0]
             n_dev = mesh.shape[axis]

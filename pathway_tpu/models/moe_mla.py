@@ -52,7 +52,11 @@ from typing import Any, Dict, Optional
 import numpy as np
 
 from pathway_tpu.models.decoder import _rms_norm, _rope
-from pathway_tpu.models.transformer import TransformerLM, _packed_positions
+from pathway_tpu.models.transformer import (  # noqa: F401  (`tokenizer`: model_module's)
+    TransformerLM,
+    _packed_positions,
+    tokenizer,
+)
 
 
 @dataclasses.dataclass(frozen=True)

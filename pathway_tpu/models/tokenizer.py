@@ -11,9 +11,11 @@ a small set of shapes.
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 import re
 import zlib
-from typing import List, Optional, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -169,6 +171,41 @@ class WordPieceTokenizer:
         return len(self.tokenize(text))
 
 
+class ByteTokenizer:
+    """A text as its UTF-8 bytes: `<bos>`, then `BYTE_OFFSET` + byte, cut to
+    `max_len` (byte-level models: 64 special ids, then the 256 bytes).  No
+    vocabulary to ship, no unknown word; a word of prose is five to eight
+    tokens.  `shapes`: the slab shapes the model that reads the ids takes
+    (its module hands the tokenizer out, `model_module(config).tokenizer`)."""
+
+    BOS_ID = 1
+    BYTE_OFFSET = 64
+    lowercase = False
+
+    def __init__(self, vocab_size: int = 320, shapes: "SlabShapes | None" = None):
+        self.vocab_size = vocab_size
+        self.pad_id = PAD_ID
+        self.shapes = shapes or SlabShapes()
+
+    def encode(self, text: str, max_len: int | None = None) -> np.ndarray:
+        """[<bos>, 64 + b0, 64 + b1, ...] as an int32 array (thousands of
+        ids a page: no list of Python ints)."""
+        raw = np.frombuffer(text.encode("utf-8"), dtype=np.uint8)
+        if max_len is not None:
+            raw = raw[: max(max_len - 1, 0)]
+        ids = np.empty(len(raw) + 1, dtype=np.int32)
+        ids[0] = self.BOS_ID
+        ids[1:] = raw.astype(np.int32) + self.BYTE_OFFSET
+        return ids[:max_len]
+
+    def decode(self, ids: Sequence[int]) -> str:
+        raw = bytes(int(i) - self.BYTE_OFFSET for i in ids if int(i) >= self.BYTE_OFFSET)
+        return raw.decode("utf-8", errors="replace")
+
+    def count_tokens(self, text: str) -> int:
+        return len(text.encode("utf-8"))
+
+
 def bucket_length(n: int, minimum: int = 16, maximum: int = 512) -> int:
     """Power-of-two buckets — the BATCH-dimension policy. Mesh sharding
     depends on it (power-of-two batches divide any power-of-two dp axis,
@@ -195,6 +232,35 @@ def seq_bucket_length(n: int, minimum: int = 16, maximum: int = 512) -> int:
     if b >= n:
         return min(b, maximum)
     return min(-(-n // 8) * 8, maximum)
+
+
+def _slab_length(lengths: Sequence[int], budget: int, max_len: int) -> int:
+    """The fixed budget, raised to the sequence bucket of the longest
+    document only when one overflows it."""
+    longest = max(lengths, default=1)
+    if longest <= budget:
+        return budget
+    return seq_bucket_length(longest, maximum=max(max_len, longest))
+
+
+@dataclasses.dataclass(frozen=True)
+class SlabShapes:
+    """The shapes a model's programs are compiled for, asked by
+    `encode_batch` and `pack_batch`: `seq_bucket(n, maximum=)` a row's
+    length for a longest text of n tokens, `row_bucket(rows)` a packed
+    slab's rows, `slab_length(lengths, budget, max_len)` the row length of
+    a packed batch.  The defaults are the word-token encoders' (rows of at
+    most 512 slots); a model whose kernel takes other tiles says so on the
+    tokenizer its module hands out (`tokenizer.shapes`, models/eva.py)."""
+
+    seq_bucket: Callable[..., int] = seq_bucket_length
+    row_bucket: Callable[[int], int] = functools.partial(
+        seq_bucket_length, minimum=8, maximum=1 << 16
+    )
+    slab_length: Callable[..., int] = _slab_length
+
+
+_DEFAULT_SHAPES = SlabShapes()
 
 
 def encode_batch(
@@ -234,7 +300,8 @@ def encode_batch(
             encoded = [tokenizer.encode(t, max_len) for t in texts]
     with span("prep.pack", rows=len(texts)):
         longest = max((len(e) for e in encoded), default=1)
-        seq_len = seq_bucket_length(longest, maximum=max_len)
+        shapes = getattr(tokenizer, "shapes", _DEFAULT_SHAPES)
+        seq_len = shapes.seq_bucket(longest, maximum=max_len)
         batch = len(encoded)
         padded_batch = bucket_length(max(batch, 1), minimum=8, maximum=1 << 16) if batch_bucket else batch
         pad_id = getattr(tokenizer, "pad_id", PAD_ID)
@@ -302,10 +369,10 @@ def pack_batch(
     with span("prep.tokenize", rows=len(texts)):
         encoded = [tokenizer.encode(t, max_len) for t in texts]
     with span("prep.pack", rows=len(texts)):
-        longest = max((len(e) for e in encoded), default=1)
-        slab = max(1, int(token_budget))
-        if longest > slab:
-            slab = seq_bucket_length(longest, maximum=max(max_len, longest))
+        shapes = getattr(tokenizer, "shapes", _DEFAULT_SHAPES)
+        slab = shapes.slab_length(
+            [len(e) for e in encoded] or [1], max(1, int(token_budget)), max_len
+        )
         rows: List[List[List[int]]] = []
         used: List[int] = []
         slots: List[Tuple[int, int]] = [(0, 0)] * len(encoded)
@@ -328,11 +395,7 @@ def pack_batch(
             rows[row].append(e)
             used[row] += need
         n_rows = max(len(rows), 1)
-        padded_rows = (
-            seq_bucket_length(n_rows, minimum=8, maximum=1 << 16)
-            if row_bucket
-            else n_rows
-        )
+        padded_rows = shapes.row_bucket(n_rows) if row_bucket else n_rows
         pad_id = getattr(tokenizer, "pad_id", PAD_ID)
         dtype = _wire_dtype(tokenizer)
         ids = np.full((padded_rows, slab), pad_id, dtype=dtype)

@@ -441,11 +441,21 @@ def forward(
 def model_module(config):
     """The module of the model a configuration belongs to: the one that
     defines the configuration's type.  It has that model's `forward`,
-    `init_params`, `param_sharding_rules`, `packed_attention_fused` and
-    `LM` (this module for a `TransformerConfig`, `models/moe_mla.py` for a
-    `MoeMlaConfig`).  The one rule by which `TransformerLM`, the encoders
-    and the fused programs of `ops/knn.py` find a configuration's model."""
+    `init_params`, `param_sharding_rules`, `packed_attention_fused`,
+    `tokenizer` and `LM` (this module for a `TransformerConfig`,
+    `models/moe_mla.py` for a `MoeMlaConfig`, `models/eva.py` for an
+    `EvaConfig`).  The one rule by which `TransformerLM`, the encoders and
+    the fused programs of `ops/knn.py` find a configuration's model."""
     return importlib.import_module(type(config).__module__)
+
+
+def tokenizer(config):
+    """The tokenizer a configuration without a checkpoint's vocabulary
+    reads texts with: one hashed id a word, from the rows its embedding
+    holds."""
+    from pathway_tpu.models.tokenizer import HashTokenizer
+
+    return HashTokenizer(vocab_size=config.vocab_size)
 
 
 class TransformerLM:

@@ -88,3 +88,34 @@ def test_the_catalogued_configuration_states_the_published_sizes_beside_its_cut(
     assert model["vocab_size"] // 8 == model["vocab_held"]
     assert model["param_dtype"] == "bfloat16"
     assert json.dumps(config).count("7168") >= 2
+
+
+def test_the_byte_level_configuration_states_the_published_sizes_beside_its_cut():
+    """evabyte-pp2-docstore: every published number at the top level as
+    published, the sizes as run in `model`, no width changed between; the
+    cut is depth, and the deployment says where the other layers lie."""
+    config = spec._load(os.path.join(spec.HERE, "configs", "evabyte-pp2-docstore.json"))
+    model = config["model"]
+    published = {k: v for k, v in config.items()
+                 if isinstance(v, (int, float)) and not isinstance(v, bool)}
+    assert published == {
+        "chunk_size": 16, "hidden_size": 4096, "init_std": 0.01275,
+        "intermediate_size": 11008, "max_position_embeddings": 32768,
+        "max_seq_length": 32768, "num_attention_heads": 32, "num_hidden_layers": 32,
+        "num_key_value_heads": 32, "num_pred_heads": 8, "rms_norm_eps": 1e-05,
+        "rope_theta": 100000, "vocab_size": 320, "window_size": 2048,
+    }
+    for key, value in published.items():
+        if key in model:
+            assert model[key] == value, key
+    assert (model["layers"], model["pp_size"]) == (16, 2)
+    assert model["layers"] * model["pp_size"] == model["num_hidden_layers"]
+    assert set(config["reduced"]) == {"layers", "filled_rows"}
+    assert model["max_len"] == config["store"]["max_len"] == 8192
+    assert model["param_dtype"] == "bfloat16" and config["env"] == {"PATHWAY_INGEST_CHUNK": "2"}
+    # the cell: two pages a file, one file a dispatch, pages of two to four windows
+    traffic = spec.cell("evabyte-pp2.ingest-pages-2").traffic
+    assert traffic["docs_per_file"] == int(config["env"]["PATHWAY_INGEST_CHUNK"]) == 2
+    costs = spec.architecture(config).costs
+    for words in (525, 900):
+        assert 1 < costs.byte_tokens(model, words + 2) / model["window_size"] < 4
