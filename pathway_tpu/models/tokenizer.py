@@ -19,7 +19,9 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from pathway_tpu import native as _native
 from pathway_tpu.internals import config as _config
+from pathway_tpu.internals import tracing
 from pathway_tpu.internals.tracing import span
 
 _WORD_RE = re.compile(r"[A-Za-z0-9]+|[^\sA-Za-z0-9]")
@@ -263,6 +265,65 @@ class SlabShapes:
 _DEFAULT_SHAPES = SlabShapes()
 
 
+def tokenize_batch(
+    tokenizer,
+    texts: Sequence[str],
+    max_len: int,
+    pair_texts: Sequence[str] | None = None,
+) -> Tuple[np.ndarray, np.ndarray]:
+    """The batch's token ids as arrays, for `encode_batch` and
+    `pack_batch`: (ids [n, longest] int32, lengths [n] int32), text i's ids
+    in `ids[i, :lengths[i]]` and zeros after them, each cut to `max_len`.
+
+    The path is chosen a text at a time, by what can be seen of it: an
+    ASCII text under a lowercasing `HashTokenizer` is read by the native
+    tokenizer (native/tokenizer.cpp: the same ids to the last one, and the
+    whole batch in one call that holds no interpreter lock, which the
+    engine's tick needs); every other text, every other tokenizer, a pair,
+    and every text where the library could not be built or
+    `PATHWAY_DISABLE_NATIVE` is set, goes through `tokenizer.encode` and is
+    laid into the same arrays.  One curly quote sends one text down the
+    Python path, not its file.  Counters `prep.tokenize.native_texts` /
+    `.python_texts` (/status "spans") say how often each engaged."""
+    n = len(texts)
+    with span("prep.tokenize", rows=n):
+        native_ok = (
+            pair_texts is None
+            and type(tokenizer) is HashTokenizer
+            and tokenizer.lowercase
+            and tokenizer.vocab_size > _RESERVED  # the library divides by the rest
+            and _native.load() is not None
+        )
+        native_rows: List[int] = []
+        python_rows: List[int] = []
+        for i, text in enumerate(texts):
+            (native_rows if native_ok and text.isascii() else python_rows).append(i)
+        if pair_texts is None:
+            encoded = [tokenizer.encode(texts[i], max_len) for i in python_rows]
+        else:
+            encoded = [
+                tokenizer.encode_pair(texts[i], pair_texts[i], max_len)
+                for i in python_rows
+            ]
+        # a word is a byte at least, and [CLS] and [SEP] two more
+        native_texts = [texts[i] for i in native_rows]
+        width = min(max_len, 2 + max(map(len, native_texts), default=0))
+        width = max(width, max(map(len, encoded), default=0), 1)
+        ids = np.zeros((n, width), dtype=np.int32)
+        lengths = np.zeros(n, dtype=np.int32)
+        if native_rows:
+            _native.tokenize_batch_native(
+                native_texts, native_rows, tokenizer.vocab_size, ids, lengths
+            )
+        for i, e in zip(python_rows, encoded):
+            ids[i, : len(e)] = e
+            lengths[i] = len(e)
+        tracing.add("prep.tokenize.native_texts", n=len(native_rows))
+        tracing.add("prep.tokenize.python_texts", n=len(python_rows))
+    # as wide as the longest text came out, whichever path read it
+    return ids[:, : int(lengths.max(initial=1))], lengths
+
+
 def encode_batch(
     tokenizer: HashTokenizer,
     texts: Sequence[str],
@@ -272,46 +333,24 @@ def encode_batch(
     batch_bucket: bool = True,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Returns (ids [B', L'], mask [B', L']) padded to bucketed shapes; the
-    first len(texts) rows are the real batch. Single-text batches go through
-    the C++ tokenizer when available (pathway_tpu/native/tokenizer.cpp)."""
-    if (
-        pair_texts is None
-        and texts
-        and isinstance(tokenizer, HashTokenizer)
-        and tokenizer.lowercase
-        and all(t.isascii() for t in texts)
-    ):
-        # the native path matches the python tokenizer exactly only for
-        # lowercased ASCII input; anything else takes the python path so
-        # ids never depend on whether a compiler was available
-        with span("prep.tokenize", rows=len(texts)):
-            native = _try_native(tokenizer, texts, max_len, batch_bucket)
-        if native is not None:
-            return native
-    # two spans a batch (never one a text): the per-text encode loop, and
-    # the slab fill after it
-    with span("prep.tokenize", rows=len(texts)):
-        if pair_texts is not None:
-            encoded = [
-                tokenizer.encode_pair(a, b, max_len)
-                for a, b in zip(texts, pair_texts)
-            ]
-        else:
-            encoded = [tokenizer.encode(t, max_len) for t in texts]
+    first len(texts) rows are the real batch."""
+    # two spans a batch (never one a text): `tokenize_batch`, and the
+    # slab fill after it
+    tokens, lengths = tokenize_batch(tokenizer, texts, max_len, pair_texts)
     with span("prep.pack", rows=len(texts)):
-        longest = max((len(e) for e in encoded), default=1)
+        batch = len(texts)
+        longest = int(lengths.max()) if batch else 1
         shapes = getattr(tokenizer, "shapes", _DEFAULT_SHAPES)
         seq_len = shapes.seq_bucket(longest, maximum=max_len)
-        batch = len(encoded)
         padded_batch = bucket_length(max(batch, 1), minimum=8, maximum=1 << 16) if batch_bucket else batch
         pad_id = getattr(tokenizer, "pad_id", PAD_ID)
         dtype = _wire_dtype(tokenizer)
         ids = np.full((padded_batch, seq_len), pad_id, dtype=dtype)
         mask = np.zeros((padded_batch, seq_len), dtype=dtype)
-        for i, e in enumerate(encoded):
-            e = e[:seq_len]
-            ids[i, : len(e)] = e
-            mask[i, : len(e)] = 1
+        real = np.arange(seq_len) < lengths[:, None]
+        mask[:batch] = real
+        width = min(tokens.shape[1], seq_len)
+        ids[:batch, :width] = np.where(real[:, :width], tokens[:, :width], pad_id)
     return ids, mask
 
 
@@ -329,6 +368,36 @@ def pack_token_budget(default: int = 256) -> int:
     encode."""
     budget = _config.env("PATHWAY_PACK_TOKEN_BUDGET")
     return default if budget is None else max(0, budget)
+
+
+def _first_fit(lengths: np.ndarray, order: np.ndarray, slab: int, max_segments: int):
+    """`pack_batch`'s placement: the documents, taken in `order`, go each
+    to the first row with room for them (of `slab` slots and fewer than
+    `max_segments` documents), or open a new one.  Returns the row, the
+    segment there and the first slot of every document as int32 arrays.
+    In the library where it is loaded (2,048 passages over 600 rows are
+    half a million steps of this loop), here otherwise: one rule."""
+    if _native.load() is not None:
+        return _native.first_fit_native(lengths, order, slab, max_segments)
+    needs = lengths.tolist()
+    placed = np.zeros((3, len(needs)), dtype=np.int32)
+    used: List[int] = []
+    held: List[int] = []
+    for d in order.tolist():
+        need = needs[d]
+        row = -1
+        for r in range(len(used)):
+            if used[r] + need <= slab and held[r] < max_segments:
+                row = r
+                break
+        if row < 0:
+            used.append(0)
+            held.append(0)
+            row = len(used) - 1
+        placed[:, d] = row, held[row], used[row]
+        held[row] += 1
+        used[row] += need
+    return tuple(placed)
 
 
 def pack_batch(
@@ -366,46 +435,31 @@ def pack_batch(
     became [432, 504], where XLA's fusion of the out-projection and the
     MLP takes 38.8 ms a layer against 25.4: chip runs, PR 30).
     """
-    with span("prep.tokenize", rows=len(texts)):
-        encoded = [tokenizer.encode(t, max_len) for t in texts]
+    tokens, lengths = tokenize_batch(tokenizer, texts, max_len)
     with span("prep.pack", rows=len(texts)):
         shapes = getattr(tokenizer, "shapes", _DEFAULT_SHAPES)
-        slab = shapes.slab_length(
-            [len(e) for e in encoded] or [1], max(1, int(token_budget)), max_len
-        )
-        rows: List[List[List[int]]] = []
-        used: List[int] = []
-        slots: List[Tuple[int, int]] = [(0, 0)] * len(encoded)
-        order = range(len(encoded))
-        if sum(map(len, encoded)) <= PACK_SORT_ROWS * slab:
-            order = sorted(order, key=lambda d: -len(encoded[d]))
-        for d in order:
-            e = encoded[d]
-            need = len(e)
-            row = -1
-            for r in range(len(rows)):
-                if used[r] + need <= slab and len(rows[r]) < max_segments:
-                    row = r
-                    break
-            if row < 0:
-                rows.append([])
-                used.append(0)
-                row = len(rows) - 1
-            slots[d] = (row, len(rows[row]))
-            rows[row].append(e)
-            used[row] += need
-        n_rows = max(len(rows), 1)
+        needs = lengths.tolist()
+        slab = shapes.slab_length(needs or [1], max(1, int(token_budget)), max_len)
+        if sum(needs) <= PACK_SORT_ROWS * slab:
+            order = np.argsort(-lengths, kind="stable")
+        else:
+            order = np.arange(len(needs))
+        row_of, seg_of, at_of = _first_fit(lengths, order, slab, max_segments)
+        slots = list(zip(row_of.tolist(), seg_of.tolist()))
+        n_rows = int(row_of.max(initial=0)) + 1
         padded_rows = shapes.row_bucket(n_rows) if row_bucket else n_rows
         pad_id = getattr(tokenizer, "pad_id", PAD_ID)
         dtype = _wire_dtype(tokenizer)
         ids = np.full((padded_rows, slab), pad_id, dtype=dtype)
         seg = np.zeros((padded_rows, slab), dtype=dtype)
-        for r, docs in enumerate(rows):
-            at = 0
-            for s, e in enumerate(docs):
-                ids[r, at : at + len(e)] = e
-                seg[r, at : at + len(e)] = s + 1
-                at += len(e)
+        # one scatter: token j of the batch (documents in arrival order)
+        # goes to flat slot row * slab + at + (its place in its document)
+        real = np.arange(tokens.shape[1]) < lengths[:, None]
+        first = np.cumsum(lengths, dtype=np.int64) - lengths
+        start = row_of.astype(np.int64) * slab + at_of
+        dest = np.repeat(start - first, lengths) + np.arange(sum(needs))
+        np.put(ids, dest, tokens[real])
+        np.put(seg, dest, np.repeat(seg_of + 1, lengths))
     return ids, seg, slots
 
 
@@ -447,34 +501,6 @@ def _wire_dtype(tokenizer):
     if nvocab < (1 << 16):
         return np.uint16
     return np.int32
-
-
-def _try_native(tokenizer, texts, max_len, batch_bucket):
-    from pathway_tpu import native
-
-    lib = native.load()
-    if lib is None:
-        return None
-    batch = len(texts)
-    padded_batch = (
-        bucket_length(max(batch, 1), minimum=8, maximum=1 << 16)
-        if batch_bucket
-        else batch
-    )
-    result = native.tokenize_batch_native(
-        list(texts), tokenizer.vocab_size, max_len
-    )
-    if result is None:
-        return None
-    ids_full, mask_full = result
-    longest = int(mask_full.sum(axis=1).max()) if batch else 1
-    seq_len = seq_bucket_length(max(longest, 1), maximum=max_len)
-    dtype = _wire_dtype(tokenizer)
-    ids = np.full((padded_batch, seq_len), PAD_ID, dtype=dtype)
-    mask = np.zeros((padded_batch, seq_len), dtype=dtype)
-    ids[:batch] = ids_full[:, :seq_len]
-    mask[:batch] = mask_full[:, :seq_len]
-    return ids, mask
 
 
 class FastTokenizer:

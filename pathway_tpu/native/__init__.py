@@ -15,6 +15,7 @@ import hashlib
 import logging
 import os
 import subprocess
+import threading
 from typing import Optional
 
 from pathway_tpu.internals import config as _config
@@ -37,6 +38,9 @@ def _log_build_failure(what: str, exc: BaseException) -> None:
 
 _lib = None
 _build_failed = False
+_load_lock = threading.Lock()
+_INT32_P = ctypes.POINTER(ctypes.c_int32)
+_INT64_P = ctypes.POINTER(ctypes.c_int64)
 
 
 def _source_path(name: str) -> str:
@@ -53,12 +57,23 @@ def _cache_dir() -> str:
 
 
 def load() -> Optional[ctypes.CDLL]:
-    """Build (if needed) and load the native library; None if unavailable."""
-    global _lib, _build_failed
-    if _lib is not None:
-        return _lib
-    if _build_failed or _config.env("PATHWAY_DISABLE_NATIVE"):
+    """Build (if needed) and load the native library; None if unavailable.
+    PATHWAY_DISABLE_NATIVE is read at every call: set after the library
+    was loaded it still sends the next batch down the python path."""
+    if _config.env("PATHWAY_DISABLE_NATIVE"):
         return None
+    if _lib is None and not _build_failed:
+        # one build a process: the pipeline's prep threads ask at the same
+        # moment on a checkout's first batches, and two builds into one
+        # temporary file left the loser on the python path for good
+        with _load_lock:
+            if _lib is None and not _build_failed:
+                _build_and_load()
+    return _lib
+
+
+def _build_and_load() -> None:
+    global _lib, _build_failed
     source = _source_path("tokenizer.cpp")
     try:
         with open(source, "rb") as f:
@@ -83,51 +98,86 @@ def load() -> Optional[ctypes.CDLL]:
             )
             os.replace(tmp, so_path)
         lib = ctypes.CDLL(so_path)
-        lib.tokenize_batch.restype = ctypes.c_int32
+        lib.tokenize_batch.restype = None
         lib.tokenize_batch.argtypes = [
-            ctypes.c_char_p,
-            ctypes.POINTER(ctypes.c_int64),
-            ctypes.c_int32,
-            ctypes.c_int32,
-            ctypes.c_int32,
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_int32),
+            ctypes.c_char_p, _INT64_P, _INT64_P,
+            ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
+            _INT32_P, _INT32_P,
+        ]
+        lib.first_fit.restype = None
+        lib.first_fit.argtypes = [
+            _INT32_P, _INT64_P,
+            ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
+            _INT32_P, _INT32_P, _INT32_P,
         ]
         lib.count_tokens.restype = ctypes.c_int32
         lib.count_tokens.argtypes = [ctypes.c_char_p, ctypes.c_int32]
         _lib = lib
-        return lib
     except Exception as exc:  # noqa: BLE001 — fall back to python
         _build_failed = True
         _log_build_failure("tokenizer", exc)
-        return None
 
 
-def tokenize_batch_native(texts, vocab_size: int, seq_len: int):
-    """Returns (ids, mask) int32 [n, seq_len] numpy arrays, or None when
-    the native library is unavailable."""
+def tokenize_batch_native(texts, rows, vocab_size: int, ids, lengths) -> None:
+    """Tokenises the ASCII `texts` in one call that holds no interpreter
+    lock: text t's ids go to row `rows[t]` of the caller's C-contiguous
+    int32 `ids` [n, seq_len] and their count to `lengths[rows[t]]`; other
+    rows are not touched.  The library must be loaded (`load()`)."""
     import numpy as np
 
-    lib = load()
-    if lib is None:
-        return None
-    encoded = [t.encode("utf-8", errors="replace") for t in texts]
-    buffer = b"".join(encoded)
-    offsets = np.zeros(len(texts) + 1, dtype=np.int64)
-    np.cumsum([len(e) for e in encoded], out=offsets[1:])
     n = len(texts)
-    ids = np.zeros((n, seq_len), dtype=np.int32)
-    mask = np.zeros((n, seq_len), dtype=np.int32)
-    lib.tokenize_batch(
+    rows = np.ascontiguousarray(rows, dtype=np.int64)
+    for array, ndim in ((ids, 2), (lengths, 1)):
+        if (
+            array.dtype != np.int32
+            or array.ndim != ndim
+            or not array.flags.c_contiguous
+            or not array.flags.writeable
+        ):
+            raise ValueError("ids and lengths must be writable C-contiguous int32")
+    if rows.shape != (n,) or lengths.shape[0] != ids.shape[0] or (
+        n and not (0 <= rows.min() and rows.max() < ids.shape[0])
+    ):
+        raise ValueError("rows must name one row of ids for each text")
+    buffer = "".join(texts).encode("ascii")
+    offsets = np.zeros(n + 1, dtype=np.int64)
+    np.cumsum(np.fromiter(map(len, texts), dtype=np.int64, count=n), out=offsets[1:])
+    _lib.tokenize_batch(
         buffer,
-        offsets.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+        offsets.ctypes.data_as(_INT64_P),
+        rows.ctypes.data_as(_INT64_P),
         n,
         vocab_size,
-        seq_len,
-        ids.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-        mask.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+        ids.shape[1],
+        ids.ctypes.data_as(_INT32_P),
+        lengths.ctypes.data_as(_INT32_P),
     )
-    return ids, mask
+
+
+def first_fit_native(lengths, order, slab: int, max_segments: int):
+    """`pack_batch`'s placement in the library: (row, segment, first slot)
+    of every document, int32 arrays, for int32 `lengths` placed in the
+    order `order` (a permutation of their indices).  The library must be
+    loaded (`load()`)."""
+    import numpy as np
+
+    n = len(lengths)
+    lengths = np.ascontiguousarray(lengths, dtype=np.int32)
+    order = np.ascontiguousarray(order, dtype=np.int64)
+    if lengths.shape != (n,) or order.shape != (n,) or (
+        n and not (0 <= order.min() and order.max() < n)
+    ):
+        raise ValueError("order must name each of the lengths")
+    placed = np.zeros((3, n), dtype=np.int32)
+    _lib.first_fit(
+        lengths.ctypes.data_as(_INT32_P),
+        order.ctypes.data_as(_INT64_P),
+        n,
+        slab,
+        max_segments,
+        *(row.ctypes.data_as(_INT32_P) for row in placed),
+    )
+    return tuple(placed)
 
 
 def count_tokens_native(text: str) -> Optional[int]:

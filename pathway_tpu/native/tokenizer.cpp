@@ -4,15 +4,16 @@
 // reference tokenizes inside Rust connectors/parsers and relies on HF
 // tokenizers for models). Feature-hashing tokenization: lowercase,
 // alnum-run splitting, CRC32 token ids — identical semantics to
-// models/tokenizer.py HashTokenizer, ~20x faster, writing the padded
-// [batch, seq] int32 id/mask buffers the XLA encoder consumes directly.
+// models/tokenizer.py HashTokenizer, ~20x faster, writing each text's ids
+// into its row of an int32 [batch, seq] buffer and its length beside it
+// (models/tokenizer.py tokenize_batch lays them into the encoder's slabs).
 //
 // Built as a shared library at first use (see native/__init__.py); the
 // Python implementation stays as the fallback.
 
+#include <cstddef>
 #include <cstdint>
-#include <cstring>
-#include <cctype>
+#include <vector>
 
 namespace {
 
@@ -38,10 +39,14 @@ struct Crc32Table {
 
 const Crc32Table kCrc;
 
-inline uint32_t crc32_update(uint32_t crc, const unsigned char* buf, size_t len) {
-    crc = crc ^ 0xFFFFFFFFu;
+// crc32 of the token with A-Z lowered, a byte at a time: a token of any
+// length hashes as python's text.lower() would have it
+inline uint32_t crc32_lowered(const unsigned char* buf, size_t len) {
+    uint32_t crc = 0xFFFFFFFFu;
     for (size_t i = 0; i < len; i++) {
-        crc = kCrc.table[(crc ^ buf[i]) & 0xFF] ^ (crc >> 8);
+        unsigned char ch = buf[i];
+        if (ch >= 'A' && ch <= 'Z') ch += 32;
+        crc = kCrc.table[(crc ^ ch) & 0xFF] ^ (crc >> 8);
     }
     return crc ^ 0xFFFFFFFFu;
 }
@@ -49,6 +54,12 @@ inline uint32_t crc32_update(uint32_t crc, const unsigned char* buf, size_t len)
 inline bool is_alnum_ascii(unsigned char c) {
     return (c >= '0' && c <= '9') || (c >= 'a' && c <= 'z') ||
            (c >= 'A' && c <= 'Z');
+}
+
+// the ASCII characters python's `\s` takes: isspace()'s six and the four
+// separators 0x1C-0x1F (str.isspace() is true of them)
+inline bool is_space_ascii(unsigned char c) {
+    return c == ' ' || (c >= 0x09 && c <= 0x0D) || (c >= 0x1C && c <= 0x1F);
 }
 
 }  // namespace
@@ -67,10 +78,9 @@ int32_t tokenize_one(const char* text, int32_t text_len, int32_t vocab_size,
     out_ids[n++] = CLS_ID;
     const unsigned char* s = reinterpret_cast<const unsigned char*>(text);
     int32_t i = 0;
-    unsigned char lowered[256];
     while (i < text_len && n < max_len) {
         unsigned char c = s[i];
-        if (isspace(c)) {
+        if (is_space_ascii(c)) {
             i++;
             continue;
         }
@@ -84,17 +94,7 @@ int32_t tokenize_one(const char* text, int32_t text_len, int32_t vocab_size,
             i++;
             while (i < text_len && (s[i] & 0xC0) == 0x80) i++;
         }
-        int32_t len = i - start;
-        uint32_t h;
-        if (len <= 256) {
-            for (int32_t k = 0; k < len; k++) {
-                unsigned char ch = s[start + k];
-                lowered[k] = (ch >= 'A' && ch <= 'Z') ? ch + 32 : ch;
-            }
-            h = crc32_update(0, lowered, len);
-        } else {
-            h = crc32_update(0, s + start, len);
-        }
+        uint32_t h = crc32_lowered(s + start, (size_t)(i - start));
         out_ids[n++] = RESERVED + (int32_t)(h % (uint32_t)(vocab_size - RESERVED));
     }
     if (n < max_len) {
@@ -105,23 +105,47 @@ int32_t tokenize_one(const char* text, int32_t text_len, int32_t vocab_size,
     return n;
 }
 
-// Batch API: texts as one concatenated buffer with offsets; fills
-// ids[batch, seq_len] and mask[batch, seq_len] (pre-zeroed by caller).
-// Returns the longest row length.
-int32_t tokenize_batch(const char* buffer, const int64_t* offsets,
-                       int32_t n_texts, int32_t vocab_size, int32_t seq_len,
-                       int32_t* ids, int32_t* mask) {
-    int32_t longest = 0;
-    for (int32_t r = 0; r < n_texts; r++) {
-        const char* text = buffer + offsets[r];
-        int32_t text_len = (int32_t)(offsets[r + 1] - offsets[r]);
-        int32_t* row_ids = ids + (int64_t)r * seq_len;
-        int32_t n = tokenize_one(text, text_len, vocab_size, seq_len, row_ids);
-        int32_t* row_mask = mask + (int64_t)r * seq_len;
-        for (int32_t k = 0; k < n; k++) row_mask[k] = 1;
-        if (n > longest) longest = n;
+// Batch API: texts as one concatenated buffer with offsets; text t goes to
+// row rows[t] of ids[*, seq_len] and its number of ids to lengths[rows[t]]
+// (the caller's other rows are left alone: it tokenises those itself).
+// Re-entrant: no state but the arguments.
+void tokenize_batch(const char* buffer, const int64_t* offsets,
+                    const int64_t* rows, int32_t n_texts, int32_t vocab_size,
+                    int32_t seq_len, int32_t* ids, int32_t* lengths) {
+    for (int32_t t = 0; t < n_texts; t++) {
+        const char* text = buffer + offsets[t];
+        int32_t text_len = (int32_t)(offsets[t + 1] - offsets[t]);
+        int64_t r = rows[t];
+        lengths[r] = tokenize_one(text, text_len, vocab_size, seq_len,
+                                  ids + r * seq_len);
     }
-    return longest;
+}
+
+// Greedy first fit of `pack_batch`: documents in the order `order` go each
+// to the first row with room for `lengths[d]` more tokens of its `slab` and
+// fewer than `max_segments` documents, or open a new row.  Writes document
+// d's row, its segment there and its first slot at index d.
+void first_fit(const int32_t* lengths, const int64_t* order, int32_t n,
+               int32_t slab, int32_t max_segments, int32_t* row_of,
+               int32_t* seg_of, int32_t* at_of) {
+    std::vector<int32_t> used, held;  // tokens and documents of each row
+    for (int32_t k = 0; k < n; k++) {
+        int64_t d = order[k];
+        int32_t need = lengths[d];
+        size_t row = 0;
+        while (row < used.size() &&
+               !(used[row] + need <= slab && held[row] < max_segments)) {
+            row++;
+        }
+        if (row == used.size()) {
+            used.push_back(0);
+            held.push_back(0);
+        }
+        row_of[d] = (int32_t)row;
+        seg_of[d] = held[row]++;
+        at_of[d] = used[row];
+        used[row] += need;
+    }
 }
 
 // Token counting (splitters use it): number of word tokens, no specials.
@@ -130,7 +154,7 @@ int32_t count_tokens(const char* text, int32_t text_len) {
     int32_t i = 0, count = 0;
     while (i < text_len) {
         unsigned char c = s[i];
-        if (isspace(c)) {
+        if (is_space_ascii(c)) {
             i++;
             continue;
         }

@@ -56,12 +56,56 @@ def _env(**kv):
 # -- packing ----------------------------------------------------------------
 
 
-def test_pack_batch_slots_and_invariants():
+def _texts_by_path() -> tuple:
+    from pathway_tpu.internals import tracing
+
+    totals = tracing.spans_status()["totals"]
+    return tuple(
+        totals.get(f"prep.tokenize.{path}_texts", {"count": 0})["count"]
+        for path in ("native", "python")
+    )
+
+
+@pytest.fixture(params=["native", "python"])
+def pack(request):
+    """`pack_batch` with its texts tokenised on one path: by the native
+    library, or by `tokenizer.encode` as where no compiler is found.  On
+    the native path every result is also held to the Python path's, array
+    for array."""
+    from pathway_tpu import native
+
+    def on_python_path(*args, **kwargs):
+        with _env(PATHWAY_DISABLE_NATIVE="1"):
+            before = _texts_by_path()
+            out = pack_batch(*args, **kwargs)
+            assert _texts_by_path()[0] == before[0]
+        return out
+
+    if request.param == "python":
+        return on_python_path
+    if native.load() is None:
+        pytest.skip("no native tokenizer: no compiler found")
+
+    def on_native_path(tok, texts, **kwargs):
+        before = _texts_by_path()
+        ids, seg, slots = pack_batch(tok, texts, **kwargs)
+        after = _texts_by_path()
+        assert (after[0] - before[0], after[1] - before[1]) == (len(texts), 0)
+        want_ids, want_seg, want_slots = on_python_path(tok, texts, **kwargs)
+        assert ids.dtype == want_ids.dtype and seg.dtype == want_seg.dtype
+        assert np.array_equal(ids, want_ids) and np.array_equal(seg, want_seg)
+        assert slots == want_slots
+        return ids, seg, slots
+
+    return on_native_path
+
+
+def test_pack_batch_slots_and_invariants(pack):
     tok = _encoder("pack-tiny").tokenizer
     texts = [
         f"alpha bravo charlie doc{i} " + "word " * (i % 7) for i in range(11)
     ]
-    ids, seg, slots = pack_batch(tok, texts, max_len=32, token_budget=64)
+    ids, seg, slots = pack(tok, texts, max_len=32, token_budget=64)
     ids, seg = np.asarray(ids), np.asarray(seg)
     assert ids.shape == seg.shape
     assert len(slots) == len(texts)
@@ -86,7 +130,7 @@ def test_pack_batch_slots_and_invariants():
 
 
 @pytest.mark.parametrize("size", ["small", "large"])
-def test_pack_batch_small_batches_pack_longest_first(size, monkeypatch):
+def test_pack_batch_small_batches_pack_longest_first(size, monkeypatch, pack):
     """A small batch takes the same rows whatever order its documents come
     in (longest first); a large one keeps first-fit in arrival order."""
     from pathway_tpu.models import tokenizer as tk
@@ -99,7 +143,7 @@ def test_pack_batch_small_batches_pack_longest_first(size, monkeypatch):
     shapes, first_rows = set(), set()
     for seed in range(6):
         order = np.random.default_rng(seed).permutation(len(texts))
-        ids, seg, slots = pack_batch(
+        ids, seg, slots = pack(
             tok, [texts[i] for i in order], max_len=64, token_budget=64,
             row_bucket=False,
         )
@@ -116,13 +160,13 @@ def test_pack_batch_small_batches_pack_longest_first(size, monkeypatch):
         assert first_rows == {0}  # the first to arrive opens the first row
 
 
-def test_pack_batch_budget_overflow_grows_slab():
+def test_pack_batch_budget_overflow_grows_slab(pack):
     tok = _encoder("pack-long", max_len=64).tokenizer
     long_doc = "stream table engine " * 20
     _ids1, mask1 = encode_batch(tok, [long_doc], max_len=64)
     need = int(np.asarray(mask1).sum())
     assert need > 16
-    ids, seg, slots = pack_batch(
+    ids, seg, slots = pack(
         tok, [long_doc], max_len=64, token_budget=16
     )
     # a doc longer than the budget grows the slab instead of truncating
@@ -131,10 +175,10 @@ def test_pack_batch_budget_overflow_grows_slab():
     assert int((np.asarray(seg)[r] == s + 1).sum()) == need
 
 
-def test_pack_batch_max_segments_spill():
+def test_pack_batch_max_segments_spill(pack):
     tok = _encoder("pack-many").tokenizer
     texts = [f"w{i}" for i in range(PACK_MAX_SEGMENTS + 8)]
-    _ids, _seg, slots = pack_batch(
+    _ids, _seg, slots = pack(
         tok, texts, max_len=32, token_budget=4096
     )
     rows_used = {r for r, _s in slots}

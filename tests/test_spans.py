@@ -358,6 +358,40 @@ def test_launch_children_come_from_the_index_path(record):
     assert 0 < launch["self_s"] < launch["total_s"]
 
 
+def test_prep_counts_a_batchs_texts_by_the_path_that_tokenised_them(record):
+    """models/tokenizer.py: after a packed ingest batch
+    prep.tokenize.native_texts + .python_texts have risen by the batch's
+    texts, split by the path each took (a text that is not ASCII goes
+    through `tokenizer.encode`; all of them where the library is not
+    built), with the two spans still under pipeline.prep."""
+    from pathway_tpu import native
+    from tests.test_device_pipeline import _encoder, _env
+    from pathway_tpu.stdlib.indexing.nearest_neighbors import (
+        _FusedKnnIndexImpl,
+    )
+
+    impl = _FusedKnnIndexImpl(_encoder("spans-paths"), "cos", 32)
+    texts = [f"sierra doc{i} tango uniform" for i in range(12)]
+    texts[3] = "sierra doc3 “tango” uniform"
+    texts[7] = "sierra doc7 tangö uniform"
+    with _env(PATHWAY_PACK_TOKEN_BUDGET="64", PATHWAY_INGEST_CHUNK="4"):
+        impl.add_many(range(12), texts, [None] * 12)
+        impl.drain()
+    by_native = 10 if native.load() is not None else 0
+    assert _totals("prep.tokenize.native_texts")["count"] == by_native
+    assert _totals("prep.tokenize.python_texts")["count"] == 12 - by_native
+    assert _totals("prep.tokenize")["rows"] == 12
+    with _env(PATHWAY_PACK_TOKEN_BUDGET="64", PATHWAY_DISABLE_NATIVE="1"):
+        impl.add_many(range(12, 16), texts[:4], [None] * 4)
+        impl.drain()
+    assert _totals("prep.tokenize.native_texts")["count"] == by_native
+    assert _totals("prep.tokenize.python_texts")["count"] == 16 - by_native
+    for name in ("prep.tokenize", "prep.pack"):
+        parents = {ev[6] for ev in _ring(record, name)}
+        assert parents == {"pipeline.prep"}, (name, parents)
+        assert _totals(name)["count"] == 4
+
+
 def test_packed_batches_are_counted_by_the_attention_they_ran(
     record, monkeypatch
 ):
