@@ -47,7 +47,7 @@ import numpy as np
 
 from pathway_tpu.models.decoder import _rms_norm
 from pathway_tpu.models.moe_mla import CHUNK_TOKENS, _dtype, _normal, row_chunks
-from pathway_tpu.models.transformer import TransformerLM
+from pathway_tpu.models.transformer import TransformerLM, _one_chip_only
 from pathway_tpu.ops.kernels import eva_attention as kernel
 
 
@@ -195,16 +195,12 @@ def init_params(rng, config: EvaConfig) -> Dict[str, Any]:
     return params
 
 
-def _one_chip_only(mesh) -> None:
-    if mesh is not None:
-        raise NotImplementedError(
-            "eva runs one pipeline stage on one chip: the hand-over between "
-            "stages, and so a mesh, is not built (PERF.md section 7)"
-        )
+# what `_one_chip_only` says of this trunk: module, what it holds, what is not built
+_ONE_CHIP = ("eva", "one pipeline stage", "the hand-over between stages")
 
 
 def param_sharding_rules(config: EvaConfig, mesh):
-    _one_chip_only(mesh)
+    _one_chip_only(mesh, *_ONE_CHIP)
 
 
 def packed_attention_fused(config: EvaConfig, length: int,
@@ -358,7 +354,7 @@ def forward(
     import jax
     import jax.numpy as jnp
 
-    _one_chip_only(mesh)
+    _one_chip_only(mesh, *_ONE_CHIP)
     packed = seg is not None
     if not packed:
         seg, max_segments = (mask > 0).astype(jnp.int32), 1
@@ -398,7 +394,7 @@ class EvaLM(TransformerLM):
 
     def encode_packed(self, ids, seg, max_segments: int, *, params=None,
                       mesh=None):
-        _one_chip_only(mesh)
+        _one_chip_only(mesh, *_ONE_CHIP)
         from pathway_tpu.internals import tracing
 
         c = self.config

@@ -55,6 +55,7 @@ from pathway_tpu.models.decoder import _rms_norm, _rope
 from pathway_tpu.models.transformer import (  # noqa: F401  (`tokenizer`: model_module's)
     TransformerLM,
     _packed_positions,
+    _one_chip_only,
     tokenizer,
 )
 
@@ -235,16 +236,12 @@ def init_params(rng, config: MoeMlaConfig) -> Dict[str, Any]:
     return params
 
 
-def _one_chip_only(mesh) -> None:
-    if mesh is not None:
-        raise NotImplementedError(
-            "moe_mla runs one expert-parallel rank on one chip: the exchange "
-            "across ranks, and so a mesh, is not built (PERF.md section 7)"
-        )
+# what `_one_chip_only` says of this trunk: module, what it holds, what is not built
+_ONE_CHIP = ("moe_mla", "one expert-parallel rank", "the exchange across ranks")
 
 
 def param_sharding_rules(config: MoeMlaConfig, mesh):
-    _one_chip_only(mesh)
+    _one_chip_only(mesh, *_ONE_CHIP)
 
 
 def yarn_freqs(config: MoeMlaConfig) -> np.ndarray:
@@ -366,17 +363,28 @@ def _swiglu(h, gate, up, down):
     return (jax.nn.silu(h @ gate.astype(dt)) * (h @ up.astype(dt))) @ down.astype(dt)
 
 
-def route(h, router, config: MoeMlaConfig):
-    """h: [T, hidden] -> (experts [T, k] int32, weights [T, k] f32): plain
-    top-k over all sigmoid scores (no group limit, no correction bias),
-    the chosen scores normalised to sum to one and scaled.  The logits are
-    f32: products of the compute dtype's operands, accumulated in f32."""
+def route(h, router, config, bias=None):
+    """h: [T, hidden] -> (experts [T, k] int32, weights [T, k] f32): top-k
+    over all sigmoid scores (no group limit), the chosen scores normalised
+    to sum to one and scaled.  `bias` [n_routed_experts], where a model has
+    one (`e_score_correction_bias`, `topk_method` "noaux_tc"), is added to
+    the scores for the selection alone: it says which experts, never how
+    much of each.  Without one this is plain top-k, as it was.  The logits
+    are f32: products of the compute dtype's operands, accumulated in
+    f32.  `config`: any trunk's with `experts_per_token` and
+    `routed_scaling_factor` (`models/moe_hybrid.py` routes here too)."""
     import jax
     import jax.numpy as jnp
 
     logits = jnp.dot(h, router.astype(h.dtype), preferred_element_type=jnp.float32)
     scores = jax.nn.sigmoid(logits)
-    top, experts = jax.lax.top_k(scores, config.experts_per_token)
+    if bias is None:
+        top, experts = jax.lax.top_k(scores, config.experts_per_token)
+    else:
+        _, experts = jax.lax.top_k(
+            scores + bias.astype(jnp.float32), config.experts_per_token
+        )
+        top = jnp.take_along_axis(scores, experts, axis=-1)
     weights = config.routed_scaling_factor * top / top.sum(-1, keepdims=True)
     return experts.astype(jnp.int32), weights
 
@@ -388,7 +396,7 @@ def route(h, router, config: MoeMlaConfig):
 PAIR_ROWS = 512
 
 
-def pair_capacity(tokens: int, config: MoeMlaConfig) -> int:
+def pair_capacity(tokens: int, config) -> int:
     """Rows of the static buffer of (token, held expert) pairs for a slab
     of `tokens` slots.  Every pair there can be (tokens x k) up to 4,096
     rows: small batches cannot overflow.  Above that one row a token slot,
@@ -402,7 +410,7 @@ def pair_capacity(tokens: int, config: MoeMlaConfig) -> int:
     return -(-min(every, max(tokens, 4096)) // PAIR_ROWS) * PAIR_ROWS
 
 
-def combine_rows(tokens: int, config: MoeMlaConfig) -> int:
+def combine_rows(tokens: int, config) -> int:
     """Slots of the compact list of tokens with two or more pairs in the
     buffer (`held_experts`' return).  A slab of at most 4,096 token slots
     (the buffer's least size) has a slot a token: it needs no list, cannot
@@ -415,7 +423,7 @@ def combine_rows(tokens: int, config: MoeMlaConfig) -> int:
     return -(-tokens // (8 * PAIR_ROWS)) * PAIR_ROWS
 
 
-def held_experts(h, valid, layer, config: MoeMlaConfig, capacity: Optional[int] = None,
+def held_experts(h, valid, layer, config, capacity: Optional[int] = None,
                  *, listed: Optional[int] = None, with_stats: bool = False):
     """The routed experts' part of an expert layer that this rank
     computes.  h: [T, hidden] (normed), valid: [T] bool (padding routes
@@ -424,7 +432,10 @@ def held_experts(h, valid, layer, config: MoeMlaConfig, capacity: Optional[int] 
     () int32), and with `with_stats` a fourth: {"multi_pair_tokens",
     "combine_spills", "groups_aligned", "groups_packed"}, each () int32.
     `capacity` and `listed` override `pair_capacity` and `combine_rows`
-    (tests).
+    (tests).  `config`: this module's or another trunk's with the same
+    routing fields (`models/moe_hybrid.py`: 16 of 256 experts at width
+    4096, a selection bias `layer["router_bias"]`, no shared expert beside
+    it); the shared expert, where a model has one, is the caller's.
 
     Selected pairs on held experts are sorted by expert into a buffer of
     `capacity` rows, and three grouped matmuls (gate, up, down) run over
@@ -461,7 +472,7 @@ def held_experts(h, valid, layer, config: MoeMlaConfig, capacity: Optional[int] 
     t, k, n_held = h.shape[0], c.experts_per_token, c.experts_held
     capacity = pair_capacity(t, c) if capacity is None else capacity
     listed = combine_rows(t, c) if listed is None else listed
-    experts, weights = route(h, layer["router"], c)
+    experts, weights = route(h, layer["router"], c, layer.get("router_bias"))
     local = experts - c.expert_offset
     held = (local >= 0) & (local < n_held) & valid[:, None]
     group = jnp.where(held, local, n_held).reshape(-1)  # [T*k]; n_held = not ours
@@ -569,6 +580,18 @@ LAYER_PASS_STATS = (
     "multi_pair_tokens", "combine_spills", "groups_aligned", "groups_packed",
 )
 
+def layer_pass_lists(experts_held: int) -> dict:
+    """What a trunk gathers of its expert layers' passes, a list a name
+    that begins empty: "expert_tokens" [layers, experts_held], "overflow"
+    and each of LAYER_PASS_STATS [layers]."""
+    import jax.numpy as jnp
+
+    stats = {"expert_tokens": [jnp.zeros((0, experts_held), jnp.int32)]}
+    for name in ("overflow",) + LAYER_PASS_STATS:
+        stats[name] = [jnp.zeros((0,), jnp.int32)]
+    return stats
+
+
 # token slots the trunk takes at a time.  A slab's rows do not see each
 # other (attention stays inside a row, routing inside a token), so a slab
 # over this runs as equal groups of rows, one after the other inside the
@@ -579,13 +602,34 @@ LAYER_PASS_STATS = (
 CHUNK_TOKENS = 16384
 
 
-def row_chunks(rows: int, length: int) -> int:
+def row_chunks(rows: int, length: int, cap: Optional[int] = None) -> int:
     """Into how many equal groups of rows a [rows, length] slab is cut:
-    the fewest whose groups hold at most CHUNK_TOKENS token slots."""
+    the fewest whose groups hold at most `cap` token slots (CHUNK_TOKENS; a
+    trunk of another width states its own: `moe_hybrid.ROW_TOKENS`)."""
+    cap = CHUNK_TOKENS if cap is None else cap
     for n in range(1, rows + 1):
-        if rows % n == 0 and rows // n * length <= CHUNK_TOKENS:
+        if rows % n == 0 and rows // n * length <= cap:
             return n
     return rows
+
+
+def pooled_by_row_groups(trunk, ids, seg, cap: Optional[int] = None):
+    """`trunk(ids, seg) -> (pooled [rows, S, hidden], statistics)` over a
+    slab cut into `row_chunks` groups of rows, one after the other inside
+    the one program; the groups' statistics summed."""
+    import jax
+
+    b, l = ids.shape
+    n = row_chunks(b, l, cap)
+    if n == 1:
+        return trunk(ids, seg)
+    pooled, stats = jax.lax.map(
+        lambda part: trunk(*part), (ids.reshape(n, b // n, l), seg.reshape(n, b // n, l))
+    )
+    return (
+        pooled.reshape(b, *pooled.shape[2:]),
+        {name: per_group.sum(0) for name, per_group in stats.items()},
+    )
 
 
 def _trunk(params, config: MoeMlaConfig, ids, seg, max_segments: int, fused: bool):
@@ -601,9 +645,7 @@ def _trunk(params, config: MoeMlaConfig, ids, seg, max_segments: int, fused: boo
     freqs = jnp.asarray(yarn_freqs(c))
     valid = (seg > 0).reshape(-1)
     x = params["embed"][ids].astype(dt)
-    stats = {"expert_tokens": [jnp.zeros((0, c.experts_held), jnp.int32)]}
-    for name in ("overflow",) + LAYER_PASS_STATS:
-        stats[name] = [jnp.zeros((0,), jnp.int32)]
+    stats = layer_pass_lists(c.experts_held)
     for layer in params["layers"]:
         x = x + _attention(x, layer, c, pos, seg, fused, freqs)
         h = _rms_norm(x, layer["ln2"], c.norm_eps)
@@ -649,25 +691,16 @@ def forward(
     held expert saw, the selected held pairs that did not fit the buffer
     (they must be 0), the real tokens, and what `held_experts` met, summed
     over the row groups."""
-    import jax
     import jax.numpy as jnp
 
-    _one_chip_only(mesh)
+    _one_chip_only(mesh, *_ONE_CHIP)
     packed = seg is not None
     if not packed:
         seg, max_segments = (mask > 0).astype(jnp.int32), 1
-    b, l = ids.shape
-    fused = packed_attention_fused(config, l, use_flash)
-    n = row_chunks(b, l)
-    if n == 1:
-        pooled, stats = _trunk(params, config, ids, seg, max_segments, fused)
-    else:
-        pooled, stats = jax.lax.map(
-            lambda part: _trunk(params, config, *part, max_segments, fused),
-            (ids.reshape(n, b // n, l), seg.reshape(n, b // n, l)),
-        )
-        pooled = pooled.reshape(b, max_segments, config.hidden)
-        stats = {name: per_group.sum(0) for name, per_group in stats.items()}
+    fused = packed_attention_fused(config, ids.shape[1], use_flash)
+    pooled, stats = pooled_by_row_groups(
+        lambda ids, seg: _trunk(params, config, ids, seg, max_segments, fused), ids, seg
+    )
     if not packed:
         pooled = pooled[:, 0, :]
     if not with_stats:
@@ -697,6 +730,17 @@ class MoeMlaLM(TransformerLM):
         import jax
 
         super().__init__(config, params=params, seed=seed)
+        self._packed_jit = jax.jit(self._packed_program(), static_argnums=(3,))
+        self._stats: deque = deque()  # of dispatches not yet counted
+        from pathway_tpu.internals import tracing
+
+        _LIVE.add(self)
+        tracing.on_read(_count_finished)
+
+    def _packed_program(self):
+        """The packed program, under the name the device trace knows it by
+        (a trunk that shares the counters brings its own: `moe_hybrid`)."""
+        config = self.config
 
         def _fwd_packed_moe_mla(params, ids, seg, max_segments):
             import jax.numpy as jnp
@@ -707,16 +751,11 @@ class MoeMlaLM(TransformerLM):
                 with_stats=True,
             )
 
-        self._packed_jit = jax.jit(_fwd_packed_moe_mla, static_argnums=(3,))
-        self._stats: deque = deque()  # of dispatches not yet counted
-        from pathway_tpu.internals import tracing
-
-        _LIVE.add(self)
-        tracing.on_read(_count_finished)
+        return _fwd_packed_moe_mla
 
     def encode_packed(self, ids, seg, max_segments: int, *, params=None,
                       mesh=None):
-        _one_chip_only(mesh)
+        _one_chip_only(mesh, *_ONE_CHIP)
         pooled, stats = self._packed_jit(
             self.params if params is None else params, ids, seg, int(max_segments)
         )

@@ -34,9 +34,16 @@ _RESERVED = 4
 
 
 class HashTokenizer:
-    def __init__(self, vocab_size: int = 30522, lowercase: bool = True):
+    """One hashed id a word.  `shapes`: the slab shapes the model that
+    reads the ids takes, where they are not the encoders' (`SlabShapes`;
+    its module hands the tokenizer out).  An argument and no subclass:
+    `tokenize_batch` reads a batch natively under exactly this class."""
+
+    def __init__(self, vocab_size: int = 30522, lowercase: bool = True,
+                 shapes: "SlabShapes | None" = None):
         self.vocab_size = vocab_size
         self.lowercase = lowercase
+        self.shapes = shapes or SlabShapes()
 
     def token_id(self, token: str) -> int:
         # crc32 runs in C and is stable across processes; collisions at

@@ -1,0 +1,456 @@
+"""Causal grouped-query attention for packed slabs whose rows are thousands
+of slots long, global or behind a sliding window, with or without a sink
+logit, as ONE Pallas TPU kernel: `mla_attention.py`'s sibling for key axes
+of many tiles and for key/value heads that several query heads share.
+
+A query head's score is `q_nope . k_nope` (128 wide) plus `q_rope .
+k_rope` (64 wide, rotated), over 128-wide values; `group = heads /
+kv_heads` query heads read ONE key/value head.  Token i sees token j iff
+both lie in the same document and `j <= i` (global) or `i - window < j <=
+i` (window): documents are contiguous in a row, so the distance in
+positions is the distance in slots.  With a sink, a learned logit `b_h` a
+query head joins the softmax's denominator and mixes nothing:
+
+    p_ij = exp(s_ij - m) / (sum_j exp(s_ij - m) + exp(b_h - m))
+
+  * one grid step is one slab row, `head_block` query heads of one group,
+    one block of `block` query rows and one block of keys; the key blocks
+    are the last grid axis and the running maximum, normaliser and context
+    stay in VMEM across them (online softmax), so a row may be any number
+    of blocks long and neither the scores nor the mask ever reach HBM.  The
+    sink is where the running maximum and normaliser begin (m = b_h, l =
+    1) instead of (-inf, 0): a key with that logit and a value of zero;
+  * a block of queries of a global layer only meets the key blocks from
+    `key_lo`, the first block of its earliest document, to its own
+    diagonal.  Which block that is is data, read from SMEM before the step
+    (`PrefetchScalarGridSpec`): a step past the diagonal maps to the block
+    already resident and computes nothing.  A window no longer than a
+    block (128 against blocks of 128, where a tile of 512 keys would meet 4
+    to 8 times the pairs that count) takes ONE step a block of queries,
+    over its own block of keys and the one before, passed as a second view
+    of the same arrays: no second pass over the running state; a longer
+    window walks its blocks like a global layer, from `key_lo`;
+  * the step's heads share the key and value blocks it loaded and the
+    mask it computed: the keys of a global layer are read once for 8 query
+    heads, not once a head.  Their queries are laid one under the other in
+    VMEM once a step, each head's row as [nope | its rope part], so a
+    score is ONE product 256 deep against [k_nope | k_rope], and a pass of
+    the softmax takes PASS_ROWS rows of them at a time: the eight heads of
+    a window layer's step are one [1024, 256] pass, not eight products of
+    128 x 128 that each pay the MXU's fill and drain;
+  * operands are read where their matmuls left them, heads contiguous:
+    `q_nope` [B, L, H*128], `q_rope` [B, L, H*64], `k_nope`, `v` [B, L,
+    KV*128], and the context is written straight into [B, L, H*128] for the
+    out-projection.  Every load, matmul and store is a full 128-lane tile:
+    two heads' rope queries share a tile, a head is picked out of it by
+    zeroing the other's lanes, against the group's rope key laid twice
+    along the lanes (`mla_attention.py`'s way; the products that drop out
+    are exact zeros).
+
+`rope` turns the 64-wide rope parts where their matmuls left them.
+
+Numerics are the dense definition's (`hybrid_attention_dense`): q arrives
+scaled and rotated, scores accumulate in f32 from operands in the compute
+dtype, the softmax is f32, p is cast to the compute dtype for `p @ v`,
+which accumulates in f32 and is normalised there.  Rows with segment 0
+(padding) come out finite.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Optional
+
+from pathway_tpu.ops.kernels.flash_attention import NEG_INF
+
+LANES = 128
+NOPE_DIM = 128  # the tiling below is written for these three widths
+ROPE_DIM = 64
+V_DIM = 128
+# query heads a grid step takes: 8 heads are 1,024 lanes of q_nope and of
+# the output, 512 of q_rope; one group of a window layer, half a group of
+# a global one
+HEAD_BLOCK = 8
+# rows of a block of queries and of keys, by kind.  A global layer's row
+# state (maximum, normaliser, context) is rescaled once a key block, four
+# 128-lane passes a row whatever the block's width: blocks of 1,024 score
+# 171 G pairs/s at the ingest slab where blocks of 512 score 108 and of 256
+# 76 (chip runs, PR 36), and eight heads' state still fits VMEM beside one
+# head's [1024, 1024] scores.  A window layer's block is the window: a
+# query block then meets two key blocks, half of whose pairs count (blocks
+# of 256 meet four times the pairs that count and are no faster)
+GLOBAL_BLOCK = 1024
+WINDOW_BLOCK = 128
+# rows of the step's stacked heads that one softmax pass takes: the eight
+# heads of a window layer's step at once ([1024, 256] scores: as eight
+# products of 128 x 128, each paying a fill and a drain, a layer took 13.8
+# ms at the ingest slab for 7.6: chip runs, PR 36), one head of a global
+# layer's ([1024, 1024])
+PASS_ROWS = 1024
+VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def block_rows(length: int, window: Optional[int]) -> int:
+    """Rows of a block of queries (and of keys) for a row of `length`."""
+    return min(length, GLOBAL_BLOCK if window is None else WINDOW_BLOCK)
+
+
+def supports(length: int, heads: int, kv_heads: int, nope_dim: int, rope_dim: int,
+             v_dim: int, window: Optional[int] = None) -> bool:
+    """Static shapes the compiled kernel's tiling covers."""
+    block = block_rows(length, window)
+    group = heads // max(kv_heads, 1)
+    return (
+        (nope_dim, rope_dim, v_dim) == (NOPE_DIM, ROPE_DIM, V_DIM)
+        and kv_heads > 0
+        and heads % kv_heads == 0
+        and group % min(HEAD_BLOCK, group) == 0
+        and min(HEAD_BLOCK, group) % 2 == 0  # two heads' rope queries a tile
+        and length % block == 0
+        and block % LANES == 0
+    )
+
+
+def key_lo(seg, pos, window: Optional[int], block: int):
+    """[B, L / block] int32: the first key block a block of queries meets.
+    seg, pos: [B, L], the segment ids and `_packed_positions(seg)`."""
+    import jax.numpy as jnp
+
+    b, l = seg.shape
+    at = jnp.broadcast_to(jnp.arange(l, dtype=jnp.int32)[None, :], (b, l))
+    first = at - pos  # the slot of the document's first token
+    if window is not None:
+        first = jnp.maximum(first, at - (window - 1))
+    first = jnp.where(seg > 0, first, at)
+    return first.reshape(b, l // block, block).min(-1) // block
+
+
+def hybrid_attention_dense(q_nope, q_rope, k_nope, k_rope, v, seg, *, kv_heads: int,
+                           window: Optional[int] = None, sink=None):
+    """The numerical definition, the path off the TPU and the tests'
+    reference of the kernel (operands in its layouts).  Writes the f32
+    scores [B, H, L, L]."""
+    import jax.numpy as jnp
+
+    b, l, _ = q_nope.shape
+    group = q_rope.shape[2] // k_rope.shape[2]
+    q = lambda a: a.reshape(b, l, kv_heads, group, -1)  # noqa: E731
+    kv = lambda a: a.reshape(b, l, kv_heads, -1)  # noqa: E731
+    s = jnp.einsum(
+        "bqngd,bknd->bngqk", q(q_nope), kv(k_nope), preferred_element_type=jnp.float32
+    ) + jnp.einsum(
+        "bqngd,bknd->bngqk", q(q_rope), kv(k_rope), preferred_element_type=jnp.float32
+    )
+    at = jnp.arange(l)
+    see = (seg[:, :, None] == seg[:, None, :]) & (at[None, None, :] <= at[None, :, None])
+    if window is not None:
+        see = see & (at[None, :, None] - at[None, None, :] < window)
+    s = jnp.where(see[:, None, None], s, NEG_INF)
+    m = s.max(-1, keepdims=True)
+    if sink is not None:
+        logit = sink.astype(jnp.float32).reshape(1, kv_heads, group, 1, 1)
+        m = jnp.maximum(m, logit)
+    p = jnp.exp(s - m)
+    denom = p.sum(-1, keepdims=True)
+    if sink is not None:
+        denom = denom + jnp.exp(logit - m)
+    ctx = jnp.einsum(
+        "bngqk,bknd->bqngd", p.astype(v.dtype), kv(v), preferred_element_type=jnp.float32
+    ) / denom.transpose(0, 3, 1, 2, 4)
+    return ctx.reshape(b, l, -1).astype(q_nope.dtype)
+
+
+def _kernel(*refs, block: int, window: Optional[int], has_sink: bool, pair: bool):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    lo_ref, segq_ref, segk_ref = refs[:3]
+    rest = refs[3:]
+    segk_prev = sink_ref = None
+    if pair:
+        segk_prev, rest = rest[0], rest[1:]
+    if has_sink:
+        sink_ref, rest = rest[0], rest[1:]
+    qn_ref, qr_ref, kn_ref, kr_ref, v_ref = rest[:5]
+    rest = rest[5:]
+    if pair:
+        kn_prev, kr_prev, v_prev = rest[:3]
+        rest = rest[3:]
+    o_ref, q_scr, m_scr, l_scr, acc_scr = rest
+    b, qi, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    heads = qn_ref.shape[2] // NOPE_DIM
+    a_pass = min(heads, max(PASS_ROWS // block, 1))  # heads whose rows one pass takes
+
+    @pl.when(j == 0)
+    def _init():
+        # the step's queries, once for all its key blocks: a head's rows
+        # [nope | its rope part, the tile's other head zeroed], the heads
+        # one under the other
+        lane_half = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1) // ROPE_DIM
+        for h in range(heads):
+            rows = slice(h * block, (h + 1) * block)
+            q_scr[rows, :NOPE_DIM] = qn_ref[0, :, h * NOPE_DIM:(h + 1) * NOPE_DIM]
+            tile = qr_ref[0, :, (h // 2) * LANES:(h // 2 + 1) * LANES]
+            q_scr[rows, NOPE_DIM:] = jnp.where(lane_half == h % 2, tile, jnp.zeros_like(tile))
+            if has_sink:
+                m_scr[rows] = jnp.broadcast_to(sink_ref[h:h + 1, :], (block, LANES))
+        if has_sink:
+            l_scr[...] = jnp.ones_like(l_scr)
+        else:
+            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    kb = qi if pair else lo_ref[b, qi] + j
+
+    @pl.when(kb <= qi)
+    def _meet():
+        # one online-softmax step of every head of the block over the
+        # step's keys.  A masked score is NEG_INF: while a row has met
+        # nothing (no sink) its maximum is NEG_INF too and a masked key
+        # weighs 1, which the first key it does meet wipes out (alpha = 0),
+        # and every query meets itself
+        keys = jnp.concatenate([kn_ref[0], kr_ref[0]], axis=1)  # [block, 256]
+        values, codes = v_ref[0], segk_ref[0]
+        first = kb * block
+        if pair:  # the block before, too: a row past the window's reach is masked
+            keys = jnp.concatenate(
+                [jnp.concatenate([kn_prev[0], kr_prev[0]], axis=1), keys], axis=0
+            )
+            values = jnp.concatenate([v_prev[0], values], axis=0)
+            codes = jnp.concatenate([segk_prev[0], codes], axis=1)
+            first = first - block
+        n = keys.shape[0]
+        row = qi * block + jax.lax.broadcasted_iota(jnp.int32, (block, n), 0)
+        col = first + jax.lax.broadcasted_iota(jnp.int32, (block, n), 1)
+        see = (segq_ref[0] == codes) & (col <= row)
+        if window is not None:
+            see = see & (row - col < window)
+        if pair:
+            see = see & (col >= 0)  # block 0 has none before it
+        see = jnp.concatenate([see] * a_pass, axis=0) if a_pass > 1 else see
+        for r0 in range(0, heads * block, a_pass * block):
+            rows = slice(r0, r0 + a_pass * block)
+            s = jax.lax.dot_general(
+                q_scr[rows], keys, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            s = jnp.where(see, s, NEG_INF)
+            m_prev = m_scr[rows, 0:1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = alpha * l_scr[rows, 0:1] + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[rows] = alpha * acc_scr[rows] + jnp.dot(
+                p.astype(values.dtype), values, preferred_element_type=jnp.float32
+            )
+            m_scr[rows] = jnp.broadcast_to(m_new, (a_pass * block, LANES))
+            l_scr[rows] = jnp.broadcast_to(l_new, (a_pass * block, LANES))
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _write():
+        for h in range(heads):
+            rows = slice(h * block, (h + 1) * block)
+            o_ref[0, :, h * V_DIM:(h + 1) * V_DIM] = (
+                acc_scr[rows] / l_scr[rows, 0:1]
+            ).astype(o_ref.dtype)
+
+
+def hybrid_attention(q_nope, q_rope, k_nope, k_rope, v, seg, lo, *, kv_heads: int,
+                     window: Optional[int] = None, sink=None,
+                     block: Optional[int] = None, interpret=None):
+    """The fused kernel.  q_nope [B, L, H*128], q_rope [B, L, H*64] (scaled,
+    rotated); k_nope, v [B, L, KV*128]; k_rope [B, L, KV*64] (rotated); seg
+    [B, L] int32, 1..S per packed document, 0 = padding; lo: `key_lo(seg,
+    pos, window, block)`; sink [H] or None.  Returns the context [B, L,
+    H*128] in q_nope's dtype.  The device op is named by kind:
+    `hybrid_attention_window` or `hybrid_attention_global`.  `block` is for
+    tests: the interpreter takes any tile."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, l, width = q_nope.shape
+    heads = width // NOPE_DIM
+    group = heads // kv_heads
+    head_block = min(HEAD_BLOCK, group)
+    block = block_rows(l, window) if block is None else min(block, l)
+    if l % block or heads % kv_heads or group % head_block or head_block % 2:
+        raise ValueError(
+            f"hybrid_attention: unsupported shape L={l} heads={heads} "
+            f"kv_heads={kv_heads} block={block}"
+        )
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    n_q = l // block
+    # a window no longer than a block: ONE step a block of queries, over its
+    # own block of keys and the one before (no second pass of the running
+    # state); a longer window: its blocks and the diagonal, a step each;
+    # no window: every block up to the diagonal
+    pair = window is not None and window <= block and n_q > 1
+    if pair:
+        n_k = 1
+    else:
+        n_k = n_q if window is None else min(-(-(window - 1) // block) + 1, n_q)
+    blocks_a_group = group // head_block
+
+    # index maps: grid indices, then the scalar prefetched to SMEM
+    def queries(i, g, qi, j, lo):
+        return (i, qi, g)
+
+    def key_block(i, qi, j, lo):
+        return qi if pair else jnp.minimum(lo[i, qi] + j, qi)
+
+    def keys(i, g, qi, j, lo):
+        return (i, key_block(i, qi, j, lo), g // blocks_a_group)
+
+    def key_codes(i, g, qi, j, lo):
+        return (i, 0, key_block(i, qi, j, lo))
+
+    def keys_before(i, g, qi, j, lo):
+        return (i, jnp.maximum(qi - 1, 0), g // blocks_a_group)
+
+    def codes_before(i, g, qi, j, lo):
+        return (i, 0, jnp.maximum(qi - 1, 0))
+
+    vmem = pltpu.VMEM
+    seg = seg.astype(jnp.int32)
+    # the group's rope key laid twice along the lanes: [B, L, KV*128]
+    k_rope2 = jnp.concatenate(
+        [k_rope.reshape(b, l, kv_heads, ROPE_DIM)] * 2, axis=-1
+    ).reshape(b, l, kv_heads * LANES)
+    key_specs = lambda at: [  # noqa: E731
+        pl.BlockSpec((1, block, NOPE_DIM), at, memory_space=vmem),
+        pl.BlockSpec((1, block, LANES), at, memory_space=vmem),
+        pl.BlockSpec((1, block, V_DIM), at, memory_space=vmem),
+    ]
+    operands = [seg[:, :, None], seg[:, None, :]]
+    in_specs = [
+        pl.BlockSpec((1, block, 1), lambda i, g, qi, j, lo: (i, qi, 0), memory_space=vmem),
+        pl.BlockSpec((1, 1, block), key_codes, memory_space=vmem),
+    ]
+    if pair:
+        operands.append(seg[:, None, :])
+        in_specs.append(pl.BlockSpec((1, 1, block), codes_before, memory_space=vmem))
+    if sink is not None:
+        operands.append(jnp.broadcast_to(sink.astype(jnp.float32)[:, None], (heads, LANES)))
+        in_specs.append(
+            pl.BlockSpec((head_block, LANES), lambda i, g, qi, j, lo: (g, 0), memory_space=vmem)
+        )
+    operands += [q_nope, q_rope, k_nope, k_rope2, v]
+    in_specs += [
+        pl.BlockSpec((1, block, head_block * NOPE_DIM), queries, memory_space=vmem),
+        pl.BlockSpec((1, block, head_block * ROPE_DIM), queries, memory_space=vmem),
+    ] + key_specs(keys)
+    if pair:
+        operands += [k_nope, k_rope2, v]
+        in_specs += key_specs(keys_before)
+    kernel = functools.partial(
+        _kernel, block=block, window=window, has_sink=sink is not None, pair=pair
+    )
+    stacked = head_block * block  # the step's heads, one under the other
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, heads // head_block, n_q, n_k),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                (1, block, head_block * V_DIM), queries, memory_space=vmem
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((stacked, NOPE_DIM + LANES), q_nope.dtype),  # the queries
+                pltpu.VMEM((stacked, LANES), jnp.float32),  # running max
+                pltpu.VMEM((stacked, LANES), jnp.float32),  # normaliser
+                pltpu.VMEM((stacked, V_DIM), jnp.float32),  # context
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, l, heads * V_DIM), q_nope.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT,
+        ),
+        name="hybrid_attention_global" if window is None else "hybrid_attention_window",
+        interpret=interpret,
+    )(lo, *operands)
+
+
+ROPE_ROWS = 256  # rows of a slab a step of `rope` turns
+
+
+def rope_tables(pos, theta: float):
+    """(cos, sin) [B, L, 128] f32 for `rope` and `rotate`: the 32 angles of
+    a 64-wide rope part, position x theta^(-2i/64), laid out for the pair
+    (x[i], x[i + 32]) as [cos | cos] and [-sin | sin], twice along the
+    lanes (two heads share a tile)."""
+    import jax.numpy as jnp
+
+    half = ROPE_DIM // 2
+    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    angle = pos[:, :, None].astype(jnp.float32) * freqs  # [B, L, 32]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    return (jnp.concatenate([cos, cos] * 2, -1), jnp.concatenate([-sin, sin] * 2, -1))
+
+
+def rotate(x, cos, sin, scale: float = 1.0):
+    """`rope`'s definition, and the path off the TPU: x [B, L, n*64], every
+    64-wide part's pair (x[i], x[i + 32]) turned by the row's angle in f32,
+    times `scale`.  cos, sin: `rope_tables`."""
+    import jax.numpy as jnp
+
+    b, l, _ = x.shape
+    half = ROPE_DIM // 2
+    parts = x.reshape(b, l, -1, ROPE_DIM).astype(jnp.float32)
+    turned = jnp.roll(parts, half, axis=-1)  # [x[i + 32] | x[i]] with the signs in sin
+    out = parts * cos[:, :, None, :ROPE_DIM] + turned * sin[:, :, None, :ROPE_DIM]
+    return (out * scale).reshape(x.shape).astype(x.dtype)
+
+
+def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref, *, scale: float):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+
+    cos, sin = cos_ref[0], sin_ref[0]  # [rows, 128] f32
+    half = ROPE_DIM // 2
+    first = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1) % ROPE_DIM < half
+    for c0 in range(0, x_ref.shape[2], LANES):
+        x = x_ref[0, :, c0:c0 + LANES].astype(jnp.float32)
+        # lane i of a part's first half takes x[i + 32], of its second x[i - 32]
+        turned = jnp.where(
+            first, pltpu.roll(x, LANES - half, axis=1), pltpu.roll(x, half, axis=1)
+        )
+        o_ref[0, :, c0:c0 + LANES] = ((x * cos + turned * sin) * scale).astype(o_ref.dtype)
+
+
+def rope(x, cos, sin, *, scale: float = 1.0, interpret=None):
+    """RoPE on the 64-wide rope parts x [B, L, n*64] where their matmul
+    left them (n even: two parts a 128-lane tile): `rotate`, as a kernel.
+    XLA does the same sums over a [.., n, 64] view whose minor axis is half
+    a tile."""
+    import jax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, l, width = x.shape
+    if width % LANES:  # an odd part has no tile to itself (one key head: tests)
+        return rotate(x, cos, sin, scale)
+    rows = math.gcd(ROPE_ROWS, l)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    block = lambda cols: pl.BlockSpec(  # noqa: E731
+        (1, rows, cols), lambda i, r: (i, r, 0), memory_space=pltpu.VMEM
+    )
+    return pl.pallas_call(
+        functools.partial(_rope_kernel, scale=float(scale)),
+        grid=(b, l // rows),
+        in_specs=[block(width), block(LANES), block(LANES)],
+        out_specs=block(width),
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        name="hybrid_rope",
+        interpret=interpret,
+    )(x, cos, sin)

@@ -334,9 +334,3 @@ def test_the_counters_count_what_the_mask_lets_through():
     proj = 4 * 64 * 64 + 3 * 64 * 160
     k128, s128 = eva.scored_pairs(128, WINDOW, CHUNK)
     assert flops == pytest.approx(2.0 * 3 * (proj * 128 + 2 * 64 * (k128 + s128)))
-
-
-def test_a_mesh_is_refused():
-    with pytest.raises(NotImplementedError, match="one pipeline stage"):
-        eva.forward({}, eva.TINY, jnp.zeros((1, 128), jnp.int32), jnp.ones((1, 128), jnp.int32),
-                    mesh=object())
