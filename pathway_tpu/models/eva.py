@@ -374,8 +374,10 @@ def forward(
 class EvaLM(TransformerLM):
     """`TransformerLM` for this trunk: the same entry points, its packed
     program under a name of its own, and what the attention of each packed
-    batch scores counted into the span record (`eva.*`,
-    internals/tracing.py) from the segment lengths, on the host."""
+    batch scores (`eva.scored_pairs`: what the mask lets through) and what
+    the kernel's steps meet to score it (`eva.met_pairs`) counted into the
+    span record (`eva.*`, internals/tracing.py) from the segment ids, on
+    the host."""
 
     def __init__(self, config: EvaConfig, params=None, seed: int = 0):
         import jax
@@ -409,6 +411,13 @@ class EvaLM(TransformerLM):
         tracing.add("eva.scored_pairs", n=int(keys.sum() + summaries.sum()) * a_pair)
         tracing.add("eva.summary_pairs", n=int(summaries.sum()) * a_pair)
         tracing.add("eva.docs_multi_window", n=int((lengths > c.window_size).sum()))
+        # what the kernel's steps make of them: the block pairs and summary
+        # tiles they score, and those of them that needed no mask
+        met, unmasked = kernel.met_pairs(
+            kernel.window_layout(seg.astype(np.int32), c.window_size, c.chunk_size, xp=np)
+        )
+        tracing.add("eva.met_pairs", n=met * a_pair)
+        tracing.add("eva.unmasked_pairs", n=unmasked * a_pair)
         return self._packed_jit(
             self.params if params is None else params, ids, seg, int(max_segments)
         )

@@ -7,22 +7,41 @@ scores, in ONE softmax, the keys t <= i of its own window exactly, and one
 summary (k-bar_j, v-bar_j) for every chunk j of the document's earlier
 windows.  A document may start at any slot of a row, so a window is no
 fixed range of slots: `window_layout` works out, from the segment ids alone
-and once for all layers, where each document's windows and summaries lie
-and which blocks each block of queries has to meet.  Three kernels share it:
-`rope` turns q and k where their matmuls left them, `pool_chunks` sums the
-chunks into their summaries (`models/eva.py::chunk_summaries` weighs them),
-and `eva_attention`, the rest of this note, scores them:
+and once for all layers, where each document's windows and summaries lie,
+which blocks each block of queries has to meet and what each meeting needs.
+Three kernels share it: `rope` turns q and k where their matmuls left them,
+`pool_chunks` sums the chunks into their summaries
+(`models/eva.py::chunk_summaries` weighs them), and `eva_attention`, the
+rest of this note, scores them:
 
   * one grid step is one slab row, `HEAD_BLOCK` heads, one block of
-    `block` query rows and ONE block of keys; the key blocks are the last
-    grid axis and the running maximum, normaliser and context stay in VMEM
-    across them (online softmax), so a row may be any number of blocks
-    long and neither the scores nor the mask ever reach HBM;
-  * a block of queries only meets the key blocks from its earliest
-    window's first slot to its own diagonal (at most W/block + 1), then the
-    blocks of summaries its documents own; which ones is data, read from
-    SMEM before the step (`PrefetchScalarGridSpec`): a step that has
-    nothing to meet maps to the block already resident and computes nothing;
+    `block` query rows and ONE block of keys: step 0 the block's own (the
+    diagonal, and with it the summaries its documents own), step j the
+    j-th block to its left, as far as its earliest window's first slot
+    (at most W/block + 1 in all).  Neither the scores nor the mask ever
+    reach HBM, and a row may be any number of blocks long;
+  * a block of queries' whole key set is bounded (W + block token slots
+    and its documents' summaries), so its scores stay in VMEM (4 MB a
+    head at the ingest slab) and the softmax is the plain one, in two
+    passes: every step keeps its block's scores, masked, and each row's
+    maximum so far; the block's last step takes the exponentials against
+    the rows' maxima, sums them and multiplies them into v.  A row's
+    running state is never rescaled (the online form's `alpha * acc`, and
+    its maximum, normaliser and `alpha` re-broadcast once a key block,
+    were half of this kernel's time: 4.74 ms a layer at the ingest slab
+    against 2.44, PERF.md section 6, PR 39), and maxima and sums stay one
+    value a lane until their pass ends: no reduction across lanes inside
+    a step;
+  * what a step does is data, a KIND a (row, query block, step) read from
+    SMEM before the step (`PrefetchScalarGridSpec`), as the blocks are:
+    NOTHING (no key of the block counts: the step maps to the block already
+    resident and computes nothing), INTERIOR (every pair counts: query and
+    key block lie in one window of one document, the key block left of the
+    diagonal: no mask is built, no `where` runs) or EDGE (the mask).  On
+    the diagonal only the sub-tiles of `SUB_TILE` at or under it are
+    scored; the summaries come in tiles of `SUMMARY_TILE`, what a window
+    owns, and a block of queries that sees all of every tile it meets (its
+    own document's earlier windows) needs no mask either;
   * q, k, v [B, L, H*D] and the summaries [B, C, H*D] are read where their
     matmuls left them, heads contiguous, and the context is written
     straight into [B, L, H*D] for the out-projection;
@@ -50,22 +69,31 @@ LANES = 128
 # rows of a block of queries, and of a block of token keys: the key tile.
 # `models/eva.py` buckets a row's length to whole tiles
 KEY_TILE = 512
-SUMMARY_TILE = 256  # summaries a step meets
+SUMMARY_TILE = 128  # summaries a tile: what a window of 2048 owns in chunks of 16
+SUB_TILE = 128  # on the diagonal, the sub-tiles above it are skipped, not masked
 HEAD_BLOCK = 4  # heads a grid step takes: 512 lanes of q, k, v and the output
+VMEM_LIMIT = 64 << 20  # of v5e's 128 MiB: the blocks, a row's summaries, a step's scores
 # a token's code is `segment * SEG_STRIDE + window`; a row holds at most
 # tokenizer.PACK_MAX_SEGMENTS documents and a document far fewer windows
 SEG_STRIDE = 1 << 16
+# a step's kind: what its (query block, key block or summary tiles) pair needs
+NOTHING, INTERIOR, EDGE = 0, 1, 2
+
+
+def row_block(length: int, block: int = KEY_TILE) -> int:
+    """Rows of a block of queries and of keys in a row of `length`: the key
+    tile, the whole row where it is shorter, and the largest tile that
+    divides a row of 640, 768 or 896 slots."""
+    return length if length <= block else math.gcd(length, block)
 
 
 def supports(length: int, heads: int, head_dim: int, window: int, chunk: int) -> bool:
     """Static shapes the compiled kernel's tiling covers: heads of whole
-    128-lane tiles, a row of whole key tiles (or one tile of whole lanes),
-    windows of whole key tiles."""
-    block = min(KEY_TILE, length)
+    128-lane tiles, a row of whole lanes, windows of whole blocks."""
+    block = row_block(length)
     return (
         head_dim % LANES == 0
         and heads % HEAD_BLOCK == 0
-        and length % block == 0
         and block % LANES == 0
         and (length <= window or (window % block == 0 and window % chunk == 0))
     )
@@ -81,8 +109,28 @@ def summary_slots(length: int, window: int, chunk: int, tile: int = SUMMARY_TILE
     return -(-(length // chunk) // tile) * tile
 
 
+def token_steps(length: int, window: int, block: int = KEY_TILE) -> int:
+    """Key blocks a block of queries can have to meet: a window's blocks
+    and the diagonal, or the whole row where that is shorter."""
+    block = row_block(length, block)
+    return min(window // block + 1, length // block)
+
+
+def _positions(seg, at, xp):
+    """A token's position in its document: `transformer._packed_positions`,
+    and its like on the host (jax.numpy's `accumulate` is a loop a slot)."""
+    import numpy as np
+
+    if xp is not np:
+        from pathway_tpu.models.transformer import _packed_positions
+
+        return _packed_positions(seg)
+    starts = np.concatenate([np.ones_like(seg[:, :1], dtype=bool), seg[:, 1:] != seg[:, :-1]], axis=1)
+    return at - np.maximum.accumulate(np.where(starts, at, 0), axis=1)
+
+
 def window_layout(seg, window: int, chunk: int, *, block: int = KEY_TILE,
-                  summary_tile: int = SUMMARY_TILE):
+                  summary_tile: int = SUMMARY_TILE, xp=None):
     """Where a slab's windows and summaries lie.  seg: [B, L] int32, 1..S
     per packed document, 0 = padding.  Returns a dict of int32 arrays:
 
@@ -95,27 +143,46 @@ def window_layout(seg, window: int, chunk: int, *, block: int = KEY_TILE,
       chunk_code [B, C]  segment * SEG_STRIDE + window of that chunk, -1
                          where the slot is empty
       key_lo [B, L/block]               first key block a block of queries meets
-      sum_lo, sum_hi [B, L/block]       its summary blocks, [lo, hi)
+      kind [B, L/block * token_steps]   what step j of a block of queries,
+                         the one that meets key block `own - j`, needs:
+                         NOTHING (left of `key_lo`, or every query padding),
+                         INTERIOR (queries and keys fill one window of one
+                         document, j > 0: every pair counts) or EDGE
+      sum_lo, sum_hi [B, L/block]       its summary tiles, [lo, hi)
+      sum_kind [B, L/block]             NOTHING (none), INTERIOR (every
+                         query sees all of every tile) or EDGE
 
     C is `summary_slots(L, ...)`; with C == 0 the summary entries are
-    absent."""
-    import jax.numpy as jnp
-
-    from pathway_tpu.models.transformer import _packed_positions
+    absent.  `xp` is the array module: jax.numpy, or numpy where the host
+    counts what the kernel will meet (`met_pairs`)."""
+    if xp is None:
+        import jax.numpy as xp
 
     b, l = seg.shape
-    block = min(block, l)
-    at = jnp.broadcast_to(jnp.arange(l, dtype=jnp.int32)[None, :], (b, l))
-    pos = _packed_positions(seg)
+    block = row_block(l, block)
+    n_q = l // block
+    at = xp.broadcast_to(xp.arange(l, dtype=xp.int32)[None, :], (b, l))
+    pos = _positions(seg, at, xp)
     real = seg > 0
     win = pos // window
-    code = jnp.where(real, seg * SEG_STRIDE + win, 0)
+    code = xp.where(real, seg * SEG_STRIDE + win, 0)
     window_first = at - pos % window  # the slot of a token's window's first token
+    key_lo = xp.where(real, window_first, at).reshape(b, n_q, block).min(-1) // block
+    # a block of one code is one window of one document, without padding
+    codes = code.reshape(b, n_q, block)
+    low, high = codes.min(-1), codes.max(-1)
+    whole = (low == high) & (low > 0)
+    own = xp.arange(n_q, dtype=xp.int32)[:, None]
+    met = own - xp.arange(token_steps(l, window, block), dtype=xp.int32)[None, :]  # [n_q, steps]
+    left = xp.maximum(met, 0)
+    alike = (met < own) & whole[:, :, None] & whole[:, left] & (low[:, :, None] == low[:, left])
+    kind = xp.where(alike, INTERIOR, EDGE)
+    kind = xp.where((met >= key_lo[:, :, None]) & (high[:, :, None] > 0), kind, NOTHING)
     layout = {
         "pos": pos,
         "code": code,
-        "key_lo": jnp.where(real, window_first, at).reshape(b, l // block, block).min(-1)
-        // block,
+        "key_lo": key_lo,
+        "kind": kind.reshape(b, -1).astype(xp.int32),
     }
     slots = summary_slots(l, window, chunk, summary_tile)
     if not slots:
@@ -123,31 +190,62 @@ def window_layout(seg, window: int, chunk: int, *, block: int = KEY_TILE,
     # a chunk gets a summary iff its window is followed by another window of
     # the same document: the first token of that one sits `window` slots on
     follows = window_first + window
-    followed = jnp.take_along_axis(seg, jnp.minimum(follows, l - 1), axis=1)
+    followed = xp.take_along_axis(seg, xp.minimum(follows, l - 1), axis=1)
     followed = real & (follows < l) & (followed == seg)
     starts = followed & (pos % chunk == 0)
     # the starts' slots, in row order, packed to the front
-    order = jnp.sort(jnp.where(starts, at, l), axis=1)[:, :slots]
+    order = xp.sort(xp.where(starts, at, l), axis=1)[:, :slots]
     if order.shape[1] < slots:
-        order = jnp.pad(order, ((0, 0), (0, slots - order.shape[1])), constant_values=l)
+        order = xp.pad(order, ((0, 0), (0, slots - order.shape[1])), constant_values=l)
     held = order < l
-    start = jnp.where(held, order, l - chunk)
+    start = xp.where(held, order, l - chunk)
+    chunk_code = xp.where(held, xp.take_along_axis(code, start, axis=1), -1)
     layout["chunk_start"] = start
-    layout["chunk_code"] = jnp.where(
-        held, jnp.take_along_axis(code, start, axis=1), -1
-    )
+    layout["chunk_code"] = chunk_code
     # a query's summaries are those of its document's earlier windows:
     # `win * window / chunk` slots from the document's first
-    before = jnp.cumsum(starts, axis=1, dtype=jnp.int32) - starts  # starts left of a slot
-    doc_first = jnp.take_along_axis(before, at - pos, axis=1)
+    before = xp.cumsum(starts, axis=1, dtype=xp.int32) - starts  # starts left of a slot
+    doc_first = xp.take_along_axis(before, at - pos, axis=1)
     sees = real & (win > 0)
-    lo = jnp.where(sees, doc_first, slots)
-    hi = jnp.where(sees, doc_first + win * (window // chunk), 0)
-    lo = lo.reshape(b, l // block, block).min(-1) // summary_tile
-    hi = -(-hi.reshape(b, l // block, block).max(-1) // summary_tile)
-    layout["sum_lo"] = jnp.minimum(lo, slots // summary_tile - 1)
+    lo = xp.where(sees, doc_first, slots)
+    hi = xp.where(sees, doc_first + win * (window // chunk), 0)
+    lo = lo.reshape(b, n_q, block).min(-1) // summary_tile
+    hi = -(-hi.reshape(b, n_q, block).max(-1) // summary_tile)
+    # no mask where the block's one code sees every slot of every tile it meets
+    tiles = chunk_code.reshape(b, slots // summary_tile, summary_tile)
+    tile = xp.arange(slots // summary_tile, dtype=xp.int32)
+    outside = (tile < lo[:, :, None]) | (tile >= hi[:, :, None])  # [B, n_q, tiles]
+    seen = (tiles.min(-1)[:, None, :] >= (low - low % SEG_STRIDE)[:, :, None]) & (
+        tiles.max(-1)[:, None, :] < low[:, :, None]
+    )
+    plain = whole & (outside | seen).all(-1)
+    layout["sum_lo"] = lo
     layout["sum_hi"] = hi
+    layout["sum_kind"] = xp.where(hi > lo, xp.where(plain, INTERIOR, EDGE), NOTHING).astype(xp.int32)
     return layout
+
+
+def met_pairs(layout, *, block: int = KEY_TILE, summary_tile: int = SUMMARY_TILE,
+              sub_tile: int = SUB_TILE):
+    """((query, key or summary) pairs the kernel's steps score in one head
+    of one layer, those of them scored without a mask), from a layout's
+    kinds: a token step meets a whole block pair, the diagonal's only the
+    sub-tiles at or under it, a summary step whole tiles."""
+    kind = layout["kind"]
+    b, l = layout["code"].shape
+    block = row_block(l, block)
+    sub = math.gcd(sub_tile, block)
+    steps = kind.shape[1] // (l // block)
+    diagonal = kind.reshape(b, -1, steps)[:, :, 0] != NOTHING
+    groups = block // sub
+    met = int((kind != NOTHING).sum() - diagonal.sum()) * block * block
+    met += int(diagonal.sum()) * sub * sub * (groups * (groups + 1) // 2)
+    plain = int((kind == INTERIOR).sum()) * block * block
+    if "sum_kind" in layout:
+        tiles = (layout["sum_hi"] - layout["sum_lo"]).clip(0)
+        met += int(tiles.sum()) * summary_tile * block
+        plain += int((tiles * (layout["sum_kind"] == INTERIOR)).sum()) * summary_tile * block
+    return met, plain
 
 
 def eva_attention_dense(q, k, v, kbar, vbar, layout, heads: int):
@@ -324,86 +422,175 @@ def rope(x, cos, sin, *, scale: float = 1.0, interpret=None):
     )(x, cos, sin)
 
 
-def _kernel(*refs, head_dim: int, n_tok: int, block: int, summaries: bool):
+def _kernel(*refs, head_dim: int, n_tok: int, sub: int, tile: int, summaries: bool):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     if summaries:
-        (key_lo, sum_lo, sum_hi, codeq_ref, codek_ref, q_ref, k_ref, v_ref,
-         codes_ref, kbar_ref, vbar_ref, o_ref, m_scr, l_scr, acc_scr) = refs
+        (kind_ref, key_lo, sum_kind, sum_lo, sum_hi, codeq_ref, codek_ref, q_ref, k_ref, v_ref,
+         codes_ref, kbar_ref, vbar_ref, o_ref, m_scr, l_scr, acc_scr, s_scr, v_scr, t_scr) = refs
     else:
-        (key_lo, codeq_ref, codek_ref, q_ref, k_ref, v_ref,
-         o_ref, m_scr, l_scr, acc_scr) = refs
+        (kind_ref, key_lo, codeq_ref, codek_ref, q_ref, k_ref, v_ref,
+         o_ref, m_scr, l_scr, acc_scr, s_scr, v_scr) = refs
     b, qi, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    block = q_ref.shape[1]
     heads = q_ref.shape[2] // head_dim
+    lanes = m_scr.shape[2]
+    head = lambda h: slice(h * head_dim, (h + 1) * head_dim)  # noqa: E731
+    # the diagonal's rows go in groups of `sub`, each against the keys up to
+    # its own last row: (first row, last row + 1 = keys)
+    groups = [(r0, r0 + sub) for r0 in range(0, block, sub)]
+
+    def columns(s):
+        return [s[:, at:at + lanes] for at in range(0, s.shape[1], lanes)]
+
+    def score(h, r0, r1, keys, see):
+        """First pass: rows [r0, r1) of head h against `keys` [n, D]: the
+        scores, masked where `see` says so, and into the rows' maxima, a
+        lane at a time."""
+        s = jax.lax.dot_general(
+            q_ref[0, r0:r1, head(h)], keys,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        if see is not None:
+            s = jnp.where(see, s, NEG_INF)
+        m_scr[h, r0:r1] = functools.reduce(jnp.maximum, columns(s), m_scr[h, r0:r1])
+        return s
+
+    def weigh(h, r0, r1, s, values):
+        """Second pass: the kept scores' exponentials against the rows'
+        maxima, summed a lane at a time, and times `values` [n, D] into the
+        context."""
+        m = m_scr[h, r0:r1]
+        p = [jnp.exp(column - m) for column in columns(s)]
+        l_scr[h, r0:r1] += functools.reduce(jnp.add, p)
+        p = (jnp.concatenate(p, axis=1) if len(p) > 1 else p[0]).astype(values.dtype)
+        acc_scr[r0:r1, head(h)] += jnp.dot(p, values, preferred_element_type=jnp.float32)
+
+    def summary_tiles(visit):
+        """`visit(t, at)` for the block's tiles of summaries, [sum_lo,
+        sum_hi) of the row's: tile t begins at slot `at`."""
+        def a_tile(t, carry):
+            visit(t, pl.multiple_of(t * tile, tile))
+            return carry
+
+        jax.lax.fori_loop(sum_lo[b, qi], sum_hi[b, qi], a_tile, 0)
+
+    kind = kind_ref[b, qi * n_tok + j]
 
     @pl.when(j == 0)
-    def _init():
+    def _begin():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+
+    # every query that is no padding meets itself: a block that meets
+    # anything meets its diagonal, and summaries only with it
+    @pl.when((j == 0) & (kind != NOTHING))
+    def _diagonal():
+        row = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+        see = (codeq_ref[0] == codek_ref[0]) & (col <= row)
+        v_scr[0] = v_ref[0]
+        for h in range(heads):
+            for r0, r1 in groups:
+                s_scr[0, h, r0:r1, :r1] = score(h, r0, r1, k_ref[0, :r1, head(h)], see[r0:r1, :r1])
+
+    for a_kind in (INTERIOR, EDGE):
+        @pl.when((j > 0) & (kind == a_kind))
+        def _left():
+            see = None if a_kind == INTERIOR else codeq_ref[0] == codek_ref[0]
+            v_scr[j] = v_ref[0]
+            for h in range(heads):
+                s_scr[j, h] = score(h, 0, block, k_ref[0, :, head(h)], see)
+
+        if summaries:
+            @pl.when((j == 0) & (sum_kind[b, qi] == a_kind))
+            def _summaries():
+                code = codeq_ref[0]  # [block, 1]
+                own = code - code % SEG_STRIDE
+
+                def visit(t, at):
+                    see = None
+                    if a_kind == EDGE:
+                        chunk_code = codes_ref[0, t]  # [1, tile]
+                        see = (chunk_code >= own) & (chunk_code < code)
+                    for h in range(heads):
+                        t_scr[t, h] = score(h, 0, block, kbar_ref[0, pl.ds(at, tile), head(h)], see)
+
+                summary_tiles(visit)
+
+    @pl.when(j == n_tok - 1)
+    def _finish():
+        for h in range(heads):  # a row's maximum: the lanes' maxima, in every lane
+            m_scr[h] = jnp.broadcast_to(jnp.max(m_scr[h], axis=1, keepdims=True), m_scr.shape[1:])
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def meet(see, keys_ref, values_ref):
-        """One online-softmax step of every head of the block over one
-        block of keys.  A masked score is NEG_INF: while a row has met
-        nothing its maximum is NEG_INF too and a masked key weighs 1, which
-        the first key it does meet wipes out (alpha = 0), and every real
-        query meets itself."""
+        @pl.when(kind_ref[b, qi * n_tok] != NOTHING)
+        def _diagonal():
+            for h in range(heads):
+                for r0, r1 in groups:
+                    weigh(h, r0, r1, s_scr[0, h, r0:r1, :r1], v_scr[0, :r1, head(h)])
+
+        for step in range(1, n_tok):
+            @pl.when(kind_ref[b, qi * n_tok + step] != NOTHING)
+            def _left():
+                for h in range(heads):
+                    weigh(h, 0, block, s_scr[step, h], v_scr[step, :, head(h)])
+
+        if summaries:
+            @pl.when(sum_kind[b, qi] != NOTHING)
+            def _summaries():
+                def visit(t, at):
+                    for h in range(heads):
+                        weigh(h, 0, block, t_scr[t, h], vbar_ref[0, pl.ds(at, tile), head(h)])
+
+                summary_tiles(visit)
+
         for h in range(heads):
-            c0 = h * head_dim
-            s = jax.lax.dot_general(
-                q_ref[0, :, c0:c0 + head_dim], keys_ref[0, :, c0:c0 + head_dim],
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            s = jnp.where(see, s, NEG_INF)
-            m_prev = m_scr[h, :, 0:1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_new = alpha * l_scr[h, :, 0:1] + jnp.sum(p, axis=1, keepdims=True)
-            values = values_ref[0, :, c0:c0 + head_dim]
-            acc_scr[:, c0:c0 + head_dim] = alpha * acc_scr[:, c0:c0 + head_dim] + jnp.dot(
-                p.astype(values.dtype), values, preferred_element_type=jnp.float32
-            )
-            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
-
-    kb = key_lo[b, qi] + j
-
-    @pl.when((j < n_tok) & (kb <= qi))
-    def _tokens():
-        row = qi * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
-        col = kb * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
-        meet((codeq_ref[0] == codek_ref[0]) & (col <= row), k_ref, v_ref)
-
-    if summaries:
-        @pl.when((j >= n_tok) & (sum_lo[b, qi] + j - n_tok < sum_hi[b, qi]))
-        def _summaries():
-            code = codeq_ref[0]  # [block, 1]
-            chunk_code = codes_ref[0]  # [1, tile]
-            own = code - code % SEG_STRIDE
-            meet((chunk_code >= own) & (chunk_code < code), kbar_ref, vbar_ref)
-
-    @pl.when(j == pl.num_programs(3) - 1)
-    def _write():
-        for h in range(heads):
-            c0 = h * head_dim
-            o_ref[0, :, c0:c0 + head_dim] = (
-                acc_scr[:, c0:c0 + head_dim] / l_scr[h, :, 0:1]
+            l = jnp.sum(l_scr[h], axis=1, keepdims=True)
+            # a block of padding alone met nothing: zeros, not 0 / 0
+            o_ref[0, :, head(h)] = (
+                acc_scr[:, head(h)] / jnp.where(l > 0.0, l, 1.0)
             ).astype(o_ref.dtype)
 
 
 def eva_attention(q, k, v, kbar, vbar, layout, heads: int, *, window: int,
                   block: int = KEY_TILE, summary_tile: int = SUMMARY_TILE,
-                  head_block: int = HEAD_BLOCK, interpret=None):
+                  sub_tile: int = SUB_TILE, head_block: int = HEAD_BLOCK, interpret=None):
     """The fused kernel.  q (scaled), k, v: [B, L, H*D]; kbar, vbar:
     [B, C, H*D], or None where `summary_slots` is 0; `layout`:
     `window_layout(seg, window, chunk, block=, summary_tile=)` of the same
     tiles.  Returns the context [B, L, H*D] in q's dtype.  `block`,
-    `summary_tile` and `head_block` are for tests: the interpreter takes
-    any tile."""
+    `summary_tile`, `sub_tile` and `head_block` are for tests: the
+    interpreter takes any tile.  The call is one jitted function a set of
+    tiles, so the layers of a trunk trace and lower the kernel once a
+    program: traced a layer it cost every program that holds it 8 s of
+    set-up, compile cache or not (the parent's 2.7 s)."""
+    import jax
+
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    names = ("code", "kind", "key_lo")
+    if kbar is not None:
+        names += ("chunk_code", "sum_kind", "sum_lo", "sum_hi")
+    call = _jitted(heads, window, block, summary_tile, sub_tile, head_block, bool(interpret))
+    return call(q, k, v, kbar, vbar, {name: layout[name] for name in names})
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted(heads, window, block, summary_tile, sub_tile, head_block, interpret):
+    import jax
+
+    return jax.jit(functools.partial(
+        _attend, heads=heads, window=window, block=block, summary_tile=summary_tile,
+        sub_tile=sub_tile, head_block=head_block, interpret=interpret,
+    ))
+
+
+def _attend(q, k, v, kbar, vbar, layout, *, heads, window, block, summary_tile, sub_tile,
+            head_block, interpret):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -411,43 +598,34 @@ def eva_attention(q, k, v, kbar, vbar, layout, heads: int, *, window: int,
 
     b, l, width = q.shape
     head_dim = width // heads
-    block = min(block, l)
+    block = row_block(l, block)
+    sub = math.gcd(sub_tile, block)
     summaries = kbar is not None
-    if l % block or (l > block and window % block) or heads % head_block:
+    if (l > block and window % block) or heads % head_block:
         raise ValueError(
             f"eva_attention: unsupported shape L={l} heads={heads} window={window} "
             f"block={block}"
         )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n_q = l // block
-    n_tok = min(window // block + 1, n_q)  # a window's blocks and the diagonal
-    n_sum = kbar.shape[1] // summary_tile if summaries else 0
+    n_tok = token_steps(l, window, block)
     cols = head_block * head_dim
+    # maxima and sums are kept a lane apart: as many lanes as every tile has
+    lanes = math.gcd(LANES, sub, summary_tile if summaries else sub)
 
-    # index maps: grid indices, then the scalars prefetched to SMEM
+    # index maps: grid indices, then the scalars prefetched to SMEM.  A step
+    # that meets nothing maps to the last block met, already resident
     def queries(i, g, qi, j, *_):
         return (i, qi, g)
 
-    def keys(i, g, qi, j, key_lo, *_):
-        return (i, jnp.minimum(key_lo[i, qi] + j, qi), g)
+    def keys(i, g, qi, j, kind, key_lo, *_):
+        return (i, jnp.maximum(qi - j, key_lo[i, qi]), g)
 
-    def key_codes(i, g, qi, j, key_lo, *_):
-        return (i, 0, jnp.minimum(key_lo[i, qi] + j, qi))
-
-    def summary_block(i, qi, j, sum_lo, sum_hi):
-        last = jnp.maximum(sum_hi[i, qi] - 1, sum_lo[i, qi])
-        return jnp.clip(sum_lo[i, qi] + j - n_tok, sum_lo[i, qi], last)
-
-    def summary_rows(i, g, qi, j, key_lo, sum_lo, sum_hi):
-        return (i, summary_block(i, qi, j, sum_lo, sum_hi), g)
-
-    def summary_codes(i, g, qi, j, key_lo, sum_lo, sum_hi):
-        return (i, 0, summary_block(i, qi, j, sum_lo, sum_hi))
+    def key_codes(i, g, qi, j, kind, key_lo, *_):
+        return (i, 0, jnp.maximum(qi - j, key_lo[i, qi]))
 
     vmem = pltpu.VMEM
     code = layout["code"]
-    scalars = [layout["key_lo"]]
+    scalars = [layout["kind"], layout["key_lo"]]
     operands = [code[:, :, None], code[:, None, :], q, k, v]
     in_specs = [
         pl.BlockSpec((1, block, 1), lambda i, g, qi, j, *_: (i, qi, 0), memory_space=vmem),
@@ -456,33 +634,48 @@ def eva_attention(q, k, v, kbar, vbar, layout, heads: int, *, window: int,
         pl.BlockSpec((1, block, cols), keys, memory_space=vmem),
         pl.BlockSpec((1, block, cols), keys, memory_space=vmem),
     ]
+    scratch = [
+        pltpu.VMEM((head_block, block, lanes), jnp.float32),  # maxima
+        pltpu.VMEM((head_block, block, lanes), jnp.float32),  # sums
+        pltpu.VMEM((block, cols), jnp.float32),  # context
+        pltpu.VMEM((n_tok, head_block, block, block), jnp.float32),  # a step's scores
+        pltpu.VMEM((n_tok, block, cols), v.dtype),  # and its values
+    ]
     if summaries:
-        scalars += [layout["sum_lo"], layout["sum_hi"]]
-        operands += [layout["chunk_code"][:, None, :], kbar, vbar]
-        in_specs += [
-            pl.BlockSpec((1, 1, summary_tile), summary_codes, memory_space=vmem),
-            pl.BlockSpec((1, summary_tile, cols), summary_rows, memory_space=vmem),
-            pl.BlockSpec((1, summary_tile, cols), summary_rows, memory_space=vmem),
+        # a row's summaries stay resident a head block long; a tile's codes
+        # are a leading index away
+        slots = kbar.shape[1]
+        scalars += [layout["sum_kind"], layout["sum_lo"], layout["sum_hi"]]
+        operands += [
+            layout["chunk_code"].reshape(b, slots // summary_tile, 1, summary_tile), kbar, vbar,
         ]
+        resident = pl.BlockSpec((1, slots, cols), lambda i, g, qi, j, *_: (i, 0, g),
+                                memory_space=vmem)
+        in_specs += [
+            pl.BlockSpec((1, slots // summary_tile, 1, summary_tile),
+                         lambda i, g, qi, j, *_: (i, 0, 0, 0), memory_space=vmem),
+            resident, resident,
+        ]
+        scratch.append(  # a tile's scores
+            pltpu.VMEM((slots // summary_tile, head_block, block, summary_tile), jnp.float32)
+        )
     kernel = functools.partial(
-        _kernel, head_dim=head_dim, n_tok=n_tok, block=block, summaries=summaries,
+        _kernel, head_dim=head_dim, n_tok=n_tok, sub=sub, tile=summary_tile,
+        summaries=summaries,
     )
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
-            grid=(b, heads // head_block, n_q, n_tok + n_sum),
+            grid=(b, heads // head_block, n_q, n_tok),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, block, cols), queries, memory_space=vmem),
-            scratch_shapes=[
-                pltpu.VMEM((head_block, block, LANES), jnp.float32),  # running max
-                pltpu.VMEM((head_block, block, LANES), jnp.float32),  # normaliser
-                pltpu.VMEM((block, cols), jnp.float32),  # context
-            ],
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((b, l, width), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT,
         ),
         name="eva_attention",
         interpret=interpret,

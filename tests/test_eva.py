@@ -111,38 +111,80 @@ def _operands(b: int, l: int, seed: int = 0):
     return make() * c.head_dim ** -0.5, make(), make(), layer
 
 
-@pytest.mark.parametrize("block,tile", [(16, 8), (32, 16), (128, 32)])
-def test_the_kernel_is_its_dense_definition(block, tile):
-    """Interpreted, over every tiling a row of 128 slots allows: documents
-    of 70 and 37 tokens in one row (the second starts at slot 70, off the
-    window and the chunk grid) and one of four whole windows.  f32 sums in
-    another order: 1e-5 on values of order one."""
+def _slab(rows, length: int) -> np.ndarray:
+    """Segment ids of a slab: each row its documents' lengths, packed from
+    slot 0, the rest padding."""
+    seg = np.zeros((len(rows), length), np.int32)
+    for r, lengths in enumerate(rows):
+        at = 0
+        for s, n in enumerate(lengths):
+            seg[r, at:at + n] = s + 1
+            at += n
+    return seg
+
+
+# name: (rows of document lengths, slots a row, block, summary tile, sub-tile)
+KERNEL_CASES = {
+    # documents of 70 and 37 tokens in one row (the second starts at slot 70,
+    # off the window and the chunk grid) and one of four whole windows, over
+    # every tiling a row of 128 slots allows
+    "tiles-16-8": ([[70, 37], [128]], 128, 16, 8, 8),
+    "tiles-32-16": ([[70, 37], [128]], 128, 32, 16, 16),
+    "tiles-128-32": ([[70, 37], [128]], 128, 128, 32, 32),
+    # what the kinds bring (windows of 32 are two blocks of 16)
+    "a-document-starts-inside-a-block": ([[21, 60]], 96, 16, 8, 8),
+    "a-window-boundary-inside-a-query-block": ([[8, 100]], 112, 16, 8, 8),
+    "interior-to-every-key-block-but-the-diagonal": ([[128]], 128, 16, 8, 4),
+    "a-padding-tail-inside-the-last-block": ([[100]], 112, 16, 8, 8),
+    "one-window-plus-one-token": ([[33]], 48, 16, 8, 8),
+    "at-most-one-window": ([[20, 10], [32]], 32, 16, 8, 8),
+    "two-documents-in-one-summary-tile": ([[40, 40]], 96, 16, 16, 8),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_the_kernel_is_its_dense_definition(case):
+    """Interpreted, against `eva_attention_dense`.  f32 sums in another
+    order: 1e-5 on values of order one."""
     c = eva.TINY
-    seg = np.zeros((2, 128), np.int32)
-    seg[0, :70], seg[0, 70:107], seg[1, :] = 1, 2, 1
-    seg = jnp.asarray(seg)
-    q, k, v, layer = _operands(2, 128)
+    rows, length, block, tile, sub = KERNEL_CASES[case]
+    seg = jnp.asarray(_slab(rows, length))
+    q, k, v, layer = _operands(len(rows), length)
     layout = kernel.window_layout(seg, WINDOW, CHUNK, block=block, summary_tile=tile)
-    kbar, vbar = eva.chunk_summaries(k, v, layer, layout, c)
-    # the pooling kernel (a chunk's rows copied from the 8-row tile it begins
-    # in, the others weighted 0) is the gather's sums: the held slots agree
-    pooled = eva.chunk_summaries(k, v, layer, layout, c, fused=True)
-    held = np.asarray(layout["chunk_code"]) >= 0
-    assert held.sum() == 16 + 8 + 24  # windows that another follows: 2, 1 and 3
-    for got, want in zip(pooled, (kbar, vbar)):
-        np.testing.assert_allclose(np.asarray(got)[held], np.asarray(want)[held], atol=1e-5)
+    kbar = vbar = None
+    if length > WINDOW:
+        kbar, vbar = eva.chunk_summaries(k, v, layer, layout, c)
+    else:
+        assert "chunk_start" not in layout  # nothing to summarise: no summary operand
+    if case.startswith("tiles-"):
+        # the pooling kernel (a chunk's rows copied from the 8-row tile it begins
+        # in, the others weighted 0) is the gather's sums: the held slots agree
+        pooled = eva.chunk_summaries(k, v, layer, layout, c, fused=True)
+        held = np.asarray(layout["chunk_code"]) >= 0
+        assert held.sum() == 16 + 8 + 24  # windows that another follows: 2, 1 and 3
+        for got, want in zip(pooled, (kbar, vbar)):
+            np.testing.assert_allclose(np.asarray(got)[held], np.asarray(want)[held], atol=1e-5)
+        # the blocks a block of queries meets: the window's first to the diagonal
+        key_lo = np.asarray(layout["key_lo"])
+        assert (key_lo <= np.arange(128 // block)).all()
+        assert key_lo[1, -1] == (96 // block)  # the fourth window's first slot
     dense = kernel.eva_attention_dense(q, k, v, kbar, vbar, layout, c.heads)
     fused = kernel.eva_attention(
         q, k, v, kbar, vbar, layout, c.heads, window=WINDOW, block=block,
-        summary_tile=tile, head_block=2, interpret=True,
+        summary_tile=tile, sub_tile=sub, head_block=2, interpret=True,
     )
     real = np.asarray(seg) > 0  # padding comes out finite, and is not read
     np.testing.assert_allclose(np.asarray(fused)[real], np.asarray(dense)[real], atol=1e-5)
     assert np.isfinite(np.asarray(fused)).all()
-    # the blocks a block of queries meets: the window's first to the diagonal
-    key_lo = np.asarray(layout["key_lo"])
-    assert (key_lo <= np.arange(128 // block)).all()
-    assert key_lo[1, -1] == (96 // block)  # the fourth window's first slot
+    # the case is what its name says
+    kind = np.asarray(layout["kind"]).reshape(len(rows), length // block, -1)
+    if case == "interior-to-every-key-block-but-the-diagonal":
+        assert (kind[0, 1::2, 1] == kernel.INTERIOR).all() and (kind[0, :, 2] == kernel.NOTHING).all()
+        assert (np.asarray(layout["sum_kind"])[0, 2:] == kernel.INTERIOR).all()
+    if case == "two-documents-in-one-summary-tile":
+        codes = np.asarray(layout["chunk_code"])[0, :16] // kernel.SEG_STRIDE
+        assert sorted(set(codes)) == [1, 2]  # tile 0 holds both documents' summaries
+        assert (kind[0, 5] == kernel.NOTHING).all()  # a block of padding meets nothing
 
 
 def test_the_rope_kernel_turns_the_pairs_decoder_rope_turns():
@@ -267,12 +309,17 @@ def test_the_byte_tokenizer_is_the_references_rule_cut_at_max_len():
 
 
 @pytest.mark.parametrize("n,want", [
-    (1, 128), (90, 128), (129, 256), (1024, 1024), (1025, 2048), (6626, 7168),
+    (1, 128), (90, 128), (129, 256), (600, 640), (1024, 1024), (1025, 2048), (6626, 7168),
     (6717, 7168), (10423, 11264), (10669, 11264),
 ])
 def test_a_rows_length_comes_in_the_kernels_tiles(n, want):
     assert eva.seq_bucket(n) == want
     assert kernel.supports(want, 32, 128, 2048, 16)
+    # and the layout cuts every such row into whole blocks (640 slots: five of 128)
+    layout = kernel.window_layout(np.ones((1, want), np.int32), 2048, 16, xp=np)
+    block = kernel.row_block(want)
+    assert want % block == 0 and block % kernel.LANES == 0
+    assert layout["kind"].shape == (1, want // block * kernel.token_steps(want, 2048))
     assert eva.seq_bucket(n, maximum=8192) == min(want, 8192)
 
 
@@ -301,6 +348,63 @@ def test_files_whose_byte_lengths_jitter_compile_their_slab_once():
         assert np.isfinite(np.asarray(out)[0, :2]).all()
     assert shapes == {(1, 256)}
     assert enc.lm._packed_jit._cache_size() == 1
+
+
+def test_the_step_kinds_are_what_the_segment_ids_imply_and_the_counters_count_them(monkeypatch):
+    """A slab listed by hand.  Windows of 32, blocks of 16, summary tiles of
+    8 (what a window owns), sub-tiles of 8.  Document A: slots 0-69 (windows
+    at 0, 32, 64), B: 70-106 (windows at 70, 102), padding from 107.  Block
+    4 holds A's end and B's start, block 6 B's window boundary and the first
+    padding, block 7 padding alone; A's first two windows and B's first get
+    summaries: tiles 0, 1 and 2."""
+    from functools import partial
+
+    from pathway_tpu.internals import tracing
+
+    N, I, E = kernel.NOTHING, kernel.INTERIOR, kernel.EDGE
+    seg = _slab([[70, 37]], 128)
+    tiles = dict(block=16, summary_tile=8)
+    for xp in (np, jnp):  # the host's count and the program's layout are one function
+        layout = kernel.window_layout(xp.asarray(seg), WINDOW, CHUNK, xp=xp, **tiles)
+        # step 0 is the diagonal, step j the j-th block to the left
+        assert np.asarray(layout["kind"]).reshape(8, 3).tolist() == [
+            [E, N, N],  # a window's first block
+            [E, I, N],  # its second: the first is all one window
+            [E, N, N],
+            [E, I, N],
+            [E, N, N],  # A's last 6 tokens and B's first 10: nothing of B's to the left
+            [E, E, N],  # all B's first window, but block 4 is not
+            [E, E, E],  # B's boundary: its first window began in block 4
+            [N, N, N],  # padding
+        ]
+        assert np.asarray(layout["key_lo"]).tolist() == [[0, 0, 2, 2, 4, 4, 4, 7]]
+        assert np.asarray(layout["sum_kind"]).tolist() == [[N, N, I, I, E, N, E, N]]
+        lo, hi = np.asarray(layout["sum_lo"])[0], np.asarray(layout["sum_hi"])[0]
+        assert [(int(a), int(b)) for a, b in zip(lo, hi) if b > a] == [(0, 1), (0, 1), (0, 2), (2, 3)]
+    # 7 diagonals of 3 sub-tiles of 8 x 8, 5 blocks to the left, 5 summary tiles
+    met, unmasked = kernel.met_pairs(layout, sub_tile=8, **tiles)
+    assert met == 7 * 3 * 64 + 5 * 256 + 5 * 8 * 16
+    assert unmasked == 2 * 256 + 2 * 8 * 16
+    # the mask lets through no pair that the steps do not meet
+    code = np.asarray(layout["code"])[0]
+    at = np.arange(128)
+    scored = ((code[:, None] == code[None, :]) & (at[None, :] <= at[:, None]) & (code[:, None] > 0)).sum()
+    assert scored < 7 * 3 * 64 + 5 * 256
+    # and the program's counters are these counts, once a head and layer
+    enc = program_encoder(tiny_model(), seed=5)
+    monkeypatch.setattr(kernel, "window_layout", partial(kernel.window_layout, **tiles))
+    monkeypatch.setattr(kernel, "met_pairs", partial(kernel.met_pairs, sub_tile=8, **tiles))
+    before = tracing.spans_status()["totals"]
+    out = enc.lm.encode_packed(np.where(seg > 0, 70, 0).astype(np.int32), seg, PACK_MAX_SEGMENTS)
+    assert np.isfinite(np.asarray(out)[0, :2]).all()
+    after = tracing.spans_status()["totals"]
+    count = lambda name: after[name]["count"] - before.get(name, {"count": 0})["count"]  # noqa: E731
+    per_pair = enc.config.heads * enc.config.layers
+    assert count("eva.met_pairs") == met * per_pair
+    assert count("eva.unmasked_pairs") == unmasked * per_pair
+    keys, summaries = eva.scored_pairs(np.array([70, 37]), WINDOW, CHUNK)
+    assert count("eva.scored_pairs") == int(keys.sum() + summaries.sum()) * per_pair
+    assert count("eva.scored_pairs") < count("eva.met_pairs")
 
 
 def test_the_counters_count_what_the_mask_lets_through():
