@@ -308,3 +308,9 @@ __all__ = [
     "schema_builder",
     "universes",
 ]
+
+# the end of `import pathway_tpu`: the first mark of a start's wall clock
+# (`setup.at.imported`, /status "spans")
+from pathway_tpu.internals import tracing as _tracing  # noqa: E402
+
+_tracing.mark("imported")

@@ -22,6 +22,7 @@ from pathway_tpu.internals import costledger as _costledger
 from pathway_tpu.internals import provenance as _provenance
 from pathway_tpu.internals import qtrace as _qtrace
 from pathway_tpu.internals import serving as _serving
+from pathway_tpu.internals import tracing as _tracing
 
 
 class IndexImpl:
@@ -302,7 +303,7 @@ class ExternalIndexNode(Node):
             and _serving._TIER is not None
             and getattr(self.index, "supports_result_cache", False)
         ):
-            return _serving._TIER.cached_search(
+            results = _serving._TIER.cached_search(
                 values,
                 ks,
                 filters,
@@ -310,7 +311,11 @@ class ExternalIndexNode(Node):
                 index_id=id(self.index),
                 q_keys=q_keys,
             )
-        return self.index.search_many(values, ks, filters)
+        else:
+            results = self.index.search_many(values, ks, filters)
+        if q_keys:
+            _tracing.mark("first_search")  # of a start: written once
+        return results
 
     def _result_row(self, matches: List[tuple]) -> tuple:
         ids = tuple(k for k, _s in matches)

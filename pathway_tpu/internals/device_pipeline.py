@@ -38,7 +38,7 @@ import weakref
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
-from pathway_tpu.internals import memtrack, tracing, utilization
+from pathway_tpu.internals import compile_cache, memtrack, tracing, utilization
 from pathway_tpu.internals.metrics import MetricsRegistry
 
 
@@ -46,6 +46,10 @@ from pathway_tpu.internals.metrics import MetricsRegistry
 MAX_PREPARED = 4  # prepared batches queued ahead of the dispatcher
 MAX_IN_FLIGHT = 2  # dispatches on the device at once: double-buffered
 PREP_WORKERS = 2  # threads that tokenize and pack
+# the dispatch thread waits for work in slices, one `pipeline.starved` span
+# each: a span still open when a profiler capture stops is not in it, and
+# a backlog that runs dry leaves the thread waiting for the rest of a run
+STARVED_SLICE_S = 0.5
 
 
 class DevicePipelineError(RuntimeError):
@@ -133,6 +137,9 @@ class DevicePipeline:
         self._error: Optional[BaseException] = None
         self._failed: List[Any] = []
         self._stop = False
+        # a deployment that never calls configure() has the compile
+        # record too (jax is loaded wherever a pipeline dispatches)
+        compile_cache.observe()
         self._thread = threading.Thread(
             target=self._run, name=f"{name}-dispatch", daemon=True
         )
@@ -314,6 +321,7 @@ class DevicePipeline:
         interval (completion-to-completion; dispatches execute in-order)
         and feed the utilization window + the mesh straggler detector."""
         t_end = time.perf_counter()
+        tracing.mark("first_completion")  # of a start: written once
         if memtrack.ENABLED:
             # the slab's packed arrays retire with the dispatch
             memtrack.tracker().adjust(
@@ -399,10 +407,9 @@ class DevicePipeline:
             # starved (nothing submitted), prep_wait (submitted, not yet
             # prepared), window_wait (the chip is behind), launch
             with self._cond:
-                if not self._pending and not self._stop:
+                while not self._pending and not self._stop:
                     with tracing.span("pipeline.starved"):
-                        while not self._pending and not self._stop:
-                            self._cond.wait()
+                        self._cond.wait(STARVED_SLICE_S)
                 if not self._pending:
                     return
                 seq, epoch, item, fut = self._pending.popleft()
@@ -432,6 +439,7 @@ class DevicePipeline:
                 ) as launch:
                     handle = self._dispatch(payload)
                 disp_end = launch.t1
+                tracing.mark("first_launch")  # of a start: written once
                 if memtrack.ENABLED:
                     # packed slab bytes live on device until the handle
                     # retires (_note_completion books the -delta)

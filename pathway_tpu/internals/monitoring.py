@@ -585,6 +585,7 @@ class PrometheusServer:
             }
             for idx, n in enumerate(e0.nodes)
         ]
+        from pathway_tpu.internals.compile_cache import compile_status
         from pathway_tpu.internals.costledger import cost_status
         from pathway_tpu.internals.device_pipeline import pipeline_status
         from pathway_tpu.internals.device_probe import device_status
@@ -616,6 +617,11 @@ class PrometheusServer:
             # collection of each recent second; a reader differences two
             # readings
             "spans": spans_status(),
+            # what compiling cost (internals/compile_cache.py): the
+            # programs with the most seconds of trace, lowering, backend
+            # compilation and cache load, and the recent backend
+            # compilations with the span open on the compiling thread
+            "compile": compile_status(),
             # accelerator health (internals/device_probe.py)
             "device": device_status(),
             # async ingest pipeline (internals/device_pipeline.py):

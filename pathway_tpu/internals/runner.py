@@ -9,10 +9,12 @@ the requested outputs/sinks are built.
 from __future__ import annotations
 
 import threading
+import time as time_mod
 from typing import Any, Dict, List, Optional
 
 from pathway_tpu.engine.engine import CaptureNode, Engine
 from pathway_tpu.internals import config as _config
+from pathway_tpu.internals import tracing
 from pathway_tpu.internals.parse_graph import G
 
 
@@ -203,6 +205,8 @@ def run(
     events and slow-query exemplars key off it.  Equivalent to setting
     PATHWAY_SLO_P99_MS."""
     global _last_engine
+    tracing.mark("run")
+    t_entry = time_mod.perf_counter()
     from pathway_tpu.internals import faults, health, telemetry
     from pathway_tpu.internals.config import pathway_config as cfg
 
@@ -267,6 +271,8 @@ def run(
         mesh_backend.activate(mesh)
 
     if cfg.threads > 1:
+        # every worker thread builds its own graph, under its own span
+        tracing.record("setup.graph_build", t_entry, time_mod.perf_counter())
         try:
             return _run_threaded(
                 cfg.threads,
@@ -323,6 +329,8 @@ def run(
             workers=engine.worker_count,
             streaming=bool(G.sources),
         ), get_persistence_engine_config(persistence_config):
+            # set-up's share of a run ends where the engine begins to tick
+            tracing.record("setup.graph_build", t_entry, time_mod.perf_counter())
             if G.sources:
                 _run_streaming(
                     engine, ctx, persistence_config, autocommit_duration_ms
@@ -398,7 +406,7 @@ def _run_threaded(
             # graph building mutates shared registries (G.sources) and
             # runs user build closures — serialize it; execution below is
             # the concurrent part
-            with build_lock:
+            with build_lock, tracing.span("setup.graph_build"):
                 ctx = RunContext(engine)
                 # the planner is deterministic over the shared parse
                 # graph, so every worker derives the identical chain set
@@ -478,8 +486,6 @@ def _supervise_thread_group(group, ts, worker, threads: int) -> None:
     aborted the barrier, survivors roll back and park in
     failover_rendezvous; we join the corpse, reset the group state and
     start a replacement thread on the same slot)."""
-    import time as time_mod
-
     rejoin_timeout = _config.env("PATHWAY_REJOIN_TIMEOUT")
     while True:
         if group._failover_pending and not group._aborted:

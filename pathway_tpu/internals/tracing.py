@@ -607,6 +607,24 @@ GC_RECENT_S = 64  # seconds of per-second longest collections kept
 SHORT_SPAN_S = 1e-3  # mean duration under which CPU time is sampled
 _perf = time_mod.perf_counter
 _thread_time = time_mod.thread_time
+
+
+def _process_start_monotonic() -> float:
+    """time.monotonic() at which this process was created (Linux), so
+    that a mark counts the interpreter's own start and the imports; where
+    /proc is absent, now: the time of `import pathway_tpu`."""
+    try:
+        with open("/proc/self/stat") as f:
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        age = uptime - start_ticks / os.sysconf("SC_CLK_TCK")
+        return time_mod.monotonic() - max(age, 0.0)
+    except (OSError, ValueError, IndexError):
+        return time_mod.monotonic()
+
+
+T_PROCESS = _process_start_monotonic()
 _FIELDS = ("count", "total_s", "cpu_s", "self_s", "rows", "max_s", "open_s")
 
 
@@ -676,6 +694,7 @@ class SpanRecord:
         # maximum between two /status readings cannot be differenced from
         # cumulative totals).  Collections do not overlap, so no lock
         self.gc_recent: list = [None] * GC_RECENT_S
+        self.marks: set = set()  # the `setup.at.*` marks written so far
         self.local = threading.local()
         self.wall_off = time_mod.time() - _perf()
 
@@ -851,11 +870,17 @@ class span:
         return False
 
 
+def current_span() -> Optional[span]:
+    """The innermost open span on this thread, or None."""
+    stack = _RECORD.here().stack
+    return stack[-1] if stack else None
+
+
 def current_epoch():
     """The epoch of the innermost open span on this thread (the engine
     tick that is running), or None."""
-    stack = _RECORD.here().stack
-    return stack[-1].epoch if stack else None
+    sp = current_span()
+    return sp.epoch if sp is not None else None
 
 
 def record(name: str, t0: float, t1: float, *, seq=None, epoch=None,
@@ -877,6 +902,23 @@ def add(name: str, seconds: float = 0.0, n: int = 1) -> None:
         tot = totals[name] = [0, 0.0, 0.0, 0.0, 0, 0.0]
     tot[0] += n
     tot[1] += seconds
+
+
+def mark(name: str) -> None:
+    """`setup.at.<name>`: the process's age, in seconds since its
+    creation, the first time this is reached; later calls write nothing.
+    Marks cut a start's wall clock where spans give a site's own time
+    (`imported`, `run`, `first_launch`, `first_completion`,
+    `first_search`).  After the first time a site on a hot path pays this
+    call and one look into a set, no lock."""
+    rec = _RECORD
+    if name in rec.marks:
+        return
+    with rec.lock:
+        if name in rec.marks:
+            return
+        rec.marks.add(name)
+    add("setup.at." + name, seconds=time_mod.monotonic() - T_PROCESS)
 
 
 def spans_status() -> Dict[str, Any]:

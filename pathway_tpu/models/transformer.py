@@ -24,6 +24,8 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
+from pathway_tpu.internals import tracing
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -479,7 +481,11 @@ class TransformerLM:
         self.config = config
         model = model_module(config)
         if params is None:
-            params = model.init_params(jax.random.PRNGKey(seed), config)
+            # the host's time to make and place the parameters (it waits
+            # for no device): rows are the leaves
+            with tracing.span("setup.weights") as made:
+                params = model.init_params(jax.random.PRNGKey(seed), config)
+                made.rows = len(jax.tree_util.tree_leaves(params))
         self.params = params
 
         def _fwd(params, ids, mask, mesh=None):

@@ -19,6 +19,7 @@ import threading
 from typing import Optional
 
 from pathway_tpu.internals import config as _config
+from pathway_tpu.internals import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -72,32 +73,34 @@ def load() -> Optional[ctypes.CDLL]:
     return _lib
 
 
+def _built(source: str, name: str, flags: list, timeout: int) -> str:
+    """The path of `source` built as a shared library, kept in the cache
+    directory under its digest; g++ runs where it is not there yet
+    (counter `setup.native_builds`)."""
+    with open(source, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    so_path = os.path.join(_cache_dir(), f"{name}_{digest}.so")
+    if not os.path.exists(so_path):
+        tracing.add("setup.native_builds")
+        tmp = so_path + f".tmp{os.getpid()}"
+        subprocess.run(
+            ["g++", *flags, "-shared", "-fPIC", "-std=c++17", source, "-o", tmp],
+            check=True,
+            capture_output=True,
+            timeout=timeout,
+        )
+        os.replace(tmp, so_path)
+    return so_path
+
+
 def _build_and_load() -> None:
     global _lib, _build_failed
-    source = _source_path("tokenizer.cpp")
     try:
-        with open(source, "rb") as f:
-            digest = hashlib.sha256(f.read()).hexdigest()[:16]
-        so_path = os.path.join(_cache_dir(), f"pw_native_{digest}.so")
-        if not os.path.exists(so_path):
-            tmp = so_path + f".tmp{os.getpid()}"
-            subprocess.run(
-                [
-                    "g++",
-                    "-O3",
-                    "-shared",
-                    "-fPIC",
-                    "-std=c++17",
-                    source,
-                    "-o",
-                    tmp,
-                ],
-                check=True,
-                capture_output=True,
-                timeout=120,
+        with tracing.span("setup.native_load"):
+            so_path = _built(
+                _source_path("tokenizer.cpp"), "pw_native", ["-O3"], 120
             )
-            os.replace(tmp, so_path)
-        lib = ctypes.CDLL(so_path)
+            lib = ctypes.CDLL(so_path)
         lib.tokenize_batch.restype = None
         lib.tokenize_batch.argtypes = [
             ctypes.c_char_p, _INT64_P, _INT64_P,
@@ -210,35 +213,17 @@ def load_wire_ext():
         import importlib.util
         import sysconfig
 
-        source = _source_path("wire_ext.cpp")
-        with open(source, "rb") as f:
-            digest = hashlib.sha256(f.read()).hexdigest()[:16]
-        so_path = os.path.join(_cache_dir(), f"pw_wire_ext_{digest}.so")
-        if not os.path.exists(so_path):
-            tmp = so_path + f".tmp{os.getpid()}"
-            subprocess.run(
-                [
-                    "g++",
-                    "-O2",
-                    "-shared",
-                    "-fPIC",
-                    "-std=c++17",
-                    f"-I{sysconfig.get_path('include')}",
-                    source,
-                    "-o",
-                    tmp,
-                ],
-                check=True,
-                capture_output=True,
-                timeout=180,
+        with tracing.span("setup.native_load"):
+            so_path = _built(
+                _source_path("wire_ext.cpp"), "pw_wire_ext",
+                ["-O2", f"-I{sysconfig.get_path('include')}"], 180,
             )
-            os.replace(tmp, so_path)
-        loader = importlib.machinery.ExtensionFileLoader(
-            "pw_wire_ext", so_path
-        )
-        spec = importlib.util.spec_from_loader("pw_wire_ext", loader)
-        mod = importlib.util.module_from_spec(spec)
-        loader.exec_module(mod)
+            loader = importlib.machinery.ExtensionFileLoader(
+                "pw_wire_ext", so_path
+            )
+            spec = importlib.util.spec_from_loader("pw_wire_ext", loader)
+            mod = importlib.util.module_from_spec(spec)
+            loader.exec_module(mod)
 
         from pathway_tpu.engine import value as _value
         from pathway_tpu.engine import wire as _wire

@@ -190,9 +190,10 @@ class DeviceKnnIndex:
                 )
             min_cap = max(min_cap, 2 * n_dev)
         self.capacity = _next_bucket(max(reserved_space, min_cap))
-        self._buffer = jnp.zeros((self.capacity, self.d), dtype=jnp.float32)
-        self._valid_dev = jnp.zeros((self.capacity,), dtype=bool)
-        self._shard_buffers()
+        with span("setup.index_alloc", rows=self.capacity):
+            self._buffer = jnp.zeros((self.capacity, self.d), dtype=jnp.float32)
+            self._valid_dev = jnp.zeros((self.capacity,), dtype=bool)
+            self._shard_buffers()
         self._slot_of_key: dict = {}
         self._key_of_slot: dict = {}
         self._free: list[int] = list(range(self.capacity - 1, -1, -1))

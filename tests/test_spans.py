@@ -546,8 +546,14 @@ def test_a_quiesced_run_with_no_data_still_shows_recent_collections(record):
     collections there — the policy's pulses on their floor in time."""
     import pathway_tpu as pw
 
+    started = []
+
     class Idle(pw.io.python.ConnectorSubject):
         def run(self):
+            # the run has switched the automatic collector off by now: a
+            # young collection between `pw.run`'s entry and that moment is
+            # not the policy's
+            started.append(time.monotonic())
             time.sleep(6.0)
 
     class Schema(pw.Schema):
@@ -556,9 +562,9 @@ def test_a_quiesced_run_with_no_data_still_shows_recent_collections(record):
     seen = []
     table = pw.io.python.read(Idle(), schema=Schema)
     pw.io.subscribe(table, on_change=lambda *a, **k: seen.append(1))
-    before = time.monotonic()
     pw.run(monitoring_level=None)
     spans = tracing.spans_status()
+    (before,) = started
     assert not seen
     assert spans["totals"]["gc.automatic"]["count"] == 0
     assert spans["totals"]["gc.pulses"]["count"] >= 5
