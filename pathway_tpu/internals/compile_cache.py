@@ -34,7 +34,11 @@ seconds are thread-seconds.  jax reports a jitted function traced inside
 another's trace by itself (every `jnp` function is one): the record's
 ``compile.trace`` counts each second once, with the outermost trace,
 while a program's row keeps jax's figure, so that the row of
-`_fwd_packed` says what tracing `_fwd_packed` costs.
+`_fwd_packed` says what tracing `_fwd_packed` costs.  jax reports such a
+function at every call inside a trace, one it has traced before in
+microseconds: the row of a Pallas kernel (`ops.kernels.kernel_call` names
+it) counts the layers' calls, and `wrapped`, every `pallas_call`'s
+wrapper, the kernels' real traces.
 """
 
 from __future__ import annotations

@@ -63,6 +63,7 @@ from __future__ import annotations
 import functools
 import math
 
+from pathway_tpu.ops.kernels import kernel_call
 from pathway_tpu.ops.kernels.flash_attention import NEG_INF
 
 LANES = 128
@@ -336,6 +337,10 @@ def pool_chunks(k, v, weights, mu, start, *, interpret=None):
     v stay in HBM, each chunk is one copy into VMEM; XLA's gather of the
     same rows and its passes over them took 4.1 ms a layer at the ingest
     slab, 65 ms a dispatch."""
+    return kernel_call("eva_pool_chunks", _pool, interpret=interpret)(k, v, weights, mu, start)
+
+
+def _pool(k, v, weights, mu, start, *, interpret: bool):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -346,8 +351,6 @@ def pool_chunks(k, v, weights, mu, start, *, interpret=None):
     heads, head_dim = mu.shape
     span = weights.shape[1] // slots
     n = math.gcd(SUMMARY_CHUNKS, slots)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     out_block = pl.BlockSpec((1, n, width), lambda i, t, *_: (i, t, 0), memory_space=pltpu.VMEM)
     out = jax.ShapeDtypeStruct((b, slots, width), k.dtype)
     return pl.pallas_call(
@@ -398,6 +401,10 @@ def rope(x, cos, sin, *, scale: float = 1.0, interpret=None):
     them and copies it back for the attention kernel, five passes of f32
     a layer at the ingest slab; here a head's half-turn is one rotation of
     its lanes."""
+    return kernel_call("eva_rope", _rope, scale=float(scale), interpret=interpret)(x, cos, sin)
+
+
+def _rope(x, cos, sin, *, scale: float, interpret: bool):
     import jax
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -405,13 +412,11 @@ def rope(x, cos, sin, *, scale: float = 1.0, interpret=None):
     b, l, width = x.shape
     head_dim = cos.shape[2]
     rows = math.gcd(ROPE_ROWS, l)  # a row is whole lanes long: 128 or 256
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     block = lambda cols: pl.BlockSpec(  # noqa: E731
         (1, rows, cols), lambda i, r: (i, r, 0), memory_space=pltpu.VMEM
     )
     return pl.pallas_call(
-        functools.partial(_rope_kernel, head_dim=head_dim, scale=float(scale)),
+        functools.partial(_rope_kernel, head_dim=head_dim, scale=scale),
         grid=(b, l // rows),
         in_specs=[block(width), block(head_dim), block(head_dim)],
         out_specs=block(width),
@@ -564,29 +569,17 @@ def eva_attention(q, k, v, kbar, vbar, layout, heads: int, *, window: int,
     `window_layout(seg, window, chunk, block=, summary_tile=)` of the same
     tiles.  Returns the context [B, L, H*D] in q's dtype.  `block`,
     `summary_tile`, `sub_tile` and `head_block` are for tests: the
-    interpreter takes any tile.  The call is one jitted function a set of
-    tiles, so the layers of a trunk trace and lower the kernel once a
-    program: traced a layer it cost every program that holds it 8 s of
-    set-up, compile cache or not (the parent's 2.7 s)."""
-    import jax
-
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpreter takes any tile.  Called, like `rope` and `pool_chunks`,
+    through `kernel_call`: once a program (the package's note)."""
     names = ("code", "kind", "key_lo")
     if kbar is not None:
         names += ("chunk_code", "sum_kind", "sum_lo", "sum_hi")
-    call = _jitted(heads, window, block, summary_tile, sub_tile, head_block, bool(interpret))
+    call = kernel_call(
+        "eva_attention", _attend, heads=heads, window=window, block=block,
+        summary_tile=summary_tile, sub_tile=sub_tile, head_block=head_block,
+        interpret=interpret,
+    )
     return call(q, k, v, kbar, vbar, {name: layout[name] for name in names})
-
-
-@functools.lru_cache(maxsize=None)
-def _jitted(heads, window, block, summary_tile, sub_tile, head_block, interpret):
-    import jax
-
-    return jax.jit(functools.partial(
-        _attend, heads=heads, window=window, block=block, summary_tile=summary_tile,
-        sub_tile=sub_tile, head_block=head_block, interpret=interpret,
-    ))
 
 
 def _attend(q, k, v, kbar, vbar, layout, *, heads, window, block, summary_tile, sub_tile,

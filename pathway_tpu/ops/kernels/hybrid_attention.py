@@ -62,6 +62,7 @@ import functools
 import math
 from typing import Optional
 
+from pathway_tpu.ops.kernels import kernel_call
 from pathway_tpu.ops.kernels.flash_attention import NEG_INF
 
 LANES = 128
@@ -268,6 +269,15 @@ def hybrid_attention(q_nope, q_rope, k_nope, k_rope, v, seg, lo, *, kv_heads: in
     H*128] in q_nope's dtype.  The device op is named by kind:
     `hybrid_attention_window` or `hybrid_attention_global`.  `block` is for
     tests: the interpreter takes any tile."""
+    call = kernel_call(
+        "hybrid_attention_global" if window is None else "hybrid_attention_window",
+        _attend, kv_heads=kv_heads, window=window, block=block, interpret=interpret,
+    )
+    return call(q_nope, q_rope, k_nope, k_rope, v, seg, lo, sink)
+
+
+def _attend(q_nope, q_rope, k_nope, k_rope, v, seg, lo, sink, *, kv_heads: int,
+            window: Optional[int], block: Optional[int], interpret: bool):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -283,8 +293,6 @@ def hybrid_attention(q_nope, q_rope, k_nope, k_rope, v, seg, lo, *, kv_heads: in
             f"hybrid_attention: unsupported shape L={l} heads={heads} "
             f"kv_heads={kv_heads} block={block}"
         )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     n_q = l // block
     # a window no longer than a block: ONE step a block of queries, over its
     # own block of keys and the one before (no second pass of the running
@@ -431,21 +439,23 @@ def rope(x, cos, sin, *, scale: float = 1.0, interpret=None):
     left them (n even: two parts a 128-lane tile): `rotate`, as a kernel.
     XLA does the same sums over a [.., n, 64] view whose minor axis is half
     a tile."""
+    if x.shape[2] % LANES:  # an odd part has no tile to itself (one key head: tests)
+        return rotate(x, cos, sin, scale)
+    return kernel_call("hybrid_rope", _rope, scale=float(scale), interpret=interpret)(x, cos, sin)
+
+
+def _rope(x, cos, sin, *, scale: float, interpret: bool):
     import jax
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, l, width = x.shape
-    if width % LANES:  # an odd part has no tile to itself (one key head: tests)
-        return rotate(x, cos, sin, scale)
     rows = math.gcd(ROPE_ROWS, l)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     block = lambda cols: pl.BlockSpec(  # noqa: E731
         (1, rows, cols), lambda i, r: (i, r, 0), memory_space=pltpu.VMEM
     )
     return pl.pallas_call(
-        functools.partial(_rope_kernel, scale=float(scale)),
+        functools.partial(_rope_kernel, scale=scale),
         grid=(b, l // rows),
         in_specs=[block(width), block(LANES), block(LANES)],
         out_specs=block(width),

@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import functools
 
+from pathway_tpu.ops.kernels import kernel_call
 from pathway_tpu.ops.kernels.flash_attention import NEG_INF
 
 LANES = 128
@@ -120,6 +121,13 @@ def mla_segment_attention(q_nope, q_rope, k_nope, k_rope, v, seg, *,
     all heads (both already rotated); seg: [B, L] int, 1..S per packed
     document, 0 = padding.  Returns the context [B, L, H*128] in
     q_nope's dtype."""
+    call = kernel_call(
+        "mla_segment_attention", _attend, sm_scale=float(sm_scale), interpret=interpret
+    )
+    return call(q_nope, q_rope, k_nope, k_rope, v, seg)
+
+
+def _attend(q_nope, q_rope, k_nope, k_rope, v, seg, *, sm_scale: float, interpret: bool):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -132,8 +140,6 @@ def mla_segment_attention(q_nope, q_rope, k_nope, k_rope, v, seg, *,
             f"mla_segment_attention: unsupported shape L={l} heads={heads} "
             f"q_rope={q_rope.shape} v={v.shape}"
         )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     lp = -(-l // LANES) * LANES
     n_blocks = heads // HEAD_BLOCK
 
@@ -150,7 +156,7 @@ def mla_segment_attention(q_nope, q_rope, k_nope, k_rope, v, seg, *,
         return pl.BlockSpec(shape, lambda i, j: (i, 0, 0), memory_space=pltpu.VMEM)
 
     kernel = functools.partial(
-        _kernel, sm_scale=float(sm_scale), length=l, block_q=_block_q(lp),
+        _kernel, sm_scale=sm_scale, length=l, block_q=_block_q(lp),
     )
     return pl.pallas_call(
         kernel,

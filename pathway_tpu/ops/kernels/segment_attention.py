@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import functools
 
+from pathway_tpu.ops.kernels import kernel_call
 from pathway_tpu.ops.kernels.flash_attention import NEG_INF
 
 LANES = 128
@@ -119,6 +120,11 @@ def segment_attention(qkv, seg, heads: int, *, interpret=None):
     matmul leaves it (q | k | v along the last axis, heads contiguous
     within each); seg: [B, L] int, 1..S per packed document, 0 = padding.
     Returns the context [B, L, hidden] in qkv's dtype."""
+    call = kernel_call("segment_attention", _attend, heads=heads, interpret=interpret)
+    return call(qkv, seg)
+
+
+def _attend(qkv, seg, *, heads: int, interpret: bool):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -133,8 +139,6 @@ def segment_attention(qkv, seg, heads: int, *, interpret=None):
             f"segment_attention: unsupported shape L={l} hidden={hidden} "
             f"head_dim={head_dim}"
         )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     lp = -(-l // LANES) * LANES
     block_w = _block_w(hidden)
     n_w = hidden // block_w

@@ -182,6 +182,54 @@ def test_status_serves_the_compile_record_beside_the_spans(record):
     eng._gc_unfreeze()
 
 
+def _trunk(name: str):
+    """(the model's module, a toy configuration its kernels tile, the calls
+    of each kernel in a trace of its layers, the traces of `wrapped`)."""
+    import dataclasses
+
+    from pathway_tpu.models import eva, moe_hybrid, moe_mla, transformer
+
+    if name == "encoder":
+        config = transformer.TransformerConfig(
+            vocab_size=512, hidden=128, layers=3, heads=4, mlp_dim=256, max_len=64)
+        return transformer, config, {"segment_attention": 3}, 1
+    if name == "moe_mla":  # the kernel's tiling is written for the published head widths
+        config = dataclasses.replace(
+            moe_mla.TINY, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128)
+        return moe_mla, config, {"mla_segment_attention": 3}, 1
+    if name == "eva":  # q and k are turned at two scales: two functions of `eva_rope`
+        return eva, eva.TINY, {"eva_attention": 3, "eva_pool_chunks": 3, "eva_rope": 6}, 4
+    # two layers of each kind; q and a window layer's k are two widths of
+    # `hybrid_rope`, a global layer's one rope key head is `rotate`'s
+    calls = {"hybrid_attention_global": 2, "hybrid_attention_window": 2, "hybrid_rope": 6}
+    return moe_hybrid, moe_hybrid.TINY, calls, 4
+
+
+@pytest.mark.parametrize("trunk", ["encoder", "moe_mla", "eva", "moe_hybrid"])
+def test_status_names_each_kernel_of_a_trunk_not_wrapped_alone(trunk, record):
+    """A packed trunk traced with its kernels (interpreted): /status
+    "compile"."programs" has a row of each kernel's own name, with its
+    calls, and `wrapped` (jax's name for every `pallas_call`'s wrapper)
+    counts one trace a kernel a shape, not one a layer."""
+    import jax
+
+    from pathway_tpu.ops import kernels
+
+    model, config, calls, traces = _trunk(trunk)
+    assert compile_cache.observe()
+    kernels._jitted.cache_clear()  # an earlier test's traces are not this trunk's
+    params = jax.eval_shape(lambda: model.init_params(jax.random.PRNGKey(0), config))
+    slab = jax.ShapeDtypeStruct((2, 128), np.int32)
+
+    def program(params, ids, seg):
+        return model.forward(params, config, ids, None, seg=seg, max_segments=4, use_flash=True)
+
+    jax.jit(program).trace(params, slab, slab)
+    served = {p["program"]: p["trace"]["count"] for p in compile_cache.compile_status()["programs"]}
+    assert {name: served.get(name) for name in calls} == calls
+    assert served["wrapped"] == traces < sum(calls.values())
+
+
 _CACHED_RUN = """
 import json, sys
 sys.path.insert(0, {repo!r})
