@@ -46,7 +46,9 @@ from typing import Any, Dict, Optional
 import numpy as np
 
 from pathway_tpu.models.decoder import _rms_norm
-from pathway_tpu.models.moe_mla import CHUNK_TOKENS, _dtype, _normal, row_chunks
+from pathway_tpu.models.moe_mla import (
+    CHUNK_TOKENS, _dtype, _normal, document_lengths, row_chunks,
+)
 from pathway_tpu.models.transformer import TransformerLM, _one_chip_only
 from pathway_tpu.ops.kernels import eva_attention as kernel
 
@@ -401,10 +403,7 @@ class EvaLM(TransformerLM):
 
         c = self.config
         seg = np.asarray(seg)
-        # a row's documents are its runs of one segment id
-        rows = np.arange(seg.shape[0])[:, None] * (int(max_segments) + 1)
-        lengths = np.bincount((rows + seg)[seg > 0])
-        lengths = lengths[lengths > 0]
+        lengths = document_lengths(seg, max_segments)
         keys, summaries = scored_pairs(lengths, c.window_size, c.chunk_size)
         a_pair = c.heads * c.layers  # a pair is counted once a head and layer
         tracing.add("eva.tokens", n=int(lengths.sum()))

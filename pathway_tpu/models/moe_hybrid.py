@@ -62,6 +62,7 @@ from pathway_tpu.models.moe_mla import (
     _dtype,
     _normal,
     _swiglu,
+    document_lengths,
     held_experts,
     layer_pass_lists,
     pooled_by_row_groups,
@@ -471,11 +472,7 @@ class MoeHybridLM(MoeMlaLM):
         from pathway_tpu.internals import tracing
 
         c = self.config
-        seg = np.asarray(seg)
-        # a row's documents are its runs of one segment id
-        rows = np.arange(seg.shape[0])[:, None] * (int(max_segments) + 1)
-        lengths = np.bincount((rows + seg)[seg > 0])
-        lengths = lengths[lengths > 0].astype(np.int64)
+        lengths = document_lengths(seg, max_segments)
         # a pair is counted once a query head and layer
         global_pairs = int(scored_pairs(lengths, None).sum()) * c.heads * (
             c.layers - c.window_layers

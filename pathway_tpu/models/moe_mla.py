@@ -398,33 +398,46 @@ PAIR_ROWS = 512
 
 def pair_capacity(tokens: int, config) -> int:
     """Rows of the static buffer of (token, held expert) pairs for a slab
-    of `tokens` slots.  Every pair there can be (tokens x k) up to 4,096
-    rows: small batches cannot overflow.  Above that one row a token slot,
-    where the expected load is tokens x k x held / routed (half a row a
-    token at 12 of 192, top-8) and the slab's padding routes nothing: the
-    room that lets every group begin a tile (`held_experts`), and a skewed
-    router's pairs still fit one after the other.  Rounded up to whole
-    tiles: at 14,112 rows, which 512 does not divide, the TPU's grouped
-    matmul took 8.6 ms where it takes 2.5 at 14,336 (chip runs, PR 30)."""
+    of `tokens` slots, from the slots, k = `experts_per_token` and the
+    share held / routed = `experts_held` / `n_routed_experts`.  Every pair
+    there can be (tokens x k) up to 4,096 rows: small batches cannot
+    overflow.  Above that one row a token slot.  The expected load is
+    tokens x k x held / routed: where that is under the rows (half a row
+    a token at a sixteenth of the experts held, top-8; the slab's padding
+    routes nothing), the rows themselves are the room that lets every
+    group begin a tile (`held_experts`), and a skewed router's pairs still
+    fit one after the other.  Where it fills them (every expert held at
+    top-1: a pair a token), a tile a held expert is added, which is the
+    most that beginning each group on a tile can take.  Rounded up to
+    whole tiles: at 14,112 rows, which 512 does not divide, the TPU's
+    grouped matmul took 8.6 ms where it takes 2.5 at 14,336 (chip runs,
+    PR 30)."""
     every = tokens * config.experts_per_token
-    return -(-min(every, max(tokens, 4096)) // PAIR_ROWS) * PAIR_ROWS
+    rows = min(every, max(tokens, 4096))
+    if every * config.experts_held >= rows * config.n_routed_experts:
+        rows += config.experts_held * PAIR_ROWS
+    return -(-rows // PAIR_ROWS) * PAIR_ROWS
 
 
 def combine_rows(tokens: int, config) -> int:
     """Slots of the compact list of tokens with two or more pairs in the
-    buffer (`held_experts`' return).  A slab of at most 4,096 token slots
-    (the buffer's least size) has a slot a token: it needs no list, cannot
-    spill, and its program stays as small as it was, which is what the
-    search programs' query slabs are loaded for.  Above that an eighth of
-    the token slots in whole tiles: 2,048 for 14,112, where 12 of 192
-    experts held at top-8 give about 915 such tokens."""
+    buffer (`held_experts`' return).  At k = 1 there are no such tokens
+    and no list.  A slab of at most 4,096 token slots (the buffer's least
+    size) has a slot a token: it needs no list, cannot spill, and its
+    program stays as small as it was, which is what the search programs'
+    query slabs are loaded for.  Above that an eighth of the token slots
+    in whole tiles: 2,048 for 14,112, where k x held / routed = 1/2 (a
+    sixteenth of the experts held, top-8) gives about 915 such tokens."""
+    if config.experts_per_token == 1:
+        return 0
     if tokens <= 4096:
         return tokens
     return -(-tokens // (8 * PAIR_ROWS)) * PAIR_ROWS
 
 
 def held_experts(h, valid, layer, config, capacity: Optional[int] = None,
-                 *, listed: Optional[int] = None, with_stats: bool = False):
+                 *, listed: Optional[int] = None, with_stats: bool = False,
+                 routing=None):
     """The routed experts' part of an expert layer that this rank
     computes.  h: [T, hidden] (normed), valid: [T] bool (padding routes
     nothing).  Returns (y [T, hidden], tokens per held expert
@@ -433,9 +446,16 @@ def held_experts(h, valid, layer, config, capacity: Optional[int] = None,
     "combine_spills", "groups_aligned", "groups_packed"}, each () int32.
     `capacity` and `listed` override `pair_capacity` and `combine_rows`
     (tests).  `config`: this module's or another trunk's with the same
-    routing fields (`models/moe_hybrid.py`: 16 of 256 experts at width
-    4096, a selection bias `layer["router_bias"]`, no shared expert beside
-    it); the shared expert, where a model has one, is the caller's.
+    routing fields, k = `experts_per_token`, `experts_held` of
+    `n_routed_experts` from `expert_offset` (`models/moe_hybrid.py`: a
+    sixteenth of 256 experts at width 4096, a selection bias
+    `layer["router_bias"]`, no shared expert beside it; `models/zaya.py`:
+    all 16 at top-1); the shared expert, where a model has one, is the
+    caller's.  `routing`: (experts [T, k] int32, weights [T, k] f32) from
+    a trunk whose router is not `route`'s matrix and sigmoid
+    (`models/zaya.py`: an MLP over a state carried from layer to layer, a
+    softmax, and a choice beyond the routed experts, "skip", which is an
+    expert nobody holds); None: `route(h, layer["router"], ...)`.
 
     Selected pairs on held experts are sorted by expert into a buffer of
     `capacity` rows, and three grouped matmuls (gate, up, down) run over
@@ -451,8 +471,10 @@ def held_experts(h, valid, layer, config, capacity: Optional[int] = None,
 
     The results go back to their tokens in one gather a token.  A pair's
     weight is put on its row in the buffer.  A token with one pair reads
-    that row, a token with none a zero.  The tokens with two or more (8%
-    at 12 of 192 experts held, top-8) are first summed, in the compute
+    that row, a token with none a zero: at k = 1 that is the whole return,
+    one inverse permutation.  The tokens with two or more (8% at k x held
+    / routed = 1/2: a sixteenth of the experts held, top-8) are first
+    summed, in the compute
     dtype and in their slots' order, in a list of `listed` slots appended
     to the buffer, and read their sum.  More such tokens than slots is
     seen in the input: then the others' further pairs are added pass by
@@ -472,7 +494,9 @@ def held_experts(h, valid, layer, config, capacity: Optional[int] = None,
     t, k, n_held = h.shape[0], c.experts_per_token, c.experts_held
     capacity = pair_capacity(t, c) if capacity is None else capacity
     listed = combine_rows(t, c) if listed is None else listed
-    experts, weights = route(h, layer["router"], c, layer.get("router_bias"))
+    if routing is None:
+        routing = route(h, layer["router"], c, layer.get("router_bias"))
+    experts, weights = routing
     local = experts - c.expert_offset
     held = (local >= 0) & (local < n_held) & valid[:, None]
     group = jnp.where(held, local, n_held).reshape(-1)  # [T*k]; n_held = not ours
@@ -534,7 +558,9 @@ def held_experts(h, valid, layer, config, capacity: Optional[int] = None,
             nth_pair(rows_of, 0, 0 < pairs),
         )
 
-    if listed >= t:  # a slot a token: the tokens are the list
+    if k == 1:  # a pair a token at most: one inverse permutation
+        y = jnp.where(mine, out[row[:, 0]], jnp.zeros((), dt))
+    elif listed >= t:  # a slot a token: the tokens are the list
         y = summed(row_of, pairs_of)
     else:
         # the tokens with two or more pairs, in the list's slots (the search
@@ -630,6 +656,17 @@ def pooled_by_row_groups(trunk, ids, seg, cap: Optional[int] = None):
         pooled.reshape(b, *pooled.shape[2:]),
         {name: per_group.sum(0) for name, per_group in stats.items()},
     )
+
+
+def document_lengths(seg, max_segments: int) -> np.ndarray:
+    """Tokens of each document of a packed batch, int64, from its segment
+    ids on the host (seg: [rows, L], 1..max_segments per packed document,
+    0 = padding): a row's documents are its runs of one segment id.  What
+    the trunks count their batches' tokens and scored pairs from."""
+    seg = np.asarray(seg)
+    rows = np.arange(seg.shape[0])[:, None] * (int(max_segments) + 1)
+    lengths = np.bincount((rows + seg)[seg > 0])
+    return lengths[lengths > 0].astype(np.int64)
 
 
 def _trunk(params, config: MoeMlaConfig, ids, seg, max_segments: int, fused: bool):
@@ -739,7 +776,7 @@ class MoeMlaLM(TransformerLM):
 
     def _packed_program(self):
         """The packed program, under the name the device trace knows it by
-        (a trunk that shares the counters brings its own: `moe_hybrid`)."""
+        (a trunk that shares the counters brings its own: `moe_hybrid`, `zaya`)."""
         config = self.config
 
         def _fwd_packed_moe_mla(params, ids, seg, max_segments):
@@ -790,6 +827,11 @@ class MoeMlaLM(TransformerLM):
             tracing.add("moe.overflow_pairs", n=int(np.asarray(stats["overflow"]).sum()))
             for name in LAYER_PASS_STATS:
                 tracing.add("moe." + name, n=int(np.asarray(stats[name]).sum()))
+            self._count_more(stats)
+
+    def _count_more(self, stats) -> None:
+        """A trunk's own counters from a finished dispatch's statistics
+        (`models/zaya.py`: the tokens that chose to skip)."""
 
 
 LM = MoeMlaLM

@@ -446,7 +446,8 @@ def model_module(config):
     `init_params`, `param_sharding_rules`, `packed_attention_fused`,
     `tokenizer` and `LM` (this module for a `TransformerConfig`,
     `models/moe_mla.py` for a `MoeMlaConfig`, `models/eva.py` for an
-    `EvaConfig`, `models/moe_hybrid.py` for a `MoeHybridConfig`).  The one rule by which `TransformerLM`, the encoders and
+    `EvaConfig`, `models/moe_hybrid.py` for a `MoeHybridConfig`,
+    `models/zaya.py` for a `ZayaConfig`).  The one rule by which `TransformerLM`, the encoders and
     the fused programs of `ops/knn.py` find a configuration's model."""
     return importlib.import_module(type(config).__module__)
 
@@ -454,7 +455,7 @@ def model_module(config):
 def _one_chip_only(mesh, module: str, holds: str, elsewhere: str) -> None:
     """The one refusal of a mesh, for the trunks that run a single chip's
     share of a deployment (`moe_mla`, `moe_hybrid`: one expert-parallel
-    rank; `eva`: one pipeline stage): `module` holds `holds` on one chip,
+    rank; `eva`, `zaya`: one pipeline stage): `module` holds `holds` on one chip,
     and what would join the chips (`elsewhere`) is not built."""
     if mesh is not None:
         raise NotImplementedError(

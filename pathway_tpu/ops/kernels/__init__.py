@@ -17,7 +17,10 @@ each, named as the device's profile names them:
     summaries and RoPE where the matmuls left q and k (`models/eva.py`);
   * `hybrid_attention_window` / `hybrid_attention_global`, `hybrid_rope`
     (`hybrid_attention.py`) — one grouped-query kernel for sliding-window
-    and global layers, and its RoPE (`models/moe_hybrid.py`).
+    and global layers, and its RoPE (`models/moe_hybrid.py`);
+  * `cca_attention` (`cca_attention.py`) — `mla_segment_attention`'s
+    sibling for one-part heads that share key/value heads, the group's
+    query heads one product a step (`models/zaya.py`).
 
 Beside them, on no packed path: `flash_attention` (online-softmax blocked
 attention, O(L) memory instead of the [L, L] score matrix) and

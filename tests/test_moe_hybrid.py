@@ -525,3 +525,19 @@ def test_the_kernel_compiles_for_the_chip_at_the_ingest_slab(kind, one_chip, no_
                 shape(heads, dt=jnp.float32))
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows,length", [(56, 504), (8, 512)])
+def test_the_cca_kernel_compiles_for_the_chip_at_its_cells_slabs(rows, length, one_chip,
+                                                                 no_compile_cache):
+    """`ops/kernels/cca_attention.py` (tests/test_zaya.py holds its numbers)
+    at the ingest slab and the read-back's, 8 query over 2 key/value heads,
+    bf16.  Here and not beside its other tests: one file holds the
+    fixture that describes the chip, because one process may."""
+    from pathway_tpu.ops.kernels import cca_attention
+
+    shape = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+    fn = lambda q, k, v, seg: cca_attention.cca_attention(q, k, v, seg, interpret=False)  # noqa: E731
+    args = (shape(rows, length, 8 * 128), shape(rows, length, 2 * 128),
+            shape(rows, length, 2 * 128), shape(rows, length, dt=jnp.int32))
+    assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()

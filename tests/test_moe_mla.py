@@ -560,3 +560,88 @@ def test_yarn_ladder_and_scale():
     m = 0.1 * np.log(32.0) + 1.0
     assert R.softmax_scale(model | {"qk_nope_head_dim": 128}) == pytest.approx(192 ** -0.5 * m * m)
     assert M.MoeMlaConfig().sm_scale == pytest.approx(192 ** -0.5 * m * m)
+
+
+# the three trunks that call `pair_capacity` / `combine_rows`, at the slabs
+# their cells dispatch and read back: token slots -> buffer rows, list slots.
+# A.X-K1's and MiMo-V2.5's are what they were before the rule learned of
+# top-1 with every expert held (PR 42): their programs' shapes, their
+# `memory_peak_bytes` and their compile cache entries hang on these numbers
+_BUFFER_PINS = {
+    "axk1-ingest-row-group": ("moe_mla", 14112, 14336, 2048),
+    "axk1-query-slab": ("moe_mla", 4096, 4096, 4096),
+    "axk1-probe-slab": ("moe_mla", 256, 2048, 256),
+    "mimo-ingest-row": ("moe_hybrid", 24576, 24576, 3072),
+    "mimo-query-row": ("moe_hybrid", 16384, 16384, 2048),
+    "mimo-probe-slab": ("moe_hybrid", 1024, 4096, 1024),
+    "zaya-ingest-slab": ("zaya", 28224, 36864, 0),
+    "zaya-query-slab": ("zaya", 4096, 12288, 0),
+    "zaya-probe-slab": ("zaya", 256, 8704, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BUFFER_PINS))
+def test_the_buffer_rule_is_pinned_at_the_cells_slabs(case):
+    """One rule from the slots, k and held / routed: a row a token slot
+    where the expected load is under that (the two ranks that hold a
+    sixteenth of the experts at top-8), and a tile a held expert more where
+    it fills them (top-1 with every expert held)."""
+    import importlib
+
+    from pathway_tpu.models import moe_mla as M
+
+    module, tokens, rows, listed = _BUFFER_PINS[case]
+    trunk = importlib.import_module(f"pathway_tpu.models.{module}")
+    config = {"moe_mla": "MoeMlaConfig", "moe_hybrid": "MoeHybridConfig",
+              "zaya": "ZayaConfig"}[module]
+    published = getattr(trunk, config)()
+    assert M.pair_capacity(tokens, published) == rows
+    assert M.combine_rows(tokens, published) == listed
+    assert rows % M.PAIR_ROWS == 0
+
+
+@pytest.mark.parametrize("tokens", [40, 900])
+def test_top_one_with_every_expert_held_aligns_its_groups_and_lists_nothing(tokens):
+    """`held_experts` under a routing handed in by the trunk (top-1 of 8
+    experts and "skip", all 8 held): every group begins a tile, no token
+    has two pairs, nothing is dropped, "skip" and the padding compute
+    nothing, and the return is the plain sum."""
+    from pathway_tpu.models import moe_mla as M
+    from pathway_tpu.models import zaya
+
+    config = zaya.TINY
+    layer = zaya.init_params(jax.random.PRNGKey(2), config)["layers"][0]
+    rng = np.random.default_rng(tokens)
+    h = jnp.asarray(rng.normal(size=(tokens, config.hidden)), jnp.float32)
+    valid = jnp.asarray(rng.random(tokens) < 0.9)
+    experts, weights, _ = zaya.route(
+        h, jnp.zeros((tokens, config.router_hidden)), layer, config
+    )
+    y, counts, overflow, stats = M.held_experts(
+        h, valid, layer, config, with_stats=True, routing=(experts, weights)
+    )
+    chosen = np.asarray(experts)[:, 0]
+    real = np.asarray(valid)
+    skip = config.n_routed_experts
+    assert 0 < (chosen == skip).sum() < tokens
+    np.testing.assert_array_equal(
+        counts, np.bincount(chosen[real & (chosen < skip)], minlength=skip)
+    )
+    assert int(overflow) == 0 and int(stats["multi_pair_tokens"]) == 0
+    assert int(stats["combine_spills"]) == 0 and int(stats["groups_aligned"]) == 1
+    assert M.pair_capacity(tokens, config) >= tokens + config.experts_held * M.PAIR_ROWS
+    want = np.zeros((tokens, config.hidden), np.float32)
+    for e in range(skip):
+        out = M._swiglu(h, layer["experts_gate"][e], layer["experts_up"][e],
+                        layer["experts_down"][e])
+        picked = (chosen == e) & real
+        want += (np.asarray(weights)[:, 0] * picked)[:, None] * np.asarray(out)
+    np.testing.assert_allclose(np.asarray(y), want, atol=2e-5)
+    assert not np.asarray(y)[~real | (chosen == skip)].any()
+    # a buffer without the room: the groups follow each other, same numbers
+    packed = M.held_experts(
+        h, valid, layer, config, -(-tokens // 512) * 512, with_stats=True,
+        routing=(experts, weights),
+    )
+    assert int(packed[3]["groups_packed"]) == 1 and int(packed[2]) == 0
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(packed[0]))
