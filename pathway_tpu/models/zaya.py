@@ -49,9 +49,15 @@ this router's choice from here (`routing=`): "skip" is an expert index
 nobody holds, so it is routed, not held, and computes nothing.  The
 attention kernel is `ops/kernels/cca_attention.py` (off the TPU its dense
 definition); RoPE's tables and rotation are `hybrid_attention.py`'s.  The
-means, convolutions, normalisation and RoPE are float32 element-wise work
-between the projection and the kernel, which XLA fuses; `conv1` is one
-batched matmul over the heads with the taps side by side.
+means, convolutions, normalisation, RoPE and the value shift are float32
+element-wise work between the projection and that kernel: on the fused
+path ONE more kernel, `ops/kernels/cca_latent.py`, which reads the
+projection's output once and writes the attention kernel's three operands
+(left to XLA they are a dozen fusions a layer, each through HBM); off it
+`latent_dense`, the same steps in jax.numpy and the kernel's numerical
+definition, where `conv1` is one batched matmul over the heads with the
+taps side by side.  One gate, `packed_attention_fused`, decides both
+kernels.
 """
 
 from __future__ import annotations
@@ -77,6 +83,7 @@ from pathway_tpu.models.transformer import (  # noqa: F401  (`tokenizer`: model_
     tokenizer,
 )
 from pathway_tpu.ops.kernels import cca_attention as kernel
+from pathway_tpu.ops.kernels import cca_latent as latent
 from pathway_tpu.ops.kernels.hybrid_attention import ROPE_DIM, rope_tables, rotate
 
 
@@ -240,12 +247,13 @@ def param_sharding_rules(config: ZayaConfig, mesh):
 
 def packed_attention_fused(config: ZayaConfig, length: int,
                            use_flash: Optional[bool] = None) -> bool:
-    """Whether a slab of `length` slots runs the fused kernel or its dense
-    definition: the backend and the static shape, as
-    `moe_mla.packed_attention_fused` decides (its floor, L > 32, is taken
-    over: below it a row's scores are a few kilobytes).  The launch site
-    asks again to count the batch.  `use_flash` overrides (tests run the
-    kernel interpreted on the CPU)."""
+    """Whether a slab of `length` slots runs the two fused kernels (the
+    latent's, then the attention's) or the dense definition of both: the
+    backend and the static shape, as `moe_mla.packed_attention_fused`
+    decides (its floor, L > 32, is taken over: below it a row's scores are
+    a few kilobytes).  A shape either kernel cannot take runs both dense.
+    The launch site asks again to count the batch.  `use_flash` overrides
+    (tests run the kernels interpreted on the CPU)."""
     if use_flash is not None:
         return use_flash
     import jax
@@ -256,6 +264,9 @@ def packed_attention_fused(config: ZayaConfig, length: int,
         and length > 32
         and c.rotary_dim == ROPE_DIM
         and kernel.supports(length, c.heads, c.kv_heads, c.head_dim)
+        and latent.supports(
+            length, c.heads, c.kv_heads, c.head_dim, c.rotary_dim, c.conv_taps0, c.conv_taps1
+        )
     )
 
 
@@ -265,8 +276,11 @@ def own_past(x, seg, n: int):
     before may be another document's, or none: a row's first slots).  x:
     [B, L, ...], seg: [B, L], 1..S per packed document, 0 = padding.  Both
     convolutions and the value shift look back through here and nowhere
-    else.  Rows are whole under a row group, so a group's first slot is a
-    row's first slot."""
+    else, in the dense definition; the fused path's kernel holds the second
+    copy, `cca_latent.own_row` (a sublane roll of a slab row's block and
+    the same comparison of `seg`), and tests/test_zaya.py pins the two
+    against each other.  Rows are whole under a row group, so a group's
+    first slot is a row's first slot."""
     import jax.numpy as jnp
 
     if n == 0:
@@ -299,18 +313,19 @@ def _merge(x, out, scales):
     return merged.astype(x.dtype)
 
 
-def _attention(x, layer, config: ZayaConfig, seg, rope, fused: bool):
-    """The attention sublayer, without the merge.  x: [B, L, hidden];
-    rope: `rope_tables` of the slab's positions."""
+def latent_dense(qkv, layer, config: ZayaConfig, seg, rope):
+    """The compressed latent's numerical definition, the path off the TPU
+    and the tests' reference of `cca_latent`: qkv [B, L, (heads + 2 kv) x
+    head_dim] as the projection leaves it -> (q [B, L, heads x head_dim],
+    k, v [B, L, kv x head_dim]) in its dtype, as the attention reads
+    them.  rope: `rope_tables` of the slab's positions."""
     import jax.numpy as jnp
 
     c = config
-    b, l, _ = x.shape
-    dt, f32 = x.dtype, jnp.float32
+    b, l, _ = qkv.shape
+    dt, f32 = qkv.dtype, jnp.float32
     hd, kv, group = c.head_dim, c.kv_heads, c.heads // c.kv_heads
     n = c.heads + kv
-    h = _rms_norm(x, layer["ln1"], c.norm_eps)
-    qkv = h @ layer["wqkv"].astype(dt)
     qk = qkv[..., : n * hd].astype(f32)
     # the value shift: the second half of the value heads are the token before's
     now = (kv - kv // 2) * hd
@@ -346,11 +361,23 @@ def _attention(x, layer, config: ZayaConfig, seg, rope, fused: bool):
         first = first.reshape(b, l, heads, c.rotary_dim)
         return jnp.concatenate([first, a[..., c.rotary_dim :]], -1).reshape(b, l, heads * hd)
 
-    q, k = turned(q).astype(dt), turned(k).astype(dt)
+    return turned(q).astype(dt), turned(k).astype(dt), v
+
+
+def _attention(x, layer, config: ZayaConfig, seg, rope, fused: bool):
+    """The attention sublayer, without the merge.  x: [B, L, hidden];
+    rope: `rope_tables` of the slab's positions; fused: both kernels, or
+    the dense definition of both."""
+    c = config
+    dt = x.dtype
+    h = _rms_norm(x, layer["ln1"], c.norm_eps)
+    qkv = h @ layer["wqkv"].astype(dt)
     if fused:
+        q, k, v = latent.cca_latent(qkv, seg, rope, layer, heads=c.heads, kv_heads=c.kv_heads)
         ctx = kernel.cca_attention(q, k, v, seg)
     else:
-        ctx = kernel.cca_attention_dense(q, k, v, seg, kv_heads=kv)
+        q, k, v = latent_dense(qkv, layer, c, seg, rope)
+        ctx = kernel.cca_attention_dense(q, k, v, seg, kv_heads=c.kv_heads)
     return ctx @ layer["wo"].astype(dt)
 
 

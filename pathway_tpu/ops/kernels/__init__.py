@@ -20,7 +20,12 @@ each, named as the device's profile names them:
     and global layers, and its RoPE (`models/moe_hybrid.py`);
   * `cca_attention` (`cca_attention.py`) — `mla_segment_attention`'s
     sibling for one-part heads that share key/value heads, the group's
-    query heads one product a step (`models/zaya.py`).
+    query heads one product a step (`models/zaya.py`);
+  * `cca_latent` (`cca_latent.py`) — that kernel's three operands from the
+    fused projection's output in one pass over a slab row: group means,
+    two causal convolutions cut at the packed documents' seams,
+    normalisation, temperature, partial RoPE, the value shift
+    (`models/zaya.py`).
 
 Beside them, on no packed path: `flash_attention` (online-softmax blocked
 attention, O(L) memory instead of the [L, L] score matrix) and

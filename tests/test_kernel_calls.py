@@ -15,6 +15,8 @@ import pytest
 
 from pathway_tpu.internals import compile_cache
 from pathway_tpu.ops import kernels
+from pathway_tpu.ops.kernels import cca_attention as cca_k
+from pathway_tpu.ops.kernels import cca_latent as latent_k
 from pathway_tpu.ops.kernels import eva_attention as eva_k
 from pathway_tpu.ops.kernels import hybrid_attention as hybrid_k
 from pathway_tpu.ops.kernels import mla_attention as mla_k
@@ -108,7 +110,50 @@ def _hybrid_bare(*operands, window):
         interpret=True)
 
 
+def _cca_operands():
+    rng = np.random.default_rng(3)
+    drawn = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
+    seg = jnp.asarray(np.r_[[1] * 9, [2] * 27, [0] * 4][None], jnp.int32)
+    return drawn(1, 40, 4 * 128), drawn(1, 40, 2 * 128), drawn(1, 40, 2 * 128), seg
+
+
+LATENT_LEAVES = ("conv0_w", "conv0_b", "conv1_w", "conv1_b", "tau")
+
+
+def _latent_operands():
+    """The projection's output for 4 query over 2 key/value heads, a packed
+    row, RoPE's tables and a layer's five leaves of the latent."""
+    from pathway_tpu.models.transformer import _packed_positions
+    from pathway_tpu.ops.kernels.hybrid_attention import rope_tables
+
+    rng = np.random.default_rng(4)
+    drawn = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
+    seg = jnp.asarray(np.r_[[1] * 9, [2] * 27, [0] * 4][None], jnp.int32)
+    leaves = (drawn(2, 6 * 128), drawn(6 * 128), drawn(6, 2 * 128, 128) * 0.06,
+              drawn(6 * 128), 1.5 + 0.25 * drawn(2))
+    return (drawn(1, 40, 8 * 128), seg, *rope_tables(_packed_positions(seg), 5e6), *leaves)
+
+
+def _latent_through(qkv, seg, cos, sin, *leaves, **statics):
+    return latent_k.cca_latent(
+        qkv, seg, (cos, sin), dict(zip(LATENT_LEAVES, leaves)), heads=4, kv_heads=2, **statics)
+
+
 SITES = {
+    # these two are built from their operands' shapes alone (the latent's
+    # head counts follow from them): the other set is the TPU's, traced
+    # here and not lowered
+    "cca_attention": Site(
+        cca_k, "_kernel", _cca_operands,
+        lambda *a, **s: cca_k.cca_attention(*a, **s),
+        lambda *a, **s: cca_k._attend(*a, **s),
+        dict(interpret=True), dict(interpret=False),
+    ),
+    "cca_latent": Site(
+        latent_k, "_kernel", _latent_operands, _latent_through,
+        lambda *a, **s: latent_k._latent(*a, heads=4, kv_heads=2, **s),
+        dict(interpret=True), dict(interpret=False),
+    ),
     "segment_attention": Site(
         seg_k, "_kernel", _segment_operands,
         lambda qkv, seg, heads: seg_k.segment_attention(qkv, seg, heads, interpret=True),

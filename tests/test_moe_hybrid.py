@@ -541,3 +541,23 @@ def test_the_cca_kernel_compiles_for_the_chip_at_its_cells_slabs(rows, length, o
     args = (shape(rows, length, 8 * 128), shape(rows, length, 2 * 128),
             shape(rows, length, 2 * 128), shape(rows, length, dt=jnp.int32))
     assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("rows,length", [(56, 504), (8, 512), (8, 40)])
+def test_the_cca_latent_kernel_compiles_for_the_chip_at_its_cells_slabs(rows, length, one_chip,
+                                                                        no_compile_cache):
+    """`ops/kernels/cca_latent.py` (tests/test_zaya.py holds its numbers) at
+    the ingest slab, a full read-back slab and a short one, 8 query over 2
+    key/value heads, two taps a convolution, bf16: a slab row's whole block
+    and its float32 working set fit the VMEM the call asks for."""
+    from pathway_tpu.ops.kernels import cca_latent
+
+    shape = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+    layer = {"conv0_w": shape(2, 1280), "conv0_b": shape(1280),
+             "conv1_w": shape(10, 256, 128, dt=jnp.bfloat16), "conv1_b": shape(1280),
+             "tau": shape(2)}
+    fn = lambda qkv, seg, cos, sin, layer: cca_latent.cca_latent(  # noqa: E731
+        qkv, seg, (cos, sin), layer, heads=8, kv_heads=2, interpret=False)
+    args = (shape(rows, length, 12 * 128, dt=jnp.bfloat16), shape(rows, length, dt=jnp.int32),
+            shape(rows, length, 128), shape(rows, length, 128), layer)
+    assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()

@@ -4,9 +4,10 @@ row, a router that carries its state from layer to layer, a learned merge)
 against its plain reference
 (`chipbench/architectures/zaya_decoder/reference.py`, which imports nothing
 of the program and packs nothing, so no seam can exist there), each
-mechanism left out in turn, its kernel against the dense definition, the
-seam rule, the shares of an expert layer, its counters: at tiny sizes on
-the CPU, seeded."""
+mechanism left out in turn (of the dense definition and of the kernels),
+its two kernels against their dense definitions, the seam rule and its
+second copy, the one gate of both kernels, the shares of an expert layer,
+its counters: at tiny sizes on the CPU, seeded."""
 
 import jax
 import jax.numpy as jnp
@@ -15,8 +16,11 @@ import pytest
 
 from pathway_tpu.models import moe_mla, zaya
 from pathway_tpu.models.tokenizer import PACK_MAX_SEGMENTS, encode_batch, pack_batch
-from pathway_tpu.models.transformer import model_module
+from pathway_tpu.models.transformer import _packed_positions, model_module
+from pathway_tpu.ops import kernels
 from pathway_tpu.ops.kernels import cca_attention as kernel
+from pathway_tpu.ops.kernels import cca_latent as latent
+from pathway_tpu.ops.kernels.hybrid_attention import rope_tables
 
 
 def tiny_model(**changes) -> dict:
@@ -97,6 +101,8 @@ def packed_vectors(enc, params=None, **kwargs) -> np.ndarray:
 
 @pytest.mark.parametrize("use_flash", [False, True], ids=["dense", "kernel-interpreted"])
 def test_the_packed_program_agrees_with_the_plain_reference(use_flash):
+    """`use_flash=True` is the fused path whole: the latent through
+    `cca_latent`, the attention through `cca_attention`, both interpreted."""
     model = tiny_model()
     enc = program_encoder(model, seed=7)
     ids, seg, _ = packed(enc)
@@ -196,23 +202,56 @@ _PATCHED = {
 }
 
 
+def _every_row_is_own(seg, n):
+    """`cca_latent.own_row` blind to the seam (the block's first rows still
+    have no past: the roll would hand them the row's last slots)."""
+    return jax.lax.broadcasted_iota(jnp.int32, seg.shape, 0) >= n
+
+
+# the same four, left out of the kernel: by the function of
+# `ops/kernels/cca_latent.py` that each goes through
+_PATCHED_IN_THE_KERNEL = {
+    "seam cut": ("own_row", _every_row_is_own),
+    "value shift": ("_shifted", lambda v, own, shift: v),
+    "q-k means": ("_means", lambda q, k: ([jnp.zeros_like(a) for a in q], jnp.zeros_like(k))),
+    "partial rope": ("_turned", lambda x, cos, sin: x),
+}
+
+
+@pytest.fixture
+def fresh_kernel_traces():
+    """A patched kernel is another function: `kernel_call` keeps none of an
+    earlier test's traces, and hands none of this one's on."""
+    kernels._jitted.cache_clear()
+    yield
+    kernels._jitted.cache_clear()
+
+
+@pytest.mark.parametrize("use_flash", [False, True], ids=["dense", "kernel-interpreted"])
 @pytest.mark.parametrize("what", sorted(_LEFT_OUT) + sorted(_PATCHED))
-def test_leaving_a_mechanism_out_fails_the_comparison(what, monkeypatch):
+def test_leaving_a_mechanism_out_fails_the_comparison(what, use_flash, monkeypatch,
+                                                      fresh_kernel_traces):
     """Every mechanism of the layer is drawn away from its neutral value,
     so a program without it is another model: each left out of the program
     moves the vectors by ten tolerances or more, and the untouched program
-    agrees."""
+    agrees.  On both paths: the dense definition, and the fused one, where
+    the latent's mechanisms run (and are left out) inside `cca_latent`,
+    interpreted."""
     model = tiny_model()
     enc = program_encoder(model, seed=7)
     want = reference_vectors(model, 7, TEXTS)
-    np.testing.assert_allclose(packed_vectors(enc), want, atol=F32_TOL)
+    np.testing.assert_allclose(packed_vectors(enc, use_flash=use_flash), want, atol=F32_TOL)
     params = enc.lm.params
     if what in _LEFT_OUT:
         params = _LEFT_OUT[what](params)
+    elif use_flash:
+        name, stand_in = _PATCHED_IN_THE_KERNEL[what]
+        monkeypatch.setattr(latent, name, stand_in)
+        kernels._jitted.cache_clear()
     else:
         name, stand_in = _PATCHED[what]
         monkeypatch.setattr(zaya, name, stand_in)
-    got = packed_vectors(enc, params=params)
+    got = packed_vectors(enc, params=params, use_flash=use_flash)
     assert np.abs(got - want).max() > 10 * F32_TOL, what
 
 
@@ -255,6 +294,200 @@ def test_the_kernel_agrees_with_its_dense_definition(length, kv_heads, group):
     np.testing.assert_allclose(np.asarray(got)[real], np.asarray(want)[real], atol=2e-5)
     assert np.isfinite(np.asarray(got)).all()
     assert not kernel.supports(640, 8, 2, 128) and not kernel.supports(504, 8, 2, 64)
+
+
+def _seam_slab(length: int) -> np.ndarray:
+    """Five rows of `length` >= 40 slots, so that every look-back meets a
+    seam, a row's start and a padding slot: one document and padding;
+    three, the first a single token (so the second begins at slot 1), the
+    last to the row's end; nine of 1 to 7 tokens and more, a padding slot
+    between two of them; a row of padding; a row that is one document."""
+    seg = np.zeros((5, length), np.int32)
+    seg[0, : length - 6] = 1
+    seg[1, 0], seg[1, 1 : length // 2], seg[1, length // 2 :] = 1, 2, 3
+    at = 0
+    for s, n in enumerate((3, 1, 2, 7, 1, 1, 5, 2, length - 30)):
+        seg[2, at : at + n] = s + 1
+        at += n + (s == 3)  # one empty slot after the fourth
+    seg[4] = 1
+    return seg
+
+
+def _latent_operands(length: int, kv_heads: int, group: int, taps: int = 2):
+    """A layer's five leaves of the latent drawn as `init_params` draws
+    them, the projection's output, a slab of seams and its RoPE tables."""
+    config = zaya.ZayaConfig(
+        heads=kv_heads * group, kv_heads=kv_heads, conv_taps0=taps, conv_taps1=taps,
+        max_len=length, dtype="float32", param_dtype="float32",
+    )
+    n, hd = config.heads + kv_heads, config.head_dim
+    rng = np.random.default_rng([length, kv_heads, group])
+
+    def drawn(*shape, mean=0.0, std=1.0):
+        return jnp.asarray(mean + std * rng.normal(size=shape), jnp.float32)
+
+    layer = {
+        "conv0_w": drawn(taps, n * hd, std=taps ** -0.5),
+        "conv0_b": drawn(n * hd, std=zaya.CONV_BIAS_STD),
+        "conv1_w": drawn(n, taps * hd, hd, std=(taps * hd) ** -0.5),
+        "conv1_b": drawn(n * hd, std=zaya.CONV_BIAS_STD),
+        "tau": drawn(kv_heads, mean=zaya.TAU_MEAN, std=zaya.TAU_STD),
+    }
+    seg = jnp.asarray(_seam_slab(length))
+    qkv = drawn(*seg.shape, (n + kv_heads) * hd)
+    return config, layer, qkv, seg, rope_tables(_packed_positions(seg), config.rope_theta)
+
+
+@pytest.mark.parametrize("kv_heads,group", [(2, 4), (1, 4), (2, 1)])
+@pytest.mark.parametrize("length", [40, 128, 504])
+def test_the_latent_kernel_agrees_with_its_dense_definition(length, kv_heads, group):
+    """`cca_latent` interpreted on the CPU against `zaya.latent_dense`:
+    rows of under one tile, one tile and the ingest slab's 504 (the overrun
+    rows never written), both halves of the value heads, rows packed with
+    1, 3 and 9 documents and padding (`_seam_slab`)."""
+    c, layer, qkv, seg, rope = _latent_operands(length, kv_heads, group)
+    assert latent.supports(length, c.heads, kv_heads, c.head_dim, c.rotary_dim, 2, 2)
+    got = latent.cca_latent(qkv, seg, rope, layer, heads=c.heads, kv_heads=kv_heads,
+                            interpret=True)
+    want = zaya.latent_dense(qkv, layer, c, seg, rope)
+    real = np.asarray(seg) > 0
+    for name, g, w in zip(("q", "k", "v"), got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        np.testing.assert_allclose(
+            np.asarray(g)[real], np.asarray(w)[real], atol=2e-5, err_msg=name
+        )
+        assert np.isfinite(np.asarray(g)).all(), name
+    # a token of the first half of a document's value heads is its own; of
+    # the second half, at a document's first slot, nobody's
+    first = np.asarray(seg) != np.pad(np.asarray(seg), ((0, 0), (1, 0)))[:, :-1]
+    shifted = np.asarray(got[2])[..., (kv_heads - kv_heads // 2) * 128:]
+    assert not shifted[first & real].any()
+
+
+def test_the_latent_kernel_takes_other_taps_and_refuses_what_it_does_not_tile():
+    """Three taps in each convolution look three rows back; the shapes
+    `supports` refuses are refused by the call too."""
+    c, layer, qkv, seg, rope = _latent_operands(40, 2, 2, taps=3)
+    got = latent.cca_latent(qkv, seg, rope, layer, heads=4, kv_heads=2, interpret=True)
+    want = zaya.latent_dense(qkv, layer, c, seg, rope)
+    real = np.asarray(seg) > 0
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g)[real], np.asarray(w)[real], atol=2e-5)
+    assert not latent.supports(640, 8, 2, 128, 64, 2, 2)  # over one tile of rows
+    assert not latent.supports(504, 8, 2, 64, 64, 2, 2)  # a head that is no lane tile
+    assert not latent.supports(504, 8, 2, 128, 128, 2, 2)  # RoPE's tables are 64 wide
+    assert not latent.supports(504, 8, 3, 128, 64, 2, 2)
+    assert not latent.supports(504, 8, 2, 128, 64, latent.MAX_TAPS + 1, 2)
+    assert not latent.supports(504, 8, 2, 128, 64, 2, 0)
+    with pytest.raises(ValueError, match="cca_latent: unsupported shape"):
+        latent.cca_latent(qkv, seg, rope, layer, heads=2, kv_heads=2, interpret=True)
+
+
+@pytest.mark.parametrize("n", [1, 2, 3])
+def test_the_kernels_seam_rule_is_own_pasts(n):
+    """The two copies of the seam rule on the same `seg`: `zaya.own_past`
+    keeps a slot's look-back exactly where `cca_latent.own_row`, run as the
+    kernel runs it (a slab row a block, a row's id along its lanes), says
+    the row n back is the slot's own document's."""
+    from jax.experimental import pallas as pl
+
+    seg = jnp.asarray(_seam_slab(48))
+    kept = np.asarray(zaya.own_past(jnp.ones(seg.shape + (1,), jnp.float32), seg, n))[..., 0]
+
+    def body(seg_ref, o_ref):
+        o_ref[0] = latent.own_row(seg_ref[0], n).astype(jnp.int32)
+
+    lanes = jnp.broadcast_to(seg[:, :, None], seg.shape + (128,))
+    block = pl.BlockSpec((1, seg.shape[1], 128), lambda i: (i, 0, 0))
+    own = pl.pallas_call(
+        body, grid=(seg.shape[0],), in_specs=[block], out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(lanes.shape, jnp.int32), interpret=True,
+    )(lanes)
+    np.testing.assert_array_equal(np.asarray(own)[..., 0], kept.astype(np.int32))
+    np.testing.assert_array_equal(np.asarray(own)[..., 127], kept.astype(np.int32))
+    assert kept.sum() > 0 and (kept[:, :n] == 0).all()
+
+
+def test_one_gate_decides_both_kernels(monkeypatch):
+    """On the TPU a slab runs the two kernels or the dense definition of
+    both: the gate is false where either kernel's `supports` is."""
+    config = zaya.ZayaConfig()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert zaya.packed_attention_fused(config, 504)
+    assert zaya.packed_attention_fused(config, 40)  # the read-back's short slab
+    assert not zaya.packed_attention_fused(config, 16)
+    assert not zaya.packed_attention_fused(config, 504, use_flash=False)
+    # the latent's kernel alone refuses: a convolution of more taps than it unrolls
+    import dataclasses
+
+    wide = dataclasses.replace(config, conv_taps0=latent.MAX_TAPS + 1)
+    assert kernel.supports(504, wide.heads, wide.kv_heads, wide.head_dim)
+    assert not zaya.packed_attention_fused(wide, 504)
+    # the attention's kernel alone refuses
+    with monkeypatch.context() as patch:
+        patch.setattr(kernel, "supports", lambda *shape: False)
+        assert latent.supports(504, 8, 2, 128, 64, 2, 2)
+        assert not zaya.packed_attention_fused(config, 504)
+    assert zaya.packed_attention_fused(config, 504)
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["attn_fused", "attn_dense"])
+def test_a_counted_batch_runs_the_kernels_its_counter_names(fused, monkeypatch,
+                                                            fresh_kernel_traces):
+    """`launch.encode.attn_fused` / `attn_dense` is the count of how often
+    the mechanism engages: the packed program of a batch counted fused
+    calls both kernels by name, of one counted dense neither."""
+    from pathway_tpu.internals import tracing
+    from pathway_tpu.ops.knn import DeviceKnnIndex, FusedEmbedSearch
+
+    monkeypatch.setattr(
+        zaya, "packed_attention_fused", lambda config, length, use_flash=None: fused
+    )
+    tracing.reset_spans()
+    enc = program_encoder(tiny_model(), 4)
+    search = FusedEmbedSearch(enc, DeviceKnnIndex(enc.dimension, metric="cos", reserved_space=64))
+    payload, _ = search.prepare_batch(list(range(6)), [text_of(20 + 7 * i, i) for i in range(6)])
+    search.dispatch_batch(payload)
+    totals = tracing.spans_status()["totals"]
+    counted, other = ("attn_fused", "attn_dense") if fused else ("attn_dense", "attn_fused")
+    assert totals[f"launch.encode.{counted}"]["count"] == 1
+    assert f"launch.encode.{other}" not in totals
+    ids, seg = payload[2:4]
+    program = enc.lm._packed_program()
+    text = str(jax.make_jaxpr(lambda p, i, s: program(p, i, s, PACK_MAX_SEGMENTS))(
+        enc.lm.params, jnp.asarray(ids), jnp.asarray(seg)
+    ))
+    assert ("name=cca_latent" in text) == fused
+    assert ("name=cca_attention" in text) == fused
+    assert text.count("pallas_call") == (2 if fused else 0)  # traced once each, called a layer
+
+
+def test_the_latent_kernel_is_one_function_for_all_the_layers(fresh_kernel_traces):
+    """PR 41's property for the new name: 20 layers of the attention
+    sublayer call ONE jitted `cca_latent` (and one `cca_attention`), traced
+    once and lowered to one function of the program's module."""
+    depth = 20
+    c, layer, qkv, seg, rope = _latent_operands(40, 2, 2)
+    layer = dict(
+        layer, ln1=jnp.ones((c.hidden,)), wqkv=jnp.zeros((c.hidden, qkv.shape[2])),
+        wo=jnp.zeros((c.heads * c.head_dim, c.hidden)),
+    )
+    calls = {
+        latent.kernel_call("cca_latent", latent._latent, heads=4, kv_heads=2) for _ in range(depth)
+    }
+    assert len(calls) == 1
+
+    def trunk(x, layers):
+        for each in layers:
+            x = x + zaya._attention(x, each, c, seg, rope, fused=True)
+        return x
+
+    x = jnp.zeros(seg.shape + (c.hidden,), jnp.float32)
+    text = jax.jit(trunk).trace(x, [layer] * depth).lower().as_text()
+    for name in ("cca_latent", "cca_attention"):
+        assert text.count(f"func.func private @{name}(") == 1, name
+        assert text.count(f"call @{name}(") == depth, name
+    assert kernels._jitted.cache_info().currsize == 2
 
 
 def test_two_shares_of_the_experts_add_up_to_the_whole_layer():
