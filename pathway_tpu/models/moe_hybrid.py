@@ -1,51 +1,61 @@
 """Hybrid-attention MoE trunk: sliding-window layers beside global
-grouped-query ones over a bias-corrected sigmoid router (the MiMo-V2
-family's layer; MiMo-V2.5's published sizes are the defaults), as ONE
-expert-parallel rank runs it: the document store's embedder on the ingest
-path, for whole documents of thousands of word tokens.
+grouped-query ones over a sigmoid router, as ONE chip of a deployment runs
+it (one expert-parallel rank, or stage 0 of a pipeline): the document
+store's embedder on the ingest path, for whole documents of thousands of
+word tokens.  Two models' layers are this trunk: the MiMo-V2 family's
+(MiMo-V2.5's published sizes are the defaults) and the Laguna family's
+(`chipbench/configs/laguna-xs2-pp8-docstore.json`).
 
 The layers are NOT alike, and nothing here assumes they are: a layer is
 global or window by the published pattern (`layer_pattern`: 0 global, 1
-window; five window layers a global one), dense or expert by
-`first_k_dense`; the two kinds of attention have their own key/value head
-counts, RoPE ladders and sink, so parameters, the kernel's gate, the
-counters and the costs go by layer kind.  What one rank of `ep_size`
-holds of a layer: attention, norms and router whole (they are replicated)
-and `experts_held` of the `n_routed_experts` routed experts, from
-`expert_offset`; the held experts' partial sum (plus the residual) goes on
-to the next layer, and nothing stands in for the absent ranks, their
-exchange or the layers held on further chips.
+window), dense or expert by `first_k_dense`; the two kinds of attention
+have their own query and key/value head counts, rotated dims, RoPE
+ladders (plain, or YaRN with its attention factor on the global kind) and
+sink, so parameters, the kernel's gate, the counters and the costs go by
+layer kind.  What the chip holds of a layer: attention, norms, router and a
+shared expert whole, and `experts_held` of the `n_routed_experts` routed
+experts, from `expert_offset`; the held experts' partial sum (plus the
+residual) goes on to the next layer, and nothing stands in for the absent
+ranks, their exchange or the layers held on further chips.
 
-Per layer, x [T, hidden], every norm RMSNorm, pre-norm, no biases:
+Per layer, x [T, hidden], every norm RMSNorm, pre-norm, no biases; H and
+kv are the layer kind's query and key/value head counts:
 
-  h = norm(x); one fused matrix gives `heads` query heads of `head_dim`,
-  kv key heads of `head_dim` and kv value heads of `v_head_dim`, kv =
-  `kv_heads_global` or `kv_heads_window`: heads / kv query heads share
-  one key/value head.  RoPE (rotate-half) on the first `rotary_dim` dims
-  of a head, theta by kind; positions restart at every document
+  h = norm(x); one fused matrix gives H query heads of `head_dim`, kv key
+  heads of `head_dim` and kv value heads of `v_head_dim`: H / kv query
+  heads share one key/value head.  RoPE (rotate-half) on the first
+  `rotary(kind)` dims of a head, the kind's ladder; a YaRN ladder's
+  attention factor multiplies its cos and sin; positions restart at every
+  document
   s_ij = q_i . k_j / sqrt(head_dim); token i sees j iff same document and
   j <= i (global) or i - window < j <= i (window).  A kind with a sink: a
   learned logit b_h a query head joins the softmax's denominator and
   mixes nothing.  Values are scaled by `value_scale` before the mix
+  with a gate: head i's context times sigmoid(h W_g)_i
   x += concat_heads(p v) W_o
   h = norm(x); the leading dense layers: x += (silu(h W_g) * (h W_u)) W_d
-  the others: s = sigmoid(h W_r); I = top-k(s + beta) (the selection bias
-  chooses, it never weighs); w_e = s_e / sum_{i in I} s_i;
-  x += sum_{e in I, e held} w_e FFN_e(h)
+  the others: s = sigmoid(h W_r); I = top-k(s + beta) (a selection bias,
+  where the model has one, chooses and never weighs); w_e = factor * s_e /
+  sum_{i in I} s_i; x += sum_{e in I, e held} w_e FFN_e(h) (+ FFN_shared(h)
+  where the model has a shared expert)
 
 then a final norm, the mean over a document's tokens and L2
 normalisation, as `transformer.forward` pools.  Prefill form: no head, no
 cache, no generation, none of the multi-token-prediction layers (PERF.md
 section 7).
 
-Program shape.  The fused matrix is kept as one matrix a part (`wq_nope`,
-`wq_rope`, `wk_nope`, `wk_rope`, `wv`: the published matrix's columns,
-regrouped once at init), so that every operand of the attention kernel
-(`ops/kernels/hybrid_attention.py`, one kernel for both kinds) leaves its
-matmul in the layout the kernel reads; off the TPU and on shapes its
-tiling does not cover, its dense definition runs.  The expert layer IS
-`moe_mla.held_experts` over `moe_mla.route` (adapted there: the selection
-bias; no shared expert beside it), with its counters `moe.*`.
+Program shape.  Heads of 192 (MiMo's: 128 + 64 rotated) keep the fused
+matrix as one matrix a part (`wq_nope`, `wq_rope`, `wk_nope`, `wk_rope`,
+`wv`: the published matrix's columns, regrouped once at init), so that
+every operand of the attention kernel leaves its matmul in the layout the
+kernel reads.  Heads as wide as their values (Laguna's 128, `whole_heads`)
+keep `wq`, `wk`, `wv` whole: RoPE turns the rotated dims in place and the
+kernel reads q and k as one operand each.  One kernel for both layouts and
+both kinds (`ops/kernels/hybrid_attention.py`); off the TPU and on shapes
+its tiling does not cover, its dense definition runs.  The expert layer
+IS `moe_mla.held_experts` over `moe_mla.route` (adapted there: the
+selection bias; any k and any share held), the shared expert
+`moe_mla._swiglu`, with the counters `moe.*`.
 """
 
 from __future__ import annotations
@@ -66,6 +76,7 @@ from pathway_tpu.models.moe_mla import (
     held_experts,
     layer_pass_lists,
     pooled_by_row_groups,
+    yarn_ladder,
 )
 from pathway_tpu.models.transformer import _one_chip_only, _packed_positions
 from pathway_tpu.ops.kernels import hybrid_attention as kernel
@@ -108,16 +119,45 @@ class MoeHybridConfig:
     param_dtype: str = "bfloat16"  # what the parameters are resident in
     pooling: str = "mean"
     causal: bool = True
+    # by kind where a model's kinds differ (None: the global kind's):
+    # query heads and rotated dims of a window layer's head
+    heads_window: Optional[int] = None
+    rotary_dim_window: Optional[int] = None
+    # the global kind's ladder as YaRN: (factor, original_max_len,
+    # beta_fast, beta_slow, attention_factor); (): the plain ladder
+    yarn_global: tuple = ()
+    head_gate: bool = False  # a head-wise sigmoid gate on the attention's output
+    selection_bias: bool = True  # `noaux_tc`'s beta beside the router
+    shared_mlp_dim: int = 0  # a shared expert beside the routed ones (0: none)
+    # the published depth, where the experts' down projection is drawn at
+    # its residual-output scale (0: the fan-in scale)
+    depth: int = 0
+    pp_size: int = 1  # a pipeline of this many stages, this chip stage 0 (1: none)
 
     @property
     def nope_dim(self) -> int:
         return self.head_dim - self.rotary_dim
+
+    @property
+    def whole_heads(self) -> bool:
+        """q . k as wide as v (128: one operand, rotated in place), not
+        cut into [rest | rotary] parts (192)."""
+        return self.head_dim == self.v_head_dim
 
     def is_window(self, layer: int) -> bool:
         return bool(self.layer_pattern[layer])
 
     def kv_heads(self, window: bool) -> int:
         return self.kv_heads_window if window else self.kv_heads_global
+
+    def q_heads(self, window: bool) -> int:
+        return self.heads_window if window and self.heads_window else self.heads
+
+    def rotary(self, window: bool) -> int:
+        return self.rotary_dim_window if window and self.rotary_dim_window else self.rotary_dim
+
+    def yarn(self, window: bool) -> tuple:
+        return () if window else self.yarn_global
 
     def has_sink(self, window: bool) -> bool:
         return self.sink_window if window else self.sink_global
@@ -133,20 +173,20 @@ class MoeHybridConfig:
         (half the document, or the window), the dense layers, and for an
         expert layer the router and the expected held pairs."""
         h, total = self.hidden, 0.0
-        a_pair = self.heads * (self.head_dim + self.v_head_dim)
         for i in range(self.layers):
             window = self.is_window(i)
-            kv = self.kv_heads(window)
+            kv, heads = self.kv_heads(window), self.q_heads(window)
             total += h * (
-                self.heads * self.head_dim + kv * (self.head_dim + self.v_head_dim)
-            ) + self.heads * self.v_head_dim * h
+                heads * self.head_dim + kv * (self.head_dim + self.v_head_dim)
+            ) + heads * self.v_head_dim * h + h * heads * self.head_gate
             met = float(scored_pairs(seq, self.window if window else None)) / max(seq, 1.0)
-            total += a_pair * met
+            total += heads * (self.head_dim + self.v_head_dim) * met
             if i < self.first_k_dense:
                 total += 3 * h * self.dense_mlp_dim
             else:
                 held = self.experts_per_token * self.experts_held / self.n_routed_experts
                 total += h * self.n_routed_experts + held * 3 * h * self.expert_mlp_dim
+                total += 3 * h * self.shared_mlp_dim
         return 2.0 * total
 
 
@@ -236,21 +276,25 @@ def init_params(rng, config: MoeHybridConfig) -> Dict[str, Any]:
     """Random weights, made leaf by leaf in float32 and kept in
     `param_dtype` (chipbench's reference repeats the recipe from the
     configuration file's `init`, not from here): the key split into 2 +
-    layers; key 0 the embedding ~ N(0, 1); layer i splits key 2+i into 6:
-    0 the fused matrix [hidden, heads x head_dim + kv x head_dim + kv x
-    v_head_dim] ~ N(0, 1/hidden) (columns: the query heads, then the key
-    heads, each [rotary | rest], then the value heads), regrouped here
-    part by part; 1 W_o; 2 the sinks [heads] ~ N(SINK_MEAN, 1), float32,
-    on the kinds that have one; a dense layer: 3 gate, 4 up, 5 down; an
-    expert layer: 3 the router, 4 the selection bias [n_routed_experts] ~
-    N(0, BIAS_STD^2), float32, and expert e (its global index) takes
-    `fold_in(key 5, e)` split into 3, so a rank's experts are the uncut
-    model's.  Norm scales 1."""
+    layers; key 0 the embedding ~ N(0, 1); layer i splits key 2+i into 6
+    (10 with `whole_heads`): 0 the fused matrix [hidden, H x head_dim + kv
+    x head_dim + kv x v_head_dim] ~ N(0, 1/hidden) (columns: the query
+    heads, then the key heads, each [rotary | rest], then the value
+    heads), regrouped here part by part, or kept whole as `wq`, `wk`,
+    `wv`; 1 W_o; 2 the sinks [H] ~ N(SINK_MEAN, 1), float32, on the kinds
+    that have one; a dense layer: 3 gate, 4 up, 5 down; an expert layer: 3
+    the router, 4 the selection bias [n_routed_experts] ~ N(0,
+    BIAS_STD^2), float32, where the model has one, and expert e (its
+    global index) takes `fold_in(key 5, e)` split into 3, so a rank's
+    experts are the uncut model's (the down projection ~ N(0, 1/(2 x depth
+    x expert_mlp_dim)) where `depth` is set); 6 the output gate W_g
+    [hidden, H] ~ N(0, 1/hidden) where the model has one; 7, 8, 9 the
+    shared expert's gate, up and down.  Norm scales 1."""
     import jax
     import jax.numpy as jnp
 
     c = config
-    h, heads, rot, nope = c.hidden, c.heads, c.rotary_dim, c.nope_dim
+    h, rot, nope = c.hidden, c.rotary_dim, c.nope_dim
 
     def dense(key, shape, fan_in=None):
         return _normal(tuple(shape), shape[-2] if fan_in is None else fan_in, c.param_dtype)(key)
@@ -267,22 +311,25 @@ def init_params(rng, config: MoeHybridConfig) -> Dict[str, Any]:
         "layers": [],
     }
     for i in range(c.layers):
-        k = jax.random.split(keys[2 + i], 6)
+        k = jax.random.split(keys[2 + i], 10 if c.whole_heads else 6)
         window = c.is_window(i)
-        kv = c.kv_heads(window)
+        kv, heads = c.kv_heads(window), c.q_heads(window)
         q_cols, k_cols = heads * c.head_dim, kv * c.head_dim
         fused = dense(k[0], (h, q_cols + k_cols + kv * c.v_head_dim))
-        wq_rope, wq_nope = split_heads(fused[:, :q_cols], heads)
-        wk_rope, wk_nope = split_heads(fused[:, q_cols:q_cols + k_cols], kv)
-        layer = {
-            "ln1": jnp.ones((h,)), "ln2": jnp.ones((h,)),
-            "wq_nope": wq_nope, "wq_rope": wq_rope,
-            "wk_nope": wk_nope, "wk_rope": wk_rope,
-            "wv": fused[:, q_cols + k_cols:],
-            "wo": dense(k[1], (heads * c.v_head_dim, h)),
-        }
+        layer = {"ln1": jnp.ones((h,)), "ln2": jnp.ones((h,))}
+        if c.whole_heads:
+            layer.update(wq=fused[:, :q_cols], wk=fused[:, q_cols:q_cols + k_cols])
+        else:
+            wq_rope, wq_nope = split_heads(fused[:, :q_cols], heads)
+            wk_rope, wk_nope = split_heads(fused[:, q_cols:q_cols + k_cols], kv)
+            layer.update(wq_nope=wq_nope, wq_rope=wq_rope, wk_nope=wk_nope, wk_rope=wk_rope)
+        layer.update(
+            wv=fused[:, q_cols + k_cols:], wo=dense(k[1], (heads * c.v_head_dim, h)),
+        )
         if c.has_sink(window):
             layer["sink"] = SINK_MEAN + jax.random.normal(k[2], (heads,), dtype=jnp.float32)
+        if c.head_gate:
+            layer["head_gate"] = dense(k[6], (h, heads))
         if i < c.first_k_dense:
             f = c.dense_mlp_dim
             layer.update(
@@ -290,29 +337,43 @@ def init_params(rng, config: MoeHybridConfig) -> Dict[str, Any]:
             )
         else:
             f = c.expert_mlp_dim
+            down_fan_in = 2 * c.depth * f if c.depth else f
             held = [
                 jax.random.split(jax.random.fold_in(k[5], c.expert_offset + e), 3)
                 for e in range(c.experts_held)
             ]
             layer.update(
                 router=dense(k[3], (h, c.n_routed_experts)),
-                router_bias=BIAS_STD * jax.random.normal(
-                    k[4], (c.n_routed_experts,), dtype=jnp.float32
-                ),
                 experts_gate=jnp.stack([dense(ke[0], (h, f)) for ke in held]),
                 experts_up=jnp.stack([dense(ke[1], (h, f)) for ke in held]),
-                experts_down=jnp.stack([dense(ke[2], (f, h)) for ke in held]),
+                experts_down=jnp.stack(
+                    [dense(ke[2], (f, h), fan_in=down_fan_in) for ke in held]
+                ),
             )
+            if c.selection_bias:
+                layer["router_bias"] = BIAS_STD * jax.random.normal(
+                    k[4], (c.n_routed_experts,), dtype=jnp.float32
+                )
+            if c.shared_mlp_dim:
+                fs = c.shared_mlp_dim
+                layer.update(
+                    shared_gate=dense(k[7], (h, fs)), shared_up=dense(k[8], (h, fs)),
+                    shared_down=dense(k[9], (fs, h)),
+                )
         params["layers"].append(layer)
     return params
 
 
-# what `_one_chip_only` says of this trunk: module, what it holds, what is not built
-_ONE_CHIP = ("moe_hybrid", "one expert-parallel rank", "the exchange across ranks")
+def _one_chip(config: MoeHybridConfig) -> tuple:
+    """What `_one_chip_only` says of this trunk: module, what the chip
+    holds, what is not built."""
+    if config.pp_size > 1:
+        return ("moe_hybrid", f"stage 0 of {config.pp_size}", "the hand-over between stages")
+    return ("moe_hybrid", "one expert-parallel rank", "the exchange across ranks")
 
 
 def param_sharding_rules(config: MoeHybridConfig, mesh):
-    _one_chip_only(mesh, *_ONE_CHIP)
+    _one_chip_only(mesh, *_one_chip(config))
 
 
 def packed_attention_fused(config: MoeHybridConfig, length: int,
@@ -329,37 +390,94 @@ def packed_attention_fused(config: MoeHybridConfig, length: int,
 
     c = config
     kinds = {c.is_window(i) for i in range(c.layers)}
+    # one operand: the rotated dims are inside it (`kernel.supports`' rope 0)
+    qk = (c.head_dim, 0) if c.whole_heads else (c.nope_dim, c.rotary_dim)
     return jax.default_backend() == "tpu" and all(
         kernel.supports(
-            length, c.heads, c.kv_heads(window), c.nope_dim, c.rotary_dim,
+            length, c.q_heads(window), c.kv_heads(window), *qk,
             c.v_head_dim, c.window if window else None,
         )
         for window in kinds
     )
 
 
+def rope_ladder(config: MoeHybridConfig, window: bool) -> tuple:
+    """A kind's ladder for heads rotated in place (`whole_heads`):
+    (frequencies [rotary / 2] f32, the attention factor that multiplies
+    cos and sin): YaRN's (`moe_mla.yarn_ladder`) where the kind has one,
+    else theta^(-2i/rotary) and 1."""
+    c = config
+    rot = c.rotary(window)
+    theta = c.rope_theta_window if window else c.rope_theta_global
+    if c.yarn(window):
+        factor, original, fast, slow, attention_factor = c.yarn(window)
+        return yarn_ladder(rot, theta, factor, original, fast, slow), float(attention_factor)
+    return (theta ** (-np.arange(0, rot, 2, dtype=np.float64) / rot)).astype(np.float32), 1.0
+
+
+def rope_angles(pos, freqs, attention_factor: float = 1.0):
+    """(cos, sin) [B, L, rotary / 2] f32 of positions pos [B, L], each
+    times the attention factor."""
+    import jax.numpy as jnp
+
+    angle = pos[:, :, None].astype(jnp.float32) * jnp.asarray(freqs)
+    return jnp.cos(angle) * attention_factor, jnp.sin(angle) * attention_factor
+
+
+def rotate_heads(x, cos, sin, head_dim: int, scale: float = 1.0):
+    """x [B, L, n x head_dim]: the first 2 x cos.shape[-1] dims of every
+    head turned rotate-half, pair (x[i], x[i + rot/2]), in f32; the whole
+    head times `scale`."""
+    import jax.numpy as jnp
+
+    b, l, _ = x.shape
+    half = cos.shape[-1]
+    heads = x.reshape(b, l, -1, head_dim).astype(jnp.float32)
+    a, r = heads[..., :half], heads[..., half:2 * half]
+    c, s = cos[:, :, None, :], sin[:, :, None, :]
+    out = jnp.concatenate([a * c - r * s, a * s + r * c, heads[..., 2 * half:]], axis=-1)
+    return (out * scale).reshape(x.shape).astype(x.dtype)
+
+
 def _attention(x, layer, config: MoeHybridConfig, window: bool, seg, rope, lo, fused: bool):
     """The attention half of a layer of one kind, without the residual.
     x: [B, L, h]; rope: (cos, sin) of the kind's ladder; lo: the kind's
     `kernel.key_lo`."""
+    import jax
+    import jax.numpy as jnp
+
     c = config
+    b, l, _ = x.shape
     dt = x.dtype
     h = _rms_norm(x, layer["ln1"], c.norm_eps)
-    rotate = kernel.rope if fused else kernel.rotate
     scale = c.head_dim ** -0.5
-    q_nope = (h @ layer["wq_nope"].astype(dt)) * scale
-    q_rope = rotate(h @ layer["wq_rope"].astype(dt), *rope, scale=scale)
-    k_nope = h @ layer["wk_nope"].astype(dt)
-    k_rope = rotate(h @ layer["wk_rope"].astype(dt), *rope)
+    if c.whole_heads:
+        q = rotate_heads(h @ layer["wq"].astype(dt), *rope, c.head_dim, scale=scale)
+        k = rotate_heads(h @ layer["wk"].astype(dt), *rope, c.head_dim)
+        operands = (q, None, k, None)
+    else:
+        rotate = kernel.rope if fused else kernel.rotate
+        operands = (
+            (h @ layer["wq_nope"].astype(dt)) * scale,
+            rotate(h @ layer["wq_rope"].astype(dt), *rope, scale=scale),
+            h @ layer["wk_nope"].astype(dt),
+            rotate(h @ layer["wk_rope"].astype(dt), *rope),
+        )
     v = (h @ layer["wv"].astype(dt)) * c.value_scale
     kind = dict(
         kv_heads=c.kv_heads(window), window=c.window if window else None,
         sink=layer.get("sink"),
     )
     if fused:
-        ctx = kernel.hybrid_attention(q_nope, q_rope, k_nope, k_rope, v, seg, lo, **kind)
+        ctx = kernel.hybrid_attention(*operands, v, seg, lo, **kind)
     else:
-        ctx = kernel.hybrid_attention_dense(q_nope, q_rope, k_nope, k_rope, v, seg, **kind)
+        ctx = kernel.hybrid_attention_dense(*operands, v, seg, **kind)
+    if "head_gate" in layer:  # a head's context times its sigmoid gate, in f32
+        gate = jax.nn.sigmoid(jnp.dot(
+            h, layer["head_gate"].astype(dt), preferred_element_type=jnp.float32
+        ))
+        ctx = ctx.reshape(b, l, gate.shape[-1], -1).astype(jnp.float32) * gate[..., None]
+        ctx = ctx.reshape(b, l, -1).astype(dt)
     return ctx @ layer["wo"].astype(dt)
 
 
@@ -380,7 +498,8 @@ def _trunk(params, config: MoeHybridConfig, ids, seg, max_segments: int, fused: 
         theta = c.rope_theta_window if window else c.rope_theta_global
         span = c.window if window else None
         by_kind[window] = (
-            kernel.rope_tables(pos, theta),
+            rope_angles(pos, *rope_ladder(c, window)) if c.whole_heads
+            else kernel.rope_tables(pos, theta),
             kernel.key_lo(seg, pos, span, kernel.block_rows(l, span)) if fused else None,
         )
     x = params["embed"][ids].astype(dt)
@@ -396,6 +515,8 @@ def _trunk(params, config: MoeHybridConfig, ids, seg, max_segments: int, fused: 
             for name, value in dict(more, expert_tokens=counts, overflow=over).items():
                 stats[name].append(value[None])
             x = x + routed.reshape(b, l, c.hidden)
+            if "shared_gate" in layer:
+                x = x + _swiglu(h, layer["shared_gate"], layer["shared_up"], layer["shared_down"])
         else:
             x = x + _swiglu(h, layer["gate"], layer["up"], layer["down"])
     x = _rms_norm(x, params["ln_f"], c.norm_eps)
@@ -429,7 +550,7 @@ def forward(
     is eight groups of one).  `with_stats`: as `moe_mla.forward`."""
     import jax.numpy as jnp
 
-    _one_chip_only(mesh, *_ONE_CHIP)
+    _one_chip_only(mesh, *_one_chip(config))
     packed = seg is not None
     if not packed:
         seg, max_segments = (mask > 0).astype(jnp.int32), 1
@@ -468,16 +589,18 @@ class MoeHybridLM(MoeMlaLM):
 
     def encode_packed(self, ids, seg, max_segments: int, *, params=None,
                       mesh=None):
-        _one_chip_only(mesh, *_ONE_CHIP)
+        _one_chip_only(mesh, *_one_chip(self.config))
         from pathway_tpu.internals import tracing
 
         c = self.config
         lengths = document_lengths(seg, max_segments)
-        # a pair is counted once a query head and layer
-        global_pairs = int(scored_pairs(lengths, None).sum()) * c.heads * (
+        # a pair is counted once a query head (of its kind) and layer
+        global_pairs = int(scored_pairs(lengths, None).sum()) * c.q_heads(False) * (
             c.layers - c.window_layers
         )
-        window_pairs = int(scored_pairs(lengths, c.window).sum()) * c.heads * c.window_layers
+        window_pairs = (
+            int(scored_pairs(lengths, c.window).sum()) * c.q_heads(True) * c.window_layers
+        )
         tracing.add("hybrid.tokens", n=int(lengths.sum()))
         tracing.add("hybrid.scored_pairs", n=global_pairs + window_pairs)
         tracing.add("hybrid.global_pairs", n=global_pairs)

@@ -244,24 +244,30 @@ def param_sharding_rules(config: MoeMlaConfig, mesh):
     _one_chip_only(mesh, *_ONE_CHIP)
 
 
-def yarn_freqs(config: MoeMlaConfig) -> np.ndarray:
-    """YaRN's frequency ladder [rope_dim / 2] as the DeepSeek family
-    computes it: the plain ladder where a pair turns more than
-    `beta_fast` times over the original length, the ladder divided by
-    `factor` where it turns less than `beta_slow` times, a linear ramp
-    between."""
-    dim, base = config.qk_rope_head_dim, config.rope_theta
+def yarn_ladder(dim: int, base: float, factor: float, original_max_len: int,
+                beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's frequency ladder [dim / 2] for a rotated part `dim` wide, as
+    the DeepSeek family and HF's `yarn` rope type compute it: the plain
+    ladder base^(-2i/dim) where a pair turns more than `beta_fast` times
+    over the original length, the ladder divided by `factor` where it
+    turns less than `beta_slow` times, a linear ramp between.  How the
+    pairs are laid out (interleaved, rotate-half) is the caller's."""
     plain = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
 
     def turns_at(n_rot: float) -> float:
-        return dim * math.log(config.rope_original_max_len / (n_rot * 2 * math.pi)) / (
-            2 * math.log(base)
-        )
+        return dim * math.log(original_max_len / (n_rot * 2 * math.pi)) / (2 * math.log(base))
 
-    low = max(math.floor(turns_at(config.rope_beta_fast)), 0)
-    high = min(math.ceil(turns_at(config.rope_beta_slow)), dim - 1)
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), dim - 1)
     ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
-    return (plain / config.rope_factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
+    return (plain / factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
+
+
+def yarn_freqs(config: MoeMlaConfig) -> np.ndarray:
+    """`yarn_ladder` of this trunk's rope part (interleaved pairs)."""
+    c = config
+    return yarn_ladder(c.qk_rope_head_dim, c.rope_theta, c.rope_factor,
+                       c.rope_original_max_len, c.rope_beta_fast, c.rope_beta_slow)
 
 
 def _mla_segment_attention(q_nope, q_rope, k_nope, k_rope, v, seg, sm_scale, heads):
@@ -399,38 +405,44 @@ PAIR_ROWS = 512
 def pair_capacity(tokens: int, config) -> int:
     """Rows of the static buffer of (token, held expert) pairs for a slab
     of `tokens` slots, from the slots, k = `experts_per_token` and the
-    share held / routed = `experts_held` / `n_routed_experts`.  Every pair
-    there can be (tokens x k) up to 4,096 rows: small batches cannot
-    overflow.  Above that one row a token slot.  The expected load is
-    tokens x k x held / routed: where that is under the rows (half a row
-    a token at a sixteenth of the experts held, top-8; the slab's padding
-    routes nothing), the rows themselves are the room that lets every
-    group begin a tile (`held_experts`), and a skewed router's pairs still
-    fit one after the other.  Where it fills them (every expert held at
-    top-1: a pair a token), a tile a held expert is added, which is the
-    most that beginning each group on a tile can take.  Rounded up to
-    whole tiles: at 14,112 rows, which 512 does not divide, the TPU's
-    grouped matmul took 8.6 ms where it takes 2.5 at 14,336 (chip runs,
-    PR 30)."""
-    every = tokens * config.experts_per_token
-    rows = min(every, max(tokens, 4096))
-    if every * config.experts_held >= rows * config.n_routed_experts:
-        rows += config.experts_held * PAIR_ROWS
+    share held / routed = `experts_held` / `n_routed_experts`, for any k
+    and share.  The most pairs a slab can put on held experts is `most` =
+    tokens x min(k, held).  Up to 4,096 rows that many: small batches
+    cannot overflow.  Above that one row a token slot.  The expected load
+    is tokens x k x held / routed: where that is under the rows (half a
+    row a token at a sixteenth of the experts held, top-8; the slab's
+    padding routes nothing), the rows themselves are the room that lets
+    every group begin a tile (`held_experts`), and a skewed router's pairs
+    still fit one after the other.  Where it fills them (every expert held:
+    k pairs a token), the rows are `most` and a tile a held expert, which
+    is the most that beginning each group on a tile can take: no pair can
+    fall beyond the buffer (top-1 of 16: 28,224 slots -> 36,864 rows; top-8
+    of 256: 23,552 -> 319,488).  Rounded up to whole tiles: at 14,112
+    rows, which 512 does not divide, the TPU's grouped matmul took 8.6 ms
+    where it takes 2.5 at 14,336 (chip runs, PR 30)."""
+    most = tokens * min(config.experts_per_token, config.experts_held)
+    rows = min(most, max(tokens, 4096))
+    if tokens * config.experts_per_token * config.experts_held >= rows * config.n_routed_experts:
+        rows = most + config.experts_held * PAIR_ROWS
     return -(-rows // PAIR_ROWS) * PAIR_ROWS
 
 
 def combine_rows(tokens: int, config) -> int:
     """Slots of the compact list of tokens with two or more pairs in the
-    buffer (`held_experts`' return).  At k = 1 there are no such tokens
-    and no list.  A slab of at most 4,096 token slots (the buffer's least
-    size) has a slot a token: it needs no list, cannot spill, and its
-    program stays as small as it was, which is what the search programs'
-    query slabs are loaded for.  Above that an eighth of the token slots
-    in whole tiles: 2,048 for 14,112, where k x held / routed = 1/2 (a
-    sixteenth of the experts held, top-8) gives about 915 such tokens."""
+    buffer (`held_experts`' return), for any k and share.  At k = 1 there
+    are no such tokens and no list.  A slot a token (the tokens are the
+    list, which cannot spill): a slab of at most 4,096 token slots (the
+    buffer's least size), whose program stays as small as it was, which is
+    what the search programs' query slabs are loaded for; and a share where
+    a token expects a held pair or more (k x held / routed >= 1: every
+    expert held at top-8 gives every real token 8).  Otherwise an eighth
+    of the token slots in whole tiles: 2,048 for 14,112, where k x held /
+    routed = 1/2 (a sixteenth of the experts held, top-8) gives about 915
+    such tokens."""
     if config.experts_per_token == 1:
         return 0
-    if tokens <= 4096:
+    if (tokens <= 4096
+            or config.experts_per_token * config.experts_held >= config.n_routed_experts):
         return tokens
     return -(-tokens // (8 * PAIR_ROWS)) * PAIR_ROWS
 
@@ -443,15 +455,16 @@ def held_experts(h, valid, layer, config, capacity: Optional[int] = None,
     nothing).  Returns (y [T, hidden], tokens per held expert
     [experts_held] int32, pairs selected and held but beyond the buffer
     () int32), and with `with_stats` a fourth: {"multi_pair_tokens",
-    "combine_spills", "groups_aligned", "groups_packed"}, each () int32.
-    `capacity` and `listed` override `pair_capacity` and `combine_rows`
-    (tests).  `config`: this module's or another trunk's with the same
-    routing fields, k = `experts_per_token`, `experts_held` of
-    `n_routed_experts` from `expert_offset` (`models/moe_hybrid.py`: a
-    sixteenth of 256 experts at width 4096, a selection bias
-    `layer["router_bias"]`, no shared expert beside it; `models/zaya.py`:
-    all 16 at top-1); the shared expert, where a model has one, is the
-    caller's.  `routing`: (experts [T, k] int32, weights [T, k] f32) from
+    "combine_spills", "groups_aligned", "groups_packed", "group_rows",
+    "group_pad_rows"}, each () int32.  `capacity` and `listed` override
+    `pair_capacity` and `combine_rows` (tests).  `config`: this module's
+    or another trunk's with the same routing fields, k =
+    `experts_per_token`, `experts_held` of `n_routed_experts` from
+    `expert_offset`, any k and any share (`models/moe_hybrid.py`: a
+    sixteenth of 256 experts at width 4096 under a selection bias
+    `layer["router_bias"]`, or all 256 of width 512 at top-8;
+    `models/zaya.py`: all 16 at top-1); the shared expert, where a model
+    has one, is the caller's.  `routing`: (experts [T, k] int32, weights [T, k] f32) from
     a trunk whose router is not `route`'s matrix and sigmoid
     (`models/zaya.py`: an MLP over a state carried from layer to layer, a
     softmax, and a choice beyond the routed experts, "skip", which is an
@@ -467,7 +480,18 @@ def held_experts(h, valid, layer, config, capacity: Optional[int] = None,
     (chip runs, PR 33).  So each group begins a tile where the buffer has
     the room (12 to 17 of its 28 tiles at the ingest slab), and otherwise
     the groups follow each other as the overflow count assumes: the layout
-    is data (`sizes`), not a second program.
+    is data (`sizes`), not a second program.  Which pair a buffer row
+    holds follows from its group's shift and the end of its pairs laid
+    along the rows by a running sum of their steps at the groups' ends:
+    no [experts_held, rows] mask (84 M elements a layer at 256 held) and
+    no search.  A search of the ends with its gathers of a group's values
+    took 31 ms a layer-pass at 319,488 rows, a quarter of the Laguna
+    cell's busy time: on the TPU a gather of one element costs about 10
+    ns (chip runs, PR 44).  A pair's row is its rank in the sorted order
+    plus its group's shift, read through the fused [experts_held, pairs]
+    compare that also counts the groups.  `group_rows` counts the rows the
+    grouped matmuls run over, `group_pad_rows` those of them that hold no
+    pair.
 
     The results go back to their tokens in one gather a token.  A pair's
     weight is put on its row in the buffer.  A token with one pair reads
@@ -479,8 +503,10 @@ def held_experts(h, valid, layer, config, capacity: Optional[int] = None,
     to the buffer, and read their sum.  More such tokens than slots is
     seen in the input: then the others' further pairs are added pass by
     pass over every token, and no pair is dropped.  Where the list has a
-    slot a token (a small slab: `combine_rows`), the tokens are the list
-    and their sums are `y`: the search programs' query slabs stay as small
+    slot a token (a small slab, or a share at which every token expects
+    several pairs: `combine_rows`), the tokens are the list, their sums
+    are `y`, one gather a pass and k passes at most, and nothing can
+    spill: the search programs' query slabs stay as small
     as they were (a program's load from the compile cache took 0.28 s for
     0.15 with the list built there too, chip runs, PR 33).  At [14112,
     7168] the return took 7.1 ms as one pass over every token for every
@@ -517,11 +543,17 @@ def held_experts(h, valid, layer, config, capacity: Optional[int] = None,
     def of(per_group, mask):  # mask: [experts_held, n] bool, one group a column at most
         return jnp.sum(jnp.where(mask, per_group[:, None], 0), axis=0)
 
-    # the pair a buffer row holds, if any
-    at = jnp.arange(capacity, dtype=jnp.int32)[None, :]
-    holds = (starts[:, None] <= at) & (at < (starts + kept)[:, None])
-    filled = holds.any(axis=0)
-    pair = jnp.where(filled, order[jnp.clip(at[0] - of(shift, holds), 0, t * k - 1)], 0)
+    # the pair a buffer row holds, if any: its group's shift and the end of
+    # its pairs laid along the rows, a running sum of their steps at the
+    # groups' ends (a row at or past the last end reads the last group's)
+    at = jnp.arange(capacity, dtype=jnp.int32)
+    per_group = jnp.stack([shift, starts + kept], axis=1)
+    steps = jnp.zeros((capacity, 2), jnp.int32).at[ends[:-1]].add(
+        per_group[1:] - per_group[:-1], mode="drop"
+    )
+    along = per_group[0] + jnp.cumsum(steps, axis=0)
+    filled = at < along[:, 1]
+    pair = jnp.where(filled, order[jnp.clip(at - along[:, 0], 0, t * k - 1)], 0)
     rows = h[pair // k]  # [capacity, hidden]
     with jax.named_scope("expert_matmul"):
         gate = jax.lax.ragged_dot(rows, layer["experts_gate"].astype(dt), sizes)
@@ -596,6 +628,8 @@ def held_experts(h, valid, layer, config, capacity: Optional[int] = None,
         "combine_spills": (n_multi > listed).astype(jnp.int32),
         "groups_aligned": aligned.astype(jnp.int32),
         "groups_packed": 1 - aligned.astype(jnp.int32),
+        "group_rows": ends[-1],
+        "group_pad_rows": ends[-1] - kept.sum(),
     }
 
 
@@ -604,6 +638,7 @@ def held_experts(h, valid, layer, config, capacity: Optional[int] = None,
 # `moe.<name>`
 LAYER_PASS_STATS = (
     "multi_pair_tokens", "combine_spills", "groups_aligned", "groups_packed",
+    "group_rows", "group_pad_rows",
 )
 
 def layer_pass_lists(experts_held: int) -> dict:

@@ -17,7 +17,9 @@ each, named as the device's profile names them:
     summaries and RoPE where the matmuls left q and k (`models/eva.py`);
   * `hybrid_attention_window` / `hybrid_attention_global`, `hybrid_rope`
     (`hybrid_attention.py`) — one grouped-query kernel for sliding-window
-    and global layers, and its RoPE (`models/moe_hybrid.py`);
+    and global layers, and its RoPE (`models/moe_hybrid.py`); for heads of
+    one 128-wide operand (the rotated dims inside) the same kernel runs as
+    `laguna_attention_window` / `laguna_attention_global`;
   * `cca_attention` (`cca_attention.py`) — `mla_segment_attention`'s
     sibling for one-part heads that share key/value heads, the group's
     query heads one product a step (`models/zaya.py`);

@@ -3,9 +3,18 @@ of slots long, global or behind a sliding window, with or without a sink
 logit, as ONE Pallas TPU kernel: `mla_attention.py`'s sibling for key axes
 of many tiles and for key/value heads that several query heads share.
 
-A query head's score is `q_nope . k_nope` (128 wide) plus `q_rope .
-k_rope` (64 wide, rotated), over 128-wide values; `group = heads /
-kv_heads` query heads read ONE key/value head.  Token i sees token j iff
+Two layouts of a head, over 128-wide values; `group = heads / kv_heads`
+query heads read ONE key/value head:
+
+  * two operands (heads of 192, MiMo-V2.5's): a score is `q_nope .
+    k_nope` (128 wide) plus `q_rope . k_rope` (64 wide, rotated);
+  * one operand (heads of 128, Laguna-XS.2's): a score is `q . k`, 128
+    wide, the rotated dims inside it wherever the caller turned them (the
+    first 64 of a global layer's head, all 128 of a window layer's), so
+    `q_rope` and `k_rope` are None; a group may be any size a step's
+    heads divide (6 or 8), and the device ops are named
+    `laguna_attention_global` / `laguna_attention_window`.
+  Token i sees token j iff
 both lie in the same document and `j <= i` (global) or `i - window < j <=
 i` (window): documents are contiguous in a row, so the distance in
 positions is the distance in slots.  With a sink, a learned logit `b_h` a
@@ -33,16 +42,18 @@ query head joins the softmax's denominator and mixes nothing:
   * the step's heads share the key and value blocks it loaded and the
     mask it computed: the keys of a global layer are read once for 8 query
     heads, not once a head.  Their queries are laid one under the other in
-    VMEM once a step, each head's row as [nope | its rope part], so a
-    score is ONE product 256 deep against [k_nope | k_rope], and a pass of
+    VMEM once a step, each head's row as [nope | its rope part] (or its one
+    operand), so a score is ONE product 256 (128) deep against [k_nope |
+    k_rope] (k), and a pass of
     the softmax takes PASS_ROWS rows of them at a time: the eight heads of
     a window layer's step are one [1024, 256] pass, not eight products of
     128 x 128 that each pay the MXU's fill and drain;
   * operands are read where their matmuls left them, heads contiguous:
     `q_nope` [B, L, H*128], `q_rope` [B, L, H*64], `k_nope`, `v` [B, L,
     KV*128], and the context is written straight into [B, L, H*128] for the
-    out-projection.  Every load, matmul and store is a full 128-lane tile:
-    two heads' rope queries share a tile, a head is picked out of it by
+    out-projection (or the gate before it).  Every load, matmul and store is
+    a full 128-lane tile: two heads' rope queries share a tile, a head is
+    picked out of it by
     zeroing the other's lanes, against the group's rope key laid twice
     along the lanes (`mla_attention.py`'s way; the products that drop out
     are exact zeros).
@@ -99,15 +110,19 @@ def block_rows(length: int, window: Optional[int]) -> int:
 
 def supports(length: int, heads: int, kv_heads: int, nope_dim: int, rope_dim: int,
              v_dim: int, window: Optional[int] = None) -> bool:
-    """Static shapes the compiled kernel's tiling covers."""
+    """Static shapes the compiled kernel's tiling covers: (nope, rope, v)
+    = (128, 64, 128), two operands, a step's heads an even number; or
+    (128, 0, 128), one operand (`rope_dim` 0: the rotated dims are inside
+    the 128)."""
     block = block_rows(length, window)
     group = heads // max(kv_heads, 1)
+    split = (nope_dim, rope_dim, v_dim) == (NOPE_DIM, ROPE_DIM, V_DIM)
     return (
-        (nope_dim, rope_dim, v_dim) == (NOPE_DIM, ROPE_DIM, V_DIM)
+        (split or (nope_dim, rope_dim, v_dim) == (NOPE_DIM, 0, V_DIM))
         and kv_heads > 0
         and heads % kv_heads == 0
         and group % min(HEAD_BLOCK, group) == 0
-        and min(HEAD_BLOCK, group) % 2 == 0  # two heads' rope queries a tile
+        and (not split or min(HEAD_BLOCK, group) % 2 == 0)  # two heads' rope queries a tile
         and length % block == 0
         and block % LANES == 0
     )
@@ -130,19 +145,21 @@ def key_lo(seg, pos, window: Optional[int], block: int):
 def hybrid_attention_dense(q_nope, q_rope, k_nope, k_rope, v, seg, *, kv_heads: int,
                            window: Optional[int] = None, sink=None):
     """The numerical definition, the path off the TPU and the tests'
-    reference of the kernel (operands in its layouts).  Writes the f32
-    scores [B, H, L, L]."""
+    reference of the kernel (operands in its layouts; one operand: q_rope
+    and k_rope None).  Writes the f32 scores [B, H, L, L]."""
     import jax.numpy as jnp
 
     b, l, _ = q_nope.shape
-    group = q_rope.shape[2] // k_rope.shape[2]
+    group = q_nope.shape[2] // k_nope.shape[2]  # the two operands' widths are alike
     q = lambda a: a.reshape(b, l, kv_heads, group, -1)  # noqa: E731
     kv = lambda a: a.reshape(b, l, kv_heads, -1)  # noqa: E731
     s = jnp.einsum(
         "bqngd,bknd->bngqk", q(q_nope), kv(k_nope), preferred_element_type=jnp.float32
-    ) + jnp.einsum(
-        "bqngd,bknd->bngqk", q(q_rope), kv(k_rope), preferred_element_type=jnp.float32
     )
+    if q_rope is not None:
+        s = s + jnp.einsum(
+            "bqngd,bknd->bngqk", q(q_rope), kv(k_rope), preferred_element_type=jnp.float32
+        )
     at = jnp.arange(l)
     see = (seg[:, :, None] == seg[:, None, :]) & (at[None, None, :] <= at[None, :, None])
     if window is not None:
@@ -162,7 +179,8 @@ def hybrid_attention_dense(q_nope, q_rope, k_nope, k_rope, v, seg, *, kv_heads: 
     return ctx.reshape(b, l, -1).astype(q_nope.dtype)
 
 
-def _kernel(*refs, block: int, window: Optional[int], has_sink: bool, pair: bool):
+def _kernel(*refs, block: int, window: Optional[int], has_sink: bool, pair: bool,
+            split: bool):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -174,11 +192,17 @@ def _kernel(*refs, block: int, window: Optional[int], has_sink: bool, pair: bool
         segk_prev, rest = rest[0], rest[1:]
     if has_sink:
         sink_ref, rest = rest[0], rest[1:]
-    qn_ref, qr_ref, kn_ref, kr_ref, v_ref = rest[:5]
-    rest = rest[5:]
+    n = 5 if split else 3  # q, (q_rope,) k, (k_rope,) v
+    if split:
+        qn_ref, qr_ref, kn_ref, kr_ref, v_ref = rest[:n]
+    else:
+        qn_ref, kn_ref, v_ref = rest[:n]
+    rest = rest[n:]
     if pair:
-        kn_prev, kr_prev, v_prev = rest[:3]
-        rest = rest[3:]
+        if split:
+            (kn_prev, kr_prev, v_prev), rest = rest[:3], rest[3:]
+        else:
+            (kn_prev, v_prev), rest = rest[:2], rest[2:]
     o_ref, q_scr, m_scr, l_scr, acc_scr = rest
     b, qi, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     heads = qn_ref.shape[2] // NOPE_DIM
@@ -193,8 +217,11 @@ def _kernel(*refs, block: int, window: Optional[int], has_sink: bool, pair: bool
         for h in range(heads):
             rows = slice(h * block, (h + 1) * block)
             q_scr[rows, :NOPE_DIM] = qn_ref[0, :, h * NOPE_DIM:(h + 1) * NOPE_DIM]
-            tile = qr_ref[0, :, (h // 2) * LANES:(h // 2 + 1) * LANES]
-            q_scr[rows, NOPE_DIM:] = jnp.where(lane_half == h % 2, tile, jnp.zeros_like(tile))
+            if split:
+                tile = qr_ref[0, :, (h // 2) * LANES:(h // 2 + 1) * LANES]
+                q_scr[rows, NOPE_DIM:] = jnp.where(
+                    lane_half == h % 2, tile, jnp.zeros_like(tile)
+                )
             if has_sink:
                 m_scr[rows] = jnp.broadcast_to(sink_ref[h:h + 1, :], (block, LANES))
         if has_sink:
@@ -213,19 +240,21 @@ def _kernel(*refs, block: int, window: Optional[int], has_sink: bool, pair: bool
         # nothing (no sink) its maximum is NEG_INF too and a masked key
         # weighs 1, which the first key it does meet wipes out (alpha = 0),
         # and every query meets itself
-        keys = jnp.concatenate([kn_ref[0], kr_ref[0]], axis=1)  # [block, 256]
+        # [block, 256], or [block, 128] for one operand
+        keys = jnp.concatenate([kn_ref[0], kr_ref[0]], axis=1) if split else kn_ref[0]
         values, codes = v_ref[0], segk_ref[0]
         first = kb * block
         if pair:  # the block before, too: a row past the window's reach is masked
-            keys = jnp.concatenate(
-                [jnp.concatenate([kn_prev[0], kr_prev[0]], axis=1), keys], axis=0
+            before = (
+                jnp.concatenate([kn_prev[0], kr_prev[0]], axis=1) if split else kn_prev[0]
             )
+            keys = jnp.concatenate([before, keys], axis=0)
             values = jnp.concatenate([v_prev[0], values], axis=0)
             codes = jnp.concatenate([segk_prev[0], codes], axis=1)
             first = first - block
-        n = keys.shape[0]
-        row = qi * block + jax.lax.broadcasted_iota(jnp.int32, (block, n), 0)
-        col = first + jax.lax.broadcasted_iota(jnp.int32, (block, n), 1)
+        width = keys.shape[0]
+        row = qi * block + jax.lax.broadcasted_iota(jnp.int32, (block, width), 0)
+        col = first + jax.lax.broadcasted_iota(jnp.int32, (block, width), 1)
         see = (segq_ref[0] == codes) & (col <= row)
         if window is not None:
             see = see & (row - col < window)
@@ -265,15 +294,24 @@ def hybrid_attention(q_nope, q_rope, k_nope, k_rope, v, seg, lo, *, kv_heads: in
     """The fused kernel.  q_nope [B, L, H*128], q_rope [B, L, H*64] (scaled,
     rotated); k_nope, v [B, L, KV*128]; k_rope [B, L, KV*64] (rotated); seg
     [B, L] int32, 1..S per packed document, 0 = padding; lo: `key_lo(seg,
-    pos, window, block)`; sink [H] or None.  Returns the context [B, L,
-    H*128] in q_nope's dtype.  The device op is named by kind:
-    `hybrid_attention_window` or `hybrid_attention_global`.  `block` is for
-    tests: the interpreter takes any tile."""
+    pos, window, block)`; sink [H] or None.  One operand: q_nope and k_nope
+    are q [B, L, H*128] (scaled) and k [B, L, KV*128], both rotated where
+    they are, q_rope and k_rope None.  Returns the context [B, L, H*128] in
+    q_nope's dtype.  The device op is named by layout and kind
+    (`op_name`).  `block` is for tests: the interpreter takes any tile."""
+    name = op_name(q_rope is not None, window)
     call = kernel_call(
-        "hybrid_attention_global" if window is None else "hybrid_attention_window",
-        _attend, kv_heads=kv_heads, window=window, block=block, interpret=interpret,
+        name, _attend, kv_heads=kv_heads, window=window, block=block, interpret=interpret,
     )
     return call(q_nope, q_rope, k_nope, k_rope, v, seg, lo, sink)
+
+
+def op_name(split: bool, window: Optional[int]) -> str:
+    """The device op of a layout and a kind: `hybrid_attention_window` /
+    `_global` (two operands), `laguna_attention_window` / `_global` (one)."""
+    return ("hybrid_attention_" if split else "laguna_attention_") + (
+        "global" if window is None else "window"
+    )
 
 
 def _attend(q_nope, q_rope, k_nope, k_rope, v, seg, lo, sink, *, kv_heads: int,
@@ -284,11 +322,12 @@ def _attend(q_nope, q_rope, k_nope, k_rope, v, seg, lo, sink, *, kv_heads: int,
     from jax.experimental.pallas import tpu as pltpu
 
     b, l, width = q_nope.shape
+    split = q_rope is not None
     heads = width // NOPE_DIM
     group = heads // kv_heads
     head_block = min(HEAD_BLOCK, group)
     block = block_rows(l, window) if block is None else min(block, l)
-    if l % block or heads % kv_heads or group % head_block or head_block % 2:
+    if l % block or heads % kv_heads or group % head_block or (split and head_block % 2):
         raise ValueError(
             f"hybrid_attention: unsupported shape L={l} heads={heads} "
             f"kv_heads={kv_heads} block={block}"
@@ -326,15 +365,26 @@ def _attend(q_nope, q_rope, k_nope, k_rope, v, seg, lo, sink, *, kv_heads: int,
 
     vmem = pltpu.VMEM
     seg = seg.astype(jnp.int32)
-    # the group's rope key laid twice along the lanes: [B, L, KV*128]
-    k_rope2 = jnp.concatenate(
-        [k_rope.reshape(b, l, kv_heads, ROPE_DIM)] * 2, axis=-1
-    ).reshape(b, l, kv_heads * LANES)
     key_specs = lambda at: [  # noqa: E731
         pl.BlockSpec((1, block, NOPE_DIM), at, memory_space=vmem),
-        pl.BlockSpec((1, block, LANES), at, memory_space=vmem),
+        *([pl.BlockSpec((1, block, LANES), at, memory_space=vmem)] if split else []),
         pl.BlockSpec((1, block, V_DIM), at, memory_space=vmem),
     ]
+    if split:
+        # the group's rope key laid twice along the lanes: [B, L, KV*128]
+        k_rope2 = jnp.concatenate(
+            [k_rope.reshape(b, l, kv_heads, ROPE_DIM)] * 2, axis=-1
+        ).reshape(b, l, kv_heads * LANES)
+        q_ops, key_ops = [q_nope, q_rope], [k_nope, k_rope2, v]
+        q_specs = [
+            pl.BlockSpec((1, block, head_block * NOPE_DIM), queries, memory_space=vmem),
+            pl.BlockSpec((1, block, head_block * ROPE_DIM), queries, memory_space=vmem),
+        ]
+    else:
+        q_ops, key_ops = [q_nope], [k_nope, v]
+        q_specs = [
+            pl.BlockSpec((1, block, head_block * NOPE_DIM), queries, memory_space=vmem),
+        ]
     operands = [seg[:, :, None], seg[:, None, :]]
     in_specs = [
         pl.BlockSpec((1, block, 1), lambda i, g, qi, j, lo: (i, qi, 0), memory_space=vmem),
@@ -348,16 +398,14 @@ def _attend(q_nope, q_rope, k_nope, k_rope, v, seg, lo, sink, *, kv_heads: int,
         in_specs.append(
             pl.BlockSpec((head_block, LANES), lambda i, g, qi, j, lo: (g, 0), memory_space=vmem)
         )
-    operands += [q_nope, q_rope, k_nope, k_rope2, v]
-    in_specs += [
-        pl.BlockSpec((1, block, head_block * NOPE_DIM), queries, memory_space=vmem),
-        pl.BlockSpec((1, block, head_block * ROPE_DIM), queries, memory_space=vmem),
-    ] + key_specs(keys)
+    operands += q_ops + key_ops
+    in_specs += q_specs + key_specs(keys)
     if pair:
-        operands += [k_nope, k_rope2, v]
+        operands += key_ops
         in_specs += key_specs(keys_before)
     kernel = functools.partial(
-        _kernel, block=block, window=window, has_sink=sink is not None, pair=pair
+        _kernel, block=block, window=window, has_sink=sink is not None, pair=pair,
+        split=split,
     )
     stacked = head_block * block  # the step's heads, one under the other
     return pl.pallas_call(
@@ -370,7 +418,7 @@ def _attend(q_nope, q_rope, k_nope, k_rope, v, seg, lo, sink, *, kv_heads: int,
                 (1, block, head_block * V_DIM), queries, memory_space=vmem
             ),
             scratch_shapes=[
-                pltpu.VMEM((stacked, NOPE_DIM + LANES), q_nope.dtype),  # the queries
+                pltpu.VMEM((stacked, NOPE_DIM + (LANES if split else 0)), q_nope.dtype),
                 pltpu.VMEM((stacked, LANES), jnp.float32),  # running max
                 pltpu.VMEM((stacked, LANES), jnp.float32),  # normaliser
                 pltpu.VMEM((stacked, V_DIM), jnp.float32),  # context
@@ -381,7 +429,7 @@ def _attend(q_nope, q_rope, k_nope, k_rope, v, seg, lo, sink, *, kv_heads: int,
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT,
         ),
-        name="hybrid_attention_global" if window is None else "hybrid_attention_window",
+        name=op_name(split, window),
         interpret=interpret,
     )(lo, *operands)
 

@@ -471,6 +471,7 @@ def test_a_mesh_is_refused(trunk):
     with pytest.raises(NotImplementedError, match="a mesh, is not built"):
         module.param_sharding_rules(module.TINY, object())
     lm = module.LM.__new__(module.LM)
+    lm.config = module.TINY
     with pytest.raises(NotImplementedError, match=message):
         lm.encode_packed(ids, ids, 1, mesh=object())
 
@@ -527,6 +528,23 @@ def test_the_kernel_compiles_for_the_chip_at_the_ingest_slab(kind, one_chip, no_
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("kind", ["global", "window"])
+def test_the_one_operand_layout_compiles_for_the_chip_at_its_cells_slab(kind, one_chip,
+                                                                        no_compile_cache):
+    """Laguna-XS.2's row of 23,552 slots (tests/test_laguna.py holds its
+    numbers): 48 query heads of 128 over 8 key/value heads on a full
+    layer, 64 behind a window of 512 on a sliding one, bf16."""
+    l = 23552
+    heads, window = (48, None) if kind == "global" else (64, 512)
+    shape = lambda *s, dt=jnp.bfloat16: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+    fn = lambda q, k, v, seg, lo: kernel.hybrid_attention(  # noqa: E731
+        q, None, k, None, v, seg, lo, kv_heads=8, window=window, interpret=False)
+    args = (shape(1, l, heads * 128), shape(1, l, 8 * 128), shape(1, l, 8 * 128),
+            shape(1, l, dt=jnp.int32),
+            shape(1, l // kernel.block_rows(l, window), dt=jnp.int32))
+    assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
+
+
 @pytest.mark.parametrize("rows,length", [(56, 504), (8, 512)])
 def test_the_cca_kernel_compiles_for_the_chip_at_its_cells_slabs(rows, length, one_chip,
                                                                  no_compile_cache):
@@ -561,3 +579,30 @@ def test_the_cca_latent_kernel_compiles_for_the_chip_at_its_cells_slabs(rows, le
     args = (shape(rows, length, 12 * 128, dt=jnp.bfloat16), shape(rows, length, dt=jnp.int32),
             shape(rows, length, 128), shape(rows, length, 128), layer)
     assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_mimo_builds_the_leaves_and_shapes_it_built():
+    """The trunk learned kinds that differ in query heads, rotary width,
+    ladder and gate, and a shared expert (PR 44); MiMo-V2.5's configuration
+    builds the same parameters as before: the tiny one's leaves to the
+    bit, the published one's names, shapes and types (digests taken at the
+    parent commit, 222fe9d)."""
+    import hashlib
+
+    params = moe_hybrid.init_params(jax.random.PRNGKey(3), moe_hybrid.TINY)
+    flat = jax.tree_util.tree_flatten_with_path(params)[0]
+    digest = hashlib.sha256()
+    for path, leaf in flat:
+        digest.update(jax.tree_util.keystr(path).encode())
+        digest.update(np.asarray(leaf).tobytes())
+    assert (len(flat), digest.hexdigest()[:16]) == (54, "031d3d8f2a0cc4dd")
+    shapes = jax.eval_shape(
+        lambda key: moe_hybrid.init_params(key, moe_hybrid.MoeHybridConfig()),
+        jax.random.PRNGKey(0),
+    )
+    flat = jax.tree_util.tree_flatten_with_path(shapes)[0]
+    digest = hashlib.sha256()
+    for path, leaf in flat:
+        digest.update(f"{jax.tree_util.keystr(path)}{leaf.shape}{leaf.dtype}".encode())
+    assert (len(flat), digest.hexdigest()[:16]) == (96, "920637d70def1a81")
+    assert not moe_hybrid.MoeHybridConfig().whole_heads

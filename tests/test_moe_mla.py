@@ -577,24 +577,32 @@ _BUFFER_PINS = {
     "zaya-ingest-slab": ("zaya", 28224, 36864, 0),
     "zaya-query-slab": ("zaya", 4096, 12288, 0),
     "zaya-probe-slab": ("zaya", 256, 8704, 0),
+    # every expert held at top-8 (PR 44): the rows are every pair and a tile
+    # a held expert; the list has a slot a token and cannot spill
+    "laguna-ingest-row": ("moe_hybrid", 23552, 319488, 23552, "laguna"),
+    "laguna-query-row-group": ("moe_hybrid", 16384, 262144, 16384, "laguna"),
+    "laguna-probe-slab": ("moe_hybrid", 1024, 139264, 1024, "laguna"),
 }
+# the routing a configuration changes from its trunk's defaults
+_ROUTING = {"laguna": dict(n_routed_experts=256, experts_held=256, experts_per_token=8)}
 
 
 @pytest.mark.parametrize("case", sorted(_BUFFER_PINS))
 def test_the_buffer_rule_is_pinned_at_the_cells_slabs(case):
     """One rule from the slots, k and held / routed: a row a token slot
     where the expected load is under that (the two ranks that hold a
-    sixteenth of the experts at top-8), and a tile a held expert more where
-    it fills them (top-1 with every expert held)."""
+    sixteenth of the experts at top-8), and every pair there can be and a
+    tile a held expert where it fills them (top-1 and top-8 with every
+    expert held)."""
     import importlib
 
     from pathway_tpu.models import moe_mla as M
 
-    module, tokens, rows, listed = _BUFFER_PINS[case]
+    module, tokens, rows, listed, *routing = _BUFFER_PINS[case]
     trunk = importlib.import_module(f"pathway_tpu.models.{module}")
     config = {"moe_mla": "MoeMlaConfig", "moe_hybrid": "MoeHybridConfig",
               "zaya": "ZayaConfig"}[module]
-    published = getattr(trunk, config)()
+    published = getattr(trunk, config)(**_ROUTING.get(*routing, {}) if routing else {})
     assert M.pair_capacity(tokens, published) == rows
     assert M.combine_rows(tokens, published) == listed
     assert rows % M.PAIR_ROWS == 0
