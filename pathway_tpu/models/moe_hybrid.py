@@ -441,8 +441,8 @@ def rotate_heads(x, cos, sin, head_dim: int, scale: float = 1.0):
 
 def _attention(x, layer, config: MoeHybridConfig, window: bool, seg, rope, lo, fused: bool):
     """The attention half of a layer of one kind, without the residual.
-    x: [B, L, h]; rope: (cos, sin) of the kind's ladder; lo: the kind's
-    `kernel.key_lo`."""
+    x: [B, L, h]; rope: (cos, sin) of the kind's ladder; lo: a global
+    layer's `kernel.key_lo` (None for a window layer)."""
     import jax
     import jax.numpy as jnp
 
@@ -492,15 +492,14 @@ def _trunk(params, config: MoeHybridConfig, ids, seg, max_segments: int, fused: 
     pos = _packed_positions(seg)
     valid = (seg > 0).reshape(-1)
     # what differs by kind and not by layer, once: the ladder's angles and
-    # the first key block a block of queries meets
+    # the first key block a block of queries of a global layer meets
     by_kind = {}
     for window in sorted({c.is_window(i) for i in range(c.layers)}):
         theta = c.rope_theta_window if window else c.rope_theta_global
-        span = c.window if window else None
         by_kind[window] = (
             rope_angles(pos, *rope_ladder(c, window)) if c.whole_heads
             else kernel.rope_tables(pos, theta),
-            kernel.key_lo(seg, pos, span, kernel.block_rows(l, span)) if fused else None,
+            kernel.key_lo(seg, pos, kernel.block_rows(l, None)) if fused and not window else None,
         )
     x = params["embed"][ids].astype(dt)
     stats = layer_pass_lists(c.experts_held)
@@ -569,9 +568,10 @@ def forward(
 class MoeHybridLM(MoeMlaLM):
     """`MoeMlaLM` for this trunk: its entry points and its routing
     statistics (`moe.*`), the packed program under a name of its own, and
-    what the attention of each packed batch scores, by kind, counted into
-    the span record (`hybrid.*`, internals/tracing.py) from the segment
-    lengths, on the host."""
+    what the attention of each packed batch scores, by kind, and what the
+    kernel's window steps meet to score it, counted into the span record
+    (`hybrid.*`, internals/tracing.py) from the segment lengths and the
+    slab's shape, on the host."""
 
     def _packed_program(self):
         config = self.config
@@ -605,6 +605,13 @@ class MoeHybridLM(MoeMlaLM):
         tracing.add("hybrid.scored_pairs", n=global_pairs + window_pairs)
         tracing.add("hybrid.global_pairs", n=global_pairs)
         tracing.add("hybrid.window_pairs", n=window_pairs)
+        # the kernel's window steps: every block of queries of the slab
+        # against its key views, whole blocks (the tiling's count whichever
+        # path ran; over `hybrid.window_pairs`, the tiling's padding)
+        rows, length = np.shape(ids)
+        block, n_q, views = kernel.window_tiling(length, c.window)
+        tracing.add("hybrid.window_met_pairs", n=rows * n_q * views * block * block
+                    * c.q_heads(True) * c.window_layers)
         tracing.add("hybrid.docs_over_window", n=int((lengths > c.window).sum()))
         return super().encode_packed(ids, seg, max_segments, params=params)
 

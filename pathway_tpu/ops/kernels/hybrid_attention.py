@@ -22,23 +22,25 @@ query head joins the softmax's denominator and mixes nothing:
 
     p_ij = exp(s_ij - m) / (sum_j exp(s_ij - m) + exp(b_h - m))
 
-  * one grid step is one slab row, `head_block` query heads of one group,
-    one block of `block` query rows and one block of keys; the key blocks
-    are the last grid axis and the running maximum, normaliser and context
-    stay in VMEM across them (online softmax), so a row may be any number
-    of blocks long and neither the scores nor the mask ever reach HBM.  The
-    sink is where the running maximum and normaliser begin (m = b_h, l =
-    1) instead of (-inf, 0): a key with that logit and a value of zero;
-  * a block of queries of a global layer only meets the key blocks from
+  * a grid step is one slab row, `head_block` query heads of one group and
+    one block of `block` query rows; neither the scores nor the mask ever
+    reach HBM, so a row may be any number of blocks long;
+  * a window layer takes ONE step a block of queries, over every key its
+    window reaches: its own block of keys and the `views - 1` before it
+    (`window_tiling`: 2 for a window of 128 over blocks of 128, 5 for
+    512), each a view of the same arrays.  The step's scores, [stacked
+    rows, views x block] f32, stay in VMEM and the softmax over them is
+    plain: one maximum, one sum, one `p @ v`, one division, no running
+    state.  A sink joins the maximum and the sum: m = max(b_h, max_j
+    s_ij), l = exp(b_h - m) + sum_j exp(s_ij - m);
+  * a global layer walks its key blocks as the last grid axis, the running
+    maximum, normaliser and context kept in VMEM across them (online
+    softmax; a sink is where they begin, m = b_h and l = 1, instead of
+    (-inf, 0)).  A block of queries only meets the key blocks from
     `key_lo`, the first block of its earliest document, to its own
     diagonal.  Which block that is is data, read from SMEM before the step
     (`PrefetchScalarGridSpec`): a step past the diagonal maps to the block
-    already resident and computes nothing.  A window no longer than a
-    block (128 against blocks of 128, where a tile of 512 keys would meet 4
-    to 8 times the pairs that count) takes ONE step a block of queries,
-    over its own block of keys and the one before, passed as a second view
-    of the same arrays: no second pass over the running state; a longer
-    window walks its blocks like a global layer, from `key_lo`;
+    already resident and computes nothing;
   * the step's heads share the key and value blocks it loaded and the
     mask it computed: the keys of a global layer are read once for 8 query
     heads, not once a head.  Their queries are laid one under the other in
@@ -46,8 +48,8 @@ query head joins the softmax's denominator and mixes nothing:
     operand), so a score is ONE product 256 (128) deep against [k_nope |
     k_rope] (k), and a pass of
     the softmax takes PASS_ROWS rows of them at a time: the eight heads of
-    a window layer's step are one [1024, 256] pass, not eight products of
-    128 x 128 that each pay the MXU's fill and drain;
+    a window layer's step are one pass of [1024, views x 128] scores, not
+    eight products that each pay the MXU's fill and drain;
   * operands are read where their matmuls left them, heads contiguous:
     `q_nope` [B, L, H*128], `q_rope` [B, L, H*64], `k_nope`, `v` [B, L,
     KV*128], and the context is written straight into [B, L, H*128] for the
@@ -89,16 +91,21 @@ HEAD_BLOCK = 8
 # 128-lane passes a row whatever the block's width: blocks of 1,024 score
 # 171 G pairs/s at the ingest slab where blocks of 512 score 108 and of 256
 # 76 (chip runs, PR 36), and eight heads' state still fits VMEM beside one
-# head's [1024, 1024] scores.  A window layer's block is the window: a
-# query block then meets two key blocks, half of whose pairs count (blocks
-# of 256 meet four times the pairs that count and are no faster)
+# head's [1024, 1024] scores.  A window layer's block is 128 rows: a query
+# block meets the blocks its window reaches, two for a window of 128, of
+# which half the pairs count (blocks of 256 meet four times the pairs that
+# count and are no faster)
 GLOBAL_BLOCK = 1024
 WINDOW_BLOCK = 128
+# keys a window layer's step scores at most (views x block): its eight
+# heads' scores are then [1024, 1024] f32 at most, the global kind's size
+WINDOW_KEYS = 1024
 # rows of the step's stacked heads that one softmax pass takes: the eight
-# heads of a window layer's step at once ([1024, 256] scores: as eight
-# products of 128 x 128, each paying a fill and a drain, a layer took 13.8
-# ms at the ingest slab for 7.6: chip runs, PR 36), one head of a global
-# layer's ([1024, 1024])
+# heads of a window layer's step at once (two views, [1024, 256] scores: as
+# eight products of 128 x 128, each paying a fill and a drain, a layer took
+# 13.8 ms at the ingest slab for 7.6; five views, [1024, 640]: 4.1 ms a
+# layer at a row of 23,552 slots, where passes of 512 rows take 4.3 and of
+# 256 4.5; TPU v5e), one head of a global layer's ([1024, 1024])
 PASS_ROWS = 1024
 VMEM_LIMIT = 64 * 1024 * 1024
 
@@ -108,12 +115,22 @@ def block_rows(length: int, window: Optional[int]) -> int:
     return min(length, GLOBAL_BLOCK if window is None else WINDOW_BLOCK)
 
 
+def window_tiling(length: int, window: int, block: Optional[int] = None) -> tuple:
+    """(rows of a block, blocks of queries, key views a step) of a window
+    layer over a row of `length`: a block of queries takes its own block
+    of keys and every block the `window - 1` slots before its first row
+    reach into, no more than the row has.  `block` is for tests."""
+    block = block_rows(length, window) if block is None else min(block, length)
+    n_q = length // block
+    return block, n_q, min(-(-(window - 1) // block) + 1, n_q)
+
+
 def supports(length: int, heads: int, kv_heads: int, nope_dim: int, rope_dim: int,
              v_dim: int, window: Optional[int] = None) -> bool:
     """Static shapes the compiled kernel's tiling covers: (nope, rope, v)
     = (128, 64, 128), two operands, a step's heads an even number; or
     (128, 0, 128), one operand (`rope_dim` 0: the rotated dims are inside
-    the 128)."""
+    the 128); a window whose step scores at most `WINDOW_KEYS` keys."""
     block = block_rows(length, window)
     group = heads // max(kv_heads, 1)
     split = (nope_dim, rope_dim, v_dim) == (NOPE_DIM, ROPE_DIM, V_DIM)
@@ -125,20 +142,19 @@ def supports(length: int, heads: int, kv_heads: int, nope_dim: int, rope_dim: in
         and (not split or min(HEAD_BLOCK, group) % 2 == 0)  # two heads' rope queries a tile
         and length % block == 0
         and block % LANES == 0
+        and (window is None or window_tiling(length, window)[2] * block <= WINDOW_KEYS)
     )
 
 
-def key_lo(seg, pos, window: Optional[int], block: int):
-    """[B, L / block] int32: the first key block a block of queries meets.
-    seg, pos: [B, L], the segment ids and `_packed_positions(seg)`."""
+def key_lo(seg, pos, block: int):
+    """[B, L / block] int32: the first key block a block of queries of a
+    global layer meets, its earliest document's first.  seg, pos: [B, L],
+    the segment ids and `_packed_positions(seg)`."""
     import jax.numpy as jnp
 
     b, l = seg.shape
     at = jnp.broadcast_to(jnp.arange(l, dtype=jnp.int32)[None, :], (b, l))
-    first = at - pos  # the slot of the document's first token
-    if window is not None:
-        first = jnp.maximum(first, at - (window - 1))
-    first = jnp.where(seg > 0, first, at)
+    first = jnp.where(seg > 0, at - pos, at)  # the slot of the document's first token
     return first.reshape(b, l // block, block).min(-1) // block
 
 
@@ -179,59 +195,52 @@ def hybrid_attention_dense(q_nope, q_rope, k_nope, k_rope, v, seg, *, kv_heads: 
     return ctx.reshape(b, l, -1).astype(q_nope.dtype)
 
 
-def _kernel(*refs, block: int, window: Optional[int], has_sink: bool, pair: bool,
+def _kernel(*refs, block: int, window: Optional[int], views: int, has_sink: bool,
             split: bool):
+    """Both kinds' body: a global layer's step of its walk from `key_lo`
+    (online softmax), or a window layer's one step over its `views` key
+    blocks (plain softmax)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    lo_ref, segq_ref, segk_ref = refs[:3]
-    rest = refs[3:]
-    segk_prev = sink_ref = None
-    if pair:
-        segk_prev, rest = rest[0], rest[1:]
+    lo_ref = sink_ref = None
+    if window is None:  # the scalar prefetched to SMEM
+        lo_ref, refs = refs[0], refs[1:]
+    segq_ref, code_refs, refs = refs[0], refs[1:1 + views], refs[1 + views:]
     if has_sink:
-        sink_ref, rest = rest[0], rest[1:]
-    n = 5 if split else 3  # q, (q_rope,) k, (k_rope,) v
-    if split:
-        qn_ref, qr_ref, kn_ref, kr_ref, v_ref = rest[:n]
-    else:
-        qn_ref, kn_ref, v_ref = rest[:n]
-    rest = rest[n:]
-    if pair:
-        if split:
-            (kn_prev, kr_prev, v_prev), rest = rest[:3], rest[3:]
-        else:
-            (kn_prev, v_prev), rest = rest[:2], rest[2:]
-    o_ref, q_scr, m_scr, l_scr, acc_scr = rest
+        sink_ref, refs = refs[0], refs[1:]
+    n_qs, per_view = (2, 3) if split else (1, 2)  # q (, q_rope); k (, k_rope), v
+    q_refs, refs = refs[:n_qs], refs[n_qs:]
+    key_refs = [refs[i * per_view:(i + 1) * per_view] for i in range(views)]
+    o_ref, q_scr, *state = refs[views * per_view:]
     b, qi, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-    heads = qn_ref.shape[2] // NOPE_DIM
+    heads = q_refs[0].shape[2] // NOPE_DIM
+    if window is not None:
+        _stack_queries(q_scr, q_refs, heads, block)
+        _window_step(segq_ref, code_refs, sink_ref, key_refs, o_ref, q_scr, qi=qi,
+                     heads=heads, block=block, window=window)
+        return
+    m_scr, l_scr, acc_scr = state
+    (view,), (segk_ref,) = key_refs, code_refs
     a_pass = min(heads, max(PASS_ROWS // block, 1))  # heads whose rows one pass takes
 
     @pl.when(j == 0)
     def _init():
-        # the step's queries, once for all its key blocks: a head's rows
-        # [nope | its rope part, the tile's other head zeroed], the heads
-        # one under the other
-        lane_half = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1) // ROPE_DIM
-        for h in range(heads):
-            rows = slice(h * block, (h + 1) * block)
-            q_scr[rows, :NOPE_DIM] = qn_ref[0, :, h * NOPE_DIM:(h + 1) * NOPE_DIM]
-            if split:
-                tile = qr_ref[0, :, (h // 2) * LANES:(h // 2 + 1) * LANES]
-                q_scr[rows, NOPE_DIM:] = jnp.where(
-                    lane_half == h % 2, tile, jnp.zeros_like(tile)
-                )
-            if has_sink:
-                m_scr[rows] = jnp.broadcast_to(sink_ref[h:h + 1, :], (block, LANES))
+        # the step's queries, once for all its key blocks
+        _stack_queries(q_scr, q_refs, heads, block)
         if has_sink:
+            for h in range(heads):
+                m_scr[h * block:(h + 1) * block] = jnp.broadcast_to(
+                    sink_ref[h:h + 1, :], (block, LANES)
+                )
             l_scr[...] = jnp.ones_like(l_scr)
         else:
             m_scr[...] = jnp.full_like(m_scr, NEG_INF)
             l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    kb = qi if pair else lo_ref[b, qi] + j
+    kb = lo_ref[b, qi] + j
 
     @pl.when(kb <= qi)
     def _meet():
@@ -240,26 +249,10 @@ def _kernel(*refs, block: int, window: Optional[int], has_sink: bool, pair: bool
         # nothing (no sink) its maximum is NEG_INF too and a masked key
         # weighs 1, which the first key it does meet wipes out (alpha = 0),
         # and every query meets itself
-        # [block, 256], or [block, 128] for one operand
-        keys = jnp.concatenate([kn_ref[0], kr_ref[0]], axis=1) if split else kn_ref[0]
-        values, codes = v_ref[0], segk_ref[0]
-        first = kb * block
-        if pair:  # the block before, too: a row past the window's reach is masked
-            before = (
-                jnp.concatenate([kn_prev[0], kr_prev[0]], axis=1) if split else kn_prev[0]
-            )
-            keys = jnp.concatenate([before, keys], axis=0)
-            values = jnp.concatenate([v_prev[0], values], axis=0)
-            codes = jnp.concatenate([segk_prev[0], codes], axis=1)
-            first = first - block
-        width = keys.shape[0]
-        row = qi * block + jax.lax.broadcasted_iota(jnp.int32, (block, width), 0)
-        col = first + jax.lax.broadcasted_iota(jnp.int32, (block, width), 1)
-        see = (segq_ref[0] == codes) & (col <= row)
-        if window is not None:
-            see = see & (row - col < window)
-        if pair:
-            see = see & (col >= 0)  # block 0 has none before it
+        keys, values = _keys(view), view[-1][0]
+        row = qi * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
+        col = kb * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+        see = (segq_ref[0] == segk_ref[0]) & (col <= row)
         see = jnp.concatenate([see] * a_pass, axis=0) if a_pass > 1 else see
         for r0 in range(0, heads * block, a_pass * block):
             rows = slice(r0, r0 + a_pass * block)
@@ -288,13 +281,83 @@ def _kernel(*refs, block: int, window: Optional[int], has_sink: bool, pair: bool
             ).astype(o_ref.dtype)
 
 
+def _keys(view):
+    """A view's keys [block, 256] ([k_nope | k_rope]), or [block, 128] for
+    one operand; `view` is its (k_nope, (k_rope,) v) refs."""
+    import jax.numpy as jnp
+
+    kn, *kr, _ = view
+    return jnp.concatenate([kn[0], kr[0][0]], axis=1) if kr else kn[0]
+
+
+def _stack_queries(q_scr, q_refs, heads: int, block: int):
+    """The step's queries one head under the other: a head's rows [nope |
+    its rope part, the tile's other head zeroed], or its one operand."""
+    import jax
+    import jax.numpy as jnp
+
+    lane_half = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1) // ROPE_DIM
+    for h in range(heads):
+        rows = slice(h * block, (h + 1) * block)
+        q_scr[rows, :NOPE_DIM] = q_refs[0][0, :, h * NOPE_DIM:(h + 1) * NOPE_DIM]
+        if len(q_refs) == 2:
+            tile = q_refs[1][0, :, (h // 2) * LANES:(h // 2 + 1) * LANES]
+            q_scr[rows, NOPE_DIM:] = jnp.where(lane_half == h % 2, tile, jnp.zeros_like(tile))
+
+
+def _window_step(segq_ref, code_refs, sink_ref, key_refs, o_ref, q_scr, *, qi, heads: int,
+                 block: int, window: int):
+    """Every head of a block of queries over every key its window reaches:
+    the views one after the other along the key axis, the earliest first
+    (a view before block 0 shows block 0, masked), the scores of a pass's
+    heads [rows, views x block] f32 in VMEM, one softmax over them."""
+    import jax
+    import jax.numpy as jnp
+
+    views = len(key_refs)
+    a_pass = min(heads, max(PASS_ROWS // block, 1))  # heads whose rows one pass takes
+    keys = jnp.concatenate([_keys(view) for view in key_refs], axis=0)
+    values = jnp.concatenate([view[-1][0] for view in key_refs], axis=0)
+    codes = jnp.concatenate([code[0] for code in code_refs], axis=1)
+    width = views * block
+    row = qi * block + jax.lax.broadcasted_iota(jnp.int32, (block, width), 0)
+    col = (qi - views + 1) * block + jax.lax.broadcasted_iota(jnp.int32, (block, width), 1)
+    see = (segq_ref[0] == codes) & (col <= row) & (row - col < window) & (col >= 0)
+    see = jnp.concatenate([see] * a_pass, axis=0) if a_pass > 1 else see
+    for h0 in range(0, heads, a_pass):
+        rows = slice(h0 * block, (h0 + a_pass) * block)
+        s = jax.lax.dot_general(
+            q_scr[rows], keys, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        # every query sees itself, so a row's maximum is a score it sees
+        s = jnp.where(see, s, NEG_INF)
+        m = jnp.max(s, axis=1, keepdims=True)
+        if sink_ref is not None:
+            logit = jnp.concatenate([
+                jnp.broadcast_to(sink_ref[h:h + 1, 0:1], (block, 1))
+                for h in range(h0, h0 + a_pass)
+            ], axis=0)
+            m = jnp.maximum(logit, m)
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, axis=1, keepdims=True)
+        if sink_ref is not None:
+            l = jnp.exp(logit - m) + l
+        ctx = jnp.dot(p.astype(values.dtype), values, preferred_element_type=jnp.float32) / l
+        for h in range(h0, h0 + a_pass):
+            o_ref[0, :, h * V_DIM:(h + 1) * V_DIM] = (
+                ctx[(h - h0) * block:(h - h0 + 1) * block].astype(o_ref.dtype)
+            )
+
+
 def hybrid_attention(q_nope, q_rope, k_nope, k_rope, v, seg, lo, *, kv_heads: int,
                      window: Optional[int] = None, sink=None,
                      block: Optional[int] = None, interpret=None):
     """The fused kernel.  q_nope [B, L, H*128], q_rope [B, L, H*64] (scaled,
     rotated); k_nope, v [B, L, KV*128]; k_rope [B, L, KV*64] (rotated); seg
     [B, L] int32, 1..S per packed document, 0 = padding; lo: `key_lo(seg,
-    pos, window, block)`; sink [H] or None.  One operand: q_nope and k_nope
+    pos, block)` of a global layer, None for a window layer (its steps
+    need no data); sink [H] or None.  One operand: q_nope and k_nope
     are q [B, L, H*128] (scaled) and k [B, L, KV*128], both rotated where
     they are, q_rope and k_rope None.  Returns the context [B, L, H*128] in
     q_nope's dtype.  The device op is named by layout and kind
@@ -333,43 +396,27 @@ def _attend(q_nope, q_rope, k_nope, k_rope, v, seg, lo, sink, *, kv_heads: int,
             f"kv_heads={kv_heads} block={block}"
         )
     n_q = l // block
-    # a window no longer than a block: ONE step a block of queries, over its
-    # own block of keys and the one before (no second pass of the running
-    # state); a longer window: its blocks and the diagonal, a step each;
-    # no window: every block up to the diagonal
-    pair = window is not None and window <= block and n_q > 1
-    if pair:
-        n_k = 1
-    else:
-        n_k = n_q if window is None else min(-(-(window - 1) // block) + 1, n_q)
     blocks_a_group = group // head_block
+    # index maps: grid indices, then (a global layer) the scalar prefetched
+    # to SMEM.  A window layer: ONE step a block of queries, its key views
+    # `qi - views + 1 .. qi` clamped at block 0; a global layer: a step a
+    # key block, from `key_lo` to the diagonal (past it: the diagonal again)
+    if window is None:
+        views, n_k = 1, n_q
+        at = [lambda i, qi, j, lo: jnp.minimum(lo[i, qi] + j, qi)]
+    else:
+        _, _, views = window_tiling(l, window, block)
+        n_k = 1
+        at = [
+            lambda i, qi, j, back=views - 1 - view: jnp.maximum(qi - back, 0)
+            for view in range(views)
+        ]
 
-    # index maps: grid indices, then the scalar prefetched to SMEM
-    def queries(i, g, qi, j, lo):
+    def queries(i, g, qi, j, *lo):
         return (i, qi, g)
-
-    def key_block(i, qi, j, lo):
-        return qi if pair else jnp.minimum(lo[i, qi] + j, qi)
-
-    def keys(i, g, qi, j, lo):
-        return (i, key_block(i, qi, j, lo), g // blocks_a_group)
-
-    def key_codes(i, g, qi, j, lo):
-        return (i, 0, key_block(i, qi, j, lo))
-
-    def keys_before(i, g, qi, j, lo):
-        return (i, jnp.maximum(qi - 1, 0), g // blocks_a_group)
-
-    def codes_before(i, g, qi, j, lo):
-        return (i, 0, jnp.maximum(qi - 1, 0))
 
     vmem = pltpu.VMEM
     seg = seg.astype(jnp.int32)
-    key_specs = lambda at: [  # noqa: E731
-        pl.BlockSpec((1, block, NOPE_DIM), at, memory_space=vmem),
-        *([pl.BlockSpec((1, block, LANES), at, memory_space=vmem)] if split else []),
-        pl.BlockSpec((1, block, V_DIM), at, memory_space=vmem),
-    ]
     if split:
         # the group's rope key laid twice along the lanes: [B, L, KV*128]
         k_rope2 = jnp.concatenate(
@@ -385,44 +432,52 @@ def _attend(q_nope, q_rope, k_nope, k_rope, v, seg, lo, sink, *, kv_heads: int,
         q_specs = [
             pl.BlockSpec((1, block, head_block * NOPE_DIM), queries, memory_space=vmem),
         ]
-    operands = [seg[:, :, None], seg[:, None, :]]
+    key_widths = [NOPE_DIM, LANES, V_DIM] if split else [NOPE_DIM, V_DIM]
+    operands = [seg[:, :, None]] + [seg[:, None, :]] * views
     in_specs = [
-        pl.BlockSpec((1, block, 1), lambda i, g, qi, j, lo: (i, qi, 0), memory_space=vmem),
-        pl.BlockSpec((1, 1, block), key_codes, memory_space=vmem),
+        pl.BlockSpec((1, block, 1), lambda i, g, qi, j, *lo: (i, qi, 0), memory_space=vmem),
+        *[
+            pl.BlockSpec((1, 1, block), lambda i, g, qi, j, *lo, kb=kb: (i, 0, kb(i, qi, j, *lo)),
+                         memory_space=vmem)
+            for kb in at
+        ],
     ]
-    if pair:
-        operands.append(seg[:, None, :])
-        in_specs.append(pl.BlockSpec((1, 1, block), codes_before, memory_space=vmem))
     if sink is not None:
         operands.append(jnp.broadcast_to(sink.astype(jnp.float32)[:, None], (heads, LANES)))
         in_specs.append(
-            pl.BlockSpec((head_block, LANES), lambda i, g, qi, j, lo: (g, 0), memory_space=vmem)
+            pl.BlockSpec((head_block, LANES), lambda i, g, qi, j, *lo: (g, 0), memory_space=vmem)
         )
-    operands += q_ops + key_ops
-    in_specs += q_specs + key_specs(keys)
-    if pair:
-        operands += key_ops
-        in_specs += key_specs(keys_before)
+    operands += q_ops + key_ops * views
+    in_specs += q_specs + [
+        pl.BlockSpec(
+            (1, block, w),
+            lambda i, g, qi, j, *lo, kb=kb: (i, kb(i, qi, j, *lo), g // blocks_a_group),
+            memory_space=vmem,
+        )
+        for kb in at for w in key_widths
+    ]
     kernel = functools.partial(
-        _kernel, block=block, window=window, has_sink=sink is not None, pair=pair,
+        _kernel, block=block, window=window, views=views, has_sink=sink is not None,
         split=split,
     )
     stacked = head_block * block  # the step's heads, one under the other
+    scratch = [pltpu.VMEM((stacked, NOPE_DIM + (LANES if split else 0)), q_nope.dtype)]
+    if window is None:
+        scratch += [
+            pltpu.VMEM((stacked, LANES), jnp.float32),  # running max
+            pltpu.VMEM((stacked, LANES), jnp.float32),  # normaliser
+            pltpu.VMEM((stacked, V_DIM), jnp.float32),  # context
+        ]
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=int(window is None),
             grid=(b, heads // head_block, n_q, n_k),
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
                 (1, block, head_block * V_DIM), queries, memory_space=vmem
             ),
-            scratch_shapes=[
-                pltpu.VMEM((stacked, NOPE_DIM + (LANES if split else 0)), q_nope.dtype),
-                pltpu.VMEM((stacked, LANES), jnp.float32),  # running max
-                pltpu.VMEM((stacked, LANES), jnp.float32),  # normaliser
-                pltpu.VMEM((stacked, V_DIM), jnp.float32),  # context
-            ],
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((b, l, heads * V_DIM), q_nope.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -431,7 +486,7 @@ def _attend(q_nope, q_rope, k_nope, k_rope, v, seg, lo, sink, *, kv_heads: int,
         ),
         name=op_name(split, window),
         interpret=interpret,
-    )(lo, *operands)
+    )(*([lo] if window is None else []), *operands)
 
 
 ROPE_ROWS = 256  # rows of a slab a step of `rope` turns

@@ -180,16 +180,37 @@ def test_each_mechanism_moves_the_vectors(mechanism):
     assert np.abs(got - want).max() > 10 * F32_TOL, mechanism
 
 
-@pytest.mark.parametrize("group", [6, 8])
-@pytest.mark.parametrize("window", [None, 512], ids=["global", "window-512"])
-def test_the_one_operand_kernel_agrees_with_its_dense_definition(group, window):
+# documents (first slot, end) in a row of `length` slots, the rest padding
+_DOCUMENTS = ((0, 300), (300, 1500), (1500, 1530), (1530, 2000))
+# at a window of 512 over blocks of 128 (five views): documents that begin
+# inside a block of queries' earliest view and inside its own, one of 55
+# slots and one of 7 in the middle of windows, one that begins on a block
+_EDGES = ((0, 645), (645, 700), (700, 1283), (1283, 1290), (1290, 1408), (1408, 1990))
+_ONE_OPERAND_CASES = {
+    # (window, group, length, documents)
+    "global-6": (None, 6, 2048, _DOCUMENTS),
+    "global-8": (None, 8, 2048, _DOCUMENTS),
+    "window-512-6": (512, 6, 2048, _DOCUMENTS),
+    "window-512-8": (512, 8, 2048, _DOCUMENTS),
+    "window-512-8-view-edges": (512, 8, 2048, _EDGES),
+    # three blocks of queries: fewer views than the window reaches
+    "window-512-6-short-row": (512, 6, 384, ((0, 200), (200, 330))),
+    "global-6-short-row": (None, 6, 384, ((0, 200), (200, 330))),
+}
+
+
+@pytest.mark.parametrize("case", list(_ONE_OPERAND_CASES))
+def test_the_one_operand_kernel_agrees_with_its_dense_definition(case):
     """Interpreted on the CPU at the cell's tiling (blocks of 1,024 keys
-    for a global layer, 128 walked for a window of 512), documents longer
-    and shorter than the window in one row."""
-    length, kv = 2048, 1
+    for a global layer; for a window of 512, one step a block of 128
+    queries over five views of 128 keys), documents longer and shorter
+    than the window in one row, every view's edges; the padded tail
+    finite."""
+    window, group, length, documents = _ONE_OPERAND_CASES[case]
+    kv = 1
     rng = np.random.default_rng(group)
     seg = np.zeros((1, length), np.int32)
-    for s, (lo, hi) in enumerate(((0, 300), (300, 1500), (1500, 1530), (1530, 2000))):
+    for s, (lo, hi) in enumerate(documents):
         seg[0, lo:hi] = s + 1
     seg = jnp.asarray(seg)
     heads = group * kv
@@ -197,13 +218,15 @@ def test_the_one_operand_kernel_agrees_with_its_dense_definition(group, window):
     k = jnp.asarray(rng.standard_normal((1, length, kv * 128)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((1, length, kv * 128)), jnp.float32)
     assert kernel.supports(length, heads, kv, 128, 0, 128, window)
-    block = kernel.block_rows(length, window)
-    lo = kernel.key_lo(seg, _packed_positions(seg), window, block)
+    lo = None
+    if window is None:
+        lo = kernel.key_lo(seg, _packed_positions(seg), kernel.block_rows(length, window))
     got = kernel.hybrid_attention(q, None, k, None, v, seg, lo, kv_heads=kv, window=window,
                                   interpret=True)
     want = kernel.hybrid_attention_dense(q, None, k, None, v, seg, kv_heads=kv, window=window)
     real = np.asarray(seg)[0] > 0
     np.testing.assert_allclose(np.asarray(got)[0, real], np.asarray(want)[0, real], atol=2e-6)
+    assert np.isfinite(np.asarray(got)).all()
     assert kernel.op_name(False, window) == (
         "laguna_attention_global" if window is None else "laguna_attention_window"
     )
@@ -351,3 +374,35 @@ def test_the_counters_count_pairs_by_kind_and_the_buffers_rows():
     assert got["moe.overflow_pairs"] == 0 and got["moe.combine_spills"] == 0
     assert got["moe.group_rows"] >= got["moe.pairs_held"]
     assert got["moe.group_pad_rows"] == got["moe.group_rows"] - got["moe.pairs_held"]
+
+
+def test_the_window_steps_count_the_pairs_their_tiling_meets(monkeypatch):
+    """`hybrid.window_met_pairs` of a batch of the cell's slab, one row of
+    23,552 slots: 184 blocks of queries, each five views of 128 keys for
+    128 rows, in each of 64 query heads and three window layers, whatever
+    the documents; over `hybrid.window_pairs`, the tiling's padding."""
+    from chipbench.architectures.laguna_decoder import program
+    from pathway_tpu.internals import tracing
+
+    cfg = json.load(open(os.path.join(ROOT, "chipbench", "configs",
+                                      "laguna-xs2-pp8-docstore.json")))
+    lm = moe_hybrid.LM.__new__(moe_hybrid.LM)
+    lm.config = program.config_of(cfg["model"], cfg["store"])
+    # the counting alone: the program itself is not run
+    monkeypatch.setattr(moe_mla.MoeMlaLM, "encode_packed", lambda *a, **k: None)
+    seg = np.zeros((1, 23552), np.int32)
+    for s, at in enumerate(range(0, 23400, 1950)):  # 12 files of 1,950 tokens
+        seg[0, at:at + 1950] = s + 1
+    names = ("hybrid.window_met_pairs", "hybrid.window_pairs")
+
+    def counts():
+        totals = tracing.spans_status()["totals"]
+        return {n: totals.get(n, {"count": 0})["count"] for n in names}
+
+    before = counts()
+    lm.encode_packed(np.zeros_like(seg), seg, PACK_MAX_SEGMENTS)
+    got = {n: counts()[n] - before[n] for n in names}
+    assert got["hybrid.window_met_pairs"] == 184 * 5 * 128 * 128 * 64 * 3
+    lengths = np.full(12, 1950)
+    assert got["hybrid.window_pairs"] == int(moe_hybrid.scored_pairs(lengths, 512).sum()) * 64 * 3
+    assert 1.4 < got["hybrid.window_met_pairs"] / got["hybrid.window_pairs"] < 1.5

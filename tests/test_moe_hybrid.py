@@ -201,7 +201,7 @@ def test_the_kernel_is_its_dense_definition(case):
     sinks = jnp.asarray(np.random.default_rng(1).normal(size=heads), jnp.float32) if sink else None
     pos = _packed_positions(seg)
     rows = kernel.block_rows(l, window) if block is None else block
-    lo = kernel.key_lo(seg, pos, window, rows)
+    lo = kernel.key_lo(seg, pos, rows) if window is None else None
     want = kernel.hybrid_attention_dense(*ops, seg, kv_heads=kv_heads, window=window, sink=sinks)
     got = kernel.hybrid_attention(*ops, seg, lo, kv_heads=kv_heads, window=window,
                                   sink=sinks, block=block, interpret=True)
@@ -213,17 +213,33 @@ def test_the_kernel_is_its_dense_definition(case):
 
 def test_the_kernels_blocks_follow_the_documents_and_the_window():
     """`key_lo`: a block of queries of a global layer begins at its earliest
-    document's first block, of a window layer at the block `window - 1`
-    slots before its first row; padding meets itself."""
+    document's first block; padding meets itself.  A window layer's block
+    of queries takes the blocks its window reaches, whatever the
+    documents: window 128 over blocks of 128, the block before."""
     seg = _packed_seg(1024, [[300, 500]])
     pos = _packed_positions(seg)
-    assert np.asarray(kernel.key_lo(seg, pos, None, 128)).tolist() == [[0, 0, 0, 2, 2, 2, 2, 7]]
-    # window 128 over blocks of 128: the block before, but never another document's
-    assert np.asarray(kernel.key_lo(seg, pos, 128, 128)).tolist() == [[0, 0, 1, 2, 3, 4, 5, 7]]
+    assert np.asarray(kernel.key_lo(seg, pos, 128)).tolist() == [[0, 0, 0, 2, 2, 2, 2, 7]]
+    assert kernel.window_tiling(1024, 128) == (128, 8, 2)
     assert kernel.block_rows(24576, None) == 1024 and kernel.block_rows(24576, 128) == 128
     assert kernel.supports(24576, 64, 4, 128, 64, 128) and kernel.supports(16384, 64, 8, 128, 64, 128, 128)
     assert not kernel.supports(24576, 64, 4, 64, 64, 64)  # other heads: the dense definition
     assert not kernel.supports(24000, 64, 4, 128, 64, 128)  # a row off the blocks
+    # a window whose views pass `WINDOW_KEYS`: the dense definition runs it
+    assert kernel.supports(23552, 64, 8, 128, 0, 128, 897)  # 8 views of 128: [1024, 1024]
+    assert not kernel.supports(23552, 64, 8, 128, 0, 128, 898)  # 9 views
+    assert not kernel.supports(24576, 64, 8, 128, 64, 128, 1024)
+
+
+@pytest.mark.parametrize("length,window,block,want", [
+    (23552, 512, None, (128, 184, 5)),  # Laguna's: 1,472 steps a layer of 64 heads, not 7,360
+    (24576, 128, None, (128, 192, 2)),  # MiMo's: the block before and its own
+    (384, 48, 32, (32, 12, 3)),
+    (384, 512, None, (128, 3, 3)),  # no more views than the row has blocks
+], ids=["laguna", "mimo", "narrow", "short-row"])
+def test_a_window_step_takes_every_key_block_its_window_reaches(length, window, block, want):
+    """`window_tiling`: (rows of a block, blocks of queries, key views a
+    step), one step a block of queries."""
+    assert kernel.window_tiling(length, window, block) == want
 
 
 @pytest.mark.parametrize("fused", [False, True], ids=["dense", "kernel-interpreted"])
@@ -238,7 +254,7 @@ def test_a_global_layer_sees_key_0_from_query_500_and_a_window_layer_does_not(fu
         if not fused:
             return np.asarray(kernel.hybrid_attention_dense(
                 qn, qr, kn, kr, values, seg, kv_heads=kv_heads, window=window))
-        lo = kernel.key_lo(seg, pos, window, kernel.block_rows(l, window))
+        lo = kernel.key_lo(seg, pos, kernel.block_rows(l, None)) if window is None else None
         return np.asarray(kernel.hybrid_attention(
             qn, qr, kn, kr, values, seg, lo, kv_heads=kv_heads, window=window, interpret=True))
 
@@ -259,7 +275,7 @@ def test_a_group_of_query_heads_reads_its_own_key_value_head(heads, kv_heads):
     kn2 = kn.at[:, :, 128:].multiply(-1.0)
     kr2 = kr.at[:, :, 64:].multiply(-1.0)
     v2 = v.at[:, :, 128:].add(1.0)
-    lo = kernel.key_lo(seg, pos, None, 128)
+    lo = kernel.key_lo(seg, pos, 128)
     run = lambda *kv: np.asarray(kernel.hybrid_attention(  # noqa: E731
         qn, qr, *kv, seg, lo, kv_heads=kv_heads, interpret=True))[0, :100]
     dense = np.asarray(kernel.hybrid_attention_dense(
@@ -522,7 +538,7 @@ def test_the_kernel_compiles_for_the_chip_at_the_ingest_slab(kind, one_chip, no_
         args = (shape(1, l, heads * 128), shape(1, l, heads * 64), shape(1, l, kv_heads * 128),
                 shape(1, l, kv_heads * 64), shape(1, l, kv_heads * 128),
                 shape(1, l, dt=jnp.int32),
-                shape(1, l // kernel.block_rows(l, window), dt=jnp.int32),
+                None if window else shape(1, l // kernel.block_rows(l, None), dt=jnp.int32),
                 shape(heads, dt=jnp.float32))
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
@@ -541,7 +557,7 @@ def test_the_one_operand_layout_compiles_for_the_chip_at_its_cells_slab(kind, on
         q, None, k, None, v, seg, lo, kv_heads=8, window=window, interpret=False)
     args = (shape(1, l, heads * 128), shape(1, l, 8 * 128), shape(1, l, 8 * 128),
             shape(1, l, dt=jnp.int32),
-            shape(1, l // kernel.block_rows(l, window), dt=jnp.int32))
+            None if window else shape(1, l // kernel.block_rows(l, None), dt=jnp.int32))
     assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
 
 
