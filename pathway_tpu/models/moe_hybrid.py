@@ -502,7 +502,7 @@ def _trunk(params, config: MoeHybridConfig, ids, seg, max_segments: int, fused: 
             kernel.key_lo(seg, pos, kernel.block_rows(l, None)) if fused and not window else None,
         )
     x = params["embed"][ids].astype(dt)
-    stats = layer_pass_lists(c.experts_held)
+    stats = layer_pass_lists(c)
     for i, layer in enumerate(params["layers"]):
         window = c.is_window(i)
         x = x + _attention(x, layer, c, window, seg, *by_kind[window], fused)

@@ -37,7 +37,9 @@ each expert's group begun on a tile of the grouped matmul where the buffer
 has the room; pairs beyond the buffer are counted (`overflow`), never
 dropped silently.  What the path costs follows the pairs the slab holds:
 the results return to their tokens in one gather a token, the few tokens
-with several pairs summed first in a compact list (`held_experts`).
+with several pairs summed first in a compact list, or, with every expert
+held, each token's k rows gathered, weighted and summed in one pass
+(`held_experts`).
 """
 
 from __future__ import annotations
@@ -447,6 +449,18 @@ def combine_rows(tokens: int, config) -> int:
     return -(-tokens // (8 * PAIR_ROWS)) * PAIR_ROWS
 
 
+def returns_fused(config) -> bool:
+    """Whether `held_experts` returns the pairs in one fused weighted sum at
+    the tokens' side: k > 1 with every routed expert held.  Then every real
+    token has k pairs and the buffer is k rows a token and a tile a held
+    expert (13.6 rows a token slot at top-8 of 256), so a pass over the
+    buffer costs many over the tokens, and a loop bounded by the busiest
+    token always makes k passes.  Where a sixteenth of the experts is held
+    (about a row a token, 1-8 pairs) the compact list and the buffer-side
+    weight stay; at k = 1 there is no sum."""
+    return config.experts_per_token > 1 and config.experts_held >= config.n_routed_experts
+
+
 def held_experts(h, valid, layer, config, capacity: Optional[int] = None,
                  *, listed: Optional[int] = None, with_stats: bool = False,
                  routing=None):
@@ -456,8 +470,9 @@ def held_experts(h, valid, layer, config, capacity: Optional[int] = None,
     [experts_held] int32, pairs selected and held but beyond the buffer
     () int32), and with `with_stats` a fourth: {"multi_pair_tokens",
     "combine_spills", "groups_aligned", "groups_packed", "group_rows",
-    "group_pad_rows"}, each () int32.  `capacity` and `listed` override
-    `pair_capacity` and `combine_rows` (tests).  `config`: this module's
+    "group_pad_rows"}, each () int32, and "fused_returns" (1) where the
+    return is fused.  `capacity` and `listed` override `pair_capacity` and
+    `combine_rows` (tests).  `config`: this module's
     or another trunk's with the same routing fields, k =
     `experts_per_token`, `experts_held` of `n_routed_experts` from
     `expert_offset`, any k and any share (`models/moe_hybrid.py`: a
@@ -493,15 +508,22 @@ def held_experts(h, valid, layer, config, capacity: Optional[int] = None,
     grouped matmuls run over, `group_pad_rows` those of them that hold no
     pair.
 
-    The results go back to their tokens in one gather a token.  A pair's
-    weight is put on its row in the buffer.  A token with one pair reads
-    that row, a token with none a zero: at k = 1 that is the whole return,
-    one inverse permutation.  The tokens with two or more (8% at k x held
-    / routed = 1/2: a sixteenth of the experts held, top-8) are first
-    summed, in the compute
-    dtype and in their slots' order, in a list of `listed` slots appended
-    to the buffer, and read their sum.  More such tokens than slots is
-    seen in the input: then the others' further pairs are added pass by
+    With every expert held at k > 1 (`returns_fused`: 319,488 buffer rows
+    for 23,552 token slots at top-8 of 256) a token's k rows come back in
+    one fused weighted sum, the weight put on at the token's side, in slot
+    order and in the compute dtype: a pass over the buffer to put the
+    weights on, their one-element gathers and a loop of k passes over the
+    tokens took 55 ms a dispatch of four expert layers on a TPU v5e, 18.5%
+    of the Laguna cell's busy time, and the fused sum takes about half of
+    it; `fused_returns` counts such passes.  Otherwise the results go back
+    to their tokens in one gather a token.  A pair's weight is put on its
+    row in the buffer.  A token with one pair reads that row, a token with
+    none a zero: at k = 1 that is the whole return, one inverse permutation.
+    The tokens with two or more (8% at k x held / routed = 1/2: a sixteenth
+    of the experts held, top-8) are first summed, in the compute dtype and
+    in their slots' order, in a list of `listed` slots appended to the
+    buffer, and read their sum.  More such tokens than slots is seen in the
+    input: then the others' further pairs are added pass by
     pass over every token, and no pair is dropped.  Where the list has a
     slot a token (a small slab, or a share at which every token expects
     several pairs: `combine_rows`), the tokens are the list, their sums
@@ -561,10 +583,12 @@ def held_experts(h, valid, layer, config, capacity: Optional[int] = None,
         out = jax.lax.ragged_dot(
             jax.nn.silu(gate) * up, layer["experts_down"].astype(dt), sizes
         )
-    # a pair's weight goes on here.  The other rows hold no pair or were
-    # never written: a zero weight does not silence what they hold
-    weight = weights.reshape(-1)[pair].astype(dt)
-    out = jnp.where(filled[:, None], weight[:, None] * out, jnp.zeros_like(out))
+    fused = returns_fused(c)
+    if not fused:
+        # a pair's weight goes on here.  The other rows hold no pair or were
+        # never written: a zero weight does not silence what they hold
+        weight = weights.reshape(-1)[pair].astype(dt)
+        out = jnp.where(filled[:, None], weight[:, None] * out, jnp.zeros_like(out))
     # a pair's row in the buffer; each token's pairs that are in it moved
     # to the front of its k slots
     nth_sorted = jnp.argsort(order).astype(jnp.int32)
@@ -592,6 +616,19 @@ def held_experts(h, valid, layer, config, capacity: Optional[int] = None,
 
     if k == 1:  # a pair a token at most: one inverse permutation
         y = jnp.where(mine, out[row[:, 0]], jnp.zeros((), dt))
+    elif fused:
+        # a token's k rows gathered, weighted and summed in one expression,
+        # in slot order; the mask after the product, so that a row no pair
+        # filled is never read into `y`
+        w_of = jnp.sum(jnp.where(slot, weights[:, :, None], 0.0), axis=1).astype(dt)
+
+        def term(j):
+            return jnp.where((j < pairs_of)[:, None], w_of[:, j, None] * out[row_of[:, j]],
+                             jnp.zeros((), dt))
+
+        y = term(0)
+        for j in range(1, k):
+            y = y + term(j)
     elif listed >= t:  # a slot a token: the tokens are the list
         y = summed(row_of, pairs_of)
     else:
@@ -623,7 +660,7 @@ def held_experts(h, valid, layer, config, capacity: Optional[int] = None,
         )
     if not with_stats:
         return y, counts, overflow
-    return y, counts, overflow, {
+    stats = {
         "multi_pair_tokens": n_multi,
         "combine_spills": (n_multi > listed).astype(jnp.int32),
         "groups_aligned": aligned.astype(jnp.int32),
@@ -631,25 +668,32 @@ def held_experts(h, valid, layer, config, capacity: Optional[int] = None,
         "group_rows": ends[-1],
         "group_pad_rows": ends[-1] - kept.sum(),
     }
+    if fused:
+        stats["fused_returns"] = jnp.int32(1)
+    return y, counts, overflow, stats
 
 
 # what `held_experts` counts of a pass (a row group's pass through one expert
 # layer) beside the tokens per expert and the overflow: each a counter
-# `moe.<name>`
+# `moe.<name>`.  "fused_returns" is in the statistics only where the return
+# is fused (`returns_fused`): elsewhere its counter reads 0 and the program
+# has no output that always reads 0
 LAYER_PASS_STATS = (
     "multi_pair_tokens", "combine_spills", "groups_aligned", "groups_packed",
-    "group_rows", "group_pad_rows",
+    "group_rows", "group_pad_rows", "fused_returns",
 )
 
-def layer_pass_lists(experts_held: int) -> dict:
+def layer_pass_lists(config) -> dict:
     """What a trunk gathers of its expert layers' passes, a list a name
     that begins empty: "expert_tokens" [layers, experts_held], "overflow"
-    and each of LAYER_PASS_STATS [layers]."""
+    and each of LAYER_PASS_STATS that `held_experts` gives under `config`
+    [layers]."""
     import jax.numpy as jnp
 
-    stats = {"expert_tokens": [jnp.zeros((0, experts_held), jnp.int32)]}
+    stats = {"expert_tokens": [jnp.zeros((0, config.experts_held), jnp.int32)]}
     for name in ("overflow",) + LAYER_PASS_STATS:
-        stats[name] = [jnp.zeros((0,), jnp.int32)]
+        if name != "fused_returns" or returns_fused(config):
+            stats[name] = [jnp.zeros((0,), jnp.int32)]
     return stats
 
 
@@ -717,7 +761,7 @@ def _trunk(params, config: MoeMlaConfig, ids, seg, max_segments: int, fused: boo
     freqs = jnp.asarray(yarn_freqs(c))
     valid = (seg > 0).reshape(-1)
     x = params["embed"][ids].astype(dt)
-    stats = layer_pass_lists(c.experts_held)
+    stats = layer_pass_lists(c)
     for layer in params["layers"]:
         x = x + _attention(x, layer, c, pos, seg, fused, freqs)
         h = _rms_norm(x, layer["ln2"], c.norm_eps)
@@ -861,7 +905,7 @@ class MoeMlaLM(TransformerLM):
             )
             tracing.add("moe.overflow_pairs", n=int(np.asarray(stats["overflow"]).sum()))
             for name in LAYER_PASS_STATS:
-                tracing.add("moe." + name, n=int(np.asarray(stats[name]).sum()))
+                tracing.add("moe." + name, n=int(np.asarray(stats.get(name, 0)).sum()))
             self._count_more(stats)
 
     def _count_more(self, stats) -> None:

@@ -419,7 +419,7 @@ def _trunk(params, config: ZayaConfig, ids, seg, max_segments: int, fused: bool)
     valid = (seg > 0).reshape(-1)
     x = params["embed"][ids].astype(dt)
     r = jnp.zeros((b * l, c.router_hidden), jnp.float32)  # the second stream
-    stats = dict(layer_pass_lists(c.experts_held), skipped=[jnp.zeros((0,), jnp.int32)])
+    stats = dict(layer_pass_lists(c), skipped=[jnp.zeros((0,), jnp.int32)])
     for layer in params["layers"]:
         x = _merge(x, _attention(x, layer, c, seg, rope, fused), layer["alpha"][:2])
         h = _rms_norm(x, layer["ln2"], c.norm_eps).reshape(b * l, c.hidden)

@@ -277,7 +277,9 @@ def test_two_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
 def test_held_experts_with_every_expert_held_at_top8(tokens):
     """256 experts all held, 8 a token: the buffer holds every pair (no
     overflow), the return needs no list that could spill, each group begins
-    a tile, and y is a plain loop over the experts."""
+    a tile, and y is a plain loop over the experts.  The return is the
+    fused weighted sum at the tokens' side: no loop in the program, and the
+    padding's rows exact zeros."""
     c = dataclasses.replace(
         moe_hybrid.TINY, hidden=32, expert_mlp_dim=16, n_routed_experts=256,
         experts_per_token=8, experts_held=256, routed_scaling_factor=2.5,
@@ -293,9 +295,14 @@ def test_held_experts_with_every_expert_held_at_top8(tokens):
     valid = jnp.arange(tokens) < tokens - 7
     capacity = moe_mla.pair_capacity(tokens, c)
     assert moe_mla.combine_rows(tokens, c) == tokens
-    y, counts, over, stats = jax.jit(
+    assert moe_mla.returns_fused(c)
+    program = jax.jit(
         lambda h, valid, layer: moe_mla.held_experts(h, valid, layer, c, with_stats=True)
-    )(h, valid, layer)
+    )
+    assert "while" not in program.lower(h, valid, layer).as_text()
+    y, counts, over, stats = program(h, valid, layer)
+    assert int(stats["fused_returns"]) == 1
+    assert not np.asarray(y)[tokens - 7:].any()  # padding routes nothing: exact zeros
     assert int(over) == 0 and int(stats["combine_spills"]) == 0
     assert int(counts.sum()) == 8 * (tokens - 7)
     assert int(stats["groups_aligned"]) == 1
@@ -353,7 +360,7 @@ def test_the_counters_count_pairs_by_kind_and_the_buffers_rows():
     texts = [text_of(30, 1), text_of(100, 2)]
     names = ("hybrid.global_pairs", "hybrid.window_pairs", "moe.group_rows",
              "moe.group_pad_rows", "moe.pairs_held", "moe.overflow_pairs",
-             "moe.combine_spills")
+             "moe.combine_spills", "moe.fused_returns")
 
     def counts():
         totals = tracing.spans_status()["totals"]
@@ -374,6 +381,9 @@ def test_the_counters_count_pairs_by_kind_and_the_buffers_rows():
     assert got["moe.overflow_pairs"] == 0 and got["moe.combine_spills"] == 0
     assert got["moe.group_rows"] >= got["moe.pairs_held"]
     assert got["moe.group_pad_rows"] == got["moe.group_rows"] - got["moe.pairs_held"]
+    # every expert held at top-4: each of three sparse layers returned fused,
+    # in each of the batch's row groups (one: two short texts)
+    assert got["moe.fused_returns"] == 3
 
 
 def test_the_window_steps_count_the_pairs_their_tiling_meets(monkeypatch):

@@ -403,6 +403,7 @@ def test_the_counters_count_what_the_masks_let_through():
     assert count("hybrid.docs_over_window") == 4
     assert count("moe.pairs_routed") == 264 * 4 * 3 and 0 < count("moe.pairs_held") < 264 * 4 * 3
     assert count("moe.overflow_pairs") == 0
+    assert count("moe.fused_returns") == 0  # 4 of 16 held: the list's return
     # the flops the utilisation gauge takes for a document are those pairs' too
     from chipbench.architectures.moe_hybrid_decoder import costs
 

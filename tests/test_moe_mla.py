@@ -417,6 +417,7 @@ def test_return_to_the_tokens_equals_the_plain_sum(case):
     assert int(counts.sum()) == held_pairs.sum()
     assert int(stats["groups_aligned"]) == (layout == "aligned")
     assert int(stats["groups_packed"]) == (layout == "packed")
+    assert "fused_returns" not in stats  # 4 of 16 held: the list's return
     if case == "aligned-two-tiles-a-group":
         assert int(counts.min()) > M.PAIR_ROWS
     if int(overflow) == 0:
@@ -471,6 +472,7 @@ def test_routing_statistics_reach_the_span_record():
     assert totals["moe.combine_spills"]["count"] == 0
     assert totals["moe.groups_aligned"]["count"] == 0
     assert totals["moe.groups_packed"]["count"] == layers
+    assert totals["moe.fused_returns"]["count"] == 0
     # the unpacked form is the same trunk
     mask = (seg > 0).astype(np.int16)
     np.testing.assert_allclose(
@@ -608,6 +610,26 @@ def test_the_buffer_rule_is_pinned_at_the_cells_slabs(case):
     assert rows % M.PAIR_ROWS == 0
 
 
+# routed experts, held, k -> whether the return is the fused weighted sum
+_FUSED_RETURNS = {
+    "laguna-256-of-256-top8": (256, 256, 8, True),
+    "axk1-12-of-192-top8": (192, 12, 8, False),
+    "mimo-16-of-256-top8": (256, 16, 8, False),
+    "zaya-16-of-16-top1": (16, 16, 1, False),
+    # a held pair a token expected, but not every expert held: the list's
+    "tiny-4-of-16-top4": (16, 4, 4, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FUSED_RETURNS))
+def test_the_fused_return_engages_with_every_expert_held_at_top_k_over_one(case):
+    from pathway_tpu.models import moe_mla as M
+
+    routed, held, k, want = _FUSED_RETURNS[case]
+    config = M.MoeMlaConfig(n_routed_experts=routed, experts_held=held, experts_per_token=k)
+    assert M.returns_fused(config) is want
+
+
 @pytest.mark.parametrize("tokens", [40, 900])
 def test_top_one_with_every_expert_held_aligns_its_groups_and_lists_nothing(tokens):
     """`held_experts` under a routing handed in by the trunk (top-1 of 8
@@ -637,6 +659,7 @@ def test_top_one_with_every_expert_held_aligns_its_groups_and_lists_nothing(toke
     )
     assert int(overflow) == 0 and int(stats["multi_pair_tokens"]) == 0
     assert int(stats["combine_spills"]) == 0 and int(stats["groups_aligned"]) == 1
+    assert "fused_returns" not in stats  # top-1: one inverse permutation
     assert M.pair_capacity(tokens, config) >= tokens + config.experts_held * M.PAIR_ROWS
     want = np.zeros((tokens, config.hidden), np.float32)
     for e in range(skip):

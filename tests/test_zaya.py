@@ -556,6 +556,7 @@ def test_the_counters_follow_the_batches():
     assert grew("moe.pairs_held") == grew("moe.pairs_routed") - grew("zaya.skipped_tokens")
     assert grew("moe.groups_aligned") + grew("moe.groups_packed") == 3
     assert grew("moe.overflow_pairs") == 0 and grew("moe.multi_pair_tokens") == 0
+    assert grew("moe.fused_returns") == 0  # top-1: one inverse permutation
 
 
 def test_served_path_ingests_and_retrieves_with_the_embedder():
