@@ -13,7 +13,7 @@ engine-build time:
     (`pack_batch_dp`) and dispatches them with a `NamedSharding` on the
     batch axis through the existing async device pipeline — one
     in-flight window per dp replica;
-  * `models/transformer.TransformerLM.mesh_params` tp-shards the
+  * `models/trunk.TransformerLM.mesh_params` tp-shards the
     encoder weights with the partition rules from
     `param_sharding_rules`, so the matmuls run tensor-parallel.
 

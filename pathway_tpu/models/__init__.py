@@ -5,10 +5,10 @@ jax.sharding.Mesh."""
 
 from pathway_tpu.models.transformer import (
     TransformerConfig,
-    TransformerLM,
     init_params,
     param_sharding_rules,
 )
+from pathway_tpu.models.trunk import TransformerLM
 
 __all__ = [
     "TransformerConfig",

@@ -14,10 +14,8 @@ from typing import List, Sequence, Tuple
 import numpy as np
 
 from pathway_tpu.models.tokenizer import HashTokenizer, encode_batch
-from pathway_tpu.models.transformer import (
-    TransformerConfig,
-    TransformerLM,
-)
+from pathway_tpu.models.transformer import TransformerConfig
+from pathway_tpu.models.trunk import TransformerLM
 
 CROSS_ENCODER_CFG = TransformerConfig(
     vocab_size=30522, hidden=384, layers=4, heads=12, mlp_dim=1536,

@@ -21,6 +21,8 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
+from pathway_tpu.models.trunk import attention, rms_norm, rope
+
 
 @dataclasses.dataclass(frozen=True)
 class DecoderConfig:
@@ -114,38 +116,6 @@ def decoder_sharding_rules(config: DecoderConfig, mesh):
     }
 
 
-def _rms_norm(x, scale, eps):
-    import jax.numpy as jnp
-
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-    return (x32 * (1.0 / jnp.sqrt(var + eps)) * scale).astype(x.dtype)
-
-
-def _rope(x, positions, theta, *, freqs=None, interleaved=False):
-    """x: [B, H, L, D]; positions: [B, L] absolute token positions.
-    `freqs` [D/2] replaces the plain theta ladder (a scaled one, as YaRN's).
-    `interleaved`: the pairs are (x[2i], x[2i+1]), as the DeepSeek family
-    stores them, and not (x[i], x[i+D/2]); the output is in the split
-    layout either way, which q.k does not see as long as q and k agree."""
-    import jax.numpy as jnp
-
-    d = x.shape[-1]
-    half = d // 2
-    if freqs is None:
-        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = positions[:, None, :, None].astype(jnp.float32) * freqs  # B,1,L,half
-    cos, sin = jnp.cos(angles), jnp.sin(angles)
-    if interleaved:
-        x1, x2 = x[..., 0::2], x[..., 1::2]
-    else:
-        x1, x2 = x[..., :half], x[..., half:]
-    out = jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
-    )
-    return out.astype(x.dtype)
-
-
 def _repeat_kv(x, n_rep: int):
     import jax.numpy as jnp
 
@@ -218,12 +188,12 @@ def decoder_forward(params, config: DecoderConfig, ids, mask, *,
         )
 
     for li, layer in enumerate(params["layers"]):
-        y = _rms_norm(x, layer["ln1"], config.norm_eps)
+        y = rms_norm(x, layer["ln1"], config.norm_eps)
         q = (y @ layer["wq"].astype(compute_dtype)).reshape(b, l, qh, hd)
         k = (y @ layer["wk"].astype(compute_dtype)).reshape(b, l, kvh, hd)
         v = (y @ layer["wv"].astype(compute_dtype)).reshape(b, l, kvh, hd)
-        q = _rope(q.transpose(0, 2, 1, 3), positions, config.rope_theta)
-        k = _rope(k.transpose(0, 2, 1, 3), positions, config.rope_theta)
+        q = rope(q.transpose(0, 2, 1, 3), positions, config.rope_theta)
+        k = rope(k.transpose(0, 2, 1, 3), positions, config.rope_theta)
         v = v.transpose(0, 2, 1, 3)
 
         if kv_cache is not None:
@@ -243,9 +213,7 @@ def decoder_forward(params, config: DecoderConfig, ids, mask, *,
                 # kv_valid's first L slots, per the cache-mode contract) —
                 # O(L) flash path instead of a dense [B, H, L, max_len]
                 # f32 score matrix.
-                from pathway_tpu.models.transformer import _attention
-
-                ctx = _attention(
+                ctx = attention(
                     q, _repeat_kv(k, n_rep), _repeat_kv(v, n_rep),
                     kv_valid[:, :l], True, use_flash,
                 ).astype(compute_dtype)
@@ -263,16 +231,14 @@ def decoder_forward(params, config: DecoderConfig, ids, mask, *,
                     _repeat_kv(cv.astype(compute_dtype), n_rep),
                 )
         else:
-            from pathway_tpu.models.transformer import _attention
-
-            ctx = _attention(
+            ctx = attention(
                 q, _repeat_kv(k, n_rep), _repeat_kv(v, n_rep), mask,
                 True, use_flash,
             ).astype(compute_dtype)
 
         ctx = ctx.transpose(0, 2, 1, 3).reshape(b, l, config.hidden)
         x = x + ctx @ layer["wo"].astype(compute_dtype)
-        y = _rms_norm(x, layer["ln2"], config.norm_eps)
+        y = rms_norm(x, layer["ln2"], config.norm_eps)
         gate = y @ layer["gate"].astype(compute_dtype)
         up = y @ layer["up"].astype(compute_dtype)
         swish = gate * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
@@ -280,7 +246,7 @@ def decoder_forward(params, config: DecoderConfig, ids, mask, *,
         )
         x = x + (swish * up) @ layer["down"].astype(compute_dtype)
 
-    x = _rms_norm(x, params["ln_f"], config.norm_eps)
+    x = rms_norm(x, params["ln_f"], config.norm_eps)
     # HF Llama/Mistral checkpoints ship an untied lm_head; fall back to
     # weight tying (our from-scratch init) when absent
     head = params.get("lm_head", params["embed"])

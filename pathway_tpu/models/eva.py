@@ -11,7 +11,7 @@ Per layer, x [T, hidden] float32 (`fp32_skip_add`: the residual sums are
 float32), norm(x) = x / rms(x) * (1 + w) (`norm_add_unit_offset`), no biases:
 
   h = norm1(x); q, k, v = h W_q, h W_k, h W_v in heads of `head_dim`; RoPE
-  (`decoder._rope`'s pairs (x[i], x[i + d/2]), the whole head, positions
+  (`trunk.rope`'s pairs (x[i], x[i + d/2]), the whole head, positions
   restart at every document) on q and k
   the document's positions are cut into windows of `window_size` and chunks
   of `chunk_size`, both counted from its first token; for head h and every
@@ -34,8 +34,8 @@ attention softmax and the norms are f32.  Where each document's windows
 and summaries lie is worked out once for all layers from the segment ids
 (`ops/kernels/eva_attention.py::window_layout`); on the TPU the attention
 is that module's Pallas kernel, elsewhere its dense definition.  Row
-lengths come in the kernel's key tiles (`seq_bucket`), so a run of files
-whose byte lengths jitter compiles its slab shapes once.
+lengths come in pairs of the kernel's key tiles (`trunk.seq_bucket`), so
+a run of files whose byte lengths jitter compiles its slab shapes once.
 """
 
 from __future__ import annotations
@@ -45,11 +45,10 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
-from pathway_tpu.models.decoder import _rms_norm
-from pathway_tpu.models.moe_mla import (
-    CHUNK_TOKENS, _dtype, _normal, document_lengths, row_chunks,
+from pathway_tpu.models.trunk import (
+    CHUNK_TOKENS, PackedTrunk, PackedTrunkLM, _dtype, _normal, one_chip_only,
+    pooled_by_row_groups, rms_norm, slab_shapes,
 )
-from pathway_tpu.models.transformer import TransformerLM, _one_chip_only
 from pathway_tpu.ops.kernels import eva_attention as kernel
 
 
@@ -105,53 +104,14 @@ def scored_pairs(tokens, window: int, chunk: int):
     return keys, summaries
 
 
-# -- slab shapes: what `tokenizer.pack_batch` and `encode_batch` ask ---------------
-
-
-def seq_bucket(n: int, maximum: Optional[int] = None) -> int:
-    """A row's length: whole lanes up to two key tiles, whole pairs of key
-    tiles above.  A 900-word page is 6,671 +- 45 bytes: buckets of one
-    tile would put 6,656 between two files of one run; pairs put every
-    such page at 7,168.  `maximum` caps it, on the same grid."""
-    step = kernel.LANES if n <= 2 * kernel.KEY_TILE else 2 * kernel.KEY_TILE
-    if maximum is not None:
-        n = min(n, maximum)
-    return -(-max(n, 1) // step) * step
-
-
-def slab_length(lengths, budget: int, max_len: int = 0) -> int:
-    """The row length of a packed batch of documents `lengths` tokens long.
-    Attention costs a token the same wherever its row ends, so a batch
-    takes as few rows as it can: one of all its tokens up to a row group
-    of the trunk (`moe_mla.CHUNK_TOKENS` slots;
-    two pages of 3.9k and 6.7k bytes are one row of 11,264 slots, 6% of
-    them padding, where two rows of 7,168 would pad 26%), a row holding at
-    most PACK_MAX_SEGMENTS documents, and never less than the budget or the
-    longest document."""
-    from pathway_tpu.models.tokenizer import PACK_MAX_SEGMENTS
-
-    rows = -(-len(lengths) // PACK_MAX_SEGMENTS)
-    a_row = min(-(-sum(lengths) // rows), CHUNK_TOKENS)
-    return seq_bucket(max(budget, max(lengths), a_row))
-
-
-def row_bucket(rows: int) -> int:
-    """Rows of a packed slab: a power of two up to 8, whole eights above
-    (a row is thousands of slots: the encoders' floor of 8 rows would
-    multiply a two-page batch by four)."""
-    if rows <= 8:
-        return 1 << max(rows - 1, 0).bit_length()
-    return -(-rows // 8) * 8
-
-
 def tokenizer(config: EvaConfig):
     """The tokenizer a configuration of this module reads texts with, and
     the slab shapes its kernel takes (`minilm.SentenceEncoder`)."""
-    from pathway_tpu.models.tokenizer import ByteTokenizer, SlabShapes
+    from pathway_tpu.models.tokenizer import ByteTokenizer
 
     return ByteTokenizer(
         vocab_size=config.vocab_size,
-        shapes=SlabShapes(seq_bucket, row_bucket, slab_length),
+        shapes=slab_shapes(kernel.LANES, 2 * kernel.KEY_TILE, CHUNK_TOKENS),
     )
 
 
@@ -197,12 +157,12 @@ def init_params(rng, config: EvaConfig) -> Dict[str, Any]:
     return params
 
 
-# what `_one_chip_only` says of this trunk: module, what it holds, what is not built
+# what `one_chip_only` says of this trunk: module, what it holds, what is not built
 _ONE_CHIP = ("eva", "one pipeline stage", "the hand-over between stages")
 
 
 def param_sharding_rules(config: EvaConfig, mesh):
-    _one_chip_only(mesh, *_ONE_CHIP)
+    one_chip_only(mesh, *_ONE_CHIP)
 
 
 def packed_attention_fused(config: EvaConfig, length: int,
@@ -275,7 +235,7 @@ def _into_hidden(a, w_t):
 def _rotate(x, cos, sin, scale: float):
     """`kernel.rope`'s definition, and the path off the TPU: x [B, L,
     hidden], cos, sin [B, L, head_dim] = [cos | cos], [-sin | sin];
-    `decoder._rope`'s pairs (x[i], x[i + d/2]) without its [B, H, L, D]
+    `trunk.rope`'s pairs (x[i], x[i + d/2]) without its [B, H, L, D]
     contract's two transposes a slab."""
     import jax.numpy as jnp
 
@@ -290,7 +250,7 @@ def _attention(x, layer, config: EvaConfig, layout, rope, fused: bool):
     float32; rope: (cos, sin) of `_rotate`."""
     c = config
     dt = _dtype(c.dtype)
-    h = _rms_norm(x, 1.0 + layer["ln1"], c.norm_eps).astype(dt)
+    h = rms_norm(x, 1.0 + layer["ln1"], c.norm_eps).astype(dt)
     rotate = kernel.rope if fused else _rotate
     q = rotate(h @ layer["wq"].astype(dt), *rope, scale=c.head_dim ** -0.5)
     k = rotate(h @ layer["wk"].astype(dt), *rope, scale=1.0)
@@ -323,10 +283,10 @@ def _trunk(params, config: EvaConfig, ids, seg, max_segments: int, fused: bool):
     x = params["embed"][ids].astype(jnp.float32)
     for layer in params["layers"]:
         x = x + _attention(x, layer, c, layout, rope, fused).astype(jnp.float32)
-        h = _rms_norm(x, 1.0 + layer["ln2"], c.norm_eps).astype(dt)
+        h = rms_norm(x, 1.0 + layer["ln2"], c.norm_eps).astype(dt)
         mlp = (jax.nn.silu(h @ layer["gate"].astype(dt)) * (h @ layer["up"].astype(dt)))
         x = x + _into_hidden(mlp, layer["down"].astype(dt)).astype(jnp.float32)
-    x = _rms_norm(x, 1.0 + params["ln_f"], c.norm_eps).astype(dt)
+    x = rms_norm(x, 1.0 + params["ln_f"], c.norm_eps).astype(dt)
     # per-segment mean pooling on the MXU, as transformer.forward pools; the
     # sum over a page's thousands of tokens stays f32
     oh = (seg[:, :, None] == jnp.arange(1, max_segments + 1)[None, None, :]).astype(dt)
@@ -350,76 +310,44 @@ def forward(
     int32 -> pooled unit vectors [B, hidden]; packed (seg is not None): [B,
     max_segments, hidden], one per packed document, mask ignored.  The
     unpacked form IS the packed one with one segment a row, so the two
-    cannot drift.  A slab over `moe_mla.CHUNK_TOKENS` slots runs as equal
+    cannot drift.  A slab over `trunk.CHUNK_TOKENS` slots runs as equal
     groups of rows inside the one program (a round of eight queries of
     7,168 slots is four groups of two)."""
-    import jax
     import jax.numpy as jnp
 
-    _one_chip_only(mesh, *_ONE_CHIP)
+    one_chip_only(mesh, *_ONE_CHIP)
     packed = seg is not None
     if not packed:
         seg, max_segments = (mask > 0).astype(jnp.int32), 1
-    b, l = ids.shape
-    fused = packed_attention_fused(config, l, use_flash)
-    n = row_chunks(b, l)
-    if n == 1:
-        pooled = _trunk(params, config, ids, seg, max_segments, fused)
-    else:
-        pooled = jax.lax.map(
-            lambda part: _trunk(params, config, *part, max_segments, fused),
-            (ids.reshape(n, b // n, l), seg.reshape(n, b // n, l)),
-        ).reshape(b, max_segments, config.hidden)
+    fused = packed_attention_fused(config, ids.shape[1], use_flash)
+    pooled, _ = pooled_by_row_groups(
+        lambda ids, seg: (_trunk(params, config, ids, seg, max_segments, fused), {}), ids, seg
+    )
     return pooled if packed else pooled[:, 0, :]
 
 
-class EvaLM(TransformerLM):
-    """`TransformerLM` for this trunk: the same entry points, its packed
-    program under a name of its own, and what the attention of each packed
-    batch scores (`eva.scored_pairs`: what the mask lets through) and what
-    the kernel's steps meet to score it (`eva.met_pairs`) counted into the
-    span record (`eva.*`, internals/tracing.py) from the segment ids, on
-    the host."""
+def _count_batch(config: EvaConfig, ids, seg, lengths) -> None:
+    """`eva.*`: what a packed batch's attention scores (`scored_pairs`) and
+    what the kernel's steps meet to score it (`kernel.met_pairs`)."""
+    from pathway_tpu.internals import tracing
 
-    def __init__(self, config: EvaConfig, params=None, seed: int = 0):
-        import jax
-
-        super().__init__(config, params=params, seed=seed)
-
-        def _fwd_packed_eva(params, ids, seg, max_segments):
-            import jax.numpy as jnp
-
-            return forward(
-                params, config, ids.astype(jnp.int32), None,
-                seg=seg.astype(jnp.int32), max_segments=max_segments,
-            )
-
-        self._packed_jit = jax.jit(_fwd_packed_eva, static_argnums=(3,))
-
-    def encode_packed(self, ids, seg, max_segments: int, *, params=None,
-                      mesh=None):
-        _one_chip_only(mesh, *_ONE_CHIP)
-        from pathway_tpu.internals import tracing
-
-        c = self.config
-        seg = np.asarray(seg)
-        lengths = document_lengths(seg, max_segments)
-        keys, summaries = scored_pairs(lengths, c.window_size, c.chunk_size)
-        a_pair = c.heads * c.layers  # a pair is counted once a head and layer
-        tracing.add("eva.tokens", n=int(lengths.sum()))
-        tracing.add("eva.scored_pairs", n=int(keys.sum() + summaries.sum()) * a_pair)
-        tracing.add("eva.summary_pairs", n=int(summaries.sum()) * a_pair)
-        tracing.add("eva.docs_multi_window", n=int((lengths > c.window_size).sum()))
-        # what the kernel's steps make of them: the block pairs and summary
-        # tiles they score, and those of them that needed no mask
-        met, unmasked = kernel.met_pairs(
-            kernel.window_layout(seg.astype(np.int32), c.window_size, c.chunk_size, xp=np)
-        )
-        tracing.add("eva.met_pairs", n=met * a_pair)
-        tracing.add("eva.unmasked_pairs", n=unmasked * a_pair)
-        return self._packed_jit(
-            self.params if params is None else params, ids, seg, int(max_segments)
-        )
+    c = config
+    seg = np.asarray(seg)
+    keys, summaries = scored_pairs(lengths, c.window_size, c.chunk_size)
+    a_pair = c.heads * c.layers  # a pair is counted once a head and layer
+    tracing.add("eva.tokens", n=int(lengths.sum()))
+    tracing.add("eva.scored_pairs", n=int(keys.sum() + summaries.sum()) * a_pair)
+    tracing.add("eva.summary_pairs", n=int(summaries.sum()) * a_pair)
+    tracing.add("eva.docs_multi_window", n=int((lengths > c.window_size).sum()))
+    # what the kernel's steps make of them: the block pairs and summary
+    # tiles they score, and those of them that needed no mask
+    met, unmasked = kernel.met_pairs(
+        kernel.window_layout(seg.astype(np.int32), c.window_size, c.chunk_size, xp=np)
+    )
+    tracing.add("eva.met_pairs", n=met * a_pair)
+    tracing.add("eva.unmasked_pairs", n=unmasked * a_pair)
 
 
-LM = EvaLM
+PACKED = PackedTrunk("_fwd_packed_eva", lambda config: _ONE_CHIP, count_batch=_count_batch)
+
+LM = PackedTrunkLM

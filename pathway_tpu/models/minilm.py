@@ -19,11 +19,8 @@ from pathway_tpu.models.tokenizer import (
     pack_batch,
     pack_token_budget,
 )
-from pathway_tpu.models.transformer import (
-    MINILM_L6,
-    TransformerConfig,
-    model_module,
-)
+from pathway_tpu.models.transformer import MINILM_L6, TransformerConfig
+from pathway_tpu.models.trunk import model_module
 
 _model_cache: dict = {}
 

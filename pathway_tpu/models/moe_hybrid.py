@@ -53,9 +53,9 @@ keep `wq`, `wk`, `wv` whole: RoPE turns the rotated dims in place and the
 kernel reads q and k as one operand each.  One kernel for both layouts and
 both kinds (`ops/kernels/hybrid_attention.py`); off the TPU and on shapes
 its tiling does not cover, its dense definition runs.  The expert layer
-IS `moe_mla.held_experts` over `moe_mla.route` (adapted there: the
+IS `experts.held_experts` over `experts.route` (adapted there: the
 selection bias; any k and any share held), the shared expert
-`moe_mla._swiglu`, with the counters `moe.*`.
+`experts.swiglu`, with the counters `moe.*`.
 """
 
 from __future__ import annotations
@@ -65,20 +65,19 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
-from pathway_tpu.models.decoder import _rms_norm
-from pathway_tpu.models.eva import row_bucket  # rows of thousands of slots: a power of two up to 8
-from pathway_tpu.models.moe_mla import (
-    MoeMlaLM,
+from pathway_tpu.models.experts import count_stats, held_experts, layer_pass_lists, swiglu
+from pathway_tpu.models.trunk import (
+    PackedTrunk,
+    PackedTrunkLM,
     _dtype,
     _normal,
-    _swiglu,
-    document_lengths,
-    held_experts,
-    layer_pass_lists,
+    one_chip_only,
+    packed_positions,
     pooled_by_row_groups,
+    rms_norm,
+    slab_shapes,
     yarn_ladder,
 )
-from pathway_tpu.models.transformer import _one_chip_only, _packed_positions
 from pathway_tpu.ops.kernels import hybrid_attention as kernel
 
 # MiMo-V2.5's `hybrid_layer_pattern` (0 global, 1 window), 48 layers
@@ -213,53 +212,26 @@ def scored_pairs(tokens, window: Optional[int]):
 
 # token slots the trunk takes at a time: a slab over this runs as equal
 # groups of rows, one after the other inside the one program
-# (`moe_mla.pooled_by_row_groups`).  What bounds it is the bytes of a row group's
+# (`trunk.pooled_by_row_groups`).  What bounds it is the bytes of a row group's
 # activations at this width, not a count of slots: the widest arrays are
 # the dense layer's [slots, 16384] gate and up and the heads' [slots,
 # 12288] queries, in bf16 80 KB a slot, 2.0 GB at 24,576 slots, which two
 # dispatches in flight hold twice beside 6.7 GB of parameters and a 1.1 GB
-# store on a 16 GB chip.  `moe_mla.CHUNK_TOKENS` (16,384) was set at a width
+# store on a 16 GB chip.  `trunk.CHUNK_TOKENS` (16,384) was set at a width
 # of 7168 and a dense layer of 18,432 and would cut this trunk's dispatch
 # of 24,504 tokens into two rows with 25% padding
 ROW_TOKENS = 24576
-
-
-def seq_bucket(n: int, maximum: Optional[int] = None) -> int:
-    """A row's length: whole lanes up to one block of the global kind,
-    whole such blocks above, so that both kinds' tilings divide it and
-    documents whose lengths jitter by a few words compile one slab.
-    `maximum` caps it, on the same grid."""
-    step = kernel.LANES if n <= kernel.GLOBAL_BLOCK else kernel.GLOBAL_BLOCK
-    if maximum is not None:
-        n = min(n, maximum)
-    return -(-max(n, 1) // step) * step
-
-
-def slab_length(lengths, budget: int, max_len: int = 0) -> int:
-    """The row length of a packed batch of documents `lengths` tokens
-    long.  A window layer costs a token the same wherever its row ends and
-    a global layer only meets a document's own blocks, so a batch takes as
-    few rows as it can: one of all its tokens up to a row group of the
-    trunk (ROW_TOKENS slots: documents of 8,502 and 16,002 tokens are one
-    row of 24,576 slots, 0.3% of them padding, where two rows of 16,384
-    would pad 25%), a row holding at most PACK_MAX_SEGMENTS documents, and
-    never less than the budget or the longest document."""
-    from pathway_tpu.models.tokenizer import PACK_MAX_SEGMENTS
-
-    rows = -(-len(lengths) // PACK_MAX_SEGMENTS)
-    a_row = min(-(-sum(lengths) // rows), ROW_TOKENS)
-    return seq_bucket(max(budget, max(lengths), a_row))
 
 
 def tokenizer(config: MoeHybridConfig):
     """The tokenizer a configuration of this module reads texts with (one
     hashed id a word, from the rows of the embedding held here), and the
     slab shapes its kernel takes (`minilm.SentenceEncoder`)."""
-    from pathway_tpu.models.tokenizer import HashTokenizer, SlabShapes
+    from pathway_tpu.models.tokenizer import HashTokenizer
 
     return HashTokenizer(
         vocab_size=config.vocab_size,
-        shapes=SlabShapes(seq_bucket, row_bucket, slab_length),
+        shapes=slab_shapes(kernel.LANES, kernel.GLOBAL_BLOCK, ROW_TOKENS),
     )
 
 
@@ -365,7 +337,7 @@ def init_params(rng, config: MoeHybridConfig) -> Dict[str, Any]:
 
 
 def _one_chip(config: MoeHybridConfig) -> tuple:
-    """What `_one_chip_only` says of this trunk: module, what the chip
+    """What `one_chip_only` says of this trunk: module, what the chip
     holds, what is not built."""
     if config.pp_size > 1:
         return ("moe_hybrid", f"stage 0 of {config.pp_size}", "the hand-over between stages")
@@ -373,7 +345,7 @@ def _one_chip(config: MoeHybridConfig) -> tuple:
 
 
 def param_sharding_rules(config: MoeHybridConfig, mesh):
-    _one_chip_only(mesh, *_one_chip(config))
+    one_chip_only(mesh, *_one_chip(config))
 
 
 def packed_attention_fused(config: MoeHybridConfig, length: int,
@@ -404,7 +376,7 @@ def packed_attention_fused(config: MoeHybridConfig, length: int,
 def rope_ladder(config: MoeHybridConfig, window: bool) -> tuple:
     """A kind's ladder for heads rotated in place (`whole_heads`):
     (frequencies [rotary / 2] f32, the attention factor that multiplies
-    cos and sin): YaRN's (`moe_mla.yarn_ladder`) where the kind has one,
+    cos and sin): YaRN's (`trunk.yarn_ladder`) where the kind has one,
     else theta^(-2i/rotary) and 1."""
     c = config
     rot = c.rotary(window)
@@ -449,7 +421,7 @@ def _attention(x, layer, config: MoeHybridConfig, window: bool, seg, rope, lo, f
     c = config
     b, l, _ = x.shape
     dt = x.dtype
-    h = _rms_norm(x, layer["ln1"], c.norm_eps)
+    h = rms_norm(x, layer["ln1"], c.norm_eps)
     scale = c.head_dim ** -0.5
     if c.whole_heads:
         q = rotate_heads(h @ layer["wq"].astype(dt), *rope, c.head_dim, scale=scale)
@@ -483,13 +455,13 @@ def _attention(x, layer, config: MoeHybridConfig, window: bool, seg, rope, lo, f
 
 def _trunk(params, config: MoeHybridConfig, ids, seg, max_segments: int, fused: bool):
     """ids, seg: [B, L] -> (pooled unit vectors [B, max_segments, hidden]
-    f32, `moe_mla._trunk`'s statistics of the expert layers)."""
+    f32, the expert layers' statistics: `experts.layer_pass_lists`)."""
     import jax.numpy as jnp
 
     c = config
     b, l = ids.shape
     dt = _dtype(c.dtype)
-    pos = _packed_positions(seg)
+    pos = packed_positions(seg)
     valid = (seg > 0).reshape(-1)
     # what differs by kind and not by layer, once: the ladder's angles and
     # the first key block a block of queries of a global layer meets
@@ -506,7 +478,7 @@ def _trunk(params, config: MoeHybridConfig, ids, seg, max_segments: int, fused: 
     for i, layer in enumerate(params["layers"]):
         window = c.is_window(i)
         x = x + _attention(x, layer, c, window, seg, *by_kind[window], fused)
-        h = _rms_norm(x, layer["ln2"], c.norm_eps)
+        h = rms_norm(x, layer["ln2"], c.norm_eps)
         if "router" in layer:
             routed, counts, over, more = held_experts(
                 h.reshape(b * l, c.hidden), valid, layer, c, with_stats=True
@@ -515,10 +487,10 @@ def _trunk(params, config: MoeHybridConfig, ids, seg, max_segments: int, fused: 
                 stats[name].append(value[None])
             x = x + routed.reshape(b, l, c.hidden)
             if "shared_gate" in layer:
-                x = x + _swiglu(h, layer["shared_gate"], layer["shared_up"], layer["shared_down"])
+                x = x + swiglu(h, layer["shared_gate"], layer["shared_up"], layer["shared_down"])
         else:
-            x = x + _swiglu(h, layer["gate"], layer["up"], layer["down"])
-    x = _rms_norm(x, params["ln_f"], c.norm_eps)
+            x = x + swiglu(h, layer["gate"], layer["up"], layer["down"])
+    x = rms_norm(x, params["ln_f"], c.norm_eps)
     # per-segment mean pooling on the MXU, as transformer.forward pools; the
     # sum over a document's thousands of tokens stays f32
     oh = (seg[:, :, None] == jnp.arange(1, max_segments + 1)[None, None, :]).astype(dt)
@@ -549,7 +521,7 @@ def forward(
     is eight groups of one).  `with_stats`: as `moe_mla.forward`."""
     import jax.numpy as jnp
 
-    _one_chip_only(mesh, *_one_chip(config))
+    one_chip_only(mesh, *_one_chip(config))
     packed = seg is not None
     if not packed:
         seg, max_segments = (mask > 0).astype(jnp.int32), 1
@@ -565,55 +537,35 @@ def forward(
     return pooled, dict(stats, tokens=(seg > 0).sum(dtype=jnp.int32))
 
 
-class MoeHybridLM(MoeMlaLM):
-    """`MoeMlaLM` for this trunk: its entry points and its routing
-    statistics (`moe.*`), the packed program under a name of its own, and
-    what the attention of each packed batch scores, by kind, and what the
-    kernel's window steps meet to score it, counted into the span record
-    (`hybrid.*`, internals/tracing.py) from the segment lengths and the
-    slab's shape, on the host."""
+def _count_batch(config: MoeHybridConfig, ids, seg, lengths) -> None:
+    """`hybrid.*`: what a packed batch's attention scores, by kind, and
+    what the kernel's window steps meet to score it."""
+    from pathway_tpu.internals import tracing
 
-    def _packed_program(self):
-        config = self.config
-
-        def _fwd_packed_moe_hybrid(params, ids, seg, max_segments):
-            import jax.numpy as jnp
-
-            return forward(
-                params, config, ids.astype(jnp.int32), None,
-                seg=seg.astype(jnp.int32), max_segments=max_segments,
-                with_stats=True,
-            )
-
-        return _fwd_packed_moe_hybrid
-
-    def encode_packed(self, ids, seg, max_segments: int, *, params=None,
-                      mesh=None):
-        _one_chip_only(mesh, *_one_chip(self.config))
-        from pathway_tpu.internals import tracing
-
-        c = self.config
-        lengths = document_lengths(seg, max_segments)
-        # a pair is counted once a query head (of its kind) and layer
-        global_pairs = int(scored_pairs(lengths, None).sum()) * c.q_heads(False) * (
-            c.layers - c.window_layers
-        )
-        window_pairs = (
-            int(scored_pairs(lengths, c.window).sum()) * c.q_heads(True) * c.window_layers
-        )
-        tracing.add("hybrid.tokens", n=int(lengths.sum()))
-        tracing.add("hybrid.scored_pairs", n=global_pairs + window_pairs)
-        tracing.add("hybrid.global_pairs", n=global_pairs)
-        tracing.add("hybrid.window_pairs", n=window_pairs)
-        # the kernel's window steps: every block of queries of the slab
-        # against its key views, whole blocks (the tiling's count whichever
-        # path ran; over `hybrid.window_pairs`, the tiling's padding)
-        rows, length = np.shape(ids)
-        block, n_q, views = kernel.window_tiling(length, c.window)
-        tracing.add("hybrid.window_met_pairs", n=rows * n_q * views * block * block
-                    * c.q_heads(True) * c.window_layers)
-        tracing.add("hybrid.docs_over_window", n=int((lengths > c.window).sum()))
-        return super().encode_packed(ids, seg, max_segments, params=params)
+    c = config
+    # a pair is counted once a query head (of its kind) and layer
+    global_pairs = int(scored_pairs(lengths, None).sum()) * c.q_heads(False) * (
+        c.layers - c.window_layers
+    )
+    window_pairs = (
+        int(scored_pairs(lengths, c.window).sum()) * c.q_heads(True) * c.window_layers
+    )
+    tracing.add("hybrid.tokens", n=int(lengths.sum()))
+    tracing.add("hybrid.scored_pairs", n=global_pairs + window_pairs)
+    tracing.add("hybrid.global_pairs", n=global_pairs)
+    tracing.add("hybrid.window_pairs", n=window_pairs)
+    # the kernel's window steps: every block of queries of the slab
+    # against its key views, whole blocks (the tiling's count whichever
+    # path ran; over `hybrid.window_pairs`, the tiling's padding)
+    rows, length = np.shape(ids)
+    block, n_q, views = kernel.window_tiling(length, c.window)
+    tracing.add("hybrid.window_met_pairs", n=rows * n_q * views * block * block
+                * c.q_heads(True) * c.window_layers)
+    tracing.add("hybrid.docs_over_window", n=int((lengths > c.window).sum()))
 
 
-LM = MoeHybridLM
+PACKED = PackedTrunk(
+    "_fwd_packed_moe_hybrid", _one_chip, count_batch=_count_batch, count_stats=count_stats
+)
+
+LM = PackedTrunkLM

@@ -18,13 +18,17 @@ TPU-first choices:
 from __future__ import annotations
 
 import dataclasses
-import functools
-import importlib
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional
 
 import numpy as np
 
-from pathway_tpu.internals import tracing
+from pathway_tpu.models.trunk import (  # noqa: F401  (`tokenizer`: model_module's)
+    TransformerLM,
+    attention,
+    mesh_axis,
+    packed_positions,
+    tokenizer,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -130,67 +134,6 @@ def _layer_norm(x, scale, bias, eps=1e-6):
     return (out * scale + bias).astype(x.dtype)
 
 
-def _attention(q, k, v, mask, causal: bool, use_flash, mesh=None):
-    """Dispatch between the Pallas flash kernel (TPU; O(L) memory) and the
-    dense XLA path. q,k,v: [B,H,L,D]; mask: [B,L]. `mesh`: the mesh the
-    surrounding jit is partitioned over, when there is one."""
-    import jax
-
-    if use_flash is None:
-        # flash where O(L^2) score materialization hurts, dense at short L;
-        # this gate's crossover is not measured on this machine (ROADMAP
-        # queue 3 item 6: it has no cell on either side yet).  The packed
-        # side was (PR 28, `packed_attention_fused`): there dense scores
-        # lose to a kernel that keeps a slab row in VMEM from L 32-256 up,
-        # which says the crossover is low, not where it is for this
-        # kernel (f32 operands, head_dim padded to 128 lanes in HBM)
-        use_flash = jax.default_backend() == "tpu" and q.shape[2] > 256
-    if use_flash:
-        from pathway_tpu.ops.kernels import flash_attention
-
-        if mesh is None:
-            return flash_attention(q, k, v, mask, causal=causal)
-        return _flash_attention_on_mesh(mesh, q, k, v, mask, causal)
-
-    # dense path shares the flash kernel's numerical definition (it is also
-    # the kernel's custom_vjp backward), so the two can't drift apart
-    from pathway_tpu.ops.kernels.flash_attention import _reference_attention
-
-    return _reference_attention(
-        q, k, v, mask, 1.0 / np.sqrt(q.shape[3]), causal
-    )
-
-
-def _mesh_axis(mesh, name: str, size: int):
-    """`name` if the mesh has that axis and it divides `size`, else None
-    (replicated): the shard_map spec of a kernel's batch or head axis."""
-    fits = name in mesh.axis_names and size % mesh.shape[name] == 0
-    return name if fits else None
-
-
-def _flash_attention_on_mesh(mesh, q, k, v, mask, causal: bool):
-    """Mosaic kernels cannot be partitioned automatically ("wrap the call
-    in a shard_map", the TPU compiler says): inside a jit that spans a
-    mesh the kernel runs per device under shard_map — batch rows over
-    'dp' and heads over 'tp' (where the Megatron qkv split already puts
-    them) when they divide, replicated otherwise."""
-    from jax import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    from pathway_tpu.ops.kernels import flash_attention
-
-    dp = _mesh_axis(mesh, "dp", q.shape[0])
-    tp = _mesh_axis(mesh, "tp", q.shape[1])
-    qkv = P(dp, tp, None, None)
-    return shard_map(
-        lambda q, k, v, m: flash_attention(q, k, v, m, causal=causal),
-        mesh=mesh,
-        in_specs=(qkv, qkv, qkv, P(dp, None)),
-        out_specs=qkv,
-        check_vma=False,
-    )(q, k, v, mask)
-
-
 def _segment_attention(q, k, v, seg, sm_scale):
     """Dense attention with a pairwise same-segment mask for packed
     ragged batches. q,k,v: [B,H,L,D]; seg: [B,L] int32, 1..S per packed
@@ -256,7 +199,7 @@ def packed_attention_fused(config: TransformerConfig, length: int,
 def _fused_segment_attention(qkv, seg, heads: int, mesh=None):
     """The fused kernel over qkv [B, L, 3·hidden] as the QKV matmul left
     it. Inside a jit that spans a mesh it runs per device under
-    shard_map, like `_flash_attention_on_mesh`: slab rows over 'dp' when
+    shard_map, like `trunk.attention`'s flash kernel: slab rows over 'dp' when
     they divide (pack_batch_dp pads replicas to a common block), the
     hidden axis whole on every device."""
     from pathway_tpu.ops.kernels.segment_attention import segment_attention
@@ -266,7 +209,7 @@ def _fused_segment_attention(qkv, seg, heads: int, mesh=None):
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    dp = _mesh_axis(mesh, "dp", qkv.shape[0])
+    dp = mesh_axis(mesh, "dp", qkv.shape[0])
     return shard_map(
         lambda qkv, seg: segment_attention(qkv, seg, heads),
         mesh=mesh,
@@ -274,25 +217,6 @@ def _fused_segment_attention(qkv, seg, heads: int, mesh=None):
         out_specs=P(dp, None, None),
         check_vma=False,
     )(qkv, seg)
-
-
-def _packed_positions(seg):
-    """Per-token positions that RESTART at every segment boundary, so a
-    packed doc reads the same pos_embed rows it would alone. Computed on
-    device from seg (no third wire upload): a token starts a segment
-    where seg differs from its left neighbor; cummax propagates each
-    segment's start index rightward."""
-    import jax
-    import jax.numpy as jnp
-
-    l = seg.shape[1]
-    pos = jnp.broadcast_to(jnp.arange(l, dtype=jnp.int32)[None, :], seg.shape)
-    is_start = jnp.concatenate(
-        [jnp.ones_like(seg[:, :1], dtype=bool), seg[:, 1:] != seg[:, :-1]],
-        axis=1,
-    )
-    seg_start = jax.lax.cummax(jnp.where(is_start, pos, 0), axis=1)
-    return pos - seg_start
 
 
 def forward(
@@ -329,7 +253,7 @@ def forward(
     if seg is not None:
         if config.causal:
             raise ValueError("packed segment batching requires a bidirectional encoder")
-        pos = _packed_positions(seg)
+        pos = packed_positions(seg)
         x = params["embed"][ids] + params["pos_embed"][pos]
         fused = packed_attention_fused(config, l, use_flash)
     else:
@@ -365,7 +289,7 @@ def forward(
             if seg is not None:
                 ctx = _segment_attention(q, k, v, seg, 1.0 / np.sqrt(hd))
             else:
-                ctx = _attention(
+                ctx = attention(
                     q, k, v, mask, config.causal, use_flash, mesh
                 )
             ctx = ctx.astype(compute_dtype)
@@ -438,145 +362,6 @@ def forward(
         jnp.linalg.norm(pooled, axis=-1, keepdims=True) + 1e-9
     )
     return pooled
-
-
-def model_module(config):
-    """The module of the model a configuration belongs to: the one that
-    defines the configuration's type.  It has that model's `forward`,
-    `init_params`, `param_sharding_rules`, `packed_attention_fused`,
-    `tokenizer` and `LM` (this module for a `TransformerConfig`,
-    `models/moe_mla.py` for a `MoeMlaConfig`, `models/eva.py` for an
-    `EvaConfig`, `models/moe_hybrid.py` for a `MoeHybridConfig`,
-    `models/zaya.py` for a `ZayaConfig`).  The one rule by which `TransformerLM`, the encoders and
-    the fused programs of `ops/knn.py` find a configuration's model."""
-    return importlib.import_module(type(config).__module__)
-
-
-def _one_chip_only(mesh, module: str, holds: str, elsewhere: str) -> None:
-    """The one refusal of a mesh, for the trunks that run a single chip's
-    share of a deployment (`moe_mla`, `moe_hybrid`: one expert-parallel
-    rank; `eva`, `zaya`: one pipeline stage): `module` holds `holds` on one chip,
-    and what would join the chips (`elsewhere`) is not built."""
-    if mesh is not None:
-        raise NotImplementedError(
-            f"{module} runs {holds} on one chip: {elsewhere}, and so a mesh, "
-            "is not built (PERF.md section 7)"
-        )
-
-
-def tokenizer(config):
-    """The tokenizer a configuration without a checkpoint's vocabulary
-    reads texts with: one hashed id a word, from the rows its embedding
-    holds."""
-    from pathway_tpu.models.tokenizer import HashTokenizer
-
-    return HashTokenizer(vocab_size=config.vocab_size)
-
-
-class TransformerLM:
-    """Bundles config+params with jitted entry points."""
-
-    def __init__(self, config, params=None, seed: int = 0):
-        import jax
-
-        self.config = config
-        model = model_module(config)
-        if params is None:
-            # the host's time to make and place the parameters (it waits
-            # for no device): rows are the leaves
-            with tracing.span("setup.weights") as made:
-                params = model.init_params(jax.random.PRNGKey(seed), config)
-                made.rows = len(jax.tree_util.tree_leaves(params))
-        self.params = params
-
-        def _fwd(params, ids, mask, mesh=None):
-            # narrow wire dtypes (tokenizer._wire_dtype policy) upcast on
-            # device: 16-bit ids/mask halve the token upload vs int32
-            import jax.numpy as jnp
-
-            return model.forward(
-                params,
-                config=self.config,
-                ids=ids.astype(jnp.int32),
-                mask=mask.astype(jnp.int32),
-                mesh=mesh,
-            )
-
-        # the mesh (hashable) is static: one executable per mesh and shape
-        self._encode_jit = jax.jit(_fwd, static_argnames=("mesh",))
-
-        def _fwd_packed(params, ids, seg, max_segments, mesh=None):
-            import jax.numpy as jnp
-
-            return model.forward(
-                params,
-                config=self.config,
-                ids=ids.astype(jnp.int32),
-                mask=None,
-                seg=seg.astype(jnp.int32),
-                max_segments=max_segments,
-                mesh=mesh,
-            )
-
-        # max_segments is a static one-hot width; callers pass a fixed
-        # constant (tokenizer.PACK_MAX_SEGMENTS) so there is one compile
-        # per (R, L) slab shape, same cache discipline as the classic path
-        self._packed_jit = jax.jit(
-            _fwd_packed, static_argnums=(3,), static_argnames=("mesh",)
-        )
-        self._mesh_params: tuple | None = None
-
-    def mesh_params(self, mesh):
-        """Tensor-parallel copy of the weights for a mesh backend: each
-        array device_put once under the `param_sharding_rules` partition
-        specs (qkv/up column-, out/down row-sharded on 'tp'), cached per
-        mesh. `self.params` — and every caller that doesn't opt in via
-        the `params=` override — keeps its exact single-device layout."""
-        cached = self._mesh_params
-        if cached is not None and cached[0] is mesh:
-            return cached[1]
-        import jax
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as P
-
-        rules = model_module(self.config).param_sharding_rules(self.config, mesh)
-        shardings = jax.tree_util.tree_map(
-            lambda spec: NamedSharding(mesh, spec),
-            rules,
-            is_leaf=lambda x: isinstance(x, P),
-        )
-        placed = jax.device_put(self.params, shardings)
-        self._mesh_params = (mesh, placed)
-        return placed
-
-    def encode_packed(self, ids, seg, max_segments: int, *, params=None,
-                      mesh=None):
-        """Packed ragged encode: ids/seg from tokenizer.pack_batch (wire
-        dtypes; upcast on device). Returns [R, max_segments, H] pooled
-        L2-normalized vectors; empty slots are zero. Inputs are NOT
-        donated — the device-side int upcast changes the buffer dtype, so
-        XLA could never reuse them and would warn on every dispatch.
-        `mesh`: pass it whenever params or inputs are sharded over one."""
-        return self._packed_jit(
-            self.params if params is None else params,
-            ids,
-            seg,
-            int(max_segments),
-            mesh=mesh,
-        )
-
-    def __call__(self, ids, mask, *, params=None, mesh=None):
-        # ids/mask arrive already wire-narrowed by encode_batch (tokenizer
-        # _wire_dtype is the single policy); no host casts here — a cast
-        # would pull mesh-sharded inputs back to host and destroy their
-        # NamedSharding placement.  `mesh`: pass it whenever params or
-        # inputs are sharded over one (see forward)
-        return self._encode_jit(
-            self.params if params is None else params,
-            ids=ids,
-            mask=mask,
-            mesh=mesh,
-        )
 
 
 LM = TransformerLM  # `model_module(config).LM`

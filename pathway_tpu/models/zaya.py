@@ -44,7 +44,7 @@ no head (tied, on the last stage), no cache, no decode state of the
 convolutions and the shift, no hand-over of (x, r) to the next stage
 (PERF.md section 7).
 
-Program shape.  The expert layer IS `moe_mla.held_experts`, which takes
+Program shape.  The expert layer IS `experts.held_experts`, which takes
 this router's choice from here (`routing=`): "skip" is an expert index
 nobody holds, so it is routed, not held, and computes nothing.  The
 attention kernel is `ops/kernels/cca_attention.py` (off the TPU its dense
@@ -67,19 +67,16 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
-from pathway_tpu.models.decoder import _rms_norm
-from pathway_tpu.models.moe_mla import (
-    MoeMlaLM,
+from pathway_tpu.models.experts import count_stats, held_experts, layer_pass_lists
+from pathway_tpu.models.trunk import (  # noqa: F401  (`tokenizer`: model_module's)
+    PackedTrunk,
+    PackedTrunkLM,
     _dtype,
     _normal,
-    document_lengths,
-    held_experts,
-    layer_pass_lists,
+    one_chip_only,
+    packed_positions,
     pooled_by_row_groups,
-)
-from pathway_tpu.models.transformer import (  # noqa: F401  (`tokenizer`: model_module's)
-    _one_chip_only,
-    _packed_positions,
+    rms_norm,
     tokenizer,
 )
 from pathway_tpu.ops.kernels import cca_attention as kernel
@@ -136,11 +133,11 @@ TINY = ZayaConfig(
     dtype="float32", param_dtype="float32",
 )
 
-# token slots the trunk takes at a time (`moe_mla.pooled_by_row_groups`):
+# token slots the trunk takes at a time (`trunk.pooled_by_row_groups`):
 # at this width a 28k-slot ingest slab's widest arrays (the pairs' buffer
 # and the experts' gate and up, [slots + a tile an expert, 2048] in bf16)
 # are 0.15 GB each, so the whole slab is one group and an expert sees all
-# of a dispatch's tokens that chose it, about 1,300; `moe_mla.CHUNK_TOKENS`
+# of a dispatch's tokens that chose it, about 1,300; `trunk.CHUNK_TOKENS`
 # was set at a width of 7168 and would halve that
 ROW_TOKENS = 32768
 
@@ -237,12 +234,12 @@ def init_params(rng, config: ZayaConfig) -> Dict[str, Any]:
     return params
 
 
-# what `_one_chip_only` says of this trunk: module, what it holds, what is not built
+# what `one_chip_only` says of this trunk: module, what it holds, what is not built
 _ONE_CHIP = ("zaya", "stage 0 of two", "the hand-over of the two streams between stages")
 
 
 def param_sharding_rules(config: ZayaConfig, mesh):
-    _one_chip_only(mesh, *_ONE_CHIP)
+    one_chip_only(mesh, *_ONE_CHIP)
 
 
 def packed_attention_fused(config: ZayaConfig, length: int,
@@ -370,7 +367,7 @@ def _attention(x, layer, config: ZayaConfig, seg, rope, fused: bool):
     the dense definition of both."""
     c = config
     dt = x.dtype
-    h = _rms_norm(x, layer["ln1"], c.norm_eps)
+    h = rms_norm(x, layer["ln1"], c.norm_eps)
     qkv = h @ layer["wqkv"].astype(dt)
     if fused:
         q, k, v = latent.cca_latent(qkv, seg, rope, layer, heads=c.heads, kv_heads=c.kv_heads)
@@ -398,7 +395,7 @@ def route(h, r_prev, layer, config: ZayaConfig):
         return jnp.dot(a.astype(dt), w.astype(dt), preferred_element_type=f32)
 
     r = linear(h, layer["router_down"]) + layer["gamma"] * r_prev
-    z = _rms_norm(r, layer["router_ln"], c.norm_eps)
+    z = rms_norm(r, layer["router_ln"], c.norm_eps)
     z = jax.nn.gelu(linear(z, layer["router_w1"]), approximate=False)
     z = jax.nn.gelu(linear(z, layer["router_w2"]), approximate=False)
     p = jax.nn.softmax(linear(z, layer["router_w3"]), axis=-1)
@@ -408,21 +405,21 @@ def route(h, r_prev, layer, config: ZayaConfig):
 
 def _trunk(params, config: ZayaConfig, ids, seg, max_segments: int, fused: bool):
     """ids, seg: [B, L] -> (pooled unit vectors [B, max_segments, hidden]
-    f32, `moe_mla._trunk`'s statistics of the expert layers and "skipped"
+    f32, the expert layers' statistics (`experts.layer_pass_lists`) and "skipped"
     [layers]: the real tokens that chose to skip)."""
     import jax.numpy as jnp
 
     c = config
     b, l = ids.shape
     dt = _dtype(c.dtype)
-    rope = rope_tables(_packed_positions(seg), c.rope_theta)
+    rope = rope_tables(packed_positions(seg), c.rope_theta)
     valid = (seg > 0).reshape(-1)
     x = params["embed"][ids].astype(dt)
     r = jnp.zeros((b * l, c.router_hidden), jnp.float32)  # the second stream
     stats = dict(layer_pass_lists(c), skipped=[jnp.zeros((0,), jnp.int32)])
     for layer in params["layers"]:
         x = _merge(x, _attention(x, layer, c, seg, rope, fused), layer["alpha"][:2])
-        h = _rms_norm(x, layer["ln2"], c.norm_eps).reshape(b * l, c.hidden)
+        h = rms_norm(x, layer["ln2"], c.norm_eps).reshape(b * l, c.hidden)
         experts, weights, r = route(h, r, layer, c)
         routed, counts, over, more = held_experts(
             h, valid, layer, c, with_stats=True, routing=(experts, weights)
@@ -433,7 +430,7 @@ def _trunk(params, config: ZayaConfig, ids, seg, max_segments: int, fused: bool)
         ).items():
             stats[name].append(value[None])
         x = _merge(x, routed.reshape(b, l, c.hidden), layer["alpha"][2:])
-    x = _rms_norm(x, params["ln_f"], c.norm_eps)
+    x = rms_norm(x, params["ln_f"], c.norm_eps)
     # per-segment mean pooling on the MXU, as transformer.forward pools; the
     # sum over a document's tokens stays f32
     oh = (seg[:, :, None] == jnp.arange(1, max_segments + 1)[None, None, :]).astype(dt)
@@ -464,7 +461,7 @@ def forward(
     `moe_mla.forward`, and "skipped" [layers]."""
     import jax.numpy as jnp
 
-    _one_chip_only(mesh, *_ONE_CHIP)
+    one_chip_only(mesh, *_ONE_CHIP)
     packed = seg is not None
     if not packed:
         seg, max_segments = (mask > 0).astype(jnp.int32), 1
@@ -480,49 +477,32 @@ def forward(
     return pooled, dict(stats, tokens=(seg > 0).sum(dtype=jnp.int32))
 
 
-class ZayaLM(MoeMlaLM):
-    """`MoeMlaLM` for this trunk: its entry points and its routing
-    statistics (`moe.*`: "skip" is routed and not held), the packed program
-    under a name of its own, and what each packed batch is made of counted
-    into the span record (`zaya.*`, internals/tracing.py): from the segment
-    lengths on the host, and the skipped tokens from the device's
-    statistics."""
+def _count_batch(config: ZayaConfig, ids, seg, lengths) -> None:
+    """`zaya.*`: what a packed batch is made of."""
+    from pathway_tpu.internals import tracing
 
-    def _packed_program(self):
-        config = self.config
-
-        def _fwd_packed_zaya(params, ids, seg, max_segments):
-            import jax.numpy as jnp
-
-            return forward(
-                params, config, ids.astype(jnp.int32), None,
-                seg=seg.astype(jnp.int32), max_segments=max_segments,
-                with_stats=True,
-            )
-
-        return _fwd_packed_zaya
-
-    def encode_packed(self, ids, seg, max_segments: int, *, params=None,
-                      mesh=None):
-        _one_chip_only(mesh, *_ONE_CHIP)
-        from pathway_tpu.internals import tracing
-
-        c = self.config
-        lengths = document_lengths(seg, max_segments)
-        tracing.add("zaya.tokens", n=int(lengths.sum()))
-        # a pair: one query against one key in one query head of one layer
-        tracing.add(
-            "zaya.scored_pairs",
-            n=int((lengths * (lengths + 1) // 2).sum()) * c.heads * c.layers,
-        )
-        # tokens whose t-1 was cut: a document's first
-        tracing.add("zaya.seam_tokens", n=len(lengths))
-        return super().encode_packed(ids, seg, max_segments, params=params)
-
-    def _count_more(self, stats) -> None:
-        from pathway_tpu.internals import tracing
-
-        tracing.add("zaya.skipped_tokens", n=int(np.asarray(stats["skipped"]).sum()))
+    c = config
+    tracing.add("zaya.tokens", n=int(lengths.sum()))
+    # a pair: one query against one key in one query head of one layer
+    tracing.add(
+        "zaya.scored_pairs",
+        n=int((lengths * (lengths + 1) // 2).sum()) * c.heads * c.layers,
+    )
+    # tokens whose t-1 was cut: a document's first
+    tracing.add("zaya.seam_tokens", n=len(lengths))
 
 
-LM = ZayaLM
+def _count_stats(config: ZayaConfig, stats) -> None:
+    """`moe.*` ("skip" is routed and not held) and the skipped tokens."""
+    from pathway_tpu.internals import tracing
+
+    count_stats(config, stats)
+    tracing.add("zaya.skipped_tokens", n=int(np.asarray(stats["skipped"]).sum()))
+
+
+PACKED = PackedTrunk(
+    "_fwd_packed_zaya", lambda config: _ONE_CHIP, count_batch=_count_batch,
+    count_stats=_count_stats,
+)
+
+LM = PackedTrunkLM

@@ -118,14 +118,14 @@ def token_steps(length: int, window: int, block: int = KEY_TILE) -> int:
 
 
 def _positions(seg, at, xp):
-    """A token's position in its document: `transformer._packed_positions`,
+    """A token's position in its document: `trunk.packed_positions`,
     and its like on the host (jax.numpy's `accumulate` is a loop a slot)."""
     import numpy as np
 
     if xp is not np:
-        from pathway_tpu.models.transformer import _packed_positions
+        from pathway_tpu.models.trunk import packed_positions
 
-        return _packed_positions(seg)
+        return packed_positions(seg)
     starts = np.concatenate([np.ones_like(seg[:, :1], dtype=bool), seg[:, 1:] != seg[:, :-1]], axis=1)
     return at - np.maximum.accumulate(np.where(starts, at, 0), axis=1)
 

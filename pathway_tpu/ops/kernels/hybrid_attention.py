@@ -149,7 +149,7 @@ def supports(length: int, heads: int, kv_heads: int, nope_dim: int, rope_dim: in
 def key_lo(seg, pos, block: int):
     """[B, L / block] int32: the first key block a block of queries of a
     global layer meets, its earliest document's first.  seg, pos: [B, L],
-    the segment ids and `_packed_positions(seg)`."""
+    the segment ids and `trunk.packed_positions(seg)`."""
     import jax.numpy as jnp
 
     b, l = seg.shape

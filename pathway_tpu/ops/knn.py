@@ -506,7 +506,7 @@ def _compiled_fused_search(config, metric: str, k: int, mesh=None, n_rows: int =
     import jax
     import jax.numpy as jnp
 
-    from pathway_tpu.models.transformer import model_module
+    from pathway_tpu.models.trunk import model_module
 
     forward = model_module(config).forward
 
@@ -692,7 +692,7 @@ class FusedEmbedSearch:
         Ordering matters: the scatter donates the previous index buffer,
         so batches must dispatch in submission order."""
         from pathway_tpu.models.tokenizer import PACK_MAX_SEGMENTS
-        from pathway_tpu.models.transformer import model_module
+        from pathway_tpu.models.trunk import model_module
 
         kind, keys, ids, second, slots = payload
         shards = None

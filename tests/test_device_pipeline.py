@@ -190,12 +190,12 @@ def test_pack_batch_max_segments_spill(pack):
 def test_packed_positions_restart_per_segment():
     import jax.numpy as jnp
 
-    from pathway_tpu.models.transformer import _packed_positions
+    from pathway_tpu.models.trunk import packed_positions
 
     seg = jnp.asarray(
         [[1, 1, 1, 2, 2, 0, 0, 0], [1, 2, 2, 2, 3, 3, 0, 0]]
     )
-    pos = np.asarray(_packed_positions(seg))
+    pos = np.asarray(packed_positions(seg))
     assert pos[0, :5].tolist() == [0, 1, 2, 0, 1]
     assert pos[1, :6].tolist() == [0, 0, 1, 2, 0, 1]
 

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from pathway_tpu.models import eva
+from pathway_tpu.models import eva, trunk
 from pathway_tpu.models.tokenizer import (
     PACK_MAX_SEGMENTS, ByteTokenizer, HashTokenizer, encode_batch, pack_batch,
 )
@@ -96,7 +96,7 @@ def test_the_unpacked_form_is_the_packed_one_and_row_groups_change_nothing(monke
     np.testing.assert_allclose(enc.encode(TEXTS), want, atol=2e-5)
     ids, mask = encode_batch(enc.tokenizer, TEXTS, max_len=256)
     assert ids.shape == (8, 128)
-    monkeypatch.setattr(eva, "row_chunks", lambda rows, length: 4)
+    monkeypatch.setattr(trunk, "row_chunks", lambda rows, length, cap: 4)
     grouped = eva.forward(enc.lm.params, enc.config, jnp.asarray(ids, jnp.int32),
                           jnp.asarray(mask, jnp.int32))
     np.testing.assert_allclose(np.asarray(grouped)[:4], want, atol=2e-5)
@@ -189,9 +189,9 @@ def test_the_kernel_is_its_dense_definition(case):
 
 def test_the_rope_kernel_turns_the_pairs_decoder_rope_turns():
     """`kernel.rope` (interpreted) and `eva._rotate`, its definition, against
-    `decoder._rope` on the same operand in that function's [B, H, L, D]
+    `trunk.rope` on the same operand in that function's [B, H, L, D]
     contract: the same pairs (x[i], x[i + d/2]), the same angles."""
-    from pathway_tpu.models.decoder import _rope
+    from pathway_tpu.models.trunk import rope
 
     c = eva.TINY
     rng = np.random.default_rng(9)
@@ -202,7 +202,7 @@ def test_the_rope_kernel_turns_the_pairs_decoder_rope_turns():
         c.rope_theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half))
     cos, sin = jnp.cos(angle), jnp.sin(angle)
     cs = (jnp.concatenate([cos, cos], -1), jnp.concatenate([-sin, sin], -1))
-    want = _rope(
+    want = rope(
         x.reshape(2, 128, c.heads, c.head_dim).transpose(0, 2, 1, 3), pos, c.rope_theta
     ).transpose(0, 2, 1, 3).reshape(x.shape) * 0.25
     np.testing.assert_allclose(np.asarray(eva._rotate(x, *cs, 0.25)), np.asarray(want), atol=1e-6)
@@ -300,12 +300,13 @@ def test_the_byte_tokenizer_is_the_references_rule_cut_at_max_len():
     assert tok.count_tokens("naïve") == 6
     # the configuration picks it, by the module's rule; the others keep theirs
     from pathway_tpu.models import moe_mla, transformer
+    from pathway_tpu.models.trunk import model_module
 
     assert isinstance(eva.tokenizer(eva.TINY), ByteTokenizer)
     for module, config in ((transformer, transformer.MINILM_L6), (moe_mla, moe_mla.TINY)):
-        made = transformer.model_module(config).tokenizer(config)
+        made = model_module(config).tokenizer(config)
         assert isinstance(made, HashTokenizer) and made.vocab_size == config.vocab_size
-        assert transformer.model_module(config) is module
+        assert model_module(config) is module
 
 
 @pytest.mark.parametrize("n,want", [
@@ -313,24 +314,26 @@ def test_the_byte_tokenizer_is_the_references_rule_cut_at_max_len():
     (6717, 7168), (10423, 11264), (10669, 11264),
 ])
 def test_a_rows_length_comes_in_the_kernels_tiles(n, want):
-    assert eva.seq_bucket(n) == want
+    shapes = eva.tokenizer(eva.EvaConfig()).shapes
+    assert shapes.seq_bucket(n) == want
     assert kernel.supports(want, 32, 128, 2048, 16)
     # and the layout cuts every such row into whole blocks (640 slots: five of 128)
     layout = kernel.window_layout(np.ones((1, want), np.int32), 2048, 16, xp=np)
     block = kernel.row_block(want)
     assert want % block == 0 and block % kernel.LANES == 0
     assert layout["kind"].shape == (1, want // block * kernel.token_steps(want, 2048))
-    assert eva.seq_bucket(n, maximum=8192) == min(want, 8192)
+    assert shapes.seq_bucket(n, maximum=8192) == min(want, 8192)
 
 
 def test_slab_shapes_of_a_batch_of_pages():
-    assert [eva.row_bucket(r) for r in (1, 2, 3, 5, 8, 9, 17)] == [1, 2, 4, 8, 8, 16, 24]
+    shapes = eva.tokenizer(eva.EvaConfig()).shapes
+    assert [shapes.row_bucket(r) for r in (1, 2, 3, 5, 8, 9, 17)] == [1, 2, 4, 8, 8, 16, 24]
     # two pages are one row of all their tokens; the budget is the floor
-    assert eva.slab_length([3893, 6672], 256) == 11264
-    assert eva.slab_length([40, 50], 256) == 256
-    assert eva.slab_length([9000, 9000], 256) == 16384  # a row group, then a second row
-    assert eva.slab_length([20000], 256) == 20480
-    assert eva.slab_length([65] * 64, 256) == 3072  # 32 documents a row: two rows
+    assert shapes.slab_length([3893, 6672], 256) == 11264
+    assert shapes.slab_length([40, 50], 256) == 256
+    assert shapes.slab_length([9000, 9000], 256) == 16384  # a row group, then a second row
+    assert shapes.slab_length([20000], 256) == 20480
+    assert shapes.slab_length([65] * 64, 256) == 3072  # 32 documents a row: two rows
 
 
 def test_files_whose_byte_lengths_jitter_compile_their_slab_once():

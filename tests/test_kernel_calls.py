@@ -86,11 +86,11 @@ def _rope_operands(width: int):
 
 
 def _hybrid_operands():
-    from pathway_tpu.models.transformer import _packed_positions
+    from pathway_tpu.models.trunk import packed_positions
     from tests.test_moe_hybrid import _operands, _packed_seg
 
     seg = _packed_seg(128, [[77, 40]])
-    pos = _packed_positions(seg)
+    pos = packed_positions(seg)
     sink = jnp.asarray(np.random.default_rng(1).normal(size=4), jnp.float32)
     lo = hybrid_k.key_lo(seg, pos, 32)  # a global layer's; a window layer's steps need none
     return (*_operands(1, 128, 4, 2, jnp.float32), seg, lo, sink)
@@ -123,7 +123,7 @@ LATENT_LEAVES = ("conv0_w", "conv0_b", "conv1_w", "conv1_b", "tau")
 def _latent_operands():
     """The projection's output for 4 query over 2 key/value heads, a packed
     row, RoPE's tables and a layer's five leaves of the latent."""
-    from pathway_tpu.models.transformer import _packed_positions
+    from pathway_tpu.models.trunk import packed_positions
     from pathway_tpu.ops.kernels.hybrid_attention import rope_tables
 
     rng = np.random.default_rng(4)
@@ -131,7 +131,7 @@ def _latent_operands():
     seg = jnp.asarray(np.r_[[1] * 9, [2] * 27, [0] * 4][None], jnp.int32)
     leaves = (drawn(2, 6 * 128), drawn(6 * 128), drawn(6, 2 * 128, 128) * 0.06,
               drawn(6 * 128), 1.5 + 0.25 * drawn(2))
-    return (drawn(1, 40, 8 * 128), seg, *rope_tables(_packed_positions(seg), 5e6), *leaves)
+    return (drawn(1, 40, 8 * 128), seg, *rope_tables(packed_positions(seg), 5e6), *leaves)
 
 
 def _latent_through(qkv, seg, cos, sin, *leaves, **statics):

@@ -19,9 +19,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from pathway_tpu.models import moe_hybrid, moe_mla
+from pathway_tpu.models import experts as moe
+from pathway_tpu.models import moe_hybrid
 from pathway_tpu.models.tokenizer import PACK_MAX_SEGMENTS, pack_batch
-from pathway_tpu.models.transformer import _packed_positions
+from pathway_tpu.models.trunk import packed_positions
 from pathway_tpu.ops.kernels import hybrid_attention as kernel
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -157,7 +158,7 @@ def test_each_mechanism_moves_the_vectors(mechanism):
     """The program with one mechanism left out against the reference with
     all of them: each is off by ten tolerances or more, so the agreement
     above holds every one of them."""
-    from pathway_tpu.models.transformer import model_module
+    from pathway_tpu.models.trunk import model_module
 
     model = tiny_model()
     enc = program_encoder(model, seed=5)
@@ -220,7 +221,7 @@ def test_the_one_operand_kernel_agrees_with_its_dense_definition(case):
     assert kernel.supports(length, heads, kv, 128, 0, 128, window)
     lo = None
     if window is None:
-        lo = kernel.key_lo(seg, _packed_positions(seg), kernel.block_rows(length, window))
+        lo = kernel.key_lo(seg, packed_positions(seg), kernel.block_rows(length, window))
     got = kernel.hybrid_attention(q, None, k, None, v, seg, lo, kv_heads=kv, window=window,
                                   interpret=True)
     want = kernel.hybrid_attention_dense(q, None, k, None, v, seg, kv_heads=kv, window=window)
@@ -265,8 +266,8 @@ def test_two_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
         np.testing.assert_array_equal(params[0][name][8:], params[2][name])
     h = jnp.asarray(np.random.default_rng(0).standard_normal((96, 64)), jnp.float32)
     valid = jnp.arange(96) < 90
-    routed = [moe_mla.held_experts(h, valid, p, c)[0] for p, c in zip(params, [whole] + halves)]
-    shared = moe_mla._swiglu(
+    routed = [moe.held_experts(h, valid, p, c)[0] for p, c in zip(params, [whole] + halves)]
+    shared = moe.swiglu(
         h, params[0]["shared_gate"], params[0]["shared_up"], params[0]["shared_down"]
     )
     np.testing.assert_allclose(routed[1] + routed[2] + shared, routed[0] + shared, atol=1e-5)
@@ -293,11 +294,11 @@ def test_held_experts_with_every_expert_held_at_top8(tokens):
     }
     h = jnp.asarray(rng.standard_normal((tokens, 32)), jnp.float32)
     valid = jnp.arange(tokens) < tokens - 7
-    capacity = moe_mla.pair_capacity(tokens, c)
-    assert moe_mla.combine_rows(tokens, c) == tokens
-    assert moe_mla.returns_fused(c)
+    capacity = moe.pair_capacity(tokens, c)
+    assert moe.combine_rows(tokens, c) == tokens
+    assert moe.returns_fused(c)
     program = jax.jit(
-        lambda h, valid, layer: moe_mla.held_experts(h, valid, layer, c, with_stats=True)
+        lambda h, valid, layer: moe.held_experts(h, valid, layer, c, with_stats=True)
     )
     assert "while" not in program.lower(h, valid, layer).as_text()
     y, counts, over, stats = program(h, valid, layer)
@@ -306,11 +307,11 @@ def test_held_experts_with_every_expert_held_at_top8(tokens):
     assert int(over) == 0 and int(stats["combine_spills"]) == 0
     assert int(counts.sum()) == 8 * (tokens - 7)
     assert int(stats["groups_aligned"]) == 1
-    tiles = int((-(-counts // moe_mla.PAIR_ROWS)).sum()) * moe_mla.PAIR_ROWS
+    tiles = int((-(-counts // moe.PAIR_ROWS)).sum()) * moe.PAIR_ROWS
     assert int(stats["group_rows"]) == tiles <= capacity
     assert int(stats["group_pad_rows"]) == tiles - 8 * (tokens - 7)
     # the plain loop over the experts, every expert on every token at once
-    experts, weights = moe_mla.route(h, layer["router"], c)
+    experts, weights = moe.route(h, layer["router"], c)
     chose = (experts[:, :, None] == jnp.arange(256)) & valid[:, None, None]
     w = jnp.sum(jnp.where(chose, weights[:, :, None], 0.0), axis=1)  # [tokens, 256]
     hi = jax.lax.Precision.HIGHEST
@@ -343,8 +344,8 @@ def test_the_cells_configuration_is_the_published_model():
     assert (c.routed_scaling_factor, c.selection_bias, c.depth) == (2.5, False, 40)
     assert (c.vocab_size, c.max_len, c.pp_size, c.whole_heads) == (100352, 8192, 8, True)
     # the cell's slab: one row of 23,552 slots, every pair in the buffer
-    assert moe_mla.pair_capacity(23552, c) == 319488
-    assert moe_mla.combine_rows(23552, c) == 23552
+    assert moe.pair_capacity(23552, c) == 319488
+    assert moe.combine_rows(23552, c) == 23552
     # stage 0 of eight refuses a mesh, naming the hand-over
     with pytest.raises(NotImplementedError, match="stage 0 of 8 on one chip: the hand-over"):
         moe_hybrid.param_sharding_rules(c, object())
@@ -399,7 +400,7 @@ def test_the_window_steps_count_the_pairs_their_tiling_meets(monkeypatch):
     lm = moe_hybrid.LM.__new__(moe_hybrid.LM)
     lm.config = program.config_of(cfg["model"], cfg["store"])
     # the counting alone: the program itself is not run
-    monkeypatch.setattr(moe_mla.MoeMlaLM, "encode_packed", lambda *a, **k: None)
+    monkeypatch.setattr(moe_hybrid.LM, "_dispatch", lambda *a, **k: None)
     seg = np.zeros((1, 23552), np.int32)
     for s, at in enumerate(range(0, 23400, 1950)):  # 12 files of 1,950 tokens
         seg[0, at:at + 1950] = s + 1

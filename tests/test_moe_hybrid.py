@@ -3,7 +3,7 @@ expert-parallel rank: window layers beside global grouped-query ones, a
 bias-corrected router) against its plain reference
 (`chipbench/architectures/moe_hybrid_decoder/reference.py`, which imports
 nothing of the program), its kernel against the dense definition, the
-router's selection bias in the shared `moe_mla.route`, the rank's share of
+router's selection bias in the shared `experts.route`, the rank's share of
 an expert layer, its counters and its slab shapes: at tiny sizes on the
 CPU, seeded."""
 
@@ -14,11 +14,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from pathway_tpu.models import eva, moe_hybrid, moe_mla
+from pathway_tpu.models import eva, moe_hybrid, moe_mla, trunk
+from pathway_tpu.models import experts as moe
 from pathway_tpu.models.tokenizer import (
     PACK_MAX_SEGMENTS, HashTokenizer, encode_batch, pack_batch,
 )
-from pathway_tpu.models.transformer import _packed_positions
+from pathway_tpu.models.trunk import packed_positions
 from pathway_tpu.ops.kernels import hybrid_attention as kernel
 
 WINDOW = 16
@@ -114,7 +115,7 @@ def test_the_unpacked_form_is_the_packed_one_and_row_groups_change_nothing(monke
     np.testing.assert_allclose(enc.encode(TEXTS), want, atol=F32_TOL)
     ids, mask = encode_batch(enc.tokenizer, TEXTS, max_len=256)
     assert ids.shape == (8, 256)
-    monkeypatch.setattr(moe_mla, "row_chunks", lambda rows, length, cap: 4)
+    monkeypatch.setattr(trunk, "row_chunks", lambda rows, length, cap: 4)
     grouped, stats = moe_hybrid.forward(
         enc.lm.params, enc.config, jnp.asarray(ids, jnp.int32),
         jnp.asarray(mask, jnp.int32), with_stats=True,
@@ -199,7 +200,7 @@ def test_the_kernel_is_its_dense_definition(case):
     seg = _packed_seg(l, [[77, 300], [200, 100]])
     ops = _operands(2, l, heads, kv_heads, jnp.dtype(dtype))
     sinks = jnp.asarray(np.random.default_rng(1).normal(size=heads), jnp.float32) if sink else None
-    pos = _packed_positions(seg)
+    pos = packed_positions(seg)
     rows = kernel.block_rows(l, window) if block is None else block
     lo = kernel.key_lo(seg, pos, rows) if window is None else None
     want = kernel.hybrid_attention_dense(*ops, seg, kv_heads=kv_heads, window=window, sink=sinks)
@@ -217,7 +218,7 @@ def test_the_kernels_blocks_follow_the_documents_and_the_window():
     of queries takes the blocks its window reaches, whatever the
     documents: window 128 over blocks of 128, the block before."""
     seg = _packed_seg(1024, [[300, 500]])
-    pos = _packed_positions(seg)
+    pos = packed_positions(seg)
     assert np.asarray(kernel.key_lo(seg, pos, 128)).tolist() == [[0, 0, 0, 2, 2, 2, 2, 7]]
     assert kernel.window_tiling(1024, 128) == (128, 8, 2)
     assert kernel.block_rows(24576, None) == 1024 and kernel.block_rows(24576, 128) == 128
@@ -246,7 +247,7 @@ def test_a_window_step_takes_every_key_block_its_window_reaches(length, window, 
 def test_a_global_layer_sees_key_0_from_query_500_and_a_window_layer_does_not(fused):
     l, heads, kv_heads = 512, 4, 2
     seg = _packed_seg(l, [[512]])
-    pos = _packed_positions(seg)
+    pos = packed_positions(seg)
     qn, qr, kn, kr, v = _operands(1, l, heads, kv_heads, jnp.float32)
     moved = v.at[0, 0, :].add(1.0)  # the value of key 0
 
@@ -270,7 +271,7 @@ def test_a_group_of_query_heads_reads_its_own_key_value_head(heads, kv_heads):
     no other, in the kernel as in the definition."""
     l, group = 128, heads // kv_heads
     seg = _packed_seg(l, [[100]])
-    pos = _packed_positions(seg)
+    pos = packed_positions(seg)
     qn, qr, kn, kr, v = _operands(1, l, heads, kv_heads, jnp.float32)
     kn2 = kn.at[:, :, 128:].multiply(-1.0)
     kr2 = kr.at[:, :, 64:].multiply(-1.0)
@@ -304,7 +305,7 @@ def test_the_rope_kernel_turns_the_first_64_dims_as_the_reference_does():
 
 
 def test_the_bias_moves_the_selection_and_never_a_weight():
-    """`moe_mla.route`, the shared router: without a bias today's plain
+    """`experts.route`, the shared router: without a bias today's plain
     top-k to the bit; with one, other experts for some tokens, and every
     weight still the chosen score over the chosen scores' sum."""
     config = moe_hybrid.TINY
@@ -314,10 +315,10 @@ def test_the_bias_moves_the_selection_and_never_a_weight():
     bias = jnp.asarray(rng.normal(size=16) * 0.05, jnp.float32)
     scores = jax.nn.sigmoid(jnp.dot(h, router, preferred_element_type=jnp.float32))
     top, plain = jax.lax.top_k(scores, config.experts_per_token)  # the router as it was
-    experts, weights = moe_mla.route(h, router, config)
+    experts, weights = moe.route(h, router, config)
     assert (np.asarray(experts) == np.asarray(plain)).all()
     assert (np.asarray(weights) == np.asarray(top / top.sum(-1, keepdims=True))).all()
-    chosen, w = moe_mla.route(h, router, config, bias)
+    chosen, w = moe.route(h, router, config, bias)
     chosen, w = np.asarray(chosen), np.asarray(w)
     moved = (np.sort(chosen, 1) != np.sort(np.asarray(plain), 1)).any(1)
     assert 20 < moved.sum() < 200  # the bias chooses otherwise for some tokens, not all
@@ -359,15 +360,15 @@ def test_four_ranks_add_up_to_the_uncut_layer():
         ))
         params = moe_hybrid.init_params(jax.random.PRNGKey(weight_seed(seed)), config)
         layer = params["layers"][1]
-        pos = _packed_positions(seg)
+        pos = packed_positions(seg)
         alike = jnp.asarray(np.asarray(x))[None] + moe_hybrid._attention(
             jnp.asarray(np.asarray(x))[None], layer, config, True, seg,
             kernel.rope_tables(pos, config.rope_theta_window), None, False,
         )
         # every rank computes it alike, and as the reference does
         np.testing.assert_allclose(np.asarray(alike)[0, :n], common[:n], atol=F32_TOL)
-        h = moe_hybrid._rms_norm(alike[0], layer["ln2"], config.norm_eps)
-        routed, counts, over = moe_mla.held_experts(h, seg[0] > 0, layer, config)
+        h = trunk.rms_norm(alike[0], layer["ln2"], config.norm_eps)
+        routed, counts, over = moe.held_experts(h, seg[0] > 0, layer, config)
         assert int(over) == 0
         total += np.asarray(routed)
         pairs += int(counts.sum())
@@ -381,7 +382,7 @@ def test_the_counters_count_what_the_masks_let_through():
     against the definition's own masks; the routing statistics through the
     shared path (`moe.*`); the model found by `model_module`."""
     from pathway_tpu.internals import tracing
-    from pathway_tpu.models.transformer import TransformerLM, model_module
+    from pathway_tpu.models.trunk import TransformerLM, model_module
 
     enc = program_encoder(tiny_model(), seed=5)
     assert model_module(enc.config) is moe_hybrid and isinstance(enc.lm, TransformerLM)
@@ -439,8 +440,9 @@ def test_served_path_ingests_and_retrieves_with_the_hybrid_embedder():
 @pytest.mark.parametrize("n,want", [(1, 128), (14, 128), (129, 256), (1024, 1024), (1025, 2048),
                                     (8502, 9216), (16002, 16384), (24504, 24576)])
 def test_a_rows_length_comes_in_the_kernels_blocks(n, want):
-    assert moe_hybrid.seq_bucket(n) == want
-    assert moe_hybrid.seq_bucket(n, maximum=16384) == min(want, 16384)
+    shapes = moe_hybrid.tokenizer(moe_hybrid.MoeHybridConfig()).shapes
+    assert shapes.seq_bucket(n) == want
+    assert shapes.seq_bucket(n, maximum=16384) == min(want, 16384)
 
 
 def test_the_cells_two_documents_land_in_one_row_of_24576_slots():
@@ -459,11 +461,11 @@ def test_the_cells_two_documents_land_in_one_row_of_24576_slots():
         assert sorted(slots) == [(0, 0), (0, 1)]
         assert 1 - (seg > 0).mean() < 0.1
     assert shapes == {(1, 24576)}
-    assert moe_hybrid.slab_length([8502, 16002], 256, 16384) == moe_hybrid.ROW_TOKENS == 24576
-    assert moe_mla.row_chunks(1, 24576, moe_hybrid.ROW_TOKENS) == 1
-    assert moe_mla.row_chunks(8, 16384, moe_hybrid.ROW_TOKENS) == 8  # a read-back round: a row a group
-    assert moe_mla.row_chunks(8, 128, moe_hybrid.ROW_TOKENS) == 1
-    assert moe_mla.row_chunks(56, 504) == 2  # A.X-K1's cap is as it was
+    assert tok.shapes.slab_length([8502, 16002], 256, 16384) == moe_hybrid.ROW_TOKENS == 24576
+    assert trunk.row_chunks(1, 24576, moe_hybrid.ROW_TOKENS) == 1
+    assert trunk.row_chunks(8, 16384, moe_hybrid.ROW_TOKENS) == 8  # a read-back round: a row a group
+    assert trunk.row_chunks(8, 128, moe_hybrid.ROW_TOKENS) == 1
+    assert trunk.row_chunks(56, 504) == 2  # A.X-K1's cap is as it was
     # a read-back round: four documents and four probes, one row each
     ids, mask = encode_batch(tok, [docs[1]] * 4 + ["a probe of a few words"] * 4, max_len=16384)
     assert ids.shape == (8, 16384)
@@ -478,7 +480,7 @@ _TRUNKS = {
 
 @pytest.mark.parametrize("trunk", sorted(_TRUNKS))
 def test_a_mesh_is_refused(trunk):
-    """The one refusal (`transformer._one_chip_only`) names the module and
+    """The one refusal (`trunk.one_chip_only`) names the module and
     what lives elsewhere, at every way in: `forward`, the sharding rules
     and the packed encode."""
     module, message = _TRUNKS[trunk]

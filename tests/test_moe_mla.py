@@ -11,6 +11,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from pathway_tpu.models import experts as moe
+from pathway_tpu.models import trunk
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -160,11 +163,11 @@ def test_rows_in_groups_equal_the_whole_slab(monkeypatch):
     ids = rng.integers(4, config.vocab_size, size=(6, 32)).astype(np.int32)
     seg = (rng.random((6, 32)) < 0.9).astype(np.int32)
     seg[:, 16:] *= 2
-    assert M.row_chunks(6, 32) == 1 and M.row_chunks(56, 504) == 2
+    assert trunk.row_chunks(6, 32) == 1 and trunk.row_chunks(56, 504) == 2
     whole = M.forward(params, config, ids, None, seg=jnp.asarray(seg), max_segments=2,
                       with_stats=True)
-    monkeypatch.setattr(M, "CHUNK_TOKENS", 64)
-    assert M.row_chunks(6, 32) == 3
+    monkeypatch.setattr(trunk, "CHUNK_TOKENS", 64)
+    assert trunk.row_chunks(6, 32) == 3
     parts = M.forward(params, config, ids, None, seg=jnp.asarray(seg), max_segments=2,
                       with_stats=True)
     np.testing.assert_allclose(np.asarray(whole[0]), np.asarray(parts[0]), atol=2e-6)
@@ -287,20 +290,20 @@ def test_sixteen_ranks_add_up_to_the_uncut_layer():
         params = M.init_params(jax.random.PRNGKey(weight_seed(seed)), config)
         seg = jnp.asarray(mask)
         x = params["embed"][jnp.asarray(ids)]
-        pos = M._packed_positions(seg)
+        pos = trunk.packed_positions(seg)
         freqs = jnp.asarray(M.yarn_freqs(config))
         for layer in params["layers"][:1]:
             x = x + M._attention(x, layer, config, pos, seg, False, freqs)
-            hh = M._rms_norm(x, layer["ln2"], config.norm_eps)
-            x = x + M._swiglu(hh, layer["gate"], layer["up"], layer["down"])
+            hh = trunk.rms_norm(x, layer["ln2"], config.norm_eps)
+            x = x + moe.swiglu(hh, layer["gate"], layer["up"], layer["down"])
         layer = params["layers"][1]
         x = x + M._attention(x, layer, config, pos, seg, False, freqs)
-        hh = M._rms_norm(x, layer["ln2"], config.norm_eps)
-        routed, counts, over = M.held_experts(
+        hh = trunk.rms_norm(x, layer["ln2"], config.norm_eps)
+        routed, counts, over = moe.held_experts(
             hh.reshape(n * l, -1), (seg > 0).reshape(-1), layer, config
         )
         assert int(over) == 0
-        shared = M._swiglu(hh, layer["shared_gate"], layer["shared_up"], layer["shared_down"])
+        shared = moe.swiglu(hh, layer["shared_gate"], layer["shared_up"], layer["shared_down"])
         return np.asarray(x + shared), np.asarray(routed).reshape(n, l, -1), int(counts.sum())
 
     total, pairs = np.array(common), 0
@@ -331,13 +334,11 @@ def _expert_layer(seed: int, adversarial: bool):
 def _plain_sum(h, valid, layer, config) -> np.ndarray:
     """The routed part without a buffer: every held expert over every
     token, and of that each selected pair of a valid token, weighted."""
-    from pathway_tpu.models import moe_mla as M
-
-    experts, weights = M.route(h, layer["router"], config)
+    experts, weights = moe.route(h, layer["router"], config)
     experts, weights = np.asarray(experts), np.asarray(weights)
     want = np.zeros(h.shape, np.float32)
     for e in range(config.experts_held):
-        out = M._swiglu(h, layer["experts_gate"][e], layer["experts_up"][e],
+        out = moe.swiglu(h, layer["experts_gate"][e], layer["experts_up"][e],
                         layer["experts_down"][e])
         selected = (experts == config.expert_offset + e) & np.asarray(valid)[:, None]
         want += (weights * selected).sum(1)[:, None] * np.asarray(out)
@@ -356,21 +357,21 @@ def test_no_selected_held_pair_is_dropped_and_overflow_is_counted():
     h = jnp.abs(jnp.asarray(np.random.default_rng(9).normal(size=(t, config.hidden)),
                             jnp.float32))
     valid = jnp.arange(t) < 36
-    experts, weights = M.route(h, layer["router"], config)
+    experts, weights = moe.route(h, layer["router"], config)
     assert int((experts < config.experts_held).sum()) == t * k
-    y, counts, overflow = M.held_experts(h, valid, layer, config)
+    y, counts, overflow = moe.held_experts(h, valid, layer, config)
     assert int(counts.sum()) == 36 * k and int(overflow) == 0
-    assert M.pair_capacity(t, config) == 512 >= t * k  # a whole tile, every pair
-    assert M.pair_capacity(500, M.MoeMlaConfig()) == 4096 >= 500 * 8
-    assert M.pair_capacity(14112, M.MoeMlaConfig()) == 14336  # a row a token slot
+    assert moe.pair_capacity(t, config) == 512 >= t * k  # a whole tile, every pair
+    assert moe.pair_capacity(500, M.MoeMlaConfig()) == 4096 >= 500 * 8
+    assert moe.pair_capacity(14112, M.MoeMlaConfig()) == 14336  # a row a token slot
     # the list of tokens with several pairs: a slot a token up to the
     # buffer's least size, then an eighth of the slots in whole tiles
-    assert M.combine_rows(t, config) == t and M.combine_rows(4032, M.MoeMlaConfig()) == 4032
-    assert M.combine_rows(14112, M.MoeMlaConfig()) == 2048
+    assert moe.combine_rows(t, config) == t and moe.combine_rows(4032, M.MoeMlaConfig()) == 4032
+    assert moe.combine_rows(14112, M.MoeMlaConfig()) == 2048
     # against the plain sum over the selected pairs
     np.testing.assert_allclose(np.asarray(y), _plain_sum(h, valid, layer, config), atol=2e-4)
     assert not np.asarray(y)[36:].any()  # padding routes nothing
-    _, counts, overflow = M.held_experts(h, valid, layer, config, capacity=100)
+    _, counts, overflow = moe.held_experts(h, valid, layer, config, capacity=100)
     assert int(counts.sum()) == 36 * k and int(overflow) == 36 * k - 100
 
 
@@ -409,17 +410,17 @@ def test_return_to_the_tokens_equals_the_plain_sum(case):
     if adversarial:
         h = jnp.abs(h)
     valid = jnp.asarray(rng.random(t) < 0.9)
-    y, counts, overflow, stats = M.held_experts(
+    y, counts, overflow, stats = moe.held_experts(
         h, valid, layer, config, capacity, listed=listed, with_stats=True
     )
-    experts, _ = M.route(h, layer["router"], config)
+    experts, _ = moe.route(h, layer["router"], config)
     held_pairs = (np.asarray(experts) < config.experts_held) & np.asarray(valid)[:, None]
     assert int(counts.sum()) == held_pairs.sum()
     assert int(stats["groups_aligned"]) == (layout == "aligned")
     assert int(stats["groups_packed"]) == (layout == "packed")
     assert "fused_returns" not in stats  # 4 of 16 held: the list's return
     if case == "aligned-two-tiles-a-group":
-        assert int(counts.min()) > M.PAIR_ROWS
+        assert int(counts.min()) > moe.PAIR_ROWS
     if int(overflow) == 0:
         assert int(stats["multi_pair_tokens"]) == (held_pairs.sum(1) > 1).sum()
         np.testing.assert_allclose(
@@ -430,10 +431,10 @@ def test_return_to_the_tokens_equals_the_plain_sum(case):
     assert bool(stats["combine_spills"]) == spills
     assert int(stats["multi_pair_tokens"]) > (listed or t) or not spills
     # neither the list's size nor the groups' layout changes a bit
-    other = M.held_experts(h, valid, layer, config, capacity, listed=t)
+    other = moe.held_experts(h, valid, layer, config, capacity, listed=t)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(other[0]))
     if int(overflow) == 0:
-        packed = M.held_experts(
+        packed = moe.held_experts(
             h, valid, layer, config, -(-int(counts.sum()) // 512) * 512, with_stats=True
         )
         assert int(packed[3]["groups_packed"]) == 1 and int(packed[2]) == 0
@@ -443,7 +444,7 @@ def test_return_to_the_tokens_equals_the_plain_sum(case):
 def test_routing_statistics_reach_the_span_record():
     from pathway_tpu.internals import tracing
     from pathway_tpu.models import moe_mla as M
-    from pathway_tpu.models.transformer import TransformerLM, model_module
+    from pathway_tpu.models.trunk import TransformerLM, model_module
 
     tracing.reset_spans()
     config = M.TINY
@@ -598,16 +599,14 @@ def test_the_buffer_rule_is_pinned_at_the_cells_slabs(case):
     expert held)."""
     import importlib
 
-    from pathway_tpu.models import moe_mla as M
-
     module, tokens, rows, listed, *routing = _BUFFER_PINS[case]
     trunk = importlib.import_module(f"pathway_tpu.models.{module}")
     config = {"moe_mla": "MoeMlaConfig", "moe_hybrid": "MoeHybridConfig",
               "zaya": "ZayaConfig"}[module]
     published = getattr(trunk, config)(**_ROUTING.get(*routing, {}) if routing else {})
-    assert M.pair_capacity(tokens, published) == rows
-    assert M.combine_rows(tokens, published) == listed
-    assert rows % M.PAIR_ROWS == 0
+    assert moe.pair_capacity(tokens, published) == rows
+    assert moe.combine_rows(tokens, published) == listed
+    assert rows % moe.PAIR_ROWS == 0
 
 
 # routed experts, held, k -> whether the return is the fused weighted sum
@@ -627,7 +626,7 @@ def test_the_fused_return_engages_with_every_expert_held_at_top_k_over_one(case)
 
     routed, held, k, want = _FUSED_RETURNS[case]
     config = M.MoeMlaConfig(n_routed_experts=routed, experts_held=held, experts_per_token=k)
-    assert M.returns_fused(config) is want
+    assert moe.returns_fused(config) is want
 
 
 @pytest.mark.parametrize("tokens", [40, 900])
@@ -636,7 +635,6 @@ def test_top_one_with_every_expert_held_aligns_its_groups_and_lists_nothing(toke
     experts and "skip", all 8 held): every group begins a tile, no token
     has two pairs, nothing is dropped, "skip" and the padding compute
     nothing, and the return is the plain sum."""
-    from pathway_tpu.models import moe_mla as M
     from pathway_tpu.models import zaya
 
     config = zaya.TINY
@@ -647,7 +645,7 @@ def test_top_one_with_every_expert_held_aligns_its_groups_and_lists_nothing(toke
     experts, weights, _ = zaya.route(
         h, jnp.zeros((tokens, config.router_hidden)), layer, config
     )
-    y, counts, overflow, stats = M.held_experts(
+    y, counts, overflow, stats = moe.held_experts(
         h, valid, layer, config, with_stats=True, routing=(experts, weights)
     )
     chosen = np.asarray(experts)[:, 0]
@@ -660,17 +658,17 @@ def test_top_one_with_every_expert_held_aligns_its_groups_and_lists_nothing(toke
     assert int(overflow) == 0 and int(stats["multi_pair_tokens"]) == 0
     assert int(stats["combine_spills"]) == 0 and int(stats["groups_aligned"]) == 1
     assert "fused_returns" not in stats  # top-1: one inverse permutation
-    assert M.pair_capacity(tokens, config) >= tokens + config.experts_held * M.PAIR_ROWS
+    assert moe.pair_capacity(tokens, config) >= tokens + config.experts_held * moe.PAIR_ROWS
     want = np.zeros((tokens, config.hidden), np.float32)
     for e in range(skip):
-        out = M._swiglu(h, layer["experts_gate"][e], layer["experts_up"][e],
+        out = moe.swiglu(h, layer["experts_gate"][e], layer["experts_up"][e],
                         layer["experts_down"][e])
         picked = (chosen == e) & real
         want += (np.asarray(weights)[:, 0] * picked)[:, None] * np.asarray(out)
     np.testing.assert_allclose(np.asarray(y), want, atol=2e-5)
     assert not np.asarray(y)[~real | (chosen == skip)].any()
     # a buffer without the room: the groups follow each other, same numbers
-    packed = M.held_experts(
+    packed = moe.held_experts(
         h, valid, layer, config, -(-tokens // 512) * 512, with_stats=True,
         routing=(experts, weights),
     )

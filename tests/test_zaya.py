@@ -14,9 +14,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from pathway_tpu.models import moe_mla, zaya
+from pathway_tpu.models import experts as moe
+from pathway_tpu.models import trunk, zaya
 from pathway_tpu.models.tokenizer import PACK_MAX_SEGMENTS, encode_batch, pack_batch
-from pathway_tpu.models.transformer import _packed_positions, model_module
+from pathway_tpu.models.trunk import model_module, packed_positions
 from pathway_tpu.ops import kernels
 from pathway_tpu.ops.kernels import cca_attention as kernel
 from pathway_tpu.ops.kernels import cca_latent as latent
@@ -127,7 +128,7 @@ def test_the_unpacked_form_is_the_packed_one_and_row_groups_change_nothing(monke
         enc.lm.params, enc.config, ids, None, seg=seg,
         max_segments=PACK_MAX_SEGMENTS, with_stats=True,
     )
-    monkeypatch.setattr(moe_mla, "row_chunks", lambda rows, length, cap: rows)
+    monkeypatch.setattr(trunk, "row_chunks", lambda rows, length, cap: rows)
     grouped, stats = zaya.forward(
         enc.lm.params, enc.config, ids, None, seg=seg,
         max_segments=PACK_MAX_SEGMENTS, with_stats=True,
@@ -335,7 +336,7 @@ def _latent_operands(length: int, kv_heads: int, group: int, taps: int = 2):
     }
     seg = jnp.asarray(_seam_slab(length))
     qkv = drawn(*seg.shape, (n + kv_heads) * hd)
-    return config, layer, qkv, seg, rope_tables(_packed_positions(seg), config.rope_theta)
+    return config, layer, qkv, seg, rope_tables(packed_positions(seg), config.rope_theta)
 
 
 @pytest.mark.parametrize("kv_heads,group", [(2, 4), (1, 4), (2, 1)])
@@ -453,7 +454,7 @@ def test_a_counted_batch_runs_the_kernels_its_counter_names(fused, monkeypatch,
     assert totals[f"launch.encode.{counted}"]["count"] == 1
     assert f"launch.encode.{other}" not in totals
     ids, seg = payload[2:4]
-    program = enc.lm._packed_program()
+    program = enc.lm._packed_jit.__wrapped__  # the packed program, not jitted
     text = str(jax.make_jaxpr(lambda p, i, s: program(p, i, s, PACK_MAX_SEGMENTS))(
         enc.lm.params, jnp.asarray(ids), jnp.asarray(seg)
     ))
@@ -509,7 +510,7 @@ def test_two_shares_of_the_experts_add_up_to_the_whole_layer():
     valid = jnp.arange(t) < 90
     experts, weights, _ = zaya.route(h, jnp.zeros((t, 32)), layer, whole)
     assert 0 < int((experts == 16).sum()) < t  # some skip, not all
-    full, counts, over = moe_mla.held_experts(
+    full, counts, over = moe.held_experts(
         h, valid, layer, whole, routing=(experts, weights)
     )
     parts, held = [], []
@@ -520,7 +521,7 @@ def test_two_shares_of_the_experts_add_up_to_the_whole_layer():
         np.testing.assert_array_equal(
             its["experts_gate"], layer["experts_gate"][offset:offset + 8]
         )
-        y, n, o = moe_mla.held_experts(h, valid, its, share, routing=(experts, weights))
+        y, n, o = moe.held_experts(h, valid, its, share, routing=(experts, weights))
         parts.append(np.asarray(y))
         held.append(np.asarray(n))
         assert int(o) == 0
@@ -585,7 +586,7 @@ def test_served_path_ingests_and_retrieves_with_the_embedder():
 def test_the_module_is_found_by_its_configuration_and_refuses_a_mesh():
     config = zaya.TINY
     module = model_module(config)
-    assert module is zaya and module.LM is zaya.ZayaLM
+    assert module is zaya and module.LM is trunk.PackedTrunkLM
     for name in ("forward", "init_params", "param_sharding_rules",
                  "packed_attention_fused", "tokenizer"):
         assert callable(getattr(module, name))
@@ -596,8 +597,8 @@ def test_the_module_is_found_by_its_configuration_and_refuses_a_mesh():
     assert not zaya.packed_attention_fused(config, 504)
     assert zaya.packed_attention_fused(config, 504, use_flash=True)
     # a slab of the cell's size is one row group; twice that is two
-    assert moe_mla.row_chunks(56, 504, zaya.ROW_TOKENS) == 1
-    assert moe_mla.row_chunks(112, 504, zaya.ROW_TOKENS) == 2
+    assert trunk.row_chunks(56, 504, zaya.ROW_TOKENS) == 1
+    assert trunk.row_chunks(112, 504, zaya.ROW_TOKENS) == 2
     published = zaya.ZayaConfig()
     assert published.active_flops_per_token(0.0) == pytest.approx(
         2 * 20 * (2048 * 1536 + 1024 * 2048 + 10 * 2 * 128 * 128
