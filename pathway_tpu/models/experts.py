@@ -1,8 +1,9 @@
 """The expert layer every MoE trunk runs (`moe_mla`, `moe_hybrid`,
-`zaya`): a router's top-k (`route`), the routed experts one chip holds
-(`held_experts`, whose return the configuration's shape chooses), SwiGLU
-(`swiglu`), and the counters `moe.*` of a finished dispatch
-(`count_stats`)."""
+`zaya`, `longcat`): a router's top-k (`route`), the routed experts one
+chip holds (`held_experts`, whose return the configuration's shape
+chooses), the zero-compute experts every chip computes alike
+(`zero_expert_part`), SwiGLU (`swiglu`), and the counters `moe.*` of a
+finished dispatch (`count_stats`)."""
 
 from __future__ import annotations
 
@@ -18,21 +19,27 @@ def swiglu(h, gate, up, down):
     return (jax.nn.silu(h @ gate.astype(dt)) * (h @ up.astype(dt))) @ down.astype(dt)
 
 
-def route(h, router, config, bias=None):
+def route(h, router, config, bias=None, *, softmax: bool = False, normalise: bool = True):
     """h: [T, hidden] -> (experts [T, k] int32, weights [T, k] f32): top-k
-    over all sigmoid scores (no group limit), the chosen scores normalised
-    to sum to one and scaled.  `bias` [n_routed_experts], where a model has
-    one (`e_score_correction_bias`, `topk_method` "noaux_tc"), is added to
-    the scores for the selection alone: it says which experts, never how
-    much of each.  Without one this is plain top-k, as it was.  The logits
-    are f32: products of the compute dtype's operands, accumulated in
-    f32.  `config`: any trunk's with `experts_per_token` and
-    `routed_scaling_factor` (`models/moe_mla.py`, `models/moe_hybrid.py`)."""
+    over all the router's scores (no group limit), sigmoid scores whose
+    chosen ones are normalised to sum to one and scaled.  `bias` [the
+    router's outputs], where a model has one (`e_score_correction_bias`,
+    `topk_method` "noaux_tc"), is added to the scores for the selection
+    alone: it says which experts, never how much of each.  Without one
+    this is plain top-k, as it was.  `softmax`: the scores are a softmax
+    over every output of the router, which may outnumber the routed
+    experts (LongCat-Flash's 512 routed and 256 zero-compute experts:
+    `zero_expert_part`); `normalise` false: the weights are the scaled
+    scores themselves (`norm_topk_prob` false).  The logits are f32:
+    products of the compute dtype's operands, accumulated in f32.
+    `config`: any trunk's with `experts_per_token` and
+    `routed_scaling_factor` (`models/moe_mla.py`, `models/moe_hybrid.py`,
+    `models/longcat.py`)."""
     import jax
     import jax.numpy as jnp
 
     logits = jnp.dot(h, router.astype(h.dtype), preferred_element_type=jnp.float32)
-    scores = jax.nn.sigmoid(logits)
+    scores = jax.nn.softmax(logits, axis=-1) if softmax else jax.nn.sigmoid(logits)
     if bias is None:
         top, experts = jax.lax.top_k(scores, config.experts_per_token)
     else:
@@ -40,8 +47,23 @@ def route(h, router, config, bias=None):
             scores + bias.astype(jnp.float32), config.experts_per_token
         )
         top = jnp.take_along_axis(scores, experts, axis=-1)
-    weights = config.routed_scaling_factor * top / top.sum(-1, keepdims=True)
+    weights = config.routed_scaling_factor * top
+    if normalise:
+        weights = weights / top.sum(-1, keepdims=True)
     return experts.astype(jnp.int32), weights
+
+
+def zero_expert_part(h, experts, weights, n_routed: int):
+    """The zero-compute experts' part of an expert layer: sum over a
+    token's selected experts e >= `n_routed` (each the identity) of w_e h,
+    as one [T] coefficient times h, in h's dtype.  Every chip computes it
+    for its own tokens; no such pair is held, bucketed or counted as held
+    (`held_experts` holds ids in [expert_offset, expert_offset +
+    experts_held) only).  h: [T, hidden]; experts, weights: `route`'s."""
+    import jax.numpy as jnp
+
+    coef = jnp.sum(jnp.where(experts >= n_routed, weights, 0.0), axis=-1)
+    return coef.astype(h.dtype)[:, None] * h
 
 
 # a tile of the TPU's grouped matmul: it works a tile and a group at a time

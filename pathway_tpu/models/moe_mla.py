@@ -26,11 +26,11 @@ then a final norm, the mean over a segment's tokens and L2 normalisation,
 as `transformer.forward` pools.  This is the prefill form of latent
 attention: no head, no latent cache, no generation (PERF.md section 7).
 
-Program shape: the q and kv up-projections are kept as separate matrices
-per part (`wq_b_nope` / `wq_b_rope`, `wk_b` / `wv_b`: the published
-matrices' columns, regrouped once at init), so that every operand of the
-attention kernel (`ops/kernels/mla_attention.py`) leaves its matmul in the
-layout the kernel reads.  The expert layer is `experts.held_experts`
+Program shape: the attention half is `mla._attention` (the kernel
+`ops/kernels/mla_attention.py` or its dense definition), with both LoRA
+scales 1.0, over the up-projections kept as separate matrices per part
+(`wq_b_nope` / `wq_b_rope`, `wk_b` / `wv_b`: the published matrices'
+columns, regrouped once at init).  The expert layer is `experts.held_experts`
 over `experts.route` (grouped matmuls over a static buffer of the held
 pairs; here a sixteenth of the experts at top-8, whose return sums the
 few tokens with several pairs in a compact list), the shared expert
@@ -46,6 +46,11 @@ from typing import Any, Dict, Optional
 import numpy as np
 
 from pathway_tpu.models.experts import count_stats, held_experts, layer_pass_lists, swiglu
+from pathway_tpu.models.mla import (  # noqa: F401  (`_mla_segment_attention`: the kernel's tests read it here)
+    _attention,
+    _mla_segment_attention,
+    packed_attention_fused,
+)
 from pathway_tpu.models.trunk import (  # noqa: F401  (`tokenizer`: model_module's)
     PackedTrunk,
     PackedTrunkLM,
@@ -55,7 +60,6 @@ from pathway_tpu.models.trunk import (  # noqa: F401  (`tokenizer`: model_module
     packed_positions,
     pooled_by_row_groups,
     rms_norm,
-    rope,
     tokenizer,
     yarn_ladder,
 )
@@ -229,98 +233,6 @@ def yarn_freqs(config: MoeMlaConfig) -> np.ndarray:
     c = config
     return yarn_ladder(c.qk_rope_head_dim, c.rope_theta, c.rope_factor,
                        c.rope_original_max_len, c.rope_beta_fast, c.rope_beta_slow)
-
-
-def _mla_segment_attention(q_nope, q_rope, k_nope, k_rope, v, seg, sm_scale, heads):
-    """Dense causal latent attention with a pairwise same-segment mask:
-    the numerical definition, the path off the TPU and the tests'
-    reference of `ops/kernels/mla_attention.py` (operands in its layouts).
-    Writes the f32 scores [B, H, L, L]: 3.6 GB at the ingest slab."""
-    import jax.numpy as jnp
-
-    from pathway_tpu.ops.kernels.flash_attention import NEG_INF
-
-    b, l, _ = q_nope.shape
-    split = lambda a: a.reshape(b, l, heads, -1)  # noqa: E731
-    s = jnp.einsum(
-        "bqhd,bkhd->bhqk", split(q_nope), split(k_nope),
-        preferred_element_type=jnp.float32,
-    ) + jnp.einsum(
-        "bqhd,bkd->bhqk", split(q_rope), k_rope,
-        preferred_element_type=jnp.float32,
-    )
-    at = jnp.arange(l)
-    see = (
-        (seg[:, None, :, None] == seg[:, None, None, :])
-        & (seg[:, None, :, None] > 0)
-        & (at[None, None, None, :] <= at[None, None, :, None])
-    )
-    s = jnp.where(see, s * sm_scale, NEG_INF)
-    p = jnp.exp(s - s.max(-1, keepdims=True))
-    p = p / (p.sum(-1, keepdims=True) + 1e-30)
-    ctx = jnp.einsum(
-        "bhqk,bkhd->bqhd", p.astype(v.dtype), split(v),
-        preferred_element_type=jnp.float32,
-    )
-    return ctx.reshape(b, l, -1).astype(q_nope.dtype)
-
-
-def packed_attention_fused(config: MoeMlaConfig, length: int,
-                           use_flash: Optional[bool] = None) -> bool:
-    """Whether a slab of `length` tokens runs the fused kernel or the dense
-    definition: the backend and the static shape, as
-    `transformer.packed_attention_fused` decides for the encoders (its
-    measured floor for heads of 64 lanes and more, L > 32, is taken over;
-    below it a row's scores are a few kilobytes).  The launch site asks
-    again to count the batch.  `use_flash` overrides (tests)."""
-    if use_flash is not None:
-        return use_flash
-    import jax
-
-    from pathway_tpu.ops.kernels.mla_attention import supports
-
-    return (
-        jax.default_backend() == "tpu"
-        and length > 32
-        and supports(length, config.heads, config.qk_nope_head_dim,
-                     config.qk_rope_head_dim, config.v_head_dim)
-    )
-
-
-def _attention(x, layer, config: MoeMlaConfig, pos, seg, fused: bool, freqs):
-    """The attention half of a layer, without the residual.  x: [B, L, h]."""
-    from pathway_tpu.ops.kernels.mla_attention import mla_segment_attention
-
-    c = config
-    b, l, _ = x.shape
-    dt = x.dtype
-    h = rms_norm(x, layer["ln1"], c.norm_eps)
-    c_q = rms_norm(h @ layer["wq_a"].astype(dt), layer["q_ln"], c.norm_eps)
-    q_nope = c_q @ layer["wq_b_nope"].astype(dt)
-    q_rope = c_q @ layer["wq_b_rope"].astype(dt)
-    kv_a = h @ layer["wkv_a"].astype(dt)
-    c_kv = rms_norm(kv_a[..., : c.kv_lora_rank], layer["kv_ln"], c.norm_eps)
-    k_nope = c_kv @ layer["wk_b"].astype(dt)
-    v = c_kv @ layer["wv_b"].astype(dt)
-
-    def rotate(a, n_heads: int):
-        # one "batch" a token, so that the rotation needs no transposes
-        flat = a.reshape(b * l, n_heads, 1, c.qk_rope_head_dim)
-        out = rope(flat, pos.reshape(b * l, 1), c.rope_theta, freqs=freqs,
-                    interleaved=True)
-        return out.reshape(b, l, n_heads * c.qk_rope_head_dim)
-
-    q_rope = rotate(q_rope, c.heads)
-    k_rope = rotate(kv_a[..., c.kv_lora_rank:], 1)
-    if fused:
-        ctx = mla_segment_attention(
-            q_nope, q_rope, k_nope, k_rope, v, seg, sm_scale=c.sm_scale
-        )
-    else:
-        ctx = _mla_segment_attention(
-            q_nope, q_rope, k_nope, k_rope, v, seg, c.sm_scale, c.heads
-        )
-    return ctx @ layer["wo"].astype(dt)
 
 
 def _trunk(params, config: MoeMlaConfig, ids, seg, max_segments: int, fused: bool):

@@ -1,7 +1,7 @@
 """What the models of this package share, below every trunk: layers and
 numerics more than one trunk computes, the leaf maker, the slab rule and
 the row groups, the refusal of a mesh, and the LM classes.  Imports point
-one way: `trunk` and `experts` <- the trunks <- what wraps them
+one way: `trunk`, `experts` and `mla` <- the trunks <- what wraps them
 (`minilm`, `cross_encoder`, `ops/knn.py`)."""
 
 from __future__ import annotations
@@ -173,8 +173,8 @@ def packed_positions(seg):
 
 def one_chip_only(mesh, module: str, holds: str, elsewhere: str) -> None:
     """The one refusal of a mesh, for the trunks that run a single chip's
-    share of a deployment (`moe_mla`, `moe_hybrid`: one expert-parallel
-    rank; `eva`, `zaya`: one pipeline stage): `module` holds `holds` on one chip,
+    share of a deployment (`moe_mla`, `moe_hybrid`, `longcat`: one
+    expert-parallel rank; `eva`, `zaya`: one pipeline stage): `module` holds `holds` on one chip,
     and what would join the chips (`elsewhere`) is not built."""
     if mesh is not None:
         raise NotImplementedError(
@@ -305,8 +305,10 @@ def model_module(config):
     `tokenizer` and `LM` (`models/transformer.py` for a
     `TransformerConfig`, `models/moe_mla.py` for a `MoeMlaConfig`,
     `models/eva.py` for an `EvaConfig`, `models/moe_hybrid.py` for a
-    `MoeHybridConfig`, `models/zaya.py` for a `ZayaConfig`; the last four
-    also `PACKED`).  The one rule by which `TransformerLM`, the encoders
+    `MoeHybridConfig`, `models/zaya.py` for a `ZayaConfig`,
+    `models/longcat.py` for a `LongcatConfig`; the last five also
+    `PACKED`).  Latent attention, which `moe_mla` and `longcat` share, is
+    `models/mla.py`, below them as `experts` is.  The one rule by which `TransformerLM`, the encoders
     and the fused programs of `ops/knn.py` find a configuration's model."""
     return importlib.import_module(type(config).__module__)
 

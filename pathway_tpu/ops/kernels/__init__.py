@@ -11,7 +11,8 @@ each, named as the device's profile names them:
     row a grid step, segment mask, softmax and p @ v in VMEM, q/k/v read in
     place from the QKV matmul's output;
   * `mla_segment_attention` (`mla_attention.py`) — its causal sibling for
-    latent attention's two-part heads (`models/moe_mla.py`);
+    latent attention's two-part heads (`models/mla.py`, which
+    `models/moe_mla.py` and `models/longcat.py` run);
   * `eva_attention`, `eva_pool_chunks`, `eva_rope` (`eva_attention.py`) —
     chunked linear attention over rows of thousands of slots, the chunks'
     summaries and RoPE where the matmuls left q and k (`models/eva.py`);

@@ -4,7 +4,7 @@ whose scores are the sum of two products.
 
 A head's score is `q_nope . k_nope` (128 wide, the head's own keys) plus
 `q_rope . k_rope` (64 wide, ONE rope key shared by every head), over
-128-wide values.  The dense definition (`models/moe_mla.py::
+128-wide values.  The dense definition (`models/mla.py::
 _mla_segment_attention`) writes f32 scores [B, H, L, L] to HBM: 3.6 GB at
 the ingest slab [56, 64, 504, 504].  This kernel keeps scores, mask,
 softmax and `p @ v` of one slab row in VMEM:

@@ -1,5 +1,5 @@
-"""The model layer's imports point one way: `trunk` and `experts` (what
-every trunk shares) <- the trunks <- what wraps them (`minilm`,
+"""The model layer's imports point one way: `trunk`, `experts` and `mla`
+(what more than one trunk shares) <- the trunks <- what wraps them (`minilm`,
 `cross_encoder`, `ops/knn.py`).  The imports are read from the sources
 with `ast`, every one a module makes anywhere in it, and those cases
 import no jax; the packed programs' names are read from their lowered
@@ -13,9 +13,9 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MODELS = os.path.join(ROOT, "pathway_tpu", "models")
 
-TRUNKS = ("transformer", "moe_mla", "eva", "moe_hybrid", "zaya", "decoder")
+TRUNKS = ("transformer", "moe_mla", "eva", "moe_hybrid", "zaya", "decoder", "longcat")
 # the modules of pathway_tpu.models below every trunk
-SHARED = ("trunk", "experts", "tokenizer")
+SHARED = ("trunk", "experts", "mla", "tokenizer")
 
 # configuration class -> the name the device trace knows its packed program by
 PACKED = {
@@ -23,6 +23,7 @@ PACKED = {
     "eva": ("EvaConfig", "_fwd_packed_eva"),
     "moe_hybrid": ("MoeHybridConfig", "_fwd_packed_moe_hybrid"),
     "zaya": ("ZayaConfig", "_fwd_packed_zaya"),
+    "longcat": ("LongcatConfig", "_fwd_packed_longcat"),
 }
 
 
@@ -49,9 +50,9 @@ def test_a_trunk_imports_only_the_shared_layer(module):
     assert models_imported(module) <= set(SHARED), models_imported(module)
 
 
-@pytest.mark.parametrize("module", ("trunk", "experts"))
+@pytest.mark.parametrize("module", ("trunk", "experts", "mla"))
 def test_the_shared_layer_imports_no_trunk(module):
-    allowed = {"trunk": {"tokenizer"}, "experts": {"trunk"}}[module]
+    allowed = {"trunk": {"tokenizer"}, "experts": {"trunk"}, "mla": {"trunk"}}[module]
     assert models_imported(module) <= allowed, models_imported(module)
 
 
